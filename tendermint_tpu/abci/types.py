@@ -5,7 +5,7 @@ Request/Response are proto oneofs; the socket transport frames each
 message with a uvarint length prefix (abci/types/messages.go
 WriteMessage/ReadMessage).
 
-Field-surface contract (VERDICT r3 missing-item 6): only the fields the
+Field-surface contract: only the fields the
 framework and example apps touch are modeled as dataclasses; everything
 round-trips through the deterministic proto codec in wire/proto.py.
 Concretely:

@@ -9,7 +9,7 @@ packed into mesh superbatches through the shared AsyncBatchVerifier at
 PRIORITY_REPLAY (below consensus, above ingress: the PR-12 preemption
 points keep a rejoining node's flood from ever delaying live commits).
 BlockStore.save_block writes ride a writer thread BEHIND device
-verification so storage latency hides under the next range's relay.
+verification so storage latency hides under the next range's device.
 
 Failure semantics are byte-identical to the sequential path: a bad
 commit anywhere in a range falls back to per-height sequential
@@ -124,7 +124,7 @@ class _Writer:
     """Ordered store-write pipeline: save_block (which enforces strictly
     sequential heights itself) runs on this thread while the caller is
     already applying the next height / waiting on the next range's
-    relay. The first error poisons the writer; drain() re-raises it on
+    device. The first error poisons the writer; drain() re-raises it on
     the replay thread so a failed save aborts catch-up instead of
     silently diverging store from state."""
 

@@ -4,13 +4,20 @@ Reference parity: crypto/batch/batch.go:11-33 — CreateBatchVerifier /
 SupportsBatchVerifier keyed on pubkey type; ed25519 and sr25519 batch,
 secp256k1 does not.
 
-The default ed25519 batch verifier here is the device-backed one from
-tendermint_tpu.ops (JAX: TPU when available, CPU otherwise). Its semantics
-are *per-signature* cofactored ZIP-215 verification evaluated in a single
-fixed-shape vmapped kernel — deterministic, and exactly equal to the
-reference's single-verify semantics (the reference's random-linear-
-combination batch accepts the same set except with negligible probability;
-on failure it too falls back to per-signature checks, ed25519.go:225-227).
+The default ed25519 batch verifier is the device-backed one from
+tendermint_tpu.ops.backend, resolved HERE, at the seam, on the first
+call — not as a side effect of some other module importing the ops
+package — so a library caller's first commit takes the same path as its
+second. Which platform and kernel that is comes from ops/engine.py. Its
+semantics are *per-signature* cofactored ZIP-215 verification —
+deterministic, and exactly equal to the reference's single-verify
+semantics (the reference's random-linear-combination batch accepts the
+same set except with negligible probability; on failure it too falls
+back to per-signature checks, ed25519.go:225-227).
+
+Whatever path a batch takes is counted in the ops `sigs_verified` series
+(path="device" / path="host"): a commit that was host-verified shows up
+as host-verified.
 """
 
 from __future__ import annotations
@@ -23,7 +30,9 @@ from . import _edwards
 
 
 class Ed25519HostBatchVerifier(BatchVerifier):
-    """Host-only fallback: per-signature ZIP-215 via the OpenSSL fast path."""
+    """Host-only verifier (the device verifier's oracle in tests and the
+    explicit no-device choice): native RLC batch, else per-signature
+    ZIP-215 via the OpenSSL fast path."""
 
     def __init__(self):
         self._entries: List[Tuple[bytes, bytes, bytes]] = []
@@ -65,6 +74,9 @@ class Ed25519HostBatchVerifier(BatchVerifier):
         # semantics), falling back to per-signature checks for blame
         # assignment exactly like the reference (:225-227).
         n = len(self._entries)
+        from ..libs import metrics as _metrics
+
+        _metrics.ops_metrics().sigs_verified.inc(n, path="host")
         if n >= 16:
             from ..native import load as _load_native
 
@@ -83,22 +95,31 @@ class Ed25519HostBatchVerifier(BatchVerifier):
         return all(valid) and len(valid) > 0, valid
 
 
-_device_verifier_factory = None
+def _device_verifier() -> BatchVerifier:
+    """The default ed25519 engine. The import is deferred to the first
+    verifier so decoding/types code never loads jax by importing this
+    module."""
+    from ..ops.backend import Ed25519DeviceBatchVerifier
+
+    return Ed25519DeviceBatchVerifier()
 
 
-def use_device_engine(factory) -> None:
-    """Install the device (TPU) batch-verifier factory. Called by
-    tendermint_tpu.ops on import; kept injectable for tests."""
+_device_verifier_factory = _device_verifier
+
+
+def use_device_engine(factory):
+    """Replace the ed25519 verifier factory (the seam differentials use:
+    e.g. Ed25519HostBatchVerifier pins the host side). Returns the
+    factory it replaced, for the caller to restore."""
     global _device_verifier_factory
-    _device_verifier_factory = factory
+    previous, _device_verifier_factory = _device_verifier_factory, factory
+    return previous
 
 
 def create_batch_verifier(pk: PubKey) -> Optional[BatchVerifier]:
     """crypto/batch/batch.go:11-24. Returns None if unsupported."""
     if pk.type() == _ed25519.KEY_TYPE:
-        if _device_verifier_factory is not None:
-            return _device_verifier_factory()
-        return Ed25519HostBatchVerifier()
+        return _device_verifier_factory()
     from . import sr25519 as _sr25519
 
     if pk.type() == _sr25519.KEY_TYPE:

@@ -15,7 +15,7 @@ the verifier's resolver thread — the done-callback only ENQUEUES the
 encoded reply, so the resolver never blocks on socket I/O and the
 pipeline's lock discipline is preserved.
 
-Failure containment mirrors the wire's error taxonomy: a malformed or
+Failure containment mirrors the wire's error classes: a malformed or
 version-skewed frame earns an ERROR reply and the connection lives on;
 an oversize length prefix kills (only) that connection; a verifier
 exception (DispatchError et al.) earns an ERROR frame with code

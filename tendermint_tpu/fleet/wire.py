@@ -25,7 +25,7 @@ SUBMIT body (kind=1):
 VERDICT body (kind=2):   u64 request_id | u32 n | n bytes of 0/1
 ERROR body   (kind=3):   u64 request_id | u8 code | u16 msg_len | msg utf-8
 
-Error taxonomy:
+Error classes:
 
 * ``WireError`` — malformed payload. Recoverable: the 4-byte length
   prefix still framed the junk, so the connection survives and the

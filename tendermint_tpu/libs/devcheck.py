@@ -8,10 +8,10 @@ locking, so production paths pay nothing.
 
 Three checkers:
 
-1. **Relay-thread assertions** — the dispatch-owner thread (the ONLY
-   thread allowed to touch the relay, PERF_r05 §2) claims ownership via
-   ``claim_relay()``; the launch/transfer/table-upload entry points call
-   ``note_relay_touch()``, which raises (and records) when any OTHER
+1. **Device-thread assertions** — the dispatch-owner thread (the ONLY
+   thread allowed to touch the device) claims ownership via
+   ``claim_device()``; the launch/transfer/table-upload entry points call
+   ``note_device_touch()``, which raises (and records) when any OTHER
    thread reaches them. ``exempt()`` marks the sanctioned direct paths
    (oversized-batch fallback, warmup) so they do not false-positive.
 
@@ -36,13 +36,13 @@ Three checkers:
    get the byte-stability verification).
 
 Violations are recorded in a process-wide list (``violations()``) and —
-for the relay and lock checkers, where the failing stack IS the bug —
+for the device and lock checkers, where the failing stack IS the bug —
 also raised as ``DevcheckViolation`` at the offending call site. The
 canary records without raising (the mutation is detected asynchronously,
 on a thread that did nothing wrong); drive ``check()`` from tests.
 
 Test seams: ``TM_TPU_INJECT_LINTBUG=alias|owner`` re-introduces the PR-7
-readback aliasing / a resolver-thread relay touch inside ops/pipeline.py
+readback aliasing / a resolver-thread device touch inside ops/pipeline.py
 (mirroring simnet's ``--inject-bug``), so tier-1 proves each checker
 actually fires (tests/test_devcheck.py).
 
@@ -62,10 +62,10 @@ _ON = os.environ.get("TM_TPU_DEVCHECK", "") == "1"
 
 _mtx = threading.Lock()  # guards all devcheck global state below
 _violations: List[dict] = []
-_counts: Dict[str, int] = {"relay_touches": 0, "lock_acquires": 0,
+_counts: Dict[str, int] = {"device_touches": 0, "lock_acquires": 0,
                            "canary_checks": 0, "canary_registered": 0,
                            "span_opens": 0}
-_relay_owners: Set[int] = set()
+_device_owners: Set[int] = set()
 _lock_edges: Dict[str, Set[str]] = {}
 _tls = threading.local()  # .held: list of lock names; .exempt: int depth
 # unbalanced-span canary (ISSUE 10): thread ident -> open span names, in
@@ -106,7 +106,7 @@ def disable() -> None:
 def reset_state() -> None:
     with _mtx:
         _violations.clear()
-        _relay_owners.clear()
+        _device_owners.clear()
         _lock_edges.clear()
         _canaries.clear()
         _open_spans.clear()
@@ -157,32 +157,32 @@ def _bump(key: str) -> None:
 
 
 # ---------------------------------------------------------------------------
-# 1) relay-thread assertions
+# 1) device-thread assertions
 
 
-def claim_relay(name: str = "") -> None:
-    """The dispatch-owner thread claims the relay. Multiple verifiers may
+def claim_device(name: str = "") -> None:
+    """The dispatch-owner thread claims the device. Multiple verifiers may
     each claim (one dispatcher per instance); any NON-claimed thread
-    reaching a relay entry point afterwards is a violation."""
+    reaching a device entry point afterwards is a violation."""
     if not _ON:
         return
     with _mtx:
-        _relay_owners.add(threading.get_ident())
+        _device_owners.add(threading.get_ident())
 
 
-def clear_relay() -> None:
+def clear_device() -> None:
     with _mtx:
-        _relay_owners.clear()
+        _device_owners.clear()
 
 
-def unclaim_relay(idents) -> None:
+def unclaim_device(idents) -> None:
     """Drop specific thread idents from the owner set — a closing
     verifier retires its dispatcher's claim so (a) later standalone
     direct use stays legal and (b) OS thread-ident reuse cannot hand a
     dead owner's pass to an arbitrary new thread. Safe with devcheck
     off (the set is empty)."""
     with _mtx:
-        _relay_owners.difference_update(idents)
+        _device_owners.difference_update(idents)
 
 
 class _Exempt:
@@ -196,31 +196,31 @@ class _Exempt:
 
 
 def exempt() -> _Exempt:
-    """Context manager marking a sanctioned direct relay path (oversized
+    """Context manager marking a sanctioned direct device path (oversized
     fallback, warmup) on the current thread."""
     return _Exempt()
 
 
-def note_relay_touch(what: str) -> None:
-    """Assert the current thread may touch the relay. No-op until a
+def note_device_touch(what: str) -> None:
+    """Assert the current thread may touch the device. No-op until a
     dispatcher has claimed ownership (standalone/direct use stays legal);
     afterwards only owner threads and exempt() scopes pass."""
     if not _ON:
         return
-    _bump("relay_touches")
+    _bump("device_touches")
     if getattr(_tls, "exempt", 0):
         return
     with _mtx:
-        owners = set(_relay_owners)
+        owners = set(_device_owners)
     if not owners:
         return
     ident = threading.get_ident()
     if ident not in owners:
         rec = _violate(
-            "relay-ownership",
-            f"{what}: relay touched from thread "
+            "device-ownership",
+            f"{what}: device touched from thread "
             f"{threading.current_thread().name!r} (ident {ident}) but the "
-            f"relay is owned by dispatcher ident(s) {sorted(owners)} — "
+            f"device is owned by dispatcher ident(s) {sorted(owners)} — "
             f"exactly ONE dispatch-owner thread may launch/transfer",
         )
         raise DevcheckViolation(rec["message"])
@@ -548,7 +548,7 @@ def on_slot_release(arrays) -> None:
 def inject_lintbug(kind: str) -> bool:
     """True when TM_TPU_INJECT_LINTBUG names this seam AND devcheck is
     armed. The devcheck gate is load-bearing: the seams deliberately
-    corrupt verdicts / touch the relay cross-thread, so a stale env
+    corrupt verdicts / touch the device cross-thread, so a stale env
     export with the checkers off must stay inert. Read per call so tests
     can flip it via monkeypatch.setenv without reimporting."""
     return _ON and os.environ.get("TM_TPU_INJECT_LINTBUG", "") == kind

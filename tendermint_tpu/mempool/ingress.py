@@ -5,7 +5,7 @@ The second serving workload from the north star: user transactions.
 adds signature-carrying txs, and this module's accumulator batches their
 signatures into `EntryBlock`s over a short time/size window and submits
 them to the SHARED AsyncBatchVerifier at INGRESS priority, so a tx flood
-rides the device pipeline (thousands of sigs per relay command) without
+rides the device pipeline (thousands of sigs per device launch) without
 ever starving consensus commit batches (ops/pipeline.py QoS classes).
 
 Signed-tx envelope (scheme-tagged, nonce-carrying):

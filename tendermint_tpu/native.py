@@ -1,17 +1,24 @@
 """Loader for the native C++ module (native/tm_native.cpp).
 
-Builds on first use with the in-image toolchain (g++ via setuptools'
-build_ext), caches the shared object under native/_build, and degrades to
-None when no compiler is available — all callers keep a pure-Python path.
+Builds on first use with the in-image toolchain (g++), caches the shared
+object under native/_build (git-ignored: a fresh clone builds it), and
+returns None when the build or the load fails — every caller keeps a
+pure-Python path with identical outputs. That degradation is LOUD: the
+failure, with the compiler's own stderr, is logged once; chip_smoke.py
+refuses to pass without the module. TM_TPU_NO_NATIVE=1 is the explicit
+way to run the pure-Python paths.
 """
 
 from __future__ import annotations
 
 import importlib.util
+import logging
 import os
 import sys
 import sysconfig
 import threading
+
+_log = logging.getLogger("tendermint_tpu.native")
 
 _lock = threading.Lock()
 _module = None
@@ -33,18 +40,30 @@ def _build() -> bool:
     os.makedirs(_BUILD, exist_ok=True)
     import subprocess
 
-    suffix = sysconfig.get_config_var("EXT_SUFFIX") or ".so"
     out = _so_path()
+    # build beside the target and rename into place: a second process
+    # starting on a fresh clone must never dlopen a half-written file
+    tmp = f"{out}.{os.getpid()}.tmp"
     include = sysconfig.get_path("include")
     cmd = [
         "g++", "-O3", "-march=x86-64-v3", "-funroll-loops", "-shared", "-fPIC", "-std=c++17",
-        f"-I{include}", src, "-o", out,
+        f"-I{include}", src, "-o", tmp,
     ]
     try:
         res = subprocess.run(cmd, capture_output=True, timeout=120)
-        return res.returncode == 0 and os.path.exists(out)
-    except (OSError, subprocess.TimeoutExpired):
+    except (OSError, subprocess.TimeoutExpired) as e:
+        _log.error("tm_native build did not run (%s): %r — continuing on "
+                   "the pure-Python paths", " ".join(cmd[:2]), e)
         return False
+    if res.returncode != 0 or not os.path.exists(tmp):
+        _log.error(
+            "tm_native build failed (rc=%d) — continuing on the "
+            "pure-Python paths. Compiler output:\n%s",
+            res.returncode, res.stderr.decode("utf-8", "replace")[-4000:],
+        )
+        return False
+    os.replace(tmp, out)
+    return True
 
 
 def load():
@@ -69,7 +88,9 @@ def load():
         mod = importlib.util.module_from_spec(spec)
         try:
             spec.loader.exec_module(mod)
-        except ImportError:
+        except ImportError as e:
+            _log.error("tm_native built but failed to load from %s: %r — "
+                       "continuing on the pure-Python paths", so, e)
             return None
         _module = mod
         return _module

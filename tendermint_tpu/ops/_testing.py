@@ -15,7 +15,7 @@ import time
 
 class SlowReadback:
     """Proxy device result whose materialization costs `delay` seconds —
-    the resolver blocks on __array__ exactly like a relay-attached TPU's
+    the resolver blocks on __array__ exactly like a TPU's
     D2H wait; async-copy capability passes through to the real result."""
 
     def __init__(self, dev, delay: float):
@@ -55,7 +55,7 @@ def slow_mesh_prepare(real_prepare, delay: float):
     `_prepare_mesh` so every superbatch's kernel result rides a
     SlowReadback — the REAL packing, prep, transfer shardings and kernel
     run unchanged; only the readback is slowed (the `tools/prep_bench.py
-    --mesh` gate's relay-RTT proxy)."""
+    --mesh` gate's device-RTT proxy)."""
 
     def prep(block, plan):
         res = real_prepare(block, plan)
@@ -71,11 +71,11 @@ def mock_mesh_prepare(real_prepare, rtt_s: float):
     """Fully-mocked mesh DEVICE for `bench.py multichip`'s simulated-lane
     curve: the real lane packing, host prep and H2D transfer run
     unchanged, but the launch returns an all-accept verdict row behind a
-    fixed relay RTT instead of running the kernel — modeling an L-device
-    mesh (per-lane compute parallel across devices, one relay command
+    fixed device RTT instead of running the kernel — modeling an L-device
+    mesh (per-lane compute parallel across devices, one device launch
     per superbatch) on a box with one physical device. The curve then
     measures exactly what the mesh dispatcher adds: signatures packed
-    per relay command vs the dispatcher's own serial host costs."""
+    per device launch vs the dispatcher's own serial host costs."""
     import numpy as np
 
     def prep(block, plan):
@@ -91,15 +91,15 @@ def mock_mesh_prepare(real_prepare, rtt_s: float):
 
 
 def mock_light_prepare(real_prepare, rtt_s: float):
-    """Mocked-relay DEVICE for `bench.py light` and the
+    """Mocked-device DEVICE for `bench.py light` and the
     `tools/prep_bench.py --light` throughput figure: the real host prep
     (sign-bytes, epoch grouping, coalescing, packing) and the H2D
     transfer run unchanged, but the launch returns an all-accept verdict
-    row behind a fixed relay RTT instead of running the kernel — the
+    row behind a fixed device RTT instead of running the kernel — the
     mock_mesh_prepare philosophy applied to the classic single-lane
     `_prepare`. What the light-service curve then measures is exactly
     what the service adds over per-request dispatch: cross-request
-    epoch-grouped coalescing (headers per relay command) and
+    epoch-grouped coalescing (headers per device launch) and
     request-level dedup, not kernel speed."""
     import numpy as np
 
@@ -120,7 +120,7 @@ class DeadlineReadback:
     SlowReadback mock charges its delay inside __array__, which
     serializes the resolver at one RTT per batch; a real device's compute
     proceeds while the host pipelines, so concurrent launches' readbacks
-    mature in parallel. bench.py mempool uses this so the mocked relay
+    mature in parallel. bench.py mempool uses this so the mocked device
     models per-launch LATENCY (each batch's verdict is unavailable for a
     full RTT) without inventing a serial resolver bottleneck no real
     backend has."""
@@ -143,14 +143,14 @@ class DeadlineReadback:
 
 
 def mock_mempool_prepare(real_prepare, rtt_s: float):
-    """Mocked-relay DEVICE for `bench.py mempool` (ISSUE 13): the real
+    """Mocked-device DEVICE for `bench.py mempool` (ISSUE 13): the real
     ingress accumulation, EntryBlock packing, host prep and H2D transfer
     run unchanged, but the launch returns an all-accept verdict row that
     matures `rtt_s` after launch (DeadlineReadback) instead of running
     the kernel. Both bench columns — the windowed accumulator and the
-    per-tx baseline — pay this same relay latency per LAUNCH, so the
+    per-tx baseline — pay this same device latency per LAUNCH, so the
     ratio measures exactly what device-batched CheckTx adds: signatures
-    fused per relay command."""
+    fused per device launch."""
     import numpy as np
 
     def prep(entries):
@@ -168,15 +168,15 @@ def mock_mempool_prepare(real_prepare, rtt_s: float):
 
 
 def mock_vote_prepare(real_prepare, rtt_s: float):
-    """Mocked-relay DEVICE for `bench.py votes` and the
+    """Mocked-device DEVICE for `bench.py votes` and the
     `tools/prep_bench.py --votes` gate (ISSUE 15): the real vote-ingress
     windowing, EntryBlock packing, host prep and H2D transfer run
     unchanged, but the launch returns an all-accept verdict row that
     matures `rtt_s` after launch (DeadlineReadback) instead of running
     the kernel. Both bench columns — the windowed accumulator and the
-    per-vote baseline — pay this same relay latency per LAUNCH, so the
+    per-vote baseline — pay this same device latency per LAUNCH, so the
     ratio measures exactly what device-batched AddVote adds: live-vote
-    signatures fused per relay command."""
+    signatures fused per device launch."""
     import numpy as np
 
     def prep(entries):

@@ -1,6 +1,6 @@
 """Fused commit prep — CommitBlock columns to kernel-ready arrays.
 
-PERF_r05 §3: after the EntryBlock representation landed, the remaining
+After the EntryBlock representation landed, the remaining
 GIL-held host work per 10k-signature verify_commit was the stage BEFORE
 the EntryBlock existed — per-signature flag selection and voting-power
 tally, per-lane sign-bytes handling, and the entry build — ~26 ms that

@@ -97,9 +97,9 @@ def transfer(args, shardings=None) -> tuple:
     lane-per-device across the dispatcher's mesh instead of on the
     default device, so batch k+1's distributed H2D rides behind mesh
     kernel k exactly like the single-device overlap path."""
-    # devcheck relay assertion (ISSUE 8): transfers are relay touches —
-    # once a dispatcher has claimed the relay, only it may issue them
-    _devcheck.note_relay_touch("device_pool.transfer")
+    # devcheck device assertion (ISSUE 8): transfers are device touches —
+    # once a dispatcher has claimed the device, only it may issue them
+    _devcheck.note_device_touch("device_pool.transfer")
     import jax
 
     if shardings is None:
@@ -244,7 +244,7 @@ class WindowedRatio:
             elapsed = now - self._start
             # occupancy needs a minimum measurement base: a sample
             # landing right after a roll would divide by near-zero
-            # elapsed and clamp the gauge to 1.0 on an idle relay —
+            # elapsed and clamp the gauge to 1.0 on an idle device —
             # hold the previous value until the window has substance
             if elapsed >= min(self._window * 0.05, 0.05):
                 self._g.set(min(self._num / elapsed, 1.0))

@@ -234,7 +234,7 @@ def verify_kernel(a_y, a_sign, r_y, r_sign, s_bits_t, k_bits_t, s_ok):
 # (uploaded once per epoch by ops/epoch_cache.py) plus per-signature gather
 # indices, and the per-signature scalars/encodings as RAW 32-byte rows —
 # limb and bit unpacking are trivial device work, while on the host they
-# were the bulk of prepare_batch's wall time (PERF_r06 §3). Steady-state
+# were the bulk of prepare_batch's wall time. Steady-state
 # batches therefore ship ~101 B/sig instead of ~2.2 kB/sig on this path.
 
 

@@ -1,7 +1,8 @@
 """Columnar signature-batch representation — the zero-copy commit prep.
 
-PERF_r05: the RLC kernel sustains ~476k sigs/s but end-to-end
-types.verify_commit peaked at 143k because the host path between
+Round 5 (a different attachment of the chip; not re-measured on this
+machine) found end-to-end types.verify_commit at under a third of the RLC
+kernel's rate because the host path between
 verify_commit and the kernel was built from per-signature Python objects:
 a (pub32, msg, sig64) tuple per lane, PyBytes sign-bytes, and b"".join
 re-copies in every prep stage — all GIL-held, so under concurrent commits

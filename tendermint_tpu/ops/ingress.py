@@ -14,7 +14,7 @@ keeps only a `LaneSpec` — window policy, priority tier, host-stage
 check and verdict-apply callbacks — and the engine does the rest.
 
 Windows are ADAPTIVE and SLO-AWARE (`AdaptiveWindow`): under flood a
-lane's window deepens (more amortization per relay command — the
+lane's window deepens (more amortization per device launch — the
 2302.00418 batch economics applied at admission); when traffic thins it
 shrinks below its base so a lone request is not taxed the full window;
 and a lane's p99 latency budget bounds the effective window so the
@@ -198,7 +198,7 @@ class AdaptiveWindow:
 
     * deepen under flood — a FULL flush at the current target grows the
       window ×1.5 and the target ×2 (throughput: more signatures per
-      relay command), up to 8× the configured base;
+      device launch), up to 8× the configured base;
     * shrink when idle — SHRINK_PATIENCE consecutive timer flushes each
       carrying ≤¼ of the target halve both, down to ¼ window / base
       batch (latency: a lone request is not taxed a flood-depth window;
@@ -659,7 +659,7 @@ class Lane:
                 _observe(self.spec.observer, "remote_fallback")
                 try:
                     # fallback=False: remote_fallbacks is the counter
-                    # here, not sync_fallbacks (disjoint taxonomies)
+                    # here, not sync_fallbacks (disjoint classifications)
                     self._host(items, fallback=False)
                     return
                 except Exception as e:  # noqa: BLE001 — fallback failed

@@ -8,7 +8,7 @@ serializes through one device's lanes. This module turns the pipeline's
 coalescer into a **mesh dispatcher**: many commits in flight (many
 chains / many heights — the millions-of-users shape) are bin-packed into
 per-shard **lanes** of one `(n_lanes, lane_bucket)` superbatch per
-launch, so one relay command carries every device's work for the step.
+launch, so one device launch carries every device's work for the step.
 
 Packing model (committee-scale batching, arXiv 2302.00418):
 
@@ -41,8 +41,8 @@ jax, no crypto), importable standalone the way ops/device_pool.py is;
 `prepare_superbatch` is the only device-facing function and defers every
 heavy import. Uploads and launches remain the property of the
 pipeline's single dispatch-owner thread: this module builds plans and
-argument tuples, the dispatcher transfers and launches them (the relay
-single-owner invariant, tmlint relay-ownership + devcheck).
+argument tuples, the dispatcher transfers and launches them (the device
+single-owner invariant, tmlint device-ownership + devcheck).
 
 Knobs:
     TM_TPU_MESH              lane count: 0/unset = disabled (classic
@@ -605,7 +605,8 @@ def prepare_superbatch(block: EntryBlock, plan: MeshPlan):
         raise ValueError(
             f"superblock is {len(block)} rows, plan says {bucket}"
         )
-    donate = _backend.donate_enabled()
+    eng = _backend.engine()
+    donate = eng.donate
     if isinstance(block, SchemeSuperBlock):
         return _prepare_mixed_superbatch(block, donate, bucket)
     ep = _warm_entry(plan) if block.epoch_key is not None else None
@@ -621,12 +622,10 @@ def prepare_superbatch(block: EntryBlock, plan: MeshPlan):
         args = _backend.prepare_batch_secp(block, bucket)
         return _backend.secp_kernel(donate), args, None, bucket, None
     use_mesh = plan.n_lanes > 1 and _sharded.mesh_ready(plan.n_lanes)
-    if _backend._use_pallas():
-        import jax
-
+    if eng.pallas:
         from . import pallas_verify as _pv
 
-        interpret = jax.default_backend() != "tpu"
+        interpret = eng.interpret
         blk = _pv.pick_block(plan.lane_bucket)
         args = _pv.prepare_compact(block, bucket)
         if use_mesh:

@@ -90,18 +90,6 @@ def _verify_secp_batch(lane: Sequence[Tuple[PubKey, bytes, bytes]]) -> np.ndarra
     return _host_secp_batch(lane)
 
 
-# First device call (the Mosaic compile) is time-boxed: a pathologically
-# slow or hung remote compile must not wedge the caller — on timeout the
-# process permanently falls back to the host path for sr25519.
-_sr_device_state = {"ok": None}  # None = untried, True/False decided
-
-
-def _sr_compile_timeout() -> float:
-    """Read at call time so bench.py can tighten the budget after this
-    module is already imported."""
-    return float(os.environ.get("TM_TPU_SR_COMPILE_TIMEOUT", "300"))
-
-
 # host-fallback pool for sr25519 (satellite of ISSUE 20, mirroring the
 # secp pool): the native schnorrkel batch call computes outside the GIL,
 # so splitting a big batch across workers scales ~linearly; the
@@ -146,62 +134,33 @@ def _host_sr_batch(entries) -> np.ndarray:
 
 
 def _sr_device_enabled() -> bool:
-    """sr25519 device lane: ON by default since round 4 — the round-3
-    Mosaic compile hang no longer reproduces (verified on hardware:
-    compiles in ~16s, correct at production buckets vs the host oracle).
-    The first-use watchdog below still guards against a hung remote
-    compile; TM_TPU_SR_DEVICE=0 forces the native host lane."""
+    """sr25519 device lane: on by default; TM_TPU_SR_DEVICE=0 is the
+    explicit way to use the native host lane instead. There is no
+    automatic fallback: a kernel that fails to compile or launch raises
+    to the caller."""
     return os.environ.get("TM_TPU_SR_DEVICE", "1") == "1"
 
 
 def _verify_sr25519_batch(entries: List[Tuple[bytes, bytes, bytes]]) -> np.ndarray:
+    eng = _backend.engine()
     if (
         len(entries) < SR_DEVICE_THRESHOLD
         or not _sr_device_enabled()
-        or not _backend._use_pallas()
-        or _sr_device_state["ok"] is False
+        or not eng.pallas
     ):
         return _host_sr_batch(entries)
-    import jax
-
     from . import pallas_sr25519 as ps
 
-    interpret = jax.default_backend() != "tpu"
-
-    def run_chunks() -> np.ndarray:
-        out = []
-        i = 0
-        while i < len(entries):
-            chunk = entries[i : i + _backend.BUCKETS[-1]]
-            bucket = _backend._pallas_bucket(len(chunk))
-            args = ps.prepare_sr25519(chunk, bucket)
-            res = ps.verify_sr25519_compact(*args, interpret=interpret)
-            out.append(res[: len(chunk)])
-            i += len(chunk)
-        return np.concatenate(out)
-
-    if _sr_device_state["ok"]:
-        return run_chunks()
-
-    # first use: compile under a watchdog
-    import threading
-
-    holder: dict = {}
-
-    def attempt():
-        try:
-            holder["res"] = run_chunks()
-        except Exception as e:  # noqa: BLE001
-            holder["err"] = e
-
-    t = threading.Thread(target=attempt, daemon=True)
-    t.start()
-    t.join(_sr_compile_timeout())
-    if "res" in holder:
-        _sr_device_state["ok"] = True
-        return holder["res"]
-    _sr_device_state["ok"] = False  # hung or failed: host from now on
-    return _host_sr_batch(entries)
+    out = []
+    i = 0
+    while i < len(entries):
+        chunk = entries[i : i + _backend.BUCKETS[-1]]
+        bucket = _backend._pallas_bucket(len(chunk))
+        args = ps.prepare_sr25519(chunk, bucket)
+        res = ps.verify_sr25519_compact(*args, interpret=eng.interpret)
+        out.append(res[: len(chunk)])
+        i += len(chunk)
+    return np.concatenate(out)
 
 
 def verify_mixed(

@@ -15,12 +15,11 @@ Round-3 measured context: pure-Python sr25519 verify is ~10 ms/sig — the
 mixed-curve BASELINE config #4 was host-bound; this lane moves the EC
 math (2 scalar mults/sig) onto the device and the transcripts into C.
 
-STATUS (round 4): production — compiles on the TPU in ~16s and matches
-the host oracle at production buckets (block 512, bucket 2048 verified
-on hardware); the round-3 Mosaic compile hang no longer reproduces. The
-lane is ON by default; ops.mixed's first-use watchdog still time-boxes
-the compile (TM_TPU_SR_COMPILE_TIMEOUT) and falls back to the native
-host lane rather than wedge a caller.
+The lane is ON by default (TM_TPU_SR_DEVICE=0 selects the native host
+lane). A kernel that fails to compile or launch raises to the caller of
+ops.mixed — there is no watchdog and no silent host fallback. Whether it
+compiles under the installed libtpu is recorded in the README's scheme
+matrix ("runs on v5e").
 """
 
 from __future__ import annotations

@@ -15,7 +15,6 @@ shape the driver's `dryrun_multichip` exercises.
 
 from __future__ import annotations
 
-import functools
 import logging
 from typing import List, Tuple
 
@@ -33,50 +32,6 @@ _span = _trace.span
 _log = logging.getLogger("tendermint_tpu.ops.sharded")
 
 AXIS = "dp"
-
-
-@functools.lru_cache(maxsize=1)
-def shard_map_available() -> bool:
-    """ONE-TIME capability probe (ISSUE 9 satellite): does this jax ship
-    `jax.shard_map`? Older versions (e.g. 0.4.37 in some containers)
-    don't, and the sharded builders used to re-raise the ImportError on
-    EVERY warm block that auto-dispatched here — the probe result is
-    cached so the fallback decision costs one boolean test per batch."""
-    try:
-        from jax import shard_map  # noqa: F401
-
-        return True
-    except ImportError:
-        return False
-
-
-_fallback_warned: set = set()
-
-
-def _warn_fallback(where: str) -> None:
-    """Warn ONCE per entry point when the sharded path degrades to
-    single-device dispatch (jax.shard_map unavailable, or fewer devices
-    than requested lanes) — not once per batch."""
-    if where in _fallback_warned:
-        return
-    _fallback_warned.add(where)
-    _log.warning(
-        "%s: jax.shard_map unavailable on this jax version — falling "
-        "back to single-device dispatch of the same superbatch "
-        "(bit-identical verdicts, no mesh parallelism). Logged once.",
-        where,
-    )
-
-
-def _host_tally(valid: np.ndarray, pw: np.ndarray, live: np.ndarray,
-                n: int) -> Tuple[np.ndarray, int, bool]:
-    """The psum tally's host equivalent for the single-device fallback:
-    sum the base-2^16 power lanes of valid live rows, fold, and compute
-    the all-valid bit. `valid` must already be an owned bool array."""
-    ok = valid & live
-    lanes = pw[ok].sum(axis=0, dtype=np.int64)
-    all_valid = not bool((live & ~valid).any())
-    return valid[:n], join_power(lanes), all_valid
 
 
 def make_mesh(n_devices: int | None = None) -> Mesh:
@@ -177,14 +132,6 @@ def verify_commit_sharded(
         live[:n] = True
         pw = np.zeros((bucket, POWER_LANES), dtype=np.int32)
         pw[:n] = split_power(np.asarray(powers[:n]))
-    if not shard_map_available():
-        # warn-once fallback (ISSUE 9 satellite): same kernel math over
-        # the same padded batch on one device, tally folded on the host
-        _warn_fallback("verify_commit_sharded")
-        with _span("sharded.device", n=n, bucket=bucket, fallback=1):
-            kern = _kernel.jitted_verify(_backend.donate_enabled())
-            valid = np.array(kern(*args)).astype(bool)
-        return _host_tally(valid, pw, live, n)
     fn, _ = _jitted_for(mesh)
     with _span("sharded.device", n=n, bucket=bucket):
         valid, lanes, all_valid = fn(*args, pw, live)
@@ -228,7 +175,7 @@ def epoch_tables_sharded(ep, mesh: Mesh):
     module-level side table, so the PR-5 LRU owns their lifetime — an
     evicted epoch drops its mesh replicas with its single-device
     layouts, and the upload runs under the entry lock on the dispatch-
-    owner thread (devcheck note_relay_touch covers it)."""
+    owner thread (devcheck note_device_touch covers it)."""
     return ep.sharded_xla_tables(mesh)
 
 
@@ -302,18 +249,7 @@ def verify_commit_sharded_cached(
         live[:n] = True
         pw = np.zeros((bucket, POWER_LANES), dtype=np.int32)
         pw[:n] = split_power(np.asarray(powers[:n]))
-    donate = _backend.donate_enabled()
-    if not shard_map_available():
-        # warn-once fallback (ISSUE 9 satellite): the warm block still
-        # rides the CACHED kernel (single-device table, device gather +
-        # unpack), host tally — previously every warm auto-dispatch
-        # re-raised the shard_map ImportError
-        _warn_fallback("verify_commit_sharded_cached")
-        with _span("sharded.device", n=n, bucket=bucket, cached=1,
-                   fallback=1):
-            kern = _backend.cached_kernel(ep, False, donate)
-            valid = np.array(kern(*args)).astype(bool)
-        return _host_tally(valid, pw, live, n)
+    donate = _backend.engine().donate
     tbl = epoch_tables_sharded(ep, mesh)
     key = ("cached", tuple(d.id for d in mesh.devices.flat), donate)
     if key not in _mesh_cache:
@@ -333,8 +269,8 @@ def verify_commit_sharded_cached(
 
 # ---------------------------------------------------------------------------
 # Production-kernel sharding: the compact Pallas pipeline under shard_map
-# (VERDICT r3 item 4 — shard the kernel VerifyCommit actually runs, not the
-# op-graph fallback). Batch-minor compact args shard on their LAST axis;
+# (shard the kernel VerifyCommit actually runs, not the op-graph
+# kernel). Batch-minor compact args shard on their LAST axis;
 # the voting-power tally and all-valid bit ride psum collectives over ICI.
 # ---------------------------------------------------------------------------
 
@@ -405,19 +341,13 @@ def verify_commit_sharded_pallas(
         bucket += nd - bucket % nd
     per_shard = bucket // nd
     block = _pv.pick_block(per_shard)
-    interpret = jax.default_backend() != "tpu"
+    interpret = _backend.engine().interpret
     with _span("sharded.host_prep", n=n, bucket=bucket):
         a_t, r_t, s_t, k_t, sok_t = _pv.prepare_compact(entries, bucket)
         live = np.zeros((bucket,), dtype=bool)
         live[:n] = True
         pw = np.zeros((bucket, POWER_LANES), dtype=np.int32)
         pw[:n] = split_power(np.asarray(powers[:n]))
-    if not shard_map_available():
-        _warn_fallback("verify_commit_sharded_pallas")
-        with _span("sharded.device", n=n, bucket=bucket, fallback=1):
-            kern = _pv._jitted_pallas_verify(bucket, block, interpret)
-            valid = np.array(kern(a_t, r_t, s_t, k_t, sok_t))[0].astype(bool)
-        return _host_tally(valid, pw, live, n)
     key = ("pallas", tuple(d.id for d in mesh.devices.flat), per_shard, block,
            interpret)
     if key not in _mesh_cache:
@@ -525,31 +455,18 @@ def verify_commit_sharded_rlc(
         live[:n] = True
         pw = np.zeros((bucket, POWER_LANES), dtype=np.int32)
         pw[:n] = split_power(np.asarray(powers[:n]))
-    interpret = jax.default_backend() != "tpu"
-    if not shard_map_available():
-        _warn_fallback("verify_commit_sharded_rlc")
-        with _span("sharded.device", n=n, bucket=bucket, fallback=1):
-            kern = _pr._jitted_rlc_verify(g, block, interpret)
-            lane_valid = np.array(
-                kern(a_t, r_t, scal_t, sok_t)
-            )[0].astype(bool)
-        sig_valid = np.repeat(lane_valid, m)
-        tallied = join_power(
-            pw[sig_valid & live].sum(axis=0, dtype=np.int64)
+    interpret = _backend.engine().interpret
+    key = ("rlc", tuple(d.id for d in mesh.devices.flat), g_shard, block,
+           interpret)
+    if key not in _mesh_cache:
+        _mesh_cache[key] = sharded_rlc_verifier(mesh, g_shard, block,
+                                                interpret)
+    with _span("sharded.device", n=n, bucket=bucket):
+        lane_valid, lanes_pw, all_valid = _mesh_cache[key](
+            a_t, r_t, scal_t, sok_t, pw, live
         )
-        all_valid = not bool((live & ~sig_valid).any())
-    else:
-        key = ("rlc", tuple(d.id for d in mesh.devices.flat), g_shard, block,
-               interpret)
-        if key not in _mesh_cache:
-            _mesh_cache[key] = sharded_rlc_verifier(mesh, g_shard, block,
-                                                    interpret)
-        with _span("sharded.device", n=n, bucket=bucket):
-            lane_valid, lanes_pw, all_valid = _mesh_cache[key](
-                a_t, r_t, scal_t, sok_t, pw, live
-            )
-            lane_valid = np.asarray(lane_valid)
-        tallied = join_power(lanes_pw)
+        lane_valid = np.asarray(lane_valid)
+    tallied = join_power(lanes_pw)
     # lane verdicts -> per-sig verdicts + host re-verify of rejected
     # lanes (shared with the single-chip path), then add the rescued
     # signatures' power back into the device tally
@@ -570,23 +487,25 @@ def verify_commit_sharded_rlc(
 # ---------------------------------------------------------------------------
 
 
+_few_devices_warned = False
+
+
 def mesh_ready(n_lanes: int) -> bool:
-    """Can a real shard_map mesh serve `n_lanes` lanes? False degrades
-    the mesh dispatcher to simulated lanes (same superbatch, plain
-    kernel, warn-once) — the tier-1/CPU face."""
-    if not shard_map_available():
-        _warn_fallback("mesh_dispatch")
-        return False
-    if len(jax.devices()) < n_lanes:
-        if "mesh_dispatch_devices" not in _fallback_warned:
-            _fallback_warned.add("mesh_dispatch_devices")
-            _log.warning(
-                "mesh dispatcher asked for %d lanes but only %d devices "
-                "are visible — running simulated lanes on one device. "
-                "Logged once.", n_lanes, len(jax.devices()),
-            )
-        return False
-    return True
+    """Can a real shard_map mesh serve `n_lanes` lanes? False (fewer
+    visible devices than lanes) runs the mesh dispatcher's superbatch as
+    simulated lanes on one device — the tier-1/CPU face — and says so
+    once."""
+    global _few_devices_warned
+    if len(jax.devices()) >= n_lanes:
+        return True
+    if not _few_devices_warned:
+        _few_devices_warned = True
+        _log.warning(
+            "mesh dispatcher asked for %d lanes but only %d devices "
+            "are visible — running simulated lanes on one device. "
+            "Logged once.", n_lanes, len(jax.devices()),
+        )
+    return False
 
 
 _dispatch_meshes: dict = {}
@@ -625,7 +544,13 @@ def mesh_valid_fn(mesh: Mesh, donate: bool = False,
             specs = (P(AXIS), P(AXIS), P(AXIS), P(AXIS),
                      P(None, AXIS), P(None, AXIS), P(AXIS))
             n_args = 7
-        fn = shard_map(body, mesh=mesh, in_specs=specs, out_specs=P(AXIS))
+        # check_vma off for the on-chip-SHA body only: its compression
+        # and mod-L loops start from constant carries (IV words, zero
+        # limbs), which jax 0.9's varying-axes check rejects inside
+        # shard_map. These are valid-bits-only kernels with no collective
+        # for the check to protect.
+        fn = shard_map(body, mesh=mesh, in_specs=specs, out_specs=P(AXIS),
+                       check_vma=not device_hash)
         _mesh_cache[key] = (
             jax.jit(fn, donate_argnums=tuple(range(n_args))) if donate
             else jax.jit(fn)
@@ -658,7 +583,9 @@ def mesh_valid_fn_cached(mesh: Mesh, ep, donate: bool = False,
                      P(AXIS), P(AXIS), P(AXIS), P(AXIS),  # idx, r, s, k
                      P(AXIS))                             # s_ok
             n_args = 7
-        fn = shard_map(body, mesh=mesh, in_specs=specs, out_specs=P(AXIS))
+        # same check_vma rationale as mesh_valid_fn
+        fn = shard_map(body, mesh=mesh, in_specs=specs, out_specs=P(AXIS),
+                       check_vma=not device_hash)
         _mesh_cache[key] = (
             jax.jit(fn, donate_argnums=tuple(range(2, n_args))) if donate
             else jax.jit(fn)

@@ -258,6 +258,13 @@ class Environment:
 
         if _pl._shared is not None:
             stats["lane_counts"] = _pl._shared.lane_counts()
+        # what the engine decided to run on (ops/engine.py) — only once
+        # some verification has resolved it; absent until then
+        from ..ops import engine as _engine
+
+        eng = _engine.resolved()
+        if eng is not None:
+            stats["engine"] = eng.describe()
         return stats
 
     def _own_voting_power(self) -> int:

@@ -158,7 +158,7 @@ class TestRollback:
 
 class TestInspect:
     def test_inspect_serves_indexer_rpcs_from_dead_node_dir(self, tmp_path):
-        """VERDICT r3 item 6 / internal/inspect/rpc/rpc.go:48-66: kill a
+        """internal/inspect/rpc/rpc.go:48-66: kill a
         node, run inspect over its DATA DIR (sqlite stores + tx_index
         sink), find a tx by hash and by event query, and block_search."""
         import json

@@ -53,7 +53,7 @@ def test_pins_file_is_wellformed():
 
 @pytest.mark.parametrize(
     "kind",
-    ["bench", "multichip", "light", "mempool", "blocksync", "votes", "soak",
+    ["multichip", "light", "mempool", "blocksync", "votes", "soak",
      "fleet", "schemes", "agg"],
 )
 def test_ratchet_gate(kind, capsys):
@@ -132,7 +132,7 @@ def test_soak_gate_is_direction_aware(tmp_path):
 def test_schemes_artifact_meets_acceptance_floor():
     """ISSUE 19 acceptance pinned into tier-1: the committed scheme-lane
     artifact must show the 10k-validator secp commit clearing >= 10x the
-    per-signature baseline in ONE relay launch. bench.py schemes already
+    per-signature baseline in ONE device launch. bench.py schemes already
     exits nonzero below 10x; this keeps the COMMITTED record honest."""
     latest = _latest_of_kind("schemes")
     assert latest is not None, "no SCHEMES_r*.json committed"

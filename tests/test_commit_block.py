@@ -472,7 +472,7 @@ class TestDispatchOwnerThread:
         try:
             futs = []
             threads = []
-            # concurrent submitters: the relay-ownership invariant must
+            # concurrent submitters: the device-ownership invariant must
             # hold regardless of caller concurrency
             def submit_from_thread(t):
                 futs.append(v.submit(self._entries(6, tag=t)))

@@ -105,7 +105,7 @@ class TestSingleValidator:
 
 
 def wire_nodes(nodes):
-    """Relay each node's own proposals/parts/votes to every other node —
+    """Device each node's own proposals/parts/votes to every other node —
     the test stand-in for the consensus reactor's gossip."""
     from tendermint_tpu.consensus import BlockPartMessage, ProposalMessage, VoteMessage
 

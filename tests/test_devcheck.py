@@ -3,7 +3,7 @@
 Two layers, same pattern as the other _isolated suites:
 
 - unit tests of the checkers themselves (lock-order cycle detection,
-  write-after-resolve canary, relay ownership, zero-cost-off) run IN
+  write-after-resolve canary, device ownership, zero-cost-off) run IN
   PROCESS — stdlib + numpy only, no jax, no crypto wheel;
 - the injected-bug integration (TM_TPU_INJECT_LINTBUG=alias|owner driven
   through a REAL AsyncBatchVerifier with a mock kernel) needs the ops
@@ -12,7 +12,7 @@ Two layers, same pattern as the other _isolated suites:
 
 The injected-bug tests are the runtime half of the seeded-regression
 requirement: re-introduce the PR-7 readback aliasing / a resolver-thread
-relay touch and assert the matching checker FIRES — proving the canary
+device touch and assert the matching checker FIRES — proving the canary
 and the ownership assertion actually guard their bug class.
 """
 
@@ -262,37 +262,37 @@ class TestCanary:
 
 
 # ---------------------------------------------------------------------------
-# units: relay ownership
+# units: device ownership
 
 
-class TestRelayOwnership:
+class TestDeviceOwnership:
     def test_no_owner_means_direct_use_is_legal(self):
-        devcheck.note_relay_touch("standalone")
+        devcheck.note_device_touch("standalone")
         assert not devcheck.violations()
 
     def test_owner_thread_passes_others_raise(self):
-        devcheck.claim_relay("me")
-        devcheck.note_relay_touch("same-thread")  # owner: fine
+        devcheck.claim_device("me")
+        devcheck.note_device_touch("same-thread")  # owner: fine
         err = []
 
         def intruder():
             try:
-                devcheck.note_relay_touch("other-thread")
+                devcheck.note_device_touch("other-thread")
             except devcheck.DevcheckViolation as e:
                 err.append(e)
 
         t = threading.Thread(target=intruder, daemon=True)
         t.start()
         t.join(timeout=5)
-        assert err and devcheck.violations()[0]["kind"] == "relay-ownership"
+        assert err and devcheck.violations()[0]["kind"] == "device-ownership"
 
     def test_exempt_scope_passes(self):
-        devcheck.claim_relay("owner")
+        devcheck.claim_device("owner")
         ok = []
 
         def sanctioned():
             with devcheck.exempt():
-                devcheck.note_relay_touch("warmup")
+                devcheck.note_device_touch("warmup")
             ok.append(True)
 
         t = threading.Thread(target=sanctioned, daemon=True)
@@ -303,11 +303,11 @@ class TestRelayOwnership:
     def test_zero_cost_off(self):
         devcheck.disable()
         try:
-            devcheck.claim_relay("x")
-            devcheck.note_relay_touch("y")
+            devcheck.claim_device("x")
+            devcheck.note_device_touch("y")
             devcheck.canary_register(np.zeros(4, dtype=np.uint8))
             assert devcheck.canary_sweep("z") == 0
-            assert devcheck.report()["counts"]["relay_touches"] == 0
+            assert devcheck.report()["counts"]["device_touches"] == 0
         finally:
             devcheck.enable()
 
@@ -317,13 +317,13 @@ class TestRelayOwnership:
             devcheck.check()
         assert "test-kind" in str(ei.value)
 
-    def test_unclaim_relay_retires_owner(self):
+    def test_unclaim_device_retires_owner(self):
         # review fix: a closing verifier drops its dispatcher ident so
         # later standalone direct use stays legal and a recycled OS
         # thread ident cannot inherit the dead owner's pass
-        devcheck.claim_relay("me")
-        devcheck.unclaim_relay({threading.get_ident()})
-        devcheck.note_relay_touch("after-close")  # no owners: legal
+        devcheck.claim_device("me")
+        devcheck.unclaim_device({threading.get_ident()})
+        devcheck.note_device_touch("after-close")  # no owners: legal
         assert not devcheck.violations()
 
     def test_inject_seams_require_devcheck_armed(self, monkeypatch):
@@ -417,7 +417,7 @@ class TestInjectedLintbugs:
         self._run_two_batches()
         assert not devcheck.violations()
         counts = devcheck.report()["counts"]
-        assert counts["relay_touches"] >= 1       # transfers asserted
+        assert counts["device_touches"] >= 1       # transfers asserted
         assert counts["canary_registered"] >= 1   # verdicts canaried
         assert counts["lock_acquires"] > 0        # locks instrumented
 
@@ -430,13 +430,13 @@ class TestInjectedLintbugs:
         kinds = [x["kind"] for x in devcheck.violations()]
         assert "write-after-resolve" in kinds, kinds
 
-    def test_owner_injection_trips_relay_assertion(self, monkeypatch):
+    def test_owner_injection_trips_device_assertion(self, monkeypatch):
         """TM_TPU_INJECT_LINTBUG=owner makes the RESOLVER thread issue a
-        device transfer — the relay-ownership assertion must fire."""
+        device transfer — the device-ownership assertion must fire."""
         monkeypatch.setenv("TM_TPU_INJECT_LINTBUG", "owner")
         self._run_two_batches()
         kinds = [x["kind"] for x in devcheck.violations()]
-        assert "relay-ownership" in kinds, kinds
+        assert "device-ownership" in kinds, kinds
 
 
 def test_injected_lintbugs_under_purepy_fallback():

@@ -366,12 +366,19 @@ class TestRlcEnvHardening:
 
         monkeypatch.setenv("TM_TPU_RLC_SEED", "5")
         monkeypatch.delenv("TM_TPU_RLC_SEED_UNSAFE", raising=False)
+        from tendermint_tpu.ops.engine import engine
+
         monkeypatch.setattr(pallas_rlc.jax, "default_backend", lambda: "tpu")
         monkeypatch.setattr(pallas_rlc, "_seed_refused", False)
-        with warnings.catch_warnings(record=True) as w:
-            warnings.simplefilter("always")
-            z1 = pallas_rlc._gen_z(64)
-            z2 = pallas_rlc._gen_z(64)
+        engine.cache_clear()  # the platform decision is made once: redo it
+        try:
+            with warnings.catch_warnings(record=True) as w:
+                warnings.simplefilter("always")
+                z1 = pallas_rlc._gen_z(64)
+                z2 = pallas_rlc._gen_z(64)
+        finally:
+            monkeypatch.undo()
+            engine.cache_clear()
         assert any("TM_TPU_RLC_SEED ignored" in str(x.message) for x in w)
         # seed ignored: draws are CSPRNG, not the deterministic stream
         assert not np.array_equal(z1, z2)

@@ -4,8 +4,8 @@ The verifier here is a STUB (futures the test resolves by hand), so
 these tests pin the transport contract itself — completion-order
 verdict streaming, QoS/flow/lane preservation into the submit seam,
 malformed-frame containment (ERROR reply, connection lives), oversize
-containment (connection dies, server lives), dispatch-error taxonomy
-(RemoteDispatchError, no host fallback) vs. fleet-death taxonomy
+containment (connection dies, server lives), dispatch-error class
+(RemoteDispatchError, no host fallback) vs. fleet-death class
 (FleetUnavailable, host fallback), deadline → degrade → rejoin — with
 no jax, no kernels and no crypto wheel in the loop.
 """
@@ -330,7 +330,7 @@ class TestLaneSpecSeam:
             assert lane.dispatch_errors == 0, "fallback must not poison"
 
             # 3) degraded: pre-submit FleetUnavailable rides the
-            # submit_error_to_host path (disjoint counter taxonomy)
+            # submit_error_to_host path (disjoint counter classes)
             futs3 = [lane.submit({"i": 20 + i}, want_future=True)
                      for i in range(4)]
             assert futs3[0].result(timeout=10) == [True] * 4

@@ -288,7 +288,7 @@ class TestRecheck:
 
 class TestQoS:
     def test_commit_preempts_queued_ingress_windows(self):
-        """Two ingress waves on a depth-1 mocked-relay pipeline: wave 1
+        """Two ingress waves on a depth-1 mocked-device pipeline: wave 1
         is in flight and wave 2 is parked at the depth semaphore when a
         PRIORITY_CONSENSUS block arrives — the commit must jump the
         queue (preemption counted, wave-2 futures still pending when it

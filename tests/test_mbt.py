@@ -7,7 +7,7 @@ Reference parity: light/mbt/driver_test.go — the JSON vectors
 tests/vectors/mbt/) are the bit-exactness oracle for the verifier
 (SURVEY.md §4): header hashing, validator-set hashing, canonical vote
 sign-bytes, ZIP-215 signature acceptance, trust-level arithmetic, and the
-SUCCESS / NOT_ENOUGH_TRUST / INVALID error taxonomy all have to line up
+SUCCESS / NOT_ENOUGH_TRUST / INVALID error classes all have to line up
 for every step of every trace.
 """
 
@@ -41,7 +41,9 @@ def batch_backend(request, monkeypatch):
     size threshold so the 4-signature commits still take the device
     path)."""
     if request.param == "host":
-        monkeypatch.setattr(cbatch, "_device_verifier_factory", None)
+        monkeypatch.setattr(
+            cbatch, "_device_verifier_factory", cbatch.Ed25519HostBatchVerifier
+        )
     else:
         from tendermint_tpu.ops.backend import Ed25519DeviceBatchVerifier
 

@@ -5,10 +5,9 @@ lane packs (including a pure identity-padding lane), on the 1-lane and
 the warn-once shard_map fallback and the mesh observability gauges.
 
 Runs with devcheck armed: the mesh superbatch path must satisfy the
-relay single-owner assertions and the write-after-resolve canary exactly
+device single-owner assertions and the write-after-resolve canary exactly
 like the single-device dispatcher."""
 
-import logging
 import os
 
 import numpy as np
@@ -219,39 +218,6 @@ class TestMeshParity:
             assert not flat[11] and flat.sum() == 47
         finally:
             epoch_cache.reset()
-
-
-class TestShardMapFallback:
-    def test_warn_once_not_per_batch(self, caplog):
-        """ISSUE 9 satellite: with jax.shard_map unavailable the sharded
-        verifiers degrade to single-device dispatch and warn exactly
-        ONCE, not on every warm block."""
-        if sharded.shard_map_available():
-            pytest.skip("jax.shard_map present — fallback not exercised")
-        sharded._fallback_warned.discard("verify_commit_sharded")
-        mesh = sharded.make_mesh(1)
-        entries = _signed(12, 50, bad=(5,))
-        powers = [10 + i for i in range(12)]
-        with caplog.at_level(logging.WARNING,
-                             logger="tendermint_tpu.ops.sharded"):
-            v1, t1, a1 = sharded.verify_commit_sharded(entries, powers, mesh)
-            v2, t2, a2 = sharded.verify_commit_sharded(entries, powers, mesh)
-        warns = [r for r in caplog.records
-                 if "verify_commit_sharded:" in r.getMessage()]
-        assert len(warns) == 1
-        assert np.array_equal(v1, v2) and t1 == t2 == sum(powers) - 15
-        assert not a1 and not v1[5] and v1.sum() == 11
-
-    def test_mesh_ready_false_degrades_to_simulated_lanes(self):
-        if sharded.shard_map_available():
-            pytest.skip("jax.shard_map present — fallback not exercised")
-        assert sharded.mesh_ready(2) is False
-        # prepare_superbatch then returns no shardings (plain kernel)
-        blk = EntryBlock.from_entries(_signed(8, 51))
-        plan, _ = ms.pack_jobs([_J(blk)], 2, 128)
-        block, _spans = ms.build_superblock(plan)
-        res = ms.prepare_superbatch(block, plan)
-        assert len(res) == 5 and res[4] is None
 
 
 class TestMeshObservability:

@@ -195,7 +195,7 @@ def _purepy_env():
     env = dict(os.environ, TM_TPU_PUREPY_CRYPTO="1", JAX_PLATFORMS="cpu")
     env.pop("TM_TPU_DONATE", None)
     env.pop("TM_TPU_MESH", None)
-    jaxcache.set_env(env, _repo_root())
+    jaxcache.set_env(env)
     return env
 
 
@@ -212,7 +212,7 @@ def test_mesh_under_purepy_fallback():
         pass
     here = os.path.dirname(os.path.abspath(__file__))
     # devcheck armed for the whole run (ISSUE 8 pattern): the mesh
-    # superbatch path must hold the relay single-owner + canary
+    # superbatch path must hold the device single-owner + canary
     # invariants under the runtime checkers, not just the AST pass
     env = dict(_purepy_env(), TM_TPU_DEVCHECK="1")
     r = subprocess.run(

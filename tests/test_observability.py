@@ -316,7 +316,7 @@ class TestHotPathInstrumentation:
         from tendermint_tpu.ops import backend
 
         monkeypatch.setenv("TM_TPU_PALLAS", "0")
-        backend._use_pallas.cache_clear()
+        backend.engine.cache_clear()
         try:
             tr.configure(enabled=True)
             before = m.ops_metrics().sigs_verified.value(path="device")
@@ -334,7 +334,7 @@ class TestHotPathInstrumentation:
             assert "128" in stats["batches_by_bucket"]
             assert 0.0 <= stats["pad_waste_ratio"] <= 1.0
         finally:
-            backend._use_pallas.cache_clear()
+            backend.engine.cache_clear()
 
     @needs_wheel
     def test_span_coverage_of_verify_wall_clock(self, monkeypatch):
@@ -343,7 +343,7 @@ class TestHotPathInstrumentation:
         from tendermint_tpu.ops import backend
 
         monkeypatch.setenv("TM_TPU_PALLAS", "0")
-        backend._use_pallas.cache_clear()
+        backend.engine.cache_clear()
         try:
             entries = _entries(64)
             backend.verify_batch(entries)  # warm: compile outside the trace
@@ -361,7 +361,7 @@ class TestHotPathInstrumentation:
             )
             assert parts >= 0.90 * wall, (parts, wall)
         finally:
-            backend._use_pallas.cache_clear()
+            backend.engine.cache_clear()
 
     def test_host_fallback_counter(self):
         from tendermint_tpu.crypto import ed25519
@@ -387,7 +387,7 @@ class TestHotPathInstrumentation:
         from tendermint_tpu.ops import backend
 
         monkeypatch.setenv("TM_TPU_PALLAS", "0")
-        backend._use_pallas.cache_clear()
+        backend.engine.cache_clear()
         try:
             assert not tr.TRACER.enabled
             entries = _entries(64)
@@ -409,7 +409,7 @@ class TestHotPathInstrumentation:
             # ~10 instrument sites fire per verify_batch dispatch
             assert per_span * 10 < 0.02 * verify_s, (per_span, verify_s)
         finally:
-            backend._use_pallas.cache_clear()
+            backend.engine.cache_clear()
 
     @needs_wheel
     def test_pipeline_records_metrics(self):

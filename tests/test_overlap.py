@@ -1,4 +1,4 @@
-"""Overlapped relay (ISSUE 7): transfer/compute pipelining in the
+"""Overlapped device (ISSUE 7): transfer/compute pipelining in the
 dispatch-owner loop, the per-shape device buffer pool, buffer donation
 parity (cold + warm epoch, buckets 128/1024), the structured async
 verdict readback, and the poisoned-batch buffer-return bookkeeping.
@@ -34,9 +34,9 @@ from tendermint_tpu.ops import ed25519_verify as ev
 @pytest.fixture(autouse=True)
 def _devcheck_armed():
     """ISSUE 8: the overlap suite runs with the runtime invariant
-    checkers on (relay assertions, lock-order cycles, write-after-
+    checkers on (device assertions, lock-order cycles, write-after-
     resolve canary); a violation fails the offending test at teardown.
-    Direct kernel launches by parity tests stay legal — the relay
+    Direct kernel launches by parity tests stay legal — the device
     assertion only gates transfer/table-upload entry points once a
     dispatcher has claimed ownership."""
     devcheck.enable(reset=True)
@@ -163,9 +163,9 @@ class TestDonationParity:
         a donated input buffer read after launch, or a recycled buffer
         leaking between batches, would flip verdicts across batches."""
         monkeypatch.setenv("TM_TPU_DONATE", "1")
-        backend.donate_enabled.cache_clear()
+        backend.engine.cache_clear()
         try:
-            assert backend.donate_enabled() is True
+            assert backend.engine().donate is True
             v = pl.AsyncBatchVerifier(depth=2)
             try:
                 futs = [
@@ -177,7 +177,7 @@ class TestDonationParity:
                 v.close()
         finally:
             monkeypatch.setenv("TM_TPU_DONATE", "0")
-            backend.donate_enabled.cache_clear()
+            backend.engine.cache_clear()
         try:
             v2 = pl.AsyncBatchVerifier(depth=2)
             try:
@@ -190,7 +190,7 @@ class TestDonationParity:
                 v2.close()
         finally:
             monkeypatch.delenv("TM_TPU_DONATE", raising=False)
-            backend.donate_enabled.cache_clear()
+            backend.engine.cache_clear()
         for t, (d, p) in enumerate(zip(donated_res, plain_res)):
             d, p = np.asarray(d), np.asarray(p)
             assert d.shape == (8,)
@@ -241,7 +241,7 @@ class TestBufferPool:
         def xfer(args):
             if state["boom"]:
                 state["boom"] = False
-                raise RuntimeError("relay transfer exploded")
+                raise RuntimeError("device transfer exploded")
             return real(args)
 
         monkeypatch.setattr(pl._dpool, "transfer", xfer)
@@ -319,7 +319,7 @@ class TestOverlapStructure:
         )
         assert overlapped >= 2, (overlapped, xfers, waits)
         assert sum(1 for x in xfers if x[2].get("hidden")) >= 3
-        # relay single-owner extends to the transfer stage
+        # device single-owner extends to the transfer stage
         assert tids == v.dispatch_thread_idents == {v._dispatch_thread.ident}
 
     def test_d2h_capability_probe_cached(self):

@@ -1,4 +1,4 @@
-"""Tier-1 face of the overlapped relay (ISSUE 7).
+"""Tier-1 face of the overlapped device (ISSUE 7).
 
 Two layers, same pattern as test_epoch_cache_isolated.py:
 

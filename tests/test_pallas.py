@@ -54,7 +54,7 @@ class TestPallasPipeline:
         """TM_TPU_PALLAS=1 routes verify_batch through the Pallas path
         (interpret mode off-TPU) and results match the oracle."""
         monkeypatch.setenv("TM_TPU_PALLAS", "1")
-        backend._use_pallas.cache_clear()
+        backend.engine.cache_clear()
         # tiny pallas block so interpret mode stays fast
         monkeypatch.setattr(pv, "BLOCK", 8)
         try:
@@ -62,7 +62,7 @@ class TestPallasPipeline:
             res = backend.verify_batch(entries)
             assert res.tolist() == _oracle(entries)
         finally:
-            backend._use_pallas.cache_clear()
+            backend.engine.cache_clear()
 
     def test_prepare_compact_matches_prepare_batch_semantics(self):
         """The s<L flag and byte packing agree between the XLA and Pallas

@@ -3,8 +3,8 @@ against the ZIP-215 oracle, lane-reject fallback blame, scalar-prep
 parity (native C vs pure Python), and pipeline dispatch wiring.
 
 Runs the real 3-kernel RLC pipeline in interpret mode at tiny buckets —
-the same traced program Mosaic compiles on TPU (hardware-validated at
-bucket 10240 in round 5; see PERF_r05.md).
+the same traced program Mosaic compiles on TPU (chip_smoke.py runs it
+compiled, at the 10240 and 81920 buckets).
 """
 
 import os
@@ -107,23 +107,20 @@ class TestRlcKernel:
         # tiny lane blocks so interpret mode stays fast (env var is read
         # at module import; patch the module attribute)
         monkeypatch.setattr(pr, "BLOCK_LANES", 4)
-        backend._use_pallas.cache_clear()
-        backend._use_rlc.cache_clear()
+        backend.engine.cache_clear()
         try:
             entries = _sign_batch(10, tamper={3})
             res = backend.verify_batch(entries)
             assert res.tolist() == [i != 3 for i in range(10)]
         finally:
-            backend._use_pallas.cache_clear()
-            backend._use_rlc.cache_clear()
+            backend.engine.cache_clear()
 
     def test_pipeline_dispatch_rlc_lane_expansion(self, monkeypatch):
         """The shared async pipeline expands RLC lane verdicts back to
         per-signature verdicts (with fallback blame on reject lanes)."""
         monkeypatch.setenv("TM_TPU_PALLAS", "1")
         monkeypatch.setenv("TM_TPU_RLC", "1")
-        backend._use_pallas.cache_clear()
-        backend._use_rlc.cache_clear()
+        backend.engine.cache_clear()
         monkeypatch.setattr(pr, "BLOCK_LANES", 4)
         from tendermint_tpu.ops import pallas_verify as pv
         monkeypatch.setattr(pv, "BLOCK", 16)  # _pallas_bucket granularity
@@ -136,8 +133,7 @@ class TestRlcKernel:
             assert res.tolist() == [i != 5 for i in range(12)]
         finally:
             v.close()
-            backend._use_pallas.cache_clear()
-            backend._use_rlc.cache_clear()
+            backend.engine.cache_clear()
 
 
 class TestShardedRlc:

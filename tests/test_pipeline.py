@@ -15,7 +15,7 @@ from tests.test_types import CHAIN_ID, build_commit, make_validators
 @pytest.fixture(autouse=True)
 def _devcheck_armed():
     """ISSUE 8: the whole pipeline suite runs with the runtime invariant
-    checkers on — relay-thread assertions, lock-order cycle detection,
+    checkers on — device-thread assertions, lock-order cycle detection,
     and the write-after-resolve canary. Any violation fails the test
     that caused it at teardown."""
     devcheck.enable(reset=True)

@@ -397,7 +397,7 @@ def _purepy_env():
     env = dict(os.environ, TM_TPU_PUREPY_CRYPTO="1", JAX_PLATFORMS="cpu")
     env.pop("TM_TPU_DONATE", None)
     env.pop("TM_TPU_MESH", None)
-    jaxcache.set_env(env, _repo_root())
+    jaxcache.set_env(env)
     return env
 
 

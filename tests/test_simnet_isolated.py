@@ -441,7 +441,7 @@ def test_smoke_cli_partition_heal_crash_restart():
 
 def test_devcheck_smoke_partition_heal_clean():
     """ISSUE 8 satellite: the 4-node partition+heal preset runs with the
-    TM_TPU_DEVCHECK runtime checkers armed (relay-thread assertions,
+    TM_TPU_DEVCHECK runtime checkers armed (device-thread assertions,
     lock-order cycle detection, write-after-resolve canary, instrumented
     from process start via --devcheck) and must come back devcheck-clean
     — zero violations, with the lock instrumentation demonstrably live."""

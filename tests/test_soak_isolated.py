@@ -6,7 +6,7 @@ TM_TPU_PUREPY_CRYPTO=1 (the env flag must NOT leak into the main pytest
 interpreter — same pattern as tests/test_simnet_isolated.py):
 
   1. mini-soak smoke: all four workload lanes drive ONE shared verifier
-     on a mocked relay for a few virtual seconds, twice at the same
+     on a mocked device for a few virtual seconds, twice at the same
      seed — green verdict, replay-exact, every lane demonstrably active.
   2. starved run: TM_TPU_INJECT_LINTBUG=starve makes the pipeline worker
      withhold ingress-priority dispatch — the soak must FAIL with the
@@ -35,7 +35,7 @@ def _env(**extra):
 def test_mini_soak_smoke_green_and_replay_exact(tmp_path, seed, duration):
     """`simnet_run.py --soak` — 4 nodes, crash + catchup rejoin +
     partition/heal, commit echo + light fleet + tx floods through one
-    shared AsyncBatchVerifier on a mocked relay, twice per seed at TWO
+    shared AsyncBatchVerifier on a mocked device, twice per seed at TWO
     seeds: green verdict, identical fingerprint/schedule digest per
     seed, zero timeouts, devcheck-clean (no devcheck key when unarmed),
     all lanes active."""
@@ -56,7 +56,7 @@ def test_mini_soak_smoke_green_and_replay_exact(tmp_path, seed, duration):
     v = json.loads(out.read_text())
     assert v["ok"] is True, v["reason"]
     assert v["replay_exact"] is True and v["runs"] == 2
-    assert v["mode"] == "mocked-relay"
+    assert v["mode"] == "mocked-device"
     assert v["slo"]["ok"] and v["slo"]["evaluated"] == 5
     assert v["violations"] == []
     # every workload lane demonstrably ran (a lane that silently no-ops

@@ -168,7 +168,7 @@ class TestSr25519Prep:
         from tendermint_tpu.crypto import ed25519, secp256k1, sr25519
         from tendermint_tpu.ops import backend, mixed
 
-        backend._use_pallas.cache_clear()
+        backend.engine.cache_clear()
         prior = os.environ.get("TM_TPU_PALLAS")
         os.environ["TM_TPU_PALLAS"] = "0"
         try:
@@ -188,7 +188,7 @@ class TestSr25519Prep:
                 del os.environ["TM_TPU_PALLAS"]
             else:
                 os.environ["TM_TPU_PALLAS"] = prior
-            backend._use_pallas.cache_clear()
+            backend.engine.cache_clear()
 
 
 class TestSr25519DeviceLaneK1:

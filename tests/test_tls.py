@@ -1,4 +1,4 @@
-"""TLS on the JSON-RPC server and clients (VERDICT r3 item 7).
+"""TLS on the JSON-RPC server and clients.
 
 Reference parity: rpc/jsonrpc/server/http_server.go ServeTLS — the same
 handler tree (HTTP JSON-RPC + the /websocket upgrade) served over TLS when

@@ -8,7 +8,7 @@ report zero non-baselined findings.
 
 The positive fixtures double as the static half of the seeded-regression
 requirement: `PR7_ALIAS_BUG` re-introduces the exact readback-aliasing
-shape PR 7 shipped and fixed, and `SINGLE_OWNER_BUG` a relay launch
+shape PR 7 shipped and fixed, and `SINGLE_OWNER_BUG` a device launch
 outside the dispatcher — each pass must flag its bug class.
 """
 
@@ -71,7 +71,7 @@ SINGLE_OWNER_BUG = """
     import jax
 
     def sneaky_verify(args):
-        return jax.device_put(args)    # relay touch outside the dispatcher
+        return jax.device_put(args)    # device touch outside the dispatcher
 """
 
 
@@ -85,13 +85,13 @@ class TestSeededRegressions:
         assert not lint(PR7_ALIAS_FIXED, OPS_PATH, "donation-aliasing")
 
     def test_single_owner_violation_is_flagged(self):
-        fs = lint(SINGLE_OWNER_BUG, REACTOR_PATH, "relay-ownership")
-        assert fs and fs[0].rule == "relay-ownership"
+        fs = lint(SINGLE_OWNER_BUG, REACTOR_PATH, "device-ownership")
+        assert fs and fs[0].rule == "device-ownership"
 
     def test_single_owner_ok_inside_dispatcher(self):
         assert not lint(
             SINGLE_OWNER_BUG, "tendermint_tpu/ops/pipeline.py",
-            "relay-ownership",
+            "device-ownership",
         )
 
 
@@ -207,28 +207,28 @@ class TestDonationAliasing:
         assert not lint(src, OPS_PATH, "donation-aliasing")
 
 
-class TestRelayOwnership:
+class TestDeviceOwnership:
     def test_positive_entry_points(self):
         src = """
             def f(backend, args):
                 k = backend.cached_kernel(None, True, True)
                 return k(*args)
         """
-        assert rules_of(lint(src, REACTOR_PATH)) == ["relay-ownership"]
+        assert rules_of(lint(src, REACTOR_PATH)) == ["device-ownership"]
 
     def test_positive_qualified_transfer(self):
         src = """
             def f(_dpool, args):
                 return _dpool.transfer(args)
         """
-        assert rules_of(lint(src, REACTOR_PATH)) == ["relay-ownership"]
+        assert rules_of(lint(src, REACTOR_PATH)) == ["device-ownership"]
 
     def test_negative_bare_transfer_is_not_flagged(self):
         src = """
             def f(conn, data):
                 return conn.transfer(data)
         """
-        assert not lint(src, REACTOR_PATH, "relay-ownership")
+        assert not lint(src, REACTOR_PATH, "device-ownership")
 
     def test_negative_whitelisted_module(self):
         src = """
@@ -237,16 +237,16 @@ class TestRelayOwnership:
                 return jax.device_put(x)
         """
         assert not lint(src, "tendermint_tpu/ops/device_pool.py",
-                        "relay-ownership")
+                        "device-ownership")
 
     def test_suppressed_next_line_comment(self):
         src = """
             import jax
             def f(x):
-                # tmlint: disable=relay-ownership — sanctioned one-off
+                # tmlint: disable=device-ownership — sanctioned one-off
                 return jax.device_put(x)
         """
-        assert not lint(src, REACTOR_PATH, "relay-ownership")
+        assert not lint(src, REACTOR_PATH, "device-ownership")
 
     def test_positive_mesh_launch_outside_whitelist(self):
         """ISSUE 9 satellite: a non-whitelisted mesh superbatch launch —
@@ -259,19 +259,19 @@ class TestRelayOwnership:
                 fn = sharded.mesh_valid_fn(mesh, donate=True)
                 return fn(*args)
         """
-        assert rules_of(lint(src, REACTOR_PATH)) == ["relay-ownership"]
+        assert rules_of(lint(src, REACTOR_PATH)) == ["device-ownership"]
         src_tbl = """
             def sneaky_tables(ep, mesh):
                 return ep.sharded_xla_tables(mesh)
         """
-        assert rules_of(lint(src_tbl, REACTOR_PATH)) == ["relay-ownership"]
+        assert rules_of(lint(src_tbl, REACTOR_PATH)) == ["device-ownership"]
         src_sh = """
             from tendermint_tpu.ops.sharded import epoch_tables_sharded
 
             def sneaky(ep, mesh):
                 return epoch_tables_sharded(ep, mesh)
         """
-        assert rules_of(lint(src_sh, REACTOR_PATH)) == ["relay-ownership"]
+        assert rules_of(lint(src_sh, REACTOR_PATH)) == ["device-ownership"]
 
     def test_negative_mesh_module_is_whitelisted(self):
         src = """
@@ -280,7 +280,7 @@ class TestRelayOwnership:
                 return fn
         """
         assert not lint(src, "tendermint_tpu/ops/mesh.py",
-                        "relay-ownership")
+                        "device-ownership")
         # the packing entry point itself is an ENTRY_POINT elsewhere
         src_prep = """
             from tendermint_tpu.ops import mesh
@@ -288,13 +288,13 @@ class TestRelayOwnership:
             def f(block, plan):
                 return mesh.prepare_superbatch(block, plan)
         """
-        assert rules_of(lint(src_prep, REACTOR_PATH)) == ["relay-ownership"]
+        assert rules_of(lint(src_prep, REACTOR_PATH)) == ["device-ownership"]
 
     # -- ISSUE 11: the light service's dispatch path -----------------------
 
-    def test_positive_light_service_direct_relay(self):
-        """A light-service-shaped module touching the relay directly —
-        launching, transferring, or wiring a mocked-relay device double
+    def test_positive_light_service_direct_device(self):
+        """A light-service-shaped module touching the device directly —
+        launching, transferring, or wiring a mocked-device double
         into the pipeline — is flagged; the service must submit through
         AsyncBatchVerifier."""
         src = """
@@ -303,7 +303,7 @@ class TestRelayOwnership:
             def verify_unique(self, stages):
                 return [jax.device_put(st.entries) for st in stages]
         """
-        assert rules_of(lint(src, LIGHT_PATH)) == ["relay-ownership"]
+        assert rules_of(lint(src, LIGHT_PATH)) == ["device-ownership"]
         src_mock = """
             from tendermint_tpu.ops._testing import mock_light_prepare
 
@@ -312,7 +312,7 @@ class TestRelayOwnership:
                     pl.AsyncBatchVerifier._prepare, 0.0
                 )
         """
-        assert rules_of(lint(src_mock, LIGHT_PATH)) == ["relay-ownership"]
+        assert rules_of(lint(src_mock, LIGHT_PATH)) == ["device-ownership"]
 
     def test_negative_light_service_submit_pattern(self):
         """The real service shape — EntryBlocks submitted to the shared
@@ -322,7 +322,7 @@ class TestRelayOwnership:
                 futs = [self._v.submit(st.entries, flow=fid) for st in stages]
                 return [f.result(timeout=600) for f in futs]
         """
-        assert not lint(src, LIGHT_PATH, "relay-ownership")
+        assert not lint(src, LIGHT_PATH, "device-ownership")
 
     # -- ISSUE 20: BLS aggregation lane launch builders --------------------
 
@@ -338,19 +338,19 @@ class TestRelayOwnership:
                 fn = bls_verify.jitted_bls_verify(True)
                 return fn(gx, gy, masks, coeffs)
         """
-        assert rules_of(lint(src, REACTOR_PATH)) == ["relay-ownership"]
+        assert rules_of(lint(src, REACTOR_PATH)) == ["device-ownership"]
         src_kern = """
             def sneaky_kernel(_backend, blk):
                 return _backend.bls_kernel(blk.bucket)(blk.rows)
         """
-        assert rules_of(lint(src_kern, REACTOR_PATH)) == ["relay-ownership"]
+        assert rules_of(lint(src_kern, REACTOR_PATH)) == ["device-ownership"]
         src_codes = """
             from tendermint_tpu.ops.backend import verify_batch_bls_codes
 
             def sneaky_codes(blk):
                 return verify_batch_bls_codes(blk)
         """
-        assert rules_of(lint(src_codes, REACTOR_PATH)) == ["relay-ownership"]
+        assert rules_of(lint(src_codes, REACTOR_PATH)) == ["device-ownership"]
 
     def test_negative_bls_kernel_module_is_whitelisted(self):
         """The kernel-definition module and the sanctioned direct path in
@@ -360,14 +360,14 @@ class TestRelayOwnership:
                 return jitted_bls_verify(False)(gx, gy, masks, coeffs)
         """
         assert not lint(src, "tendermint_tpu/ops/bls_verify.py",
-                        "relay-ownership")
+                        "device-ownership")
         src_backend = """
             def verify_batch_bls(blk):
                 codes = verify_batch_bls_codes(blk)
                 return codes == 1
         """
         assert not lint(src_backend, "tendermint_tpu/ops/backend.py",
-                        "relay-ownership")
+                        "device-ownership")
 
 
 class TestFleetTransport:
@@ -641,7 +641,7 @@ class TestLockDiscipline:
         """
         assert rules_of(lint(src, REACTOR_PATH)) == ["lock-discipline"]
 
-    def test_positive_relay_touching_thread_target(self):
+    def test_positive_device_touching_thread_target(self):
         src = """
             import threading, jax
             def worker(x):
@@ -650,7 +650,7 @@ class TestLockDiscipline:
                 threading.Thread(target=worker).start()
         """
         fs = lint(src, REACTOR_PATH)
-        # the worker body also trips relay-ownership; the thread-target
+        # the worker body also trips device-ownership; the thread-target
         # finding is the lock-discipline one
         assert "lock-discipline" in rules_of(fs)
 
@@ -731,11 +731,11 @@ class TestLockDiscipline:
         assert not lint(src, "tendermint_tpu/mempool/fake_mod.py",
                         "lock-discipline")
 
-    # -- ISSUE 13: ingress accumulator relay discipline ------------------
+    # -- ISSUE 13: ingress accumulator device discipline ------------------
 
     def test_positive_ingress_wiring_mock_outside_whitelist(self):
-        """Wiring the mempool mocked-relay double into the pipeline from
-        production ingress code is a relay violation — only bench/gate
+        """Wiring the mempool mocked-device double into the pipeline from
+        production ingress code is a device violation — only bench/gate
         harnesses (and ops/_testing.py itself) may do that."""
         src = """
             from tendermint_tpu.ops._testing import mock_mempool_prepare
@@ -747,13 +747,13 @@ class TestLockDiscipline:
         """
         assert rules_of(
             lint(src, "tendermint_tpu/mempool/ingress.py",
-                 "relay-ownership")
-        ) == ["relay-ownership"]
+                 "device-ownership")
+        ) == ["device-ownership"]
 
     def test_negative_ingress_accumulator_submit_path(self):
         """The real accumulator shape — EntryBlocks submitted to the
         shared verifier with an ingress priority, verdicts via futures —
-        is clean: no relay entry point in sight."""
+        is clean: no device entry point in sight."""
         src = """
             def _flush_device(self, batch):
                 block = self._pack(batch)
@@ -765,7 +765,7 @@ class TestLockDiscipline:
                 )
         """
         assert not lint(src, "tendermint_tpu/mempool/ingress.py",
-                        "relay-ownership")
+                        "device-ownership")
 
     # -- ISSUE 15: vote-ingress submit path ------------------------------
 
@@ -831,7 +831,7 @@ class TestLockDiscipline:
 
     def test_positive_vote_mock_wired_from_consensus(self):
         """mock_vote_prepare is a bench/gate double: wiring it from
-        production consensus code is a relay violation."""
+        production consensus code is a device violation."""
         src = """
             from tendermint_tpu.ops._testing import mock_vote_prepare
 
@@ -842,8 +842,8 @@ class TestLockDiscipline:
         """
         assert rules_of(
             lint(src, "tendermint_tpu/consensus/vote_ingress.py",
-                 "relay-ownership")
-        ) == ["relay-ownership"]
+                 "device-ownership")
+        ) == ["device-ownership"]
 
 
 class TestIngressDiscipline:
@@ -1123,7 +1123,7 @@ class TestCLI:
     def test_list_rules_names_all_five(self):
         r = self._run("--list-rules")
         assert r.returncode == 0
-        for name in ("donation-aliasing", "relay-ownership",
+        for name in ("donation-aliasing", "device-ownership",
                      "simnet-determinism", "hot-path-purity",
                      "lock-discipline"):
             assert name in r.stdout

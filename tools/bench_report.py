@@ -65,7 +65,7 @@ METRIC_KEYS = (
     "value", "vs_baseline", "host_sigs_per_s", "host_multicore_sigs_per_s",
     "vs_host_multicore", "host_batch_sigs_per_s", "vs_host_batch",
     "kernel_vs_host_batch", "single_commit_sigs_per_s",
-    "single_commit_vs_baseline", "relay_rtt_ms", "kernel_stream_sigs_per_s",
+    "single_commit_vs_baseline", "device_rtt_ms", "kernel_stream_sigs_per_s",
     "sustained_sigs_per_s", "sustained_vs_baseline", "mixed_curve_sigs_per_s",
     "pipelined_headers_per_s", "simnet_commits_per_s",
     "simnet_churn_commits_per_s", "speedup_2v1", "n_devices",
@@ -95,7 +95,7 @@ METRIC_KEYS = (
     # headline "value" is the aggregate sigs/s at the largest host count
     "clients",
     # scheme-lane artifacts (SCHEMES_r*, ISSUE 19); the headline "value"
-    # is counted secp256k1 commit sigs/s through ONE relay launch
+    # is counted secp256k1 commit sigs/s through ONE device launch
     "secp_seq_sigs_per_s", "vs_per_sig", "launches", "sigs_counted",
     # aggregation-lane artifacts (AGG_r*, ISSUE 20); the headline "value"
     # is aggregated BLS commits/s through the fused multi-pairing launch
@@ -106,7 +106,7 @@ METRIC_KEYS = (
 
 # gate semantics: for these, SMALLER is better (a rise is the regression)
 _LOWER_IS_BETTER = {
-    "relay_rtt_ms", "commit_p99_unloaded_ms", "commit_p99_flood_ms",
+    "device_rtt_ms", "commit_p99_unloaded_ms", "commit_p99_flood_ms",
     "flood_latency_ratio", "fallback_ranges",
     # soak lane p99s regress on a RISE; replay_heights_per_s (a rate)
     # stays in the default higher-is-better direction
@@ -123,7 +123,7 @@ _LOWER_IS_BETTER = {
 # keys a COMPARE tracks by default (rate-like, present across most rounds)
 COMPARE_KEYS = (
     "value", "sustained_sigs_per_s", "kernel_stream_sigs_per_s",
-    "pipelined_headers_per_s", "mixed_curve_sigs_per_s", "relay_rtt_ms",
+    "pipelined_headers_per_s", "mixed_curve_sigs_per_s", "device_rtt_ms",
     "speedup_2v1", "light_unique_headers_per_s", "flood_latency_ratio",
     "vs_kernel_serial", "consensus_commit_p99_ms", "light_verdict_p99_ms",
     "ingress_admission_p99_ms", "replay_heights_per_s",
@@ -316,7 +316,7 @@ def trajectory_rows(arts: List[dict]) -> List[dict]:
             "sustained": m.get("sustained_sigs_per_s"),
             "kernel_stream": m.get("kernel_stream_sigs_per_s"),
             "headers_per_s": m.get("pipelined_headers_per_s"),
-            "rtt_ms": m.get("relay_rtt_ms"),
+            "rtt_ms": m.get("device_rtt_ms"),
             "speedup_2v1": m.get("speedup_2v1"),
             "mode": art["mode"],
             "backend": art["backend"],

@@ -12,7 +12,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 from tendermint_tpu.libs import jaxcache  # noqa: E402
 
-jaxcache.set_env(os.environ, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+jaxcache.set_env(os.environ)
 
 import numpy as np
 

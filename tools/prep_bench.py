@@ -2,9 +2,9 @@
 """Host prep microbenchmark: tuple-list vs columnar EntryBlock commit prep.
 
 Measures the `commit_entries -> prepare_batch` path — the GIL-held host
-work between types.verify_commit and the device kernel that PERF_r05
-identified as the binding constraint (~40 ms/commit against ~23 ms of
-device time at 8 concurrent commits) — for both representations:
+work between types.verify_commit and the device kernel (round 5 found
+it the binding constraint at 8 concurrent commits; not re-measured on
+this machine) — for both representations:
 
   baseline   per-signature (pub32, msg, sig64) tuples: vote_sign_bytes_many
              (one PyBytes per lane), a tuple per signature, b"".join
@@ -298,12 +298,12 @@ def run_transfer(args) -> int:
 
 
 def run_overlap(args) -> int:
-    """--overlap: the round-8 overlapped-relay gate, an on-CPU proxy for
+    """--overlap: the round-8 overlapped-device gate, an on-CPU proxy for
     the transfer/compute pipelining ISSUE 7 adds to the dispatcher.
 
     The device is mocked SLOW on the readback side only (a proxy result
     whose materialization sleeps ~150 ms — the resolver blocks exactly
-    like a relay-attached TPU's D2H wait), so the dispatcher's loop
+    like a TPU's D2H wait), so the dispatcher's loop
     structure is what decides whether batch k+1's H2D transfer is issued
     while batch k computes. Asserts, over a stream of single-job batches
     at depth 1:
@@ -319,7 +319,7 @@ def run_overlap(args) -> int:
                at most OVERLAP_POOL_DEPTH slots for the whole stream
                (misses == depth, every later acquire is a recycled hit)
                and leaks nothing (in_flight == 0 once drained)
-      owner    transfers and launches all ran on ONE thread (the relay
+      owner    transfers and launches all ran on ONE thread (the device
                single-owner invariant extends to the transfer stage)
     """
     import numpy as np
@@ -441,7 +441,7 @@ def run_overlap(args) -> int:
     if len(tids) != 1:
         print(
             f"  FAIL: transfers/launches ran on {len(tids)} threads "
-            "(single relay owner violated)",
+            "(single device owner violated)",
             file=sys.stderr,
         )
         rc = 1
@@ -453,7 +453,7 @@ def run_mesh(args) -> int:
     (this box has one device; lane packing + demux is exactly the
     machinery that must be right WITHOUT mesh hardware). The kernel runs
     for real — verdicts are live — behind a slow-readback mock so the
-    overlap stages engage like a relay-attached mesh. Asserts:
+    overlap stages engage like a attached mesh. Asserts:
 
       pack     deterministic plan shapes: 3 full jobs over a 4-lane plan
                leave one PURE identity-padding lane; per-lane single-
@@ -463,7 +463,7 @@ def run_mesh(args) -> int:
                blame index (first invalid lane) of a tampered job
                survives the demux
       pool     zero slot leak once drained (in_flight == 0)
-      owner    transfers and launches all ran on ONE thread — the relay
+      owner    transfers and launches all ran on ONE thread — the device
                single-owner invariant extends to the mesh superbatch
       overlap  superbatch k+1's transfer is issued before batch k
                resolves (the ISSUE 7 machinery generalized to lane-
@@ -473,15 +473,7 @@ def run_mesh(args) -> int:
     """
     import numpy as np
 
-    from tendermint_tpu.libs import jaxcache
     from tendermint_tpu.libs.metrics import ops_stats
-
-    # persistent kernel cache: the 2-lane superbatch shape compiles once
-    # per machine, not once per gate run
-    import jax
-
-    jaxcache.enable(jax, os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))))
 
     from tendermint_tpu.observability import trace as tr
     from tendermint_tpu.ops import backend, mesh as ms, pipeline as pl
@@ -628,7 +620,7 @@ def run_mesh(args) -> int:
         rc = 1
     if len(tids) != 1:
         print(f"  FAIL: transfers/launches ran on {len(tids)} threads "
-              "(single relay owner violated)", file=sys.stderr)
+              "(single device owner violated)", file=sys.stderr)
         rc = 1
     if pool["in_flight"] != 0:
         print(f"  FAIL: {pool['in_flight']} pool slots leaked",
@@ -658,7 +650,7 @@ def run_schemes(args) -> int:
                superblock is a SchemeSuperBlock with contiguous
                per-scheme segments in plan.schemes() order
       launch   prepare_superbatch hands back ONE launch fn; a single
-               call verifies every lane — one relay command for a
+               call verifies every lane — one device launch for a
                mixed-scheme commit (the mixed-commit acceptance)
       parity   demuxed per-job verdict rows are bit-identical to the
                single-scheme device path (backend.verify_batch), on
@@ -671,13 +663,6 @@ def run_schemes(args) -> int:
                per-signature loop bit-for-bit, including a
                non-lower-S rejection
     """
-    import jax
-
-    from tendermint_tpu.libs import jaxcache
-
-    jaxcache.enable(jax, os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))))
-
     os.environ["TM_TPU_MESH_LANE_BUCKET"] = "16"
 
     from tendermint_tpu.crypto import ed25519 as _ed
@@ -923,13 +908,6 @@ def run_bls(args) -> int:
                ONE dispatch (launch count from the tracer)
       no leak  zero buffer-pool slots in flight once drained
     """
-    import jax
-
-    from tendermint_tpu.libs import jaxcache
-
-    jaxcache.enable(jax, os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))))
-
     os.environ["TM_TPU_MESH_LANE_BUCKET"] = "16"
 
     from tendermint_tpu.crypto import bls12381 as bls
@@ -1176,7 +1154,7 @@ def run_bls(args) -> int:
 
 
 def run_light(args) -> int:
-    """--light: the round-11 light-service gate on a mocked relay (slow
+    """--light: the round-11 light-service gate on a mocked device (slow
     readback over REAL kernels — verdicts are live). Asserts the three
     properties the batched service must hold:
 
@@ -1184,7 +1162,7 @@ def run_light(args) -> int:
                 count: R warm requests emit 2R-1 stage blocks but the
                 shared pipeline fuses them into far fewer device
                 launches (each undersized per-request dispatch would
-                otherwise pay a full relay RTT — the ~1.2k headers/s
+                otherwise pay a full device RTT — the ~1.2k headers/s
                 sequential ceiling)
       parity    verdicts AND blame byte-identical to the sequential
                 light/verifier.py path — ok requests, a forged-commit
@@ -1193,13 +1171,6 @@ def run_light(args) -> int:
       no leak   zero buffer-pool slots in flight once drained, and a
                 memoized resubmission adds ZERO launches
     """
-    import jax
-
-    from tendermint_tpu.libs import jaxcache
-
-    jaxcache.enable(jax, os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))))
-
     from dataclasses import replace as dc_replace
 
     import bench as _bench
@@ -1340,13 +1311,13 @@ def run_light(args) -> int:
 
 
 def run_ingress(args) -> int:
-    """--ingress: the round-13 mempool-ingress gate on a mocked relay
+    """--ingress: the round-13 mempool-ingress gate on a mocked device
     (slow readback over REAL kernels — verdicts are live). Asserts the
     three properties device-batched CheckTx must hold:
 
       fuse       N flooded txs reach the device in <= K launches (the
                  accumulator windows them, the coalescer fuses windows) —
-                 each per-tx dispatch would otherwise pay a full relay
+                 each per-tx dispatch would otherwise pay a full device
                  RTT, the ~25 tx/s sequential ceiling bench.py measures
       QoS        a consensus-priority batch submitted mid-flood overtakes
                  queued ingress work: preempted_total advances and the
@@ -1356,13 +1327,6 @@ def run_ingress(args) -> int:
                  FALSE, never silently dropped), and zero buffer-pool
                  slots remain in flight once drained
     """
-    import jax
-
-    from tendermint_tpu.libs import jaxcache
-
-    jaxcache.enable(jax, os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))))
-
     from tendermint_tpu.crypto import ed25519 as ed
     from tendermint_tpu.mempool import ingress as ing
     from tendermint_tpu.observability import trace as tr
@@ -1484,27 +1448,20 @@ def run_ingress(args) -> int:
 
 
 def run_votes(args) -> int:
-    """--votes: the round-15 live-vote-ingress gate on a mocked relay
+    """--votes: the round-15 live-vote-ingress gate on a mocked device
     (slow readback over REAL kernels — verdicts are live). Asserts the
     three properties device-batched AddVote must hold:
 
       fuse       N gossiped votes reach the device in <= K launches (the
                  accumulator windows them by (height, valset epoch), the
                  coalescer fuses windows) — per-vote dispatch would pay a
-                 full relay RTT each
+                 full device RTT each
       parity     a forged signature mid-flood resolves FALSE and is the
                  ONLY rejection — blame lands on exactly the forged vote
       no leak    every vote's verdict arrives (none silently dropped)
                  and zero buffer-pool slots remain in flight once drained
     """
     import threading
-
-    import jax
-
-    from tendermint_tpu.libs import jaxcache
-
-    jaxcache.enable(jax, os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))))
 
     from tendermint_tpu.consensus import vote_ingress as vi
     from tendermint_tpu.crypto import ed25519 as ed
@@ -1706,7 +1663,7 @@ def _build_replay_chain(n_blocks: int, n_vals: int, chain_id: str,
 
 
 def run_replay(args) -> int:
-    """--replay: the round-14 chain-replay gate on a mocked relay (slow
+    """--replay: the round-14 chain-replay gate on a mocked device (slow
     readback over REAL kernels — verdicts are live). Asserts the three
     properties range-batched blocksync must hold:
 
@@ -1719,13 +1676,6 @@ def run_replay(args) -> int:
                  height before the forgery still applies
       no leak    zero buffer-pool slots remain in flight once drained
     """
-    import jax
-
-    from tendermint_tpu.libs import jaxcache
-
-    jaxcache.enable(jax, os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))))
-
     from tendermint_tpu.blocksync.replay import ReplayEngine
     from tendermint_tpu.observability import trace as tr
     from tendermint_tpu.ops import backend
@@ -1856,7 +1806,7 @@ def run_replay(args) -> int:
 
 
 def run_fabric(args) -> int:
-    """--fabric: the round-17 ingress-fabric gate on a mocked relay
+    """--fabric: the round-17 ingress-fabric gate on a mocked device
     (slow readback over REAL kernels — verdicts are live). Asserts what
     unifying the four windowed accumulators bought:
 
@@ -1872,13 +1822,6 @@ def run_fabric(args) -> int:
       no leak     zero buffer-pool slots remain in flight once drained
     """
     import threading
-
-    import jax
-
-    from tendermint_tpu.libs import jaxcache
-
-    jaxcache.enable(jax, os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))))
 
     from tendermint_tpu.crypto import ed25519 as ed
     from tendermint_tpu.ops import ingress as fabric
@@ -2061,7 +2004,7 @@ def run_fabric(args) -> int:
 
 
 def run_fleet(args) -> int:
-    """--fleet: the round-18 verification-fleet gate on a mocked relay
+    """--fleet: the round-18 verification-fleet gate on a mocked device
     (slow readback over REAL kernels and REAL loopback sockets —
     verdicts are live, frames cross a real TCP stream). Asserts what
     the network-facing verify service must hold:
@@ -2081,13 +2024,6 @@ def run_fleet(args) -> int:
                 next submit rides the fleet again
       no leak   zero buffer-pool slots remain in flight once drained
     """
-    import jax
-
-    from tendermint_tpu.libs import jaxcache
-
-    jaxcache.enable(jax, os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))))
-
     from tendermint_tpu.crypto import ed25519 as ed
     from tendermint_tpu.fleet.client import FleetClient, FleetUnavailable
     from tendermint_tpu.fleet.server import FleetServer
@@ -2304,7 +2240,7 @@ def run_fleet(args) -> int:
 
 
 def run_soak(args) -> int:
-    """--soak: the round-16 soak-harness gate on a mocked relay (verdicts
+    """--soak: the round-16 soak-harness gate on a mocked device (verdicts
     come back all-accept with NO kernel — this gate checks the HARNESS,
     not the crypto). Asserts the three properties the soak driver must
     hold before its artifacts are trusted:
@@ -2322,13 +2258,6 @@ def run_soak(args) -> int:
     """
     import math
 
-    import jax
-
-    from tendermint_tpu.libs import jaxcache
-
-    jaxcache.enable(jax, os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))))
-
     from tendermint_tpu.ops import pipeline as pl
     from tendermint_tpu.ops._testing import drain_pool, mock_mempool_prepare
     from tendermint_tpu.simnet.soak import SoakConfig
@@ -2336,7 +2265,7 @@ def run_soak(args) -> int:
 
     duration, cadence, rtt_ms = 6.0, 1.0, 2.0
     print(f"prep_bench --soak: duration={duration}vs cadence={cadence}s "
-          f"runs=2 rtt={rtt_ms}ms relay=mocked")
+          f"runs=2 rtt={rtt_ms}ms device=mocked")
     rc = 0
 
     real_prepare = pl.AsyncBatchVerifier._prepare
@@ -2446,13 +2375,13 @@ def main() -> int:
         action="store_true",
         help="round-9 gate: mesh-dispatcher lane packing on a mocked "
         "2-lane mesh — pack/demux parity + blame, pure-pad-lane plan "
-        "shape, zero slot leak, single relay owner, superbatch overlap",
+        "shape, zero slot leak, single device owner, superbatch overlap",
     )
     ap.add_argument(
         "--light",
         action="store_true",
         help="round-11 gate: light-service batched verification on a "
-        "mocked relay — cross-request same-epoch coalescing by launch "
+        "mocked device — cross-request same-epoch coalescing by launch "
         "count, verdict/blame parity vs the sequential verifier, memoized "
         "resubmission launches nothing, zero pool-slot leak",
     )
@@ -2460,7 +2389,7 @@ def main() -> int:
         "--ingress",
         action="store_true",
         help="round-13 gate: device-batched mempool CheckTx on a mocked "
-        "relay — N flooded txs fuse into <= K launches, a mid-flood "
+        "device — N flooded txs fuse into <= K launches, a mid-flood "
         "consensus batch preempts queued ingress work, a forged tx "
         "resolves FALSE (never dropped), zero pool-slot leak",
     )
@@ -2468,7 +2397,7 @@ def main() -> int:
         "--replay",
         action="store_true",
         help="round-14 gate: range-batched blocksync replay on a mocked "
-        "relay — W same-epoch heights fuse into ceil(W*sigs/bucket) "
+        "device — W same-epoch heights fuse into ceil(W*sigs/bucket) "
         "launches, a forged commit mid-range falls back per-height with "
         "verify_commit_light's exact error, zero pool-slot leak",
     )
@@ -2476,13 +2405,13 @@ def main() -> int:
         "--votes",
         action="store_true",
         help="round-15 gate: device-batched live-vote ingress on a mocked "
-        "relay — N gossiped votes fuse into <= K launches, a forged "
+        "device — N gossiped votes fuse into <= K launches, a forged "
         "signature mid-flood is the ONLY rejection, zero pool-slot leak",
     )
     ap.add_argument(
         "--fabric",
         action="store_true",
-        help="round-17 gate: the unified ingress fabric on a mocked relay "
+        help="round-17 gate: the unified ingress fabric on a mocked device "
         "— four lane patterns on ONE scheduler + completer thread, the "
         "adaptive window deepens under flood AND shrinks back when idle, "
         "a forged signature is the only rejection, zero pool-slot leak",
@@ -2491,7 +2420,7 @@ def main() -> int:
         "--fleet",
         action="store_true",
         help="round-18 gate: the network-facing verification fleet on a "
-        "mocked relay over REAL loopback sockets — two client nodes' "
+        "mocked device over REAL loopback sockets — two client nodes' "
         "same-epoch blocks coalesce into fewer launches than solo, the "
         "one forged signature demuxes to the right node/row, a mid-window "
         "fleet kill loses zero items (host fallback) and a restarted "
@@ -2519,7 +2448,7 @@ def main() -> int:
     ap.add_argument(
         "--soak",
         action="store_true",
-        help="round-16 gate: soak-harness hygiene on a mocked relay — "
+        help="round-16 gate: soak-harness hygiene on a mocked device — "
         "sampler ticks on SimClock cadence, same-seed runs replay-exact, "
         "zero pool-slot leak, tmlint clean with simnet/soak.py in scope",
     )

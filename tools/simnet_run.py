@@ -42,10 +42,6 @@ import sys
 import time
 
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
-try:  # containers without the OpenSSL wheel run the pure-Python signer
-    import cryptography  # noqa: F401
-except ModuleNotFoundError:
-    os.environ.setdefault("TM_TPU_PUREPY_CRYPTO", "1")
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
@@ -175,7 +171,7 @@ def _attach_devcheck(verdict: dict) -> None:
 def run_soak(args) -> int:
     """--soak: one cluster, all four QoS workloads, time-series telemetry
     and a declarative SLO verdict (ISSUE 16). The verify engine runs with
-    the relay MOCKED by default (real packing/prep/transfer, all-accept
+    the device MOCKED by default (real packing/prep/transfer, all-accept
     verdict behind --soak-rtt-ms) so CI boxes measure the harness and the
     SLOs, not jax compile time; --soak-real runs live kernels. Exit 0 on
     a green verdict, 1 on any conclusive failure (SLO breach, invariant,
@@ -217,8 +213,8 @@ def run_soak(args) -> int:
             else:
                 os.environ["TM_TPU_FORCE_DEVICE"] = force_prev
     verdict = dict(runs[0])
-    verdict["mode"] = "real" if args.soak_real else "mocked-relay"
-    verdict["relay_rtt_ms"] = None if args.soak_real else args.soak_rtt_ms
+    verdict["mode"] = "real" if args.soak_real else "mocked-device"
+    verdict["device_rtt_ms"] = None if args.soak_real else args.soak_rtt_ms
     verdict["runs"] = len(runs)
     verdict["wall_total_s"] = round(time.monotonic() - t0, 3)
     verdict["replay_exact"] = all(
@@ -494,11 +490,11 @@ def main() -> int:
     )
     ap.add_argument(
         "--soak-rtt-ms", type=float, default=4.0,
-        help="soak mocked-relay round-trip per launch (default 4)",
+        help="soak mocked-device round-trip per launch (default 4)",
     )
     ap.add_argument(
         "--soak-real", action="store_true",
-        help="soak with live kernels instead of the mocked relay",
+        help="soak with live kernels instead of the mocked device",
     )
     ap.add_argument(
         "--soak-out", default="",
@@ -551,7 +547,7 @@ def main() -> int:
         "--devcheck",
         action="store_true",
         help="run with the TM_TPU_DEVCHECK runtime invariant checkers on "
-        "(relay-thread assertions, lock-order cycle detection, write-"
+        "(device-thread assertions, lock-order cycle detection, write-"
         "after-resolve canary); the verdict embeds the devcheck report "
         "and any violation fails the run",
     )

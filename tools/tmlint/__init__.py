@@ -1,7 +1,7 @@
 """tmlint — repo-specific static analysis for tendermint-tpu (ISSUE 8).
 
 The codebase runs on invariants that generic linters cannot see: exactly
-one dispatch-owner thread may touch the relay (ops/pipeline.py), futures
+one dispatch-owner thread may touch the device (ops/pipeline.py), futures
 must resolve to host-OWNED verdict memory (the PR-7 donation-aliasing bug
 class), simnet must stay replay-exact (no wall clock / global RNG /
 unordered-set scheduling in simnet/ and consensus/), the columnar hot

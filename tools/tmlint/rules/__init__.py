@@ -60,12 +60,12 @@ from .ingress import IngressDisciplineRule  # noqa: E402
 from .donation import DonationAliasingRule  # noqa: E402
 from .locks import LockDisciplineRule  # noqa: E402
 from .purity import HotPathPurityRule  # noqa: E402
-from .relay import RelayOwnershipRule  # noqa: E402
+from .device import DeviceOwnershipRule  # noqa: E402
 
 ALL_RULES = [
     DonationAliasingRule(),
     IngressDisciplineRule(),
-    RelayOwnershipRule(),
+    DeviceOwnershipRule(),
     FleetTransportRule(),
     SimnetDeterminismRule(),
     HotPathPurityRule(),

@@ -9,7 +9,7 @@ encoding frames by hand, or calling the codec directly to smuggle
 blocks over its own socket, forks the protocol — version negotiation,
 the oversize/malformed containment contract, metrics attribution, and
 the flow-continuation discipline all silently stop holding. Same shape
-as relay-ownership: route through fleet.client.FleetClient (or
+as device-ownership: route through fleet.client.FleetClient (or
 LoopbackSession) instead.
 
 Only the fleet codec's OWN entry-point names are flagged — generic

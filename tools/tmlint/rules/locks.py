@@ -13,17 +13,17 @@ Two shapes this repo has been burned by:
    suppression with justification.
 
 2. `threading.Thread(target=...)` where the target is a lambda (nothing
-   to audit) or, outside the relay whitelist, a same-module function
-   whose body calls relay entry points — a thread that would touch the
+   to audit) or, outside the device whitelist, a same-module function
+   whose body calls device entry points — a thread that would touch the
    device without being the dispatch-owner. The runtime twin of this
-   check is devcheck's relay-thread assertion.
+   check is devcheck's device-thread assertion.
 
 3. `fut.result()` under a mutex (ISSUE 13): a `.result()` call inside a
    `with <...mtx...>:` block parks the lock across a device round-trip.
    If the thread that completes that future ever needs the same lock
    (the ingress completer finishing CheckTx needs the mempool's `_mtx`),
    that's a deadlock, and even when it isn't, every other lock client
-   stalls for a full relay RTT. Scoped to receivers whose name contains
+   stalls for a full device RTT. Scoped to receivers whose name contains
    "mtx" — the repo's convention for state mutexes — so coordination
    locks built FOR result-collection (pipeline.py's `done_lock`) don't
    false-positive. Wait on futures outside the lock, or hand completion
@@ -50,7 +50,7 @@ from typing import Dict, Iterator
 
 from ..core import FileContext, Finding, Rule
 from . import func_name, receiver_name
-from .relay import ENTRY_POINTS, WHITELIST
+from .device import ENTRY_POINTS, WHITELIST
 
 
 def _terminal_receiver(call: ast.Call) -> str:
@@ -115,7 +115,7 @@ class LockDisciplineRule(Rule):
     name = "lock-discipline"
     description = (
         "locks are acquired via context managers (semaphores exempt); "
-        "thread targets must be auditable and relay-clean"
+        "thread targets must be auditable and device-clean"
     )
 
     def applies_to(self, relpath: str) -> bool:
@@ -132,7 +132,7 @@ class LockDisciplineRule(Rule):
         return fns
 
     @staticmethod
-    def _touches_relay(fn: ast.AST) -> bool:
+    def _touches_device(fn: ast.AST) -> bool:
         for node in ast.walk(fn):
             if isinstance(node, ast.Call) and func_name(node) in ENTRY_POINTS:
                 return True
@@ -204,14 +204,14 @@ class LockDisciplineRule(Rule):
                     yield ctx.finding(
                         self.name, node,
                         "thread target is a lambda — name the function so "
-                        "its lock/relay behavior is auditable",
+                        "its lock/device behavior is auditable",
                     )
                 elif not whitelisted and isinstance(target, ast.Name):
                     fn = local_fns.get(target.id)
-                    if fn is not None and self._touches_relay(fn):
+                    if fn is not None and self._touches_device(fn):
                         yield ctx.finding(
                             self.name, node,
-                            f"thread target `{target.id}` calls relay entry "
+                            f"thread target `{target.id}` calls device entry "
                             f"points outside the dispatcher whitelist — "
                             f"only ops/pipeline.py's dispatch-owner thread "
                             f"may touch the device",

@@ -6,7 +6,7 @@ signature. The three modules below are the columnar core; a `for` loop
 that walks signatures one Python iteration at a time (or grows a list
 with per-element .append) re-introduces exactly the per-tuple cost those
 PRs removed — at 10k signatures that is the difference between ~0.3 ms
-and ~15 ms of GIL-held host time per commit (PERF_r06).
+and ~15 ms of GIL-held host time per commit.
 
 What counts as per-element (and gets flagged):
   - `for i in range(len(x))` / `range(n)` / `range(self.n)` / `range(x.n)`
