@@ -181,7 +181,7 @@ class InstrumentationConfig:
     # and (if trace_dump_path is set, resolved under <home>) flushed as a
     # Chrome-trace JSON file on node stop. TM_TPU_TRACE=1 also enables.
     tracing: bool = False
-    trace_buffer_size: int = 16384
+    trace_buffer_size: int = 262144
     trace_dump_path: str = ""
 
 

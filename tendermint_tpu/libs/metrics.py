@@ -16,6 +16,7 @@ the global one.
 from __future__ import annotations
 
 import threading
+import time
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 from typing import Callable, Dict, List, Optional, Tuple
 
@@ -894,8 +895,6 @@ def ops_stats() -> dict:
     dispatched = sigs_device + padded
     prep_sum = m.host_prep_seconds.sum_all()
     prep_n = m.host_prep_seconds.total()
-    dev_sum = m.device_seconds.sum_all()
-    dev_n = m.device_seconds.total()
     return {
         "sigs_verified_device": int(sigs_device),
         "sigs_verified_host": int(sigs_host),
@@ -914,7 +913,6 @@ def ops_stats() -> dict:
             "dispatch_errors": int(im.dispatch_errors.total()),
         },
         "host_prep_seconds_avg": (prep_sum / prep_n) if prep_n else 0.0,
-        "device_seconds_avg": (dev_sum / dev_n) if dev_n else 0.0,
         "pipeline_queue_depth": int(m.pipeline_queue_depth.value()),
         "pipeline_inflight": int(m.pipeline_inflight.value()),
         "dispatch_queue_depth": int(m.dispatch_queue_depth.value()),
@@ -937,7 +935,31 @@ def ops_stats() -> dict:
             }
             for k, (s, c) in m.queue_wait_seconds.snapshot().items()
         },
+        "cpu_seconds_by_thread": cpu_seconds_by_thread(),
     }
+
+
+# the dispatcher's threads as ops/pipeline.py names them: verify-coalesce,
+# verify-dispatch, verify-resolve, verify-prep_<i>
+_PIPELINE_THREADS = "verify-"
+
+
+def cpu_seconds_by_thread() -> dict:
+    """CPU seconds so far: "process" (every thread, time.process_time())
+    and, by thread name, the live threads of the verify pipeline, each
+    from its own CPU clock. Read when a snapshot asks; nothing on the hot
+    path keeps it. Threads of one name (two verifiers) add up; a thread
+    that has exited is no longer counted."""
+    out = {"process": time.process_time()}
+    for t in threading.enumerate():
+        if not t.name.startswith(_PIPELINE_THREADS) or not t.is_alive():
+            continue
+        try:
+            cpu = time.clock_gettime(time.pthread_getcpuclockid(t.ident))
+        except OSError:  # the thread exited under us
+            continue
+        out[t.name] = out.get(t.name, 0.0) + cpu
+    return out
 
 
 class MetricsServer:

@@ -37,6 +37,19 @@ Causal cross-node tracing (ISSUE 10):
   event streams into ONE Chrome-trace document; flow ids are preserved
   verbatim so cross-document chains stay linked.
 
+One clock with the profiler (ISSUE 26): while the process-wide tracer is
+on, a `with` span also opens a `jax.profiler.TraceAnnotation` of the same
+name, so a `jax.profiler` capture holds the program's spans on the host
+plane of the same `.xplane.pb` as the device's `XLA Ops`, on the
+profiler's clock. Looked up lazily and only if `jax` is already imported:
+this module never imports it. Tracers on an injected clock (simnet's
+per-node ones) are excluded — their time is not the profiler's.
+
+Per-thread span args (`set_thread_args`): a thread working on one unit
+(the dispatcher's stages on one launch) names it once and every span it
+records until the next call carries the id, so the records of one launch
+join across threads without threading the id through every call.
+
 Enable via config (`[instrumentation] tracing = true`), env
 (`TM_TPU_TRACE=1`), or `configure(enabled=True)`.
 """
@@ -46,6 +59,7 @@ from __future__ import annotations
 import itertools
 import json
 import os
+import sys
 import threading
 import time
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
@@ -96,6 +110,26 @@ _node_pid_mtx = threading.Lock()
 _node_pids: Dict[int, int] = {}  # id(tracer) -> pid
 _NODE_PID_BASE = 10_000_000
 
+# The process-wide tracer's ring: a hub150 commit writes ~28 records, so
+# a 5 s stretch at 3 ms a commit is ~47k (ISSUE 26). A 2 MB list of None
+# until spans are written; ~40 MB only when full and on.
+DEFAULT_CAPACITY = 262144
+
+_annotation_cls = None
+
+
+def _profiler_annotation():
+    """jax.profiler.TraceAnnotation once `jax` is imported, else None."""
+    global _annotation_cls
+    if _annotation_cls is None:
+        jax = sys.modules.get("jax")
+        if jax is None:
+            return None
+        from jax.profiler import TraceAnnotation
+
+        _annotation_cls = TraceAnnotation
+    return _annotation_cls
+
 
 class SpanTracer:
     """Ring-buffered span recorder. One process-wide wall-clock instance
@@ -107,7 +141,10 @@ class SpanTracer:
                  epoch: Optional[float] = None):
         self.enabled = False
         self.node = node
+        # only the wall clock is the profiler's clock
+        self._annotate = now is None
         self._now = now if now is not None else time.perf_counter
+        self._thread_args = threading.local()
         # inbound-flow register: a delivery driver parks the active flow
         # id here so downstream spans (consensus.verify_dispatch) can
         # continue the chain; single-threaded drivers only
@@ -122,14 +159,21 @@ class SpanTracer:
 
     def record(self, name: str, start: float, end: float,
                args: Optional[dict] = None, flow: Optional[int] = None,
-               flow_phase: Optional[str] = None) -> None:
+               flow_phase: Optional[str] = None,
+               tid: Optional[int] = None) -> None:
         """Record one completed span (clock start/end). `flow`/`flow_phase`
-        attach a correlation id under the reserved args keys."""
+        attach a correlation id under the reserved args keys. `tid` files
+        the span under another thread than the recording one: a wait is
+        its waiter's, whoever learns when it ended."""
+        ambient = getattr(self._thread_args, "args", None)
+        if ambient:
+            args = {**ambient, **args} if args else ambient
         if flow is not None:
             args = dict(args) if args else {}
             args["flow"] = int(flow)
             args["flow_phase"] = flow_phase or "t"
-        rec = (name, start, end, threading.get_ident(), args)
+        rec = (name, start, end,
+               tid if tid is not None else threading.get_ident(), args)
         with self._mtx:
             self._buf[self._n % self._cap] = rec
             self._n += 1
@@ -141,6 +185,12 @@ class SpanTracer:
         if not self.enabled:
             return _NULL_SPAN
         return _Span(self, name, args or None, flow, flow_phase)
+
+    def set_thread_args(self, **args) -> None:
+        """Args every span THIS thread records from now on carries (a
+        span's own args win); no args clears them. Call only under an
+        `enabled` check: with tracing off nothing reads them."""
+        self._thread_args.args = args or None
 
     def flow_point(self, name: str, flow: Optional[int],
                    phase: str = "t", **args) -> None:
@@ -255,7 +305,7 @@ class SpanTracer:
 class _Span:
     """Active span: records on exit. Only built when tracing is enabled."""
 
-    __slots__ = ("_tr", "_name", "_args", "_flow", "_phase", "_t0")
+    __slots__ = ("_tr", "_name", "_args", "_flow", "_phase", "_t0", "_ann")
 
     def __init__(self, tracer: "SpanTracer", name: str, args: Optional[dict],
                  flow: Optional[int] = None, phase: Optional[str] = None):
@@ -268,6 +318,8 @@ class _Span:
     def __enter__(self) -> "_Span":
         if _devcheck.enabled():
             _devcheck.span_opened(self._name)
+        cls = _profiler_annotation() if self._tr._annotate else None
+        self._ann = cls(self._name).__enter__() if cls is not None else None
         self._t0 = self._tr._now()
         return self
 
@@ -275,6 +327,8 @@ class _Span:
         tr = self._tr
         tr.record(self._name, self._t0, tr._now(), self._args,
                   flow=self._flow, flow_phase=self._phase)
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
         # unconditional (like DevLock.release): devcheck disabled between
         # enter and exit must still pop the armed-time push. The inject
         # seam leaks ONLY this bookkeeping (the span still records) so
@@ -298,7 +352,8 @@ class _NullSpan:
 
 _NULL_SPAN = _NullSpan()
 
-TRACER = SpanTracer(int(os.environ.get("TM_TPU_TRACE_BUFFER", "16384")))
+TRACER = SpanTracer(
+    int(os.environ.get("TM_TPU_TRACE_BUFFER", str(DEFAULT_CAPACITY))))
 if os.environ.get("TM_TPU_TRACE", "0") not in ("", "0"):
     TRACER.enabled = True
 
@@ -340,12 +395,11 @@ def _percentile(sorted_vals: List[float], q: float) -> float:
 def summarize_events(trace_doc: dict) -> Dict[str, dict]:
     """Per-span-name stats over a Chrome-trace dict: count, total/p50/p95/
     p99 ms. The `_wall` pseudo-entry carries the trace's wall-clock extent
-    and `device_utilization` (fraction of wall covered by spans whose name
-    contains "device", merged across overlaps)."""
+    and its event count. (What the device did is in the profiler's trace,
+    not in host spans.)"""
     evs = trace_doc.get("traceEvents", [])
     by_name: Dict[str, List[float]] = {}
     t_min, t_max = float("inf"), float("-inf")
-    device_iv: List[Tuple[float, float]] = []
     for ev in evs:
         if ev.get("ph") != "X":
             continue
@@ -354,8 +408,6 @@ def summarize_events(trace_doc: dict) -> Dict[str, dict]:
         by_name.setdefault(ev["name"], []).append(dur)
         t_min = min(t_min, ts)
         t_max = max(t_max, ts + dur)
-        if "device" in ev["name"]:
-            device_iv.append((ts, ts + dur))
     out: Dict[str, dict] = {}
     for name, durs in sorted(by_name.items()):
         durs.sort()
@@ -367,23 +419,7 @@ def summarize_events(trace_doc: dict) -> Dict[str, dict]:
             "p99_ms": _percentile(durs, 0.99) / 1e3,
         }
     wall_us = (t_max - t_min) if evs and t_max > t_min else 0.0
-    # merge overlapping device intervals so concurrent dispatches do not
-    # count double against the wall clock
-    device_us = 0.0
-    last_e = float("-inf")
-    for s, e in sorted(device_iv):
-        if s < last_e:
-            if e > last_e:
-                device_us += e - last_e
-                last_e = e
-        else:
-            device_us += e - s
-            last_e = e
-    out["_wall"] = {
-        "wall_ms": wall_us / 1e3,
-        "device_utilization": (device_us / wall_us) if wall_us else 0.0,
-        "events": len(evs),
-    }
+    out["_wall"] = {"wall_ms": wall_us / 1e3, "events": len(evs)}
     return out
 
 

@@ -998,10 +998,19 @@ class Ed25519DeviceBatchVerifier(BatchVerifier):
         # commit verifies coalesce into full buckets and overlap host prep
         # + D2H with device compute instead of serializing RTTs.
         if n <= BUCKETS[-1]:
-            from .pipeline import shared_verifier
+            from .pipeline import resolved_at, shared_verifier
 
             with _span("ops.pipeline_wait", n=n):
-                res = shared_verifier().submit(block).result(timeout=600)
+                fut = shared_verifier().submit(block)
+                res = fut.result(timeout=600)
+                if _trace.TRACER.enabled:
+                    # the resolver's set_result -> this thread running again
+                    t_res, launch = resolved_at(fut)
+                    if t_res:
+                        _trace.TRACER.record(
+                            "ops.pipeline_wait.wake", t_res,
+                            time.perf_counter(), {"launch": launch},
+                        )
         else:
             res = verify_batch(block)
         res = np.asarray(res).astype(bool)

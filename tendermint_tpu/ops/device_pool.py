@@ -55,9 +55,12 @@ import numpy as np
 
 try:
     from ..libs import devcheck as _devcheck
+    from ..observability.trace import TRACER as _TRACER, span as _span
 except ImportError:  # pragma: no cover — standalone file load (tests on
     # crypto-less containers exec this module by path, outside the
-    # package); devcheck is stdlib+numpy so it loads the same way
+    # package); devcheck is stdlib+numpy so it loads the same way. The
+    # tracer is not loaded that way: no spans there.
+    _TRACER = None
     import importlib.util as _ilu
     import os as _os
 
@@ -103,14 +106,22 @@ def transfer(args, shardings=None) -> tuple:
     import jax
 
     if shardings is None:
-        return tuple(
-            jax.device_put(a) if isinstance(a, np.ndarray) else a
-            for a in args
-        )
-    if len(shardings) != len(args):
+        shardings = (None,) * len(args)
+    elif len(shardings) != len(args):
         raise ValueError(
             f"{len(args)} args but {len(shardings)} transfer shardings"
         )
+    if _TRACER is not None and _TRACER.enabled:
+        # one span per put: is a launch's transfer cost per operation or
+        # per byte? (the dispatcher's thread args name the launch)
+        out = []
+        for a, s in zip(args, shardings):
+            if not isinstance(a, np.ndarray):
+                out.append(a)
+                continue
+            with _span("pipeline.transfer.put", bytes=a.nbytes):
+                out.append(jax.device_put(a, s))
+        return tuple(out)
     return tuple(
         jax.device_put(a, s) if isinstance(a, np.ndarray) else a
         for a, s in zip(args, shardings)

@@ -224,7 +224,7 @@ class DispatchError(RuntimeError):
 
 class _Job:
     __slots__ = ("entries", "future", "flow", "flow_owned",
-                 "priority", "seq")
+                 "priority", "seq", "t_submit", "t_take", "tid")
 
     def __init__(self, entries: EntryBlock,
                  priority: int = PRIORITY_CONSENSUS, seq: int = 0):
@@ -244,6 +244,19 @@ class _Job:
         # earlier-arrived INGRESS jobs a CONSENSUS job overtook
         self.priority = priority
         self.seq = seq
+        # submit() instant and thread / taken-off-the-intake-queue
+        # instant, stamped only while the tracer is on:
+        # pipeline.queue_wait.intake
+        self.t_submit = 0.0
+        self.t_take = 0.0
+        self.tid = 0
+
+
+def resolved_at(future: Future) -> Tuple[float, int]:
+    """(instant the resolver completed `future`, its launch id) for a
+    future resolved while the tracer was on, else (0.0, 0): what the
+    caller's ops.pipeline_wait.wake span starts from."""
+    return getattr(future, "_tm_resolved", (0.0, 0))
 
 
 class AsyncBatchVerifier:
@@ -317,7 +330,7 @@ class AsyncBatchVerifier:
         # declared-origin attribution (ISSUE 18): fleet-server submits
         # carry each remote client's lane name; same mutex as lane counts
         self._origin_submitted: Dict[str, int] = {}
-        # (spans, prep_future, t_enqueue, priority) | None sentinel —
+        # (spans, prep_future, t_enqueue, priority, launch) | None sentinel —
         # priority-ordered so a pending consensus batch overtakes queued
         # ingress superbatches (never an in-flight launch)
         self._dispatch_q = _PriorityQueue(on_bypass=self._note_preempt)
@@ -326,6 +339,9 @@ class AsyncBatchVerifier:
         # instead of queuing behind ingress readbacks
         self._resolve_q = _PriorityQueue()
         self._job_seq = itertools.count()
+        # one id per coalesced batch, allotted by the coalescer and carried
+        # in the queue items: the spans of one launch join on it
+        self._launch_seq = itertools.count(1)
         self._stopped = threading.Event()
         self._sem = threading.Semaphore(self._depth)
         # QoS reserved lane (ISSUE 13): INGRESS batches may occupy at
@@ -377,6 +393,7 @@ class AsyncBatchVerifier:
         origin_counts(); scheduling ignores it."""
         if self._stopped.is_set():
             raise RuntimeError("verifier is closed")
+        t_submit = time.perf_counter() if _trace.TRACER.enabled else 0.0
         block = as_block(entries)
         max_b = _backend.max_coalesce()
         if self._mesh_lanes:
@@ -389,6 +406,8 @@ class AsyncBatchVerifier:
         job = _Job(block, priority=int(priority),
                    seq=next(self._job_seq))
         if _trace.TRACER.enabled:
+            job.t_submit = t_submit or time.perf_counter()
+            job.tid = threading.get_ident()
             if flow is not None:
                 # continue the CALLER's flow (ISSUE 11: the light
                 # service chains RPC arrival → epoch-group → mesh_pack →
@@ -623,10 +642,14 @@ class AsyncBatchVerifier:
         return kern, args, None, bucket
 
     @classmethod
-    def _prepare_timed(cls, entries):
+    def _prepare_timed(cls, entries, launch: int = 0):
         """_prepare plus its own completion timestamp — returned IN the
         future's value so the dispatcher's queue-wait measurement cannot
-        race the done-callback machinery."""
+        race the done-callback machinery. `launch` names the batch for
+        the spans a prep-pool thread records (the coalescer's own thread
+        already carries it)."""
+        if launch and _trace.TRACER.enabled:
+            _trace.TRACER.set_thread_args(launch=launch)
         return cls._prepare(entries), time.perf_counter()
 
     @staticmethod
@@ -647,18 +670,35 @@ class AsyncBatchVerifier:
         return res
 
     @classmethod
-    def _prepare_mesh_timed(cls, block, plan):
+    def _prepare_mesh_timed(cls, block, plan, launch: int = 0):
+        if launch and _trace.TRACER.enabled:
+            _trace.TRACER.set_thread_args(launch=launch)
         return cls._prepare_mesh(block, plan), time.perf_counter()
 
     @staticmethod
     def _resolve(spans, dev, rlc_entries=None, t_dispatch: float = 0.0,
-                 bucket: int = 0) -> None:
+                 bucket: int = 0, launch: int = 0) -> None:
+        tracing = _trace.TRACER.enabled
+        t_wait_end = 0.0
         try:
             with _span("pipeline.device_wait"):
                 # dev is a _Readback from the dispatcher (async D2H copy
                 # already in flight) or a bare device array from direct
                 # callers — both materialize here
-                arr = dev.wait() if isinstance(dev, _Readback) else np.asarray(dev)
+                if tracing:
+                    # the same materialisation, split: until the launch's
+                    # result is ready, then the device->host copy
+                    raw = dev.dev if isinstance(dev, _Readback) else dev
+                    with _span("pipeline.device_wait.kernel"):
+                        if hasattr(raw, "block_until_ready"):
+                            raw.block_until_ready()
+                    with _span("pipeline.device_wait.readback",
+                               bytes=int(getattr(raw, "nbytes", 0))):
+                        arr = np.asarray(raw)  # tmlint: disable=donation-aliasing — copied below
+                else:
+                    arr = dev.wait() if isinstance(dev, _Readback) else np.asarray(dev)
+            if tracing:
+                t_wait_end = time.perf_counter()
             if not arr.flags.owndata:
                 # np.asarray of a device array is a zero-copy VIEW of the
                 # XLA output buffer on the CPU backend. Under donation the
@@ -702,7 +742,13 @@ class AsyncBatchVerifier:
         # the batch verdict array — no per-entry Python anywhere between
         # the device result and the caller's future
         for job, off, n in spans:
+            if tracing:
+                # stamped BEFORE completion: the woken caller reads it
+                job.future._tm_resolved = (time.perf_counter(), launch)
             job.future.set_result(arr[off : off + n])
+        if tracing:
+            _trace.TRACER.record("pipeline.resolve", t_wait_end,
+                                 time.perf_counter(), {"jobs": len(spans)})
         if _trace.TRACER.enabled:
             for job, _off, n in spans:
                 if getattr(job, "flow", None) is not None:
@@ -711,6 +757,34 @@ class AsyncBatchVerifier:
                         "f" if getattr(job, "flow_owned", True) else "t",
                         n=n,
                     )
+
+    @staticmethod
+    def _trace_taken(job: _Job, launch: int) -> float:
+        """Tracer on, a batch's first job in hand: names the launch for
+        every span this thread records until the next batch, stamps a job
+        fresh off the intake queue (a held one was stamped when drained)
+        and returns the instant pipeline.coalesce starts from."""
+        _trace.TRACER.set_thread_args(launch=launch)
+        now = time.perf_counter()
+        if not job.t_take:
+            job.t_take = now
+        return now
+
+    @staticmethod
+    def _trace_coalesced(jobs, t0: float, sigs: int, bucket: int) -> None:
+        """Tracer on, the batch handed to the dispatcher: each job's wait
+        on the intake queue (submit -> taken off it), then the coalescing
+        itself (drain, linger, bucket-fit, concat, inline prep). The wait
+        is filed under the SUBMITTING thread, inside its blocking span:
+        on this thread it would lie over the previous batch's prep and
+        hide that work from whoever reads the thread's innermost span."""
+        rec = _trace.TRACER.record
+        for j in jobs:
+            if j.t_submit and j.t_take:
+                rec("pipeline.queue_wait.intake", j.t_submit, j.t_take,
+                    tid=j.tid)
+        rec("pipeline.coalesce", t0, time.perf_counter(),
+            {"jobs": len(jobs), "sigs": sigs, "bucket": bucket})
 
     def _worker(self) -> None:
         """Coalescer: many small commits (e.g. 128-signature headers
@@ -750,6 +824,10 @@ class AsyncBatchVerifier:
                         if self._stopped.is_set() and self._q.empty():
                             break
                         continue
+                launch = next(self._launch_seq)
+                tracing = _trace.TRACER.enabled
+                if tracing:
+                    t_c0 = self._trace_taken(job, launch)
                 jobs = [job]
                 total = len(job.entries)
                 # epoch-key gate: only jobs sharing a (non-None) epoch
@@ -783,9 +861,12 @@ class AsyncBatchVerifier:
                         if wait <= 0:
                             break
                         try:
-                            nxt = self._q.get(timeout=wait)
+                            with _span("pipeline.queue_wait.linger"):
+                                nxt = self._q.get(timeout=wait)
                         except queue.Empty:
                             break
+                    if tracing:
+                        nxt.t_take = time.perf_counter()
                     if (
                         total + len(nxt.entries) > limit
                         or nxt.entries.epoch_key != key0
@@ -850,10 +931,17 @@ class AsyncBatchVerifier:
                     except BaseException as e:  # noqa: BLE001
                         fut.set_exception(e)
                 else:
-                    fut = prep_pool.submit(self._prepare_timed, entries)
+                    fut = prep_pool.submit(
+                        self._prepare_timed, entries, launch
+                    )
                 self._dispatch_q.put(
-                    (spans, fut, time.perf_counter(), pri), priority=pri
+                    (spans, fut, time.perf_counter(), pri, launch),
+                    priority=pri,
                 )
+                if tracing:
+                    self._trace_coalesced(
+                        jobs, t_c0, total, _backend.quantized_bucket(total)
+                    )
                 m.dispatch_queue_depth.set(self._dispatch_q.qsize())
                 m.pipeline_queue_depth.set(self._q.qsize())
         finally:
@@ -888,6 +976,10 @@ class AsyncBatchVerifier:
                         if self._stopped.is_set() and self._q.empty():
                             break
                         continue
+                launch = next(self._launch_seq)
+                tracing = _trace.TRACER.enabled
+                if tracing:
+                    t_c0 = self._trace_taken(jobs[0], launch)
                 # cap re-read per superbatch: submit() reads it per call,
                 # so a knob change mid-run must not strand a job that was
                 # legal when it was accepted
@@ -907,9 +999,12 @@ class AsyncBatchVerifier:
                         if wait <= 0:
                             break
                         try:
-                            nxt = self._q.get(timeout=wait)
+                            with _span("pipeline.queue_wait.linger"):
+                                nxt = self._q.get(timeout=wait)
                         except queue.Empty:
                             break
+                    if tracing:
+                        nxt.t_take = time.perf_counter()
                     jobs.append(nxt)
                     total += len(nxt.entries)
                 # Coalescer survival invariant (the dispatcher's PR-6
@@ -956,7 +1051,7 @@ class AsyncBatchVerifier:
                     m.mesh_lane_occupancy.set(plan.occupancy())
                     m.mesh_pad_waste_ratio.set(plan.pad_ratio())
                     fut = prep_pool.submit(
-                        self._prepare_mesh_timed, block, plan
+                        self._prepare_mesh_timed, block, plan, launch
                     )
                 except Exception as e:  # noqa: BLE001 — pack isolation
                     self._fail_spans(
@@ -969,9 +1064,13 @@ class AsyncBatchVerifier:
                     held = []
                     continue
                 self._dispatch_q.put(
-                    (spans, fut, time.perf_counter(), min_pri),
+                    (spans, fut, time.perf_counter(), min_pri, launch),
                     priority=min_pri,
                 )
+                if tracing:
+                    self._trace_coalesced(
+                        [j for j, _, _ in spans], t_c0, plan.live, plan.bucket
+                    )
                 m.dispatch_queue_depth.set(self._dispatch_q.qsize())
                 m.pipeline_queue_depth.set(self._q.qsize())
         finally:
@@ -1002,6 +1101,7 @@ class AsyncBatchVerifier:
         # idle stretch, hiding device saturation from /status
         busy = _dpool.WindowedRatio(m.dispatch_busy_ratio, wall=True)
         overlap = _dpool.WindowedRatio(m.transfer_overlap_ratio, wall=False)
+        t_free = 0.0  # this thread's last launch: a hand-off starts no earlier
         while True:
             try:
                 item = self._dispatch_q.get(timeout=2.0)
@@ -1021,13 +1121,16 @@ class AsyncBatchVerifier:
                 # block (ISSUE 13) — its pool slot and device buffers
                 # carry over; it re-enters directly at the launch stage
                 (_tag, spans, f, dev_args, rlc_entries, bucket,
-                 xslot, t_enq, pri, t_xfer_done) = item
+                 xslot, t_enq, pri, t_xfer_done, launch) = item
                 fut = None
             else:
                 spans, fut, t_enq = item[:3]
                 pri = item[3] if len(item) > 3 else PRIORITY_CONSENSUS
+                launch = item[4] if len(item) > 4 else 0
                 xslot = None
                 t_xfer_done = 0.0
+            if _trace.TRACER.enabled:
+                _trace.TRACER.set_thread_args(launch=launch)
             # Dispatcher survival invariant: NOTHING a single batch does —
             # prep failure, metrics accounting, the transfer, epoch-table
             # upload inside the kernel closure, the launch itself — may
@@ -1060,7 +1163,8 @@ class AsyncBatchVerifier:
                             best = self._dispatch_q.best_priority()
                             if best is not None and best < pri:
                                 self._dispatch_q.put(
-                                    (spans, fut, t_enq, pri), priority=pri
+                                    (spans, fut, t_enq, pri, launch),
+                                    priority=pri,
                                 )
                                 self._note_preempt(1)
                                 requeued = True
@@ -1102,23 +1206,29 @@ class AsyncBatchVerifier:
                         )
                         hidden = self._inflight > 0
                         t_x0 = time.perf_counter()
+                        if _trace.TRACER.enabled:
+                            # the hand-off to this thread (and the pool
+                            # slot): prepared, enqueued and this thread
+                            # free -> transfer. Behind a busy dispatcher
+                            # the batch's wait is the earlier launch's spans
+                            _trace.TRACER.record(
+                                "pipeline.queue_wait.dispatch",
+                                max(t_enq, t_ready, t_free), t_x0,
+                            )
                         # positional call when unsharded: test doubles
                         # (and any older transfer impl) keep their
                         # (args)-only signature working
-                        if shardings is None:
-                            dev_args = _dpool.transfer(args)
-                        else:
-                            dev_args = _dpool.transfer(
-                                args, shardings=shardings
-                            )
+                        with _span("pipeline.transfer", bucket=bucket,
+                                   hidden=int(hidden)):
+                            if shardings is None:
+                                dev_args = _dpool.transfer(args)
+                            else:
+                                dev_args = _dpool.transfer(
+                                    args, shardings=shardings
+                                )
                         t_x1 = time.perf_counter()
                         if slot is not None:
                             slot.arrays = dev_args
-                        if _trace.TRACER.enabled:
-                            _trace.TRACER.record(
-                                "pipeline.transfer", t_x0, t_x1,
-                                {"bucket": bucket, "hidden": int(hidden)},
-                            )
                         overlap.add(
                             t_x1 - t_x0 if hidden else 0.0, t_x1 - t_x0
                         )
@@ -1170,7 +1280,8 @@ class AsyncBatchVerifier:
                         if best is not None and best < pri:
                             self._dispatch_q.put(
                                 ("xfered", spans, f, dev_args, rlc_entries,
-                                 bucket, slot, t_enq, pri, t_xfer_done),
+                                 bucket, slot, t_enq, pri, t_xfer_done,
+                                 launch),
                                 priority=pri,
                             )
                             slot = None  # rode along with the item
@@ -1184,7 +1295,8 @@ class AsyncBatchVerifier:
                         if best is not None and best < pri:
                             self._dispatch_q.put(
                                 ("xfered", spans, f, dev_args, rlc_entries,
-                                 bucket, slot, t_enq, pri, t_xfer_done),
+                                 bucket, slot, t_enq, pri, t_xfer_done,
+                                 launch),
                                 priority=pri,
                             )
                             slot = None  # ownership rode along
@@ -1231,7 +1343,8 @@ class AsyncBatchVerifier:
                     # _resolve finds the bytes already host-side.
                     # Capability probed ONCE at init
                     # (_d2h_async_supported) — no silent per-batch except.
-                    rb = _Readback(dev, self._d2h_async)
+                    with _span("pipeline.dispatch.readback_start"):
+                        rb = _Readback(dev, self._d2h_async)
                 except Exception as e:  # noqa: BLE001
                     # epoch-table upload (lazy, inside the cached-kernel
                     # closure) or the launch itself blew up: release the
@@ -1249,10 +1362,11 @@ class AsyncBatchVerifier:
                 with self._mtx:
                     self._inflight += 1
                     m.pipeline_inflight.set(self._inflight)
-                now = time.perf_counter()
+                t_free = now = time.perf_counter()
                 busy.add(now - t0)
                 self._resolve_q.put(
-                    (spans, rb, rlc_entries, now, bucket, slot, ing_held),
+                    (spans, rb, rlc_entries, now, bucket, slot, ing_held,
+                     launch),
                     priority=pri,
                 )
                 sem_held = False  # resolver now owns the release
@@ -1291,12 +1405,20 @@ class AsyncBatchVerifier:
         returns each batch's buffer-pool slot — the input buffers' flight
         ends when the verdicts are read back (or the batch fails)."""
         m = _backend._ops_m()
+        t_free = 0.0  # this thread's last resolve: a hand-off starts no earlier
         while True:
             item = self._resolve_q.get()
             if item is None:
                 break
             spans, rb, rlc_entries, t_dispatch, bucket, slot = item[:6]
             ing_held = item[6] if len(item) > 6 else False
+            launch = item[7] if len(item) > 7 else 0
+            tracing = _trace.TRACER.enabled
+            if tracing:
+                _trace.TRACER.set_thread_args(launch=launch)
+                _trace.TRACER.record("pipeline.queue_wait.resolve",
+                                     max(t_dispatch, t_free),
+                                     time.perf_counter())
             if _devcheck.inject_lintbug("owner"):
                 # test seam (ISSUE 8): touch the device from the resolver
                 # thread — devcheck's ownership assertion must fire
@@ -1305,7 +1427,8 @@ class AsyncBatchVerifier:
                 except _devcheck.DevcheckViolation:
                     pass  # recorded; the injected run continues
             try:
-                self._resolve(spans, rb, rlc_entries, t_dispatch, bucket)
+                self._resolve(spans, rb, rlc_entries, t_dispatch, bucket,
+                              launch)
             finally:
                 self._pool.release(slot)
                 with self._mtx:
@@ -1314,6 +1437,8 @@ class AsyncBatchVerifier:
                 self._sem.release()
                 if ing_held:
                     self._ing_sem.release()
+            if tracing:
+                t_free = time.perf_counter()
 
 
 _shared: Optional[AsyncBatchVerifier] = None

@@ -464,40 +464,27 @@ def test_smoke_exports_merged_trace_with_cross_node_chain(tmp_path):
 
 
 def test_disabled_overhead_guard_covers_flow_spans():
-    """Satellite 6: the <2% tracing-disabled overhead guard, extended to
-    flow-carrying span sites, wired tier-1 without the OpenSSL wheel —
-    the reference cost is a single pure-Python ed25519 verify (~3 ms,
-    ~20x STRICTER than the device-batch wall clock the in-wheel guard
-    divides by)."""
+    """The tracing-disabled overhead guard, wired tier-1 without the
+    OpenSSL wheel (a subprocess on the pure-Python signer): the best-of-k
+    cost of a disabled site, flow-carrying spans and flow points included,
+    times the sites one commit crosses on its way through the pipeline
+    (counted with the tracer on), under 0.2 % of the 5.0 ms a hub150
+    commit takes on the chip (ledger, PR 23). No crypto call's speed is
+    the yardstick: that is what made this guard fail when verification
+    went native."""
     code = r"""
-import time
-from tendermint_tpu.crypto import ed25519
-from tendermint_tpu.observability import trace as tr
+import sys
+sys.path.insert(0, %r)
+import _launch_trace as lt
 
-sk = ed25519.gen_priv_key(b"\x07" * 32)
-msg = b"overhead-guard"
-sig = sk.sign(msg)
-assert ed25519.verify_zip215_fast(sk.pub_key().bytes(), msg, sig)
-t0 = time.perf_counter()
-for _ in range(10):
-    ed25519.verify_zip215_fast(sk.pub_key().bytes(), msg, sig)
-verify_s = (time.perf_counter() - t0) / 10
-
-assert not tr.TRACER.enabled
-n = 20000
-t0 = time.perf_counter()
-for i in range(n):
-    with tr.span("x", n=64, bucket=128, flow=123, flow_phase="t"):
-        pass
-    tr.TRACER.flow_point("pipeline.submit", 123, "s", n=64)
-per_site = (time.perf_counter() - t0) / (2 * n)
-# ~10 instrument sites fire per verify_batch dispatch
-assert per_site * 10 < 0.02 * verify_s, (per_site, verify_s)
-print("OK", per_site, verify_s)
-"""
+records, _names = lt.traced_commit()
+flows = [r for r in records if r[4] and "flow" in r[4]]
+assert len(flows) >= 3, flows
+print("OK", *lt.assert_off_cost_within_budget(records))
+""" % HERE
     r = subprocess.run(
         [sys.executable, "-c", code],
-        capture_output=True, env=_purepy_env(), cwd=REPO, timeout=120,
+        capture_output=True, env=_purepy_env(), cwd=REPO, timeout=300,
     )
     out = (r.stdout or b"").decode(errors="replace")
     err = (r.stderr or b"").decode(errors="replace")

@@ -222,7 +222,7 @@ class TestTracer:
             assert [e[0] for e in evs] == [f"s{i}" for i in range(24, 40)]
             assert tr.TRACER.recorded_total == 40
         finally:
-            tr.TRACER.configure(capacity=16384)
+            tr.TRACER.configure(capacity=tr.DEFAULT_CAPACITY)
 
     def test_chrome_export_valid_json(self, tmp_path):
         tr.configure(enabled=True)
@@ -246,12 +246,11 @@ class TestTracer:
         path = tr.TRACER.dump(str(tmp_path / "trace.json"))
         assert json.load(open(path)) == doc
 
-    def test_summary_percentiles_and_device_utilization(self):
+    def test_summary_percentiles_and_wall_extent(self):
         doc = {
             "traceEvents": [
                 {"name": "host_prep", "ph": "X", "ts": 0.0, "dur": 100.0},
                 {"name": "device_wait", "ph": "X", "ts": 100.0, "dur": 850.0},
-                # overlapping device span must not double-count
                 {"name": "device_wait", "ph": "X", "ts": 500.0, "dur": 450.0},
             ]
         }
@@ -259,9 +258,10 @@ class TestTracer:
         assert s["host_prep"]["count"] == 1
         assert s["device_wait"]["count"] == 2
         assert s["device_wait"]["p50_ms"] == pytest.approx(0.65)
-        wall = s["_wall"]
-        assert wall["wall_ms"] == pytest.approx(0.95)
-        assert wall["device_utilization"] == pytest.approx(850 / 950)
+        assert s["device_wait"]["total_ms"] == pytest.approx(1.3)
+        # host spans say nothing about the device: the wall entry carries
+        # the extent and the event count, no utilization guess
+        assert s["_wall"] == {"wall_ms": pytest.approx(0.95), "events": 3}
 
     def test_trace_report_cli(self, tmp_path, capsys):
         import sys
@@ -283,7 +283,8 @@ class TestTracer:
         assert trace_report.main([path]) == 0
         out = capsys.readouterr().out
         assert "ops.host_prep" in out and "ops.device_wait" in out
-        assert "device utilization" in out
+        assert "wall clock" in out and "flow chains" in out
+        assert "device utilization" not in out
         assert trace_report.main([path, "--json"]) == 0
         doc = json.loads(capsys.readouterr().out)
         assert doc["ops.device_wait"]["count"] == 5
@@ -377,39 +378,22 @@ class TestHotPathInstrumentation:
         assert m.ops_metrics().host_fallback.total() == before + 1
 
     @needs_wheel
-    def test_tracing_disabled_overhead_guard(self, monkeypatch):
-        """Tracing off must cost ~nothing on verify_batch: the per-call
-        instrument overhead (the ~10 null-span entries a verify_batch
-        dispatch walks through) must be < 2% of the measured verify_batch
-        wall clock. Extended over flow-event sites (ISSUE 10): a span
-        carrying flow kwargs and a flow_point both take the same
-        single-attribute-check disabled path."""
-        from tendermint_tpu.ops import backend
+    def test_tracing_disabled_overhead_guard(self):
+        """Tracing off must cost ~nothing where the driver measures it:
+        the best-of-k cost of a disabled site (a `with` span with kwargs
+        and a flow id, a guarded flow point), times the sites one commit
+        crosses (counted by verifying one commit through the pipeline
+        with the tracer on, the launch path's hand-off, per-put, kernel/
+        readback and resolve sites included), must stay under 0.2 % of
+        the 5.0 ms a hub150 commit takes on the chip (ledger, PR 23)."""
+        import _launch_trace as lt
 
-        monkeypatch.setenv("TM_TPU_PALLAS", "0")
-        backend.engine.cache_clear()
-        try:
-            assert not tr.TRACER.enabled
-            entries = _entries(64)
-            backend.verify_batch(entries)  # warm compile
-            t0 = time.perf_counter()
-            for _ in range(3):
-                backend.verify_batch(entries)
-            verify_s = (time.perf_counter() - t0) / 3
-
-            n_ops = 10_000
-            t0 = time.perf_counter()
-            for _ in range(n_ops):
-                with tr.span("x", n=64, bucket=128):
-                    pass
-                with tr.span("y", flow=123, flow_phase="t", bucket=128):
-                    pass
-                tr.TRACER.flow_point("z", 123, "s", n=64)
-            per_span = (time.perf_counter() - t0) / (3 * n_ops)
-            # ~10 instrument sites fire per verify_batch dispatch
-            assert per_span * 10 < 0.02 * verify_s, (per_span, verify_s)
-        finally:
-            backend.engine.cache_clear()
+        records, _names = lt.traced_commit()
+        names = {r[0] for r in records}
+        assert {"pipeline.queue_wait.intake", "pipeline.coalesce",
+                "pipeline.transfer.put", "pipeline.device_wait.kernel",
+                "pipeline.resolve", "ops.pipeline_wait.wake"} <= names
+        lt.assert_off_cost_within_budget(records)
 
     @needs_wheel
     def test_pipeline_records_metrics(self):

@@ -12,8 +12,7 @@ instrumentation.trace_dump_path, what `tools/simnet_run.py --trace`
 exports (already merged per cluster), or any hand-rolled
 observability.trace.TRACER.dump() output. Prints a per-span table
 (count, total, p50/p95/p99 ms, sorted by total ms — `--top N` keeps the
-N heaviest rows) plus the wall-clock extent, device utilization and the
-flow-chain count; --json emits the same summary as one JSON object.
+N heaviest rows) plus the wall-clock extent and the flow-chain count; --json emits the same summary as one JSON object.
 
 `--merge` (ISSUE 10) re-keys pids and concatenates several documents
 into one (written to `--out` when given) before summarizing — the
@@ -113,7 +112,6 @@ def main(argv=None) -> int:
     if dropped:
         print(f"(… {dropped} lighter span name(s) below --top {args.top})")
     print(f"wall clock: {wall['wall_ms']:.3f} ms over {wall['events']} events; "
-          f"device utilization: {wall['device_utilization'] * 100:.1f}%; "
           f"flow chains: {len(chains)} ({cross} cross-process)")
     return 0
 
