@@ -2433,6 +2433,204 @@ static PyObject *py_commit_prep_fused(PyObject *, PyObject *args) {
   return tup;
 }
 
+// --------------------------------------------------------------------------
+// commit_decode_columns(data: bytes)
+//   -> None
+//   -> (height, round, block_id_raw, n, flags (n u8), sig (n*64),
+//       ts_seconds (n*8 LE i64), ts_nanos (n*4 LE i32), addr (n*20))
+//
+// The wire bytes of a Commit (types/block.go:744; proto fields 1 height,
+// 2 round, 3 block_id, 4 repeated CommitSig) parsed straight into the
+// CommitBlock columns commit_prep_fused consumes, in one GIL-released
+// walk. The fast path of types/block.py Commit.decode, whose Python walk
+// (wire/proto.decode_message + _decode_sig_record) is the specification:
+// this pass takes a strict subset of what that walk takes, computes the
+// same values for it, and returns None for everything else — it never
+// raises on content and never guesses. Taken:
+//   outer   one-byte tags only, fields 1, 2 (varint) and 3 (bytes) at most
+//           once, field 4 (bytes) repeated, in ascending order;
+//   record  _decode_sig_record's canonical shape: fields 1 (varint), 2, 3,
+//           4 (bytes) at most once each in any order; the timestamp of
+//           varint fields 1, 2 at most once each; ABSENT lanes with no
+//           address, no signature and the Go zero time; COMMIT/NIL lanes
+//           with exactly 20 + 64 bytes; flag in {1, 2, 3};
+//   varint  at most 10 bytes holding at most 64 bits (the Python walk
+//           reads up to 70 bits into an unbounded int).
+// Absent lanes are zero-filled. No state is kept between calls.
+namespace commitdec {
+
+static const int64_t GO_ZERO_TIME_SECONDS = -62135596800LL;
+
+static inline bool uvarint(const uint8_t *&p, const uint8_t *end,
+                           uint64_t &out) {
+  uint64_t v = 0;
+  for (int shift = 0; shift < 64; shift += 7) {
+    if (p >= end) return false;
+    uint8_t b = *p++;
+    if (shift == 63 && b > 1) return false;
+    v |= (uint64_t)(b & 0x7f) << shift;
+    if (!(b & 0x80)) {
+      out = v;
+      return true;
+    }
+  }
+  return false;
+}
+
+// a length-delimited value lying wholly before `end`
+static inline bool delimited(const uint8_t *&p, const uint8_t *end,
+                             const uint8_t *&body, size_t &len) {
+  uint64_t ln;
+  if (!uvarint(p, end, ln) || ln > (uint64_t)(end - p)) return false;
+  body = p;
+  len = (size_t)ln;
+  p += ln;
+  return true;
+}
+
+struct Outer {
+  uint64_t height = 0, round = 0;
+  const uint8_t *block_id = nullptr;
+  size_t block_id_len = 0;
+  const uint8_t *records = nullptr;  // tag byte of the first field 4
+  size_t n = 0;
+};
+
+static bool scan_outer(const uint8_t *p, const uint8_t *end, Outer &o) {
+  int last = 0;
+  const uint8_t *body;
+  size_t len;
+  while (p < end) {
+    const uint8_t *at = p;
+    uint8_t tag = *p++;
+    if (tag == 0x22) {
+      if (last < 4) o.records = at;
+      last = 4;
+      if (!delimited(p, end, body, len)) return false;
+      o.n++;
+    } else if (tag == 0x08 && last < 1) {
+      last = 1;
+      if (!uvarint(p, end, o.height)) return false;
+    } else if (tag == 0x10 && last < 2) {
+      last = 2;
+      if (!uvarint(p, end, o.round)) return false;
+    } else if (tag == 0x1a && last < 3) {
+      last = 3;
+      if (!delimited(p, end, o.block_id, o.block_id_len)) return false;
+    } else {
+      return false;
+    }
+  }
+  return true;
+}
+
+static bool parse_timestamp(const uint8_t *p, const uint8_t *end,
+                            int64_t &secs, int32_t &nanos) {
+  unsigned seen = 0;
+  uint64_t v;
+  while (p < end) {
+    uint8_t tag = *p++;
+    unsigned bit = tag == 0x08 ? 1u : tag == 0x10 ? 2u : 0u;
+    if (!bit || (seen & bit) || !uvarint(p, end, v)) return false;
+    seen |= bit;
+    if (bit == 1)
+      secs = (int64_t)v;  // wire/proto.to_signed64
+    else
+      nanos = (int32_t)(uint32_t)v;  // wire/proto.to_signed32
+  }
+  return true;
+}
+
+static bool parse_record(const uint8_t *p, const uint8_t *end, uint8_t *flag,
+                         uint8_t *sig, int64_t *secs, int32_t *nanos,
+                         uint8_t *addr) {
+  uint64_t f = 0;
+  const uint8_t *a = nullptr, *s = nullptr, *t = nullptr;
+  size_t alen = 0, slen = 0, tlen = 0;
+  unsigned seen = 0;
+  while (p < end) {
+    uint8_t tag = *p++;
+    unsigned bit;
+    bool ok;
+    switch (tag) {
+      case 0x08: bit = 1; ok = uvarint(p, end, f); break;
+      case 0x12: bit = 2; ok = delimited(p, end, a, alen); break;
+      case 0x1a: bit = 4; ok = delimited(p, end, t, tlen); break;
+      case 0x22: bit = 8; ok = delimited(p, end, s, slen); break;
+      default: return false;
+    }
+    if (!ok || (seen & bit)) return false;
+    seen |= bit;
+  }
+  int64_t sec = 0;
+  int32_t nan = 0;
+  if ((seen & 4) && !parse_timestamp(t, t + tlen, sec, nan)) return false;
+  if (f == 1) {  // BLOCK_ID_FLAG_ABSENT
+    if (alen || slen || sec != GO_ZERO_TIME_SECONDS || nan != 0) return false;
+    memset(sig, 0, 64);
+    memset(addr, 0, 20);
+  } else if (f == 2 || f == 3) {  // COMMIT, NIL
+    if (alen != 20 || slen != 64) return false;
+    memcpy(sig, s, 64);
+    memcpy(addr, a, 20);
+  } else {
+    return false;
+  }
+  *flag = (uint8_t)f;
+  *secs = sec;
+  *nanos = nan;
+  return true;
+}
+
+}  // namespace commitdec
+
+static PyObject *py_commit_decode_columns(PyObject *, PyObject *arg) {
+  // bytes only: the Python walk hands slices of its input on (bytearray
+  // and memoryview slices are other types than these columns' bytes)
+  if (!PyBytes_Check(arg)) Py_RETURN_NONE;
+  const uint8_t *data = (const uint8_t *)PyBytes_AS_STRING(arg);
+  const uint8_t *end = data + PyBytes_GET_SIZE(arg);
+  commitdec::Outer o;
+  // columns in one block: ts_seconds | ts_nanos | sig | addr | flags, so
+  // the 8- and 4-byte lanes are aligned; copied out under the GIL below
+  uint8_t *cols = nullptr;
+  bool ok;
+  Py_BEGIN_ALLOW_THREADS
+  ok = commitdec::scan_outer(data, end, o);
+  if (ok && o.n) {
+    cols = (uint8_t *)malloc(o.n * (8 + 4 + 64 + 20 + 1));
+    ok = cols != nullptr;
+    int64_t *secs = (int64_t *)cols;
+    int32_t *nanos = (int32_t *)(cols + o.n * 8);
+    uint8_t *sig = cols + o.n * 12, *addr = cols + o.n * 76,
+            *flags = cols + o.n * 96;
+    const uint8_t *p = o.records, *body;
+    size_t len;
+    for (size_t i = 0; ok && i < o.n; i++) {
+      p++;  // the 0x22 tag scan_outer saw
+      ok = commitdec::delimited(p, end, body, len) &&
+           commitdec::parse_record(body, body + len, flags + i, sig + 64 * i,
+                                   secs + i, nanos + i, addr + 20 * i);
+    }
+  }
+  Py_END_ALLOW_THREADS
+  if (!ok) {
+    free(cols);
+    Py_RETURN_NONE;
+  }
+  // "y#" turns a null pointer into None: empty values point at ""
+  const char *c = cols ? (const char *)cols : "";
+  const char *bid = o.block_id ? (const char *)o.block_id : "";
+  Py_ssize_t n = (Py_ssize_t)o.n;
+  PyObject *tup = Py_BuildValue(
+      "(Liy#ny#y#y#y#y#)", (long long)(int64_t)o.height,
+      (int)(int32_t)(uint32_t)o.round, bid, (Py_ssize_t)o.block_id_len, n,
+      c + n * 96, n, c + n * 12, n * 64, c, n * 8, c + n * 8, n * 4,
+      c + n * 76, n * 20);
+  free(cols);
+  return tup;
+}
+
 static PyMethodDef Methods[] = {
     {"commit_prep_fused", py_commit_prep_fused, METH_VARARGS,
      "Fused columnar commit prep: selection + tally + sign-bytes + "
@@ -2465,6 +2663,9 @@ static PyMethodDef Methods[] = {
      "Batch merlin signing-transcript challenges for sr25519 verification"},
     {"pack_bits_le", py_pack_bits_le, METH_VARARGS,
      "pack 32B LE scalars into transposed bit arrays"},
+    {"commit_decode_columns", py_commit_decode_columns, METH_O,
+     "Commit wire bytes -> CommitBlock columns in one GIL-released walk; "
+     "None for any input off the canonical shape"},
     {nullptr, nullptr, 0, nullptr}};
 
 static struct PyModuleDef moduledef = {PyModuleDef_HEAD_INIT, "tm_native",

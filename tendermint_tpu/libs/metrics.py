@@ -675,6 +675,14 @@ class OpsMetrics:
             "ops", "epoch_cache_evictions_total",
             "Validator-set epochs evicted from the device cache (LRU).",
         )
+        # wire decode (types/block.py Commit.decode): which path parsed
+        # the commit — native = commit_decode_columns took the bytes,
+        # python = it answered None (off the canonical shape) or the
+        # module is absent; the hit share of a deployment's own traffic
+        self.commit_decodes = registry.counter(
+            "ops", "commit_decodes_total",
+            "Commits decoded from wire bytes, by path label (native|python).",
+        )
         self.h2d_bytes_per_commit = registry.gauge(
             "ops", "h2d_bytes_per_commit",
             "Host bytes shipped to the device by the last dispatched "
@@ -920,6 +928,8 @@ def ops_stats() -> dict:
         "epoch_cache_hits": int(m.epoch_cache_hits.total()),
         "epoch_cache_misses": int(m.epoch_cache_misses.total()),
         "epoch_cache_evictions": int(m.epoch_cache_evictions.total()),
+        "commit_decode_native": int(m.commit_decodes.value(path="native")),
+        "commit_decode_python": int(m.commit_decodes.value(path="python")),
         "h2d_bytes_per_commit": float(m.h2d_bytes_per_commit.value()),
         "transfer_overlap_ratio": float(m.transfer_overlap_ratio.value()),
         "buffer_pool_hits": int(m.buffer_pool_hits.total()),

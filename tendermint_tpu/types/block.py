@@ -567,7 +567,14 @@ def _decode_commit_sigs(raws: List[bytes]):
     """Decode a commit's signature records COLUMNAR-FIRST: one pass fills
     CommitBlock columns and the result is a lazy CommitSigs view. Any
     non-canonical record falls the whole commit back to plain CommitSig
-    objects (identical to the pre-columnar decode)."""
+    objects (identical to the pre-columnar decode).
+
+    This walk, with _decode_sig_record under it, is the SPECIFICATION of
+    the canonical shape and of everything off it (tolerance, fallback,
+    exception types and messages). The fast path — native/tm_native.cpp
+    commit_decode_columns, tried first by Commit.decode — takes a subset
+    of the canonical inputs, must give these columns for it, and hands
+    every other input back here."""
     n = len(raws)
     if n == 0:
         return []
@@ -594,6 +601,51 @@ def _decode_commit_sigs(raws: List[bytes]):
         ).reshape(n, 20),
     )
     return CommitSigs(block)
+
+
+_OPS = None
+
+
+def _native_commit_columns(data):
+    """The native parse of a Commit's wire bytes (Commit.decode's fast
+    path): its column tuple, or None where the module is absent or the
+    input is off the canonical shape. Counts the commit under the path
+    that decodes it."""
+    global _OPS
+    from ..native import load as _load_native
+
+    native = _load_native()
+    cols = None
+    if native is not None and hasattr(native, "commit_decode_columns"):
+        cols = native.commit_decode_columns(data)
+    if _OPS is None:
+        from ..libs import metrics as _metrics
+
+        _OPS = _metrics.ops_metrics()
+    _OPS.commit_decodes.inc(path="python" if cols is None else "native")
+    return cols
+
+
+def _commit_sigs_from_columns(n, flags, sig, secs, nanos, addr):
+    """commit_decode_columns' buffers as `signatures`: the CommitBlock
+    _decode_commit_sigs builds (same dtypes and shapes; read-only views
+    of the buffers), behind a lazy CommitSigs view."""
+    if n == 0:
+        return []
+    import numpy as np
+
+    from ..ops.entry_block import CommitBlock
+
+    return CommitSigs(
+        CommitBlock(
+            flags=np.frombuffer(flags, dtype=np.uint8),
+            val_idx=np.arange(n, dtype=np.int32),
+            sig=np.frombuffer(sig, dtype=np.uint8).reshape(n, 64),
+            ts_seconds=np.frombuffer(secs, dtype=np.int64),
+            ts_nanos=np.frombuffer(nanos, dtype=np.int32),
+            addr=np.frombuffer(addr, dtype=np.uint8).reshape(n, 20),
+        )
+    )
 
 
 @dataclass
@@ -783,7 +835,28 @@ class Commit:
         """Columnar-from-decode: canonical-shaped signature records parse
         straight into CommitBlock columns (ONE pass, no CommitSig or
         Timestamp objects); `signatures` is a lazy view over them. A
-        non-canonical commit decodes to plain objects as before."""
+        non-canonical commit decodes to plain objects as before.
+
+        Two paths, one result. The FAST path is native/tm_native.cpp
+        commit_decode_columns: the whole message walked once with the GIL
+        released, every record parsed and shape-checked on every call,
+        nothing kept between calls. It answers None for any input off the
+        canonical shape (and is absent under TM_TPU_NO_NATIVE or a failed
+        build); then the Python walk below runs — the SPECIFICATION, which
+        alone decides tolerance, the plain-CommitSig fallback and every
+        exception. ops_stats() counts the commits each path decoded
+        (commit_decode_native / commit_decode_python)."""
+        cols = _native_commit_columns(data)
+        if cols is not None:
+            height, round_, block_id_raw, n, flags, sig, secs, nanos, addr = cols
+            return cls(
+                height=height,
+                round=round_,
+                block_id=BlockID.decode(block_id_raw),
+                signatures=_commit_sigs_from_columns(
+                    n, flags, sig, secs, nanos, addr
+                ),
+            )
         f = decode_message(data)
         sigs = _decode_commit_sigs(field_repeated_bytes(f, 4))
         return cls(

@@ -150,7 +150,13 @@ def iter_fields(data: bytes):
     lists. The columnar commit decode (types/block.py) walks each
     CommitSig record exactly once into numpy columns; at 10k signatures
     the dict/list allocations of decode_message were the dominant decode
-    cost after object construction was removed."""
+    cost after object construction was removed.
+
+    For a commit this walk is the SPECIFICATION, not the fast path: the
+    native parse (native/tm_native.cpp commit_decode_columns) takes the
+    canonical inputs in one GIL-released pass and returns None for the
+    rest, which come here — as does everything when the module is
+    absent."""
     off = 0
     ln_data = len(data)
     while off < ln_data:
