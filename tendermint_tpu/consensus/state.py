@@ -279,7 +279,6 @@ class ConsensusState(BaseService):
     def on_stop(self) -> None:
         self._ticker.stop()
         self._close_vote_ingress()
-        self._queue.put(("quit", None))
         self._msg_ready.set()
         if self._thread is not None:
             self._thread.join(timeout=5)
@@ -306,6 +305,19 @@ class ConsensusState(BaseService):
     # ------------------------------------------------------------------
     # external inputs
 
+    def _enqueue(self, q: "queue.Queue", item) -> None:
+        """state.go's `select { case cs.peerMsgQueue <- mi: case
+        <-cs.Quit(): }`: a full queue holds its producer back while the
+        service runs; once _quit is set it drops the message instead."""
+        while True:
+            try:
+                q.put(item, timeout=0.2)
+                break
+            except queue.Full:
+                if self._quit.is_set():
+                    return
+        self._wake()
+
     def _wake(self) -> None:
         self._msg_ready.set()
         hook = self.on_enqueue
@@ -316,20 +328,18 @@ class ConsensusState(BaseService):
                 pass
 
     def set_proposal(self, proposal: Proposal, peer_id: str = "") -> None:
-        self._queue.put((ProposalMessage(proposal), peer_id))
-        self._wake()
+        self._enqueue(self._queue, (ProposalMessage(proposal), peer_id))
 
     def add_block_part(self, height: int, round_: int, part: Part, peer_id: str = "") -> None:
-        self._queue.put((BlockPartMessage(height, round_, part), peer_id))
-        self._wake()
+        self._enqueue(
+            self._queue, (BlockPartMessage(height, round_, part), peer_id))
 
     def add_vote_msg(self, vote: Vote, peer_id: str = "") -> None:
         msg = VoteMessage(vote)
         tr = self._tracer
         if tr.enabled and tr.flow is not None:
             msg.flow = tr.flow  # the delivery's flow rides with the vote
-        self._queue.put((msg, peer_id))
-        self._wake()
+        self._enqueue(self._queue, (msg, peer_id))
 
     # ------------------------------------------------------------------
     # live-vote ingress (ISSUE 15)
@@ -443,8 +453,7 @@ class ConsensusState(BaseService):
             self._try_add_vote_impl(vote, peer_id, verdict=msg.valid)
 
     def _send_internal(self, msg) -> None:
-        self._internal_queue.put((msg, ""))
-        self._wake()
+        self._enqueue(self._internal_queue, (msg, ""))
         for hook in self.broadcast_hooks:
             try:
                 hook(msg)
@@ -530,27 +539,18 @@ class ConsensusState(BaseService):
                         and ing.flush_pending()):
                     continue
                 break
-            msg, peer_id = item
-            if msg == "quit":
-                break
-            self._dispatch(msg, peer_id)
+            self._dispatch(*item)
             n += 1
         return n
 
     def _receive_routine(self) -> None:
         while not self._quit.is_set():
             item = self._pop_msg()
-            if item is None:
-                # blocking wait, woken by _wake() on any enqueue; the
-                # timeout only bounds the _quit re-check
-                if not self._msg_ready.wait(timeout=0.2):
-                    continue
+            if item is not None:
+                self._dispatch(*item)
+            # woken by _wake() on any enqueue; the timeout bounds the _quit re-check
+            elif self._msg_ready.wait(timeout=0.2):
                 self._msg_ready.clear()
-                continue
-            msg, peer_id = item
-            if msg == "quit":
-                return
-            self._dispatch(msg, peer_id)
 
     def _wal_write(self, rec: WALMessage) -> None:
         if self._wal is not None:
@@ -602,8 +602,7 @@ class ConsensusState(BaseService):
 
     def _tock(self, ti: TimeoutInfo) -> None:
         """Ticker callback → queue (state.go timeoutRoutine → tockChan)."""
-        self._queue.put((ti, ""))
-        self._wake()
+        self._enqueue(self._queue, (ti, ""))
 
     def _handle_timeout(self, ti: TimeoutInfo) -> None:
         """state.go:923-1005."""

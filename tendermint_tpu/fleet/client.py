@@ -185,8 +185,10 @@ class FleetClient:
 
     # -- connection lifecycle -----------------------------------------
 
-    def _connect_locked_entry(self) -> None:
-        """Dial and install a fresh connection (raises OSError)."""
+    def _connect_locked_entry(self, rejoin: bool = False) -> None:
+        """Dial and install a fresh connection (raises OSError). A
+        rejoin is counted under the lock that installs the socket, so no
+        reader sees `connected` with the redial that made it uncounted."""
         sock = socket.create_connection(self._addr, timeout=self._timeout_s)
         sock.settimeout(None)
         sock.setsockopt(socket.IPPROTO_TCP, socket.TCP_NODELAY, 1)
@@ -202,6 +204,7 @@ class FleetClient:
             self._sock = sock
             self._epoch += 1
             epoch = self._epoch
+            self.rejoins += rejoin
         self._m.client_connected.set(1, target=self._target)
         threading.Thread(target=self._read_loop, args=(sock, epoch),
                          name=f"fleet-client-read-{self.name}",
@@ -247,10 +250,9 @@ class FleetClient:
             if self._closed.is_set():
                 break
             try:
-                self._connect_locked_entry()
+                self._connect_locked_entry(rejoin=True)
             except OSError:
                 continue
-            self.rejoins += 1
             self._m.client_rejoins.inc(target=self._target)
             return  # flag already cleared inside _connect_locked_entry
         with self._mtx:

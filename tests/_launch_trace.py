@@ -86,11 +86,13 @@ UNRECORDED_CHECKS = 8
 
 
 def _best_of(body, k=15, n=10000):
+    # this thread's CPU time, not wall time: the tier-1 run's five sibling
+    # workers can preempt a 1 ms repeat, they cannot add to its CPU time
     best = float("inf")
     for _ in range(k):
-        t0 = time.perf_counter()
+        t0 = time.thread_time()
         body(n)
-        best = min(best, (time.perf_counter() - t0) / n)
+        best = min(best, (time.thread_time() - t0) / n)
     return best
 
 

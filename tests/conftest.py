@@ -33,7 +33,51 @@ from tendermint_tpu.libs import jaxcache  # noqa: E402
 
 jaxcache.enable()
 
+import faulthandler  # noqa: E402
+import signal  # noqa: E402
+import sys  # noqa: E402
+
 import pytest  # noqa: E402
+
+# Every test runs under a limit of its own, so a hang costs one test and
+# says where it hung; it cannot hold its xdist worker to the run's cap.
+# 300 s is ~3x the slowest unmarked test on a COLD .jax_cache (CHANGES.md,
+# PR 28); a test that needs more says so with
+# @pytest.mark.time_limit(seconds), never above MAX_TIME_LIMIT.
+DEFAULT_TIME_LIMIT = 300.0
+MAX_TIME_LIMIT = 600.0
+
+
+def _time_limit(item) -> float:
+    marker = item.get_closest_marker("time_limit")
+    return float(marker.args[0]) if marker else DEFAULT_TIME_LIMIT
+
+
+@pytest.hookimpl(wrapper=True)
+def pytest_runtest_protocol(item):
+    """ITIMER_REAL on the main thread, where pytest runs the test: a
+    blocked Lock.acquire / queue.put / join is interruptible by a signal
+    handler on POSIX. At the limit the handler dumps every thread's stack
+    and fails the test from inside the blocked frame; it fires again
+    every 30 s after that, so a teardown that hangs on what the test left
+    behind is failed too. Wraps the whole protocol (not an autouse
+    fixture) so that module- and class-scoped fixtures, which are set up
+    before any function-scoped one, run under the limit as well."""
+    limit = _time_limit(item)
+
+    def on_alarm(signum, frame):
+        print(f"\n{item.nodeid}: time limit of {limit:g} s reached; "
+              "all threads:", file=sys.stderr, flush=True)
+        faulthandler.dump_traceback(file=sys.stderr, all_threads=True)
+        pytest.fail(f"{item.nodeid} exceeded its time limit of {limit:g} s")
+
+    previous = signal.signal(signal.SIGALRM, on_alarm)
+    signal.setitimer(signal.ITIMER_REAL, limit, 30.0)
+    try:
+        return (yield)
+    finally:
+        signal.setitimer(signal.ITIMER_REAL, 0)
+        signal.signal(signal.SIGALRM, previous)
 
 
 def pytest_collection_modifyitems(config, items):
@@ -47,6 +91,11 @@ def pytest_collection_modifyitems(config, items):
         for item in items:
             if "native_required" in item.keywords:
                 item.add_marker(skip)
+
+    over = [it.nodeid for it in items if _time_limit(it) > MAX_TIME_LIMIT]
+    if over:
+        raise pytest.UsageError(
+            f"time_limit above {MAX_TIME_LIMIT:g} s (split the test): {over}")
 
     # The end-to-end soak smokes are the most expensive subprocess items
     # in the suite; run them after everything else so a wall-clock-capped

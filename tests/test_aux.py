@@ -416,3 +416,58 @@ class TestWALTools:
         assert [m.end_height for m in WAL._iter_file(str(out_path))] == [
             m.end_height for m in msgs
         ]
+
+
+class TestTimeLimit:
+    """The harness's per-test limit (tests/conftest.py), driven through a
+    pytest of its own: a hang costs the test that hung and nothing else."""
+
+    REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    HUNG = (
+        "import queue\n"
+        "import pytest\n"
+        "@pytest.fixture(scope='module')\n"
+        "def flooded():\n"
+        "    q = queue.Queue(1)\n"
+        "    q.put(1)\n"
+        "    return q\n"
+        "@pytest.mark.time_limit(1)\n"
+        "def test_blocks_on_a_full_queue(flooded):\n"
+        "    flooded.put(2)\n"
+        "def test_runs_after_the_hang():\n"
+        "    pass\n"
+    )
+    TOO_MUCH = (
+        "import pytest\n"
+        "@pytest.mark.time_limit(601)\n"
+        "def test_asks_for_too_much():\n"
+        "    pass\n"
+    )
+
+    def _pytest(self, tmp_path, source):
+        (tmp_path / "test_it.py").write_text(source)
+        return subprocess.run(
+            [sys.executable, "-m", "pytest", "-p", "tests.conftest",
+             "-c", "pyproject.toml", "--rootdir", str(tmp_path),
+             "-p", "no:cacheprovider", "-v", str(tmp_path / "test_it.py")],
+            cwd=self.REPO, capture_output=True, text=True, timeout=90,
+        )
+
+    def test_a_blocked_test_fails_at_its_limit_and_the_next_one_runs(self, tmp_path):
+        r = self._pytest(tmp_path, self.HUNG)
+        out = r.stdout + r.stderr
+        assert r.returncode == 1, out
+        assert "test_blocks_on_a_full_queue FAILED" in out, out
+        assert "exceeded its time limit of 1 s" in out, out
+        # the blocked frame, in the failure's traceback and in the dump
+        assert "flooded.put(2)" in out, out
+        assert 'queue.py", line' in out and "in put" in out, out
+        assert "test_runs_after_the_hang PASSED" in out, out
+        assert "1 failed, 1 passed" in out, out
+
+    def test_a_limit_above_the_maximum_is_refused_at_collection(self, tmp_path):
+        r = self._pytest(tmp_path, self.TOO_MUCH)
+        out = r.stdout + r.stderr
+        assert r.returncode == 4, out  # pytest's usage-error exit code
+        assert "time_limit above 600 s" in out, out
+        assert "test_asks_for_too_much" in out, out

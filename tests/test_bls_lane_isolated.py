@@ -293,6 +293,7 @@ def _purepy_env():
     return env
 
 
+@pytest.mark.time_limit(600)  # 114-128 s on a cold cache; its two subprocess limits add to 540
 def test_bls_isolated_runners():
     """The purepy subprocess re-run of this file (the tier-1 home of
     the crypto-gated seam tests above) and the `prep_bench --bls`
@@ -312,6 +313,7 @@ def test_bls_isolated_runners():
                 "-q", "-m", "not slow", "-p", "no:cacheprovider",
             ],
             dict(_purepy_env(), TM_TPU_BLS_ISOLATED="1"),
+            180,
         )
     cmds["--bls gate"] = (
         [
@@ -320,16 +322,17 @@ def test_bls_isolated_runners():
             "--bls",
         ],
         _purepy_env(),
+        360,
     )
     fails = []
-    for label, (cmd, env) in cmds.items():
+    for label, (cmd, env, limit) in cmds.items():
         r = subprocess.run(
             cmd,
             stdout=subprocess.PIPE,
             stderr=subprocess.STDOUT,
             env=env,
             cwd=_repo_root(),
-            timeout=800,
+            timeout=limit,
         )
         if r.returncode != 0:
             fails.append(f"{label}: rc={r.returncode}\n"

@@ -27,7 +27,7 @@ def test_commit_block_under_purepy_fallback():
         capture_output=True,
         env=env,
         cwd=os.path.dirname(here),
-        timeout=700,
+        timeout=120,
     )
     tail = (r.stdout or b"").decode(errors="replace")[-3000:]
     assert r.returncode == 0, f"isolated test_commit_block run failed:\n{tail}"

@@ -489,7 +489,7 @@ def test_without_the_module_only_the_python_counter_moves():
         capture_output=True,
         env=env,
         cwd=REPO,
-        timeout=300,
+        timeout=60,
     )
     assert r.returncode == 0, (r.stderr or b"").decode(errors="replace")[-3000:]
     out = json.loads(r.stdout.decode().strip().splitlines()[-1])
@@ -556,11 +556,13 @@ def test_decoding_10k_lanes_leaves_the_gil_to_a_python_thread(wire_10k):
 
 
 def _best_seconds(fn, data, repeats):
+    # CPU seconds of this thread (the native walk runs on it, GIL or not):
+    # sibling test workers preempting either walk cannot move the ratio
     best = float("inf")
     for _ in range(repeats):
-        t0 = time.perf_counter()
+        t0 = time.thread_time()
         fn(data)
-        best = min(best, time.perf_counter() - t0)
+        best = min(best, time.thread_time() - t0)
     return best
 
 

@@ -171,3 +171,55 @@ class TestMultiValidator:
             for n in nodes:
                 n.stop()
         assert stores[0].load_block(2).hash() == stores[1].load_block(2).hash()
+
+
+class TestStopWhileFlooded:
+    """stop() has one signal, _quit: a full queue can neither hold
+    stop() nor keep a producer blocked after it (the flooded state-synced
+    node of tests/test_node_rpc.py, made deterministic)."""
+
+    @staticmethod
+    def _flooded_node():
+        """A started node whose receive thread sits in a handler until
+        _quit is set, behind a queue filled to maxsize."""
+        from tendermint_tpu.types.vote import Vote
+
+        sks = [ed25519.gen_priv_key(bytes([i + 1]) * 32) for i in range(2)]
+        cs, _, _ = make_node(sks, None)
+        held = threading.Event()
+
+        def hold(msg, peer_id):
+            held.set()
+            cs.quit_event.wait(60)
+
+        cs._handle_msg = hold
+        cs.start()
+        vote = Vote(height=1, validator_address=sks[1].pub_key().address())
+        cs.add_vote_msg(vote, peer_id="flood")
+        assert held.wait(10)
+        for _ in range(cs._queue.maxsize):
+            cs.add_vote_msg(vote, peer_id="flood")
+        assert cs._queue.full()
+        return cs, vote
+
+    def test_stop_returns_on_a_full_queue(self):
+        cs, _ = self._flooded_node()
+        stopper = threading.Thread(target=cs.stop, daemon=True)
+        stopper.start()
+        stopper.join(2)
+        assert not stopper.is_alive(), "stop() blocked on the full queue"
+        assert not cs._thread.is_alive()
+
+    def test_stop_releases_a_blocked_producer(self):
+        cs, vote = self._flooded_node()
+        producer = threading.Thread(
+            target=cs.add_vote_msg, args=(vote, "flood"), daemon=True)
+        producer.start()
+        producer.join(0.5)
+        assert producer.is_alive(), "a full queue must hold its producer back"
+        stopper = threading.Thread(target=cs.stop, daemon=True)
+        stopper.start()
+        producer.join(1)
+        assert not producer.is_alive(), "producer still blocked after stop()"
+        stopper.join(2)
+        assert not stopper.is_alive()

@@ -455,7 +455,7 @@ def test_injected_lintbugs_under_purepy_fallback():
         capture_output=True,
         env=env,
         cwd=os.path.dirname(here),
-        timeout=600,
+        timeout=60,
     )
     tail = (r.stdout or b"").decode(errors="replace")[-3000:]
     assert r.returncode == 0, f"isolated injected-lintbug run failed:\n{tail}"

@@ -34,7 +34,7 @@ def test_entry_block_under_purepy_fallback():
         capture_output=True,
         env=env,
         cwd=os.path.dirname(here),
-        timeout=700,
+        timeout=180,
     )
     tail = (r.stdout or b"").decode(errors="replace")[-3000:]
     assert r.returncode == 0, f"isolated test_entry_block run failed:\n{tail}"

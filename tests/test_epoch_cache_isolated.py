@@ -9,6 +9,7 @@ import sys
 import pytest
 
 
+@pytest.mark.time_limit(600)  # re-runs test_epoch_cache.py: 238 s on a cold cache
 def test_epoch_cache_under_purepy_fallback():
     try:
         import cryptography  # noqa: F401
@@ -27,7 +28,7 @@ def test_epoch_cache_under_purepy_fallback():
         capture_output=True,
         env=env,
         cwd=os.path.dirname(here),
-        timeout=800,
+        timeout=570,
     )
     tail = (r.stdout or b"").decode(errors="replace")[-3000:]
     assert r.returncode == 0, f"isolated test_epoch_cache run failed:\n{tail}"

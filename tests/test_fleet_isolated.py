@@ -58,7 +58,7 @@ def test_fleet_suite_under_purepy_fallback():
         capture_output=True,
         env=_purepy_env(),
         cwd=_repo_root(),
-        timeout=800,
+        timeout=90,
     )
     tail = (r.stdout or b"").decode(errors="replace")[-3000:]
     assert r.returncode == 0, f"isolated test_fleet run failed:\n{tail}"
@@ -80,7 +80,7 @@ def test_prep_bench_fleet_gate():
         capture_output=True,
         env=_purepy_env(),
         cwd=_repo_root(),
-        timeout=600,
+        timeout=180,
     )
     out = (r.stdout or b"").decode(errors="replace")
     err = (r.stderr or b"").decode(errors="replace")

@@ -421,7 +421,7 @@ def test_flight_recorder_suite_under_purepy_fallback():
             os.path.join(HERE, "test_flight_recorder.py"),
             "-q", "-m", "not slow", "-p", "no:cacheprovider",
         ],
-        capture_output=True, env=_purepy_env(), cwd=REPO, timeout=600,
+        capture_output=True, env=_purepy_env(), cwd=REPO, timeout=60,
     )
     tail = (r.stdout or b"").decode(errors="replace")[-3000:]
     assert r.returncode == 0, f"isolated flight-recorder run failed:\n{tail}"
@@ -436,7 +436,7 @@ def test_smoke_exports_merged_trace_with_cross_node_chain(tmp_path):
     r = subprocess.run(
         [sys.executable, os.path.join(REPO, "tools", "simnet_run.py"),
          "--smoke", "--trace", trace_path],
-        capture_output=True, env=_purepy_env(), cwd=REPO, timeout=120,
+        capture_output=True, env=_purepy_env(), cwd=REPO, timeout=60,
     )
     out = (r.stdout or b"").decode(errors="replace")
     assert r.returncode == 0, f"smoke failed:\n{out[-3000:]}"
@@ -484,7 +484,7 @@ print("OK", *lt.assert_off_cost_within_budget(records))
 """ % HERE
     r = subprocess.run(
         [sys.executable, "-c", code],
-        capture_output=True, env=_purepy_env(), cwd=REPO, timeout=300,
+        capture_output=True, env=_purepy_env(), cwd=REPO, timeout=120,
     )
     out = (r.stdout or b"").decode(errors="replace")
     err = (r.stderr or b"").decode(errors="replace")

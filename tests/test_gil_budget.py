@@ -195,7 +195,7 @@ def test_10k_sig_verify_commit_prep_stays_in_budget():
         capture_output=True,
         env=env,
         cwd=REPO,
-        timeout=600,
+        timeout=60,
     )
     assert r.returncode == 0, (r.stderr or b"").decode(errors="replace")[-3000:]
     out = json.loads((r.stdout or b"").decode().strip().splitlines()[-1])

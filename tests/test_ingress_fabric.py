@@ -468,7 +468,9 @@ class TestEngineMechanics:
         lane.flush_now()
         wait_until(lambda: sink.count() == 8, msg="split delivery")
         assert sorted(host_seen) == [1, 3, 5, 7]
-        assert v.calls and v.calls[0][0] == 4
+        # how many launches carry the 4 routed sigs is the scheduler's
+        # timing (a full batch of 4 wakes it mid-loop on a loaded host)
+        assert sum(n for n, _flow, _pri in v.calls) == 4
         st = lane.stats()
         assert st["host_lane_sigs"] == 4
         assert st["sync_fallbacks"] == 0           # routed, not fallen back

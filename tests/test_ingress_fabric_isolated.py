@@ -51,7 +51,7 @@ def test_ingress_fabric_suite_under_purepy_fallback():
         capture_output=True,
         env=_purepy_env(),
         cwd=_repo_root(),
-        timeout=800,
+        timeout=60,
     )
     tail = (r.stdout or b"").decode(errors="replace")[-3000:]
     assert r.returncode == 0, \
@@ -73,7 +73,7 @@ def test_prep_bench_fabric_gate():
         capture_output=True,
         env=_purepy_env(),
         cwd=_repo_root(),
-        timeout=600,
+        timeout=120,
     )
     out = (r.stdout or b"").decode(errors="replace")
     err = (r.stderr or b"").decode(errors="replace")

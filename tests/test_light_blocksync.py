@@ -187,7 +187,10 @@ class TestBlockSync:
         target = src_store.height() - 1  # can't verify the tip without a next block
         deadline = time.time() + 30
         try:
-            while time.time() < deadline and fresh_store.height() < target:
+            # the pool reports caught-up AFTER the last block is stored:
+            # wait for the report too, not only for the height
+            while time.time() < deadline and not (
+                    fresh_store.height() >= target and caught):
                 time.sleep(0.1)
         finally:
             serving.stop()

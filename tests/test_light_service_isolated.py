@@ -30,6 +30,7 @@ def _purepy_env():
     return env
 
 
+@pytest.mark.time_limit(360)  # re-runs test_light_service.py: 102 s on a cold cache
 def test_light_service_under_purepy_fallback():
     try:
         import cryptography  # noqa: F401
@@ -47,7 +48,7 @@ def test_light_service_under_purepy_fallback():
         capture_output=True,
         env=_purepy_env(),
         cwd=_repo_root(),
-        timeout=800,
+        timeout=330,
     )
     tail = (r.stdout or b"").decode(errors="replace")[-3000:]
     assert r.returncode == 0, f"isolated test_light_service run failed:\n{tail}"
@@ -67,7 +68,7 @@ def test_prep_bench_light_gate():
         capture_output=True,
         env=_purepy_env(),
         cwd=_repo_root(),
-        timeout=600,
+        timeout=90,
     )
     out = (r.stdout or b"").decode(errors="replace")
     err = (r.stderr or b"").decode(errors="replace")

@@ -203,6 +203,7 @@ def _repo_root():
     return os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
+@pytest.mark.time_limit(450)  # re-runs test_mesh.py: 131 s on a cold cache
 def test_mesh_under_purepy_fallback():
     try:
         import cryptography  # noqa: F401
@@ -224,7 +225,7 @@ def test_mesh_under_purepy_fallback():
         capture_output=True,
         env=env,
         cwd=_repo_root(),
-        timeout=800,
+        timeout=420,
     )
     tail = (r.stdout or b"").decode(errors="replace")[-3000:]
     assert r.returncode == 0, f"isolated test_mesh run failed:\n{tail}"
@@ -243,7 +244,7 @@ def test_prep_bench_mesh_gate():
         capture_output=True,
         env=_purepy_env(),
         cwd=_repo_root(),
-        timeout=600,
+        timeout=180,
     )
     out = (r.stdout or b"").decode(errors="replace")
     err = (r.stderr or b"").decode(errors="replace")

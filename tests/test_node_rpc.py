@@ -150,6 +150,38 @@ class TestNodeRPC:
             nodes[0].config.rpc.unsafe = False
 
 
+def test_node_stop_returns_while_peer_still_sends(two_node_net):
+    """Node.stop() stops consensus BEFORE its reactors, so the reactor
+    keeps feeding a queue nobody drains: stop() must not wait on it."""
+    import queue
+    import threading
+
+    from tendermint_tpu.consensus.ticker import TimeoutInfo
+
+    node_a, node_b = two_node_net
+    node_a.wait_for_height(2, timeout=60)
+    cs = node_b.consensus
+    held = threading.Event()
+
+    def hold(msg, peer_id):  # the receive thread sits here until _quit
+        held.set()
+        cs.quit_event.wait(60)
+
+    cs._handle_msg = hold
+    assert held.wait(30), "peer sent nothing"
+    stale = (TimeoutInfo(0.0, 0, 0, 1), "")
+    try:
+        while True:  # top the queue up behind node_a's own gossip
+            cs._queue.put_nowait(stale)
+    except queue.Full:
+        pass
+    assert node_a.consensus.is_running()  # the peer is still sending
+    stopper = threading.Thread(target=node_b.stop, daemon=True)
+    stopper.start()
+    stopper.join(15)
+    assert not stopper.is_alive(), "Node.stop() blocked on a flooded queue"
+
+
 class TestHandshakeReplay:
     def test_app_restart_replays_blocks(self):
         """Kill the app (fresh instance), restart node: handshake replays

@@ -232,6 +232,7 @@ def _repo_root():
     return os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
+@pytest.mark.time_limit(540)  # re-runs test_overlap.py: 198 s on a cold cache
 def test_overlap_under_purepy_fallback():
     try:
         import cryptography  # noqa: F401
@@ -254,7 +255,7 @@ def test_overlap_under_purepy_fallback():
         capture_output=True,
         env=env,
         cwd=_repo_root(),
-        timeout=800,
+        timeout=510,
     )
     tail = (r.stdout or b"").decode(errors="replace")[-3000:]
     assert r.returncode == 0, f"isolated test_overlap run failed:\n{tail}"
@@ -272,7 +273,7 @@ def test_prep_bench_overlap_gate():
         capture_output=True,
         env=_purepy_env(),
         cwd=_repo_root(),
-        timeout=600,
+        timeout=90,
     )
     out = (r.stdout or b"").decode(errors="replace")
     err = (r.stderr or b"").decode(errors="replace")

@@ -331,6 +331,11 @@ class TestPeerLifecycle:
         assert done.wait(30)
         dt = time.time() - t0
         assert dt >= 0.35, f"30kB at 50kB/s finished too fast: {dt:.2f}s"
+        # the sender counts a packet AFTER writing it, so the 30th message
+        # can reach on_recv before its bytes reach the monitor
+        deadline = time.time() + 5
+        while ma.send_monitor.total() < 30_000 and time.time() < deadline:
+            time.sleep(0.01)
         assert ma.send_monitor.total() >= 30_000
         ma.stop()
         mb.stop()

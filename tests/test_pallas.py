@@ -25,6 +25,7 @@ def _oracle(entries):
 
 
 class TestPallasPipeline:
+    @pytest.mark.time_limit(390)  # 96-122 s on a cold cache
     def test_edge_vectors_bit_exact(self):
         entries = _edge_entries()
         bucket = ((len(entries) + 7) // 8) * 8

@@ -48,7 +48,7 @@ def test_replay_suite_under_purepy_fallback():
         capture_output=True,
         env=_purepy_env(),
         cwd=_repo_root(),
-        timeout=800,
+        timeout=60,
     )
     tail = (r.stdout or b"").decode(errors="replace")[-3000:]
     assert r.returncode == 0, f"isolated test_blocksync_replay run failed:\n{tail}"
@@ -75,7 +75,7 @@ def test_simnet_catchup_under_purepy_fallback():
         capture_output=True,
         env=_purepy_env(),
         cwd=_repo_root(),
-        timeout=800,
+        timeout=120,
     )
     tail = (r.stdout or b"").decode(errors="replace")[-3000:]
     assert r.returncode == 0, f"isolated test_simnet_catchup run failed:\n{tail}"
@@ -96,7 +96,7 @@ def test_prep_bench_replay_gate():
         capture_output=True,
         env=_purepy_env(),
         cwd=_repo_root(),
-        timeout=600,
+        timeout=120,
     )
     out = (r.stdout or b"").decode(errors="replace")
     err = (r.stderr or b"").decode(errors="replace")

@@ -401,6 +401,7 @@ def _purepy_env():
     return env
 
 
+@pytest.mark.time_limit(540)  # 30-50 s on a cold cache; its two subprocess limits add to 510
 def test_secp_isolated_runners():
     """The purepy subprocess re-run of this file (the tier-1 home of
     every crypto-gated test above) and the `prep_bench --schemes`
@@ -426,6 +427,7 @@ def test_secp_isolated_runners():
                 "-q", "-m", "not slow", "-p", "no:cacheprovider",
             ],
             dict(_purepy_env(), TM_TPU_SECP_ISOLATED="1"),
+            360,
         )
     cmds["--schemes gate"] = (
         [
@@ -434,16 +436,17 @@ def test_secp_isolated_runners():
             "--schemes",
         ],
         _purepy_env(),
+        150,
     )
     fails = []
-    for label, (cmd, env) in cmds.items():
+    for label, (cmd, env, limit) in cmds.items():
         r = subprocess.run(
             cmd,
             stdout=subprocess.PIPE,
             stderr=subprocess.STDOUT,
             env=env,
             cwd=_repo_root(),
-            timeout=800,
+            timeout=limit,
         )
         if r.returncode != 0:
             fails.append(f"{label}: rc={r.returncode}\n"

@@ -364,7 +364,7 @@ def test_simnet_suite_under_purepy_fallback():
         capture_output=True,
         env=_purepy_env(),
         cwd=REPO,
-        timeout=700,
+        timeout=90,
     )
     tail = (r.stdout or b"").decode(errors="replace")[-3000:]
     assert r.returncode == 0, f"isolated test_simnet run failed:\n{tail}"
@@ -405,7 +405,7 @@ print(json.dumps({
         capture_output=True,
         env=_purepy_env(),
         cwd=REPO,
-        timeout=600,
+        timeout=90,
     )
     out = (r.stdout or b"").decode(errors="replace")
     assert r.returncode == 0, (
@@ -453,7 +453,7 @@ def test_devcheck_smoke_partition_heal_clean():
         capture_output=True,
         env=_purepy_env(),
         cwd=REPO,
-        timeout=120,
+        timeout=60,
     )
     out = (r.stdout or b"").decode(errors="replace")
     assert r.returncode == 0, f"devcheck smoke failed:\n{out[-3000:]}"

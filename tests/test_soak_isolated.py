@@ -49,7 +49,7 @@ def test_mini_soak_smoke_green_and_replay_exact(tmp_path, seed, duration):
         capture_output=True,
         env=_env(),
         cwd=REPO,
-        timeout=240,
+        timeout=90,
     )
     tail = (r.stdout or b"").decode(errors="replace")[-3000:]
     assert r.returncode == 0, f"mini soak failed:\n{tail}"
@@ -103,7 +103,7 @@ def test_starved_soak_fails_localized_to_ingress(tmp_path):
             TM_TPU_SOAK_INGRESS_P99_MS="1000",
         ),
         cwd=REPO,
-        timeout=120,
+        timeout=60,
     )
     tail = (r.stdout or b"").decode(errors="replace")[-3000:]
     assert r.returncode == 1, f"starved soak did not fail:\n{tail}"

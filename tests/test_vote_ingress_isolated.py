@@ -51,7 +51,7 @@ def test_vote_ingress_suite_under_purepy_fallback():
         capture_output=True,
         env=_purepy_env(),
         cwd=_repo_root(),
-        timeout=800,
+        timeout=60,
     )
     tail = (r.stdout or b"").decode(errors="replace")[-3000:]
     assert r.returncode == 0, f"isolated test_vote_ingress run failed:\n{tail}"
@@ -71,7 +71,7 @@ def test_prep_bench_votes_gate():
         capture_output=True,
         env=_purepy_env(),
         cwd=_repo_root(),
-        timeout=600,
+        timeout=90,
     )
     out = (r.stdout or b"").decode(errors="replace")
     err = (r.stderr or b"").decode(errors="replace")

@@ -1327,12 +1327,14 @@ def run_ingress(args) -> int:
                  FALSE, never silently dropped), and zero buffer-pool
                  slots remain in flight once drained
     """
+    import threading
+
     from tendermint_tpu.crypto import ed25519 as ed
     from tendermint_tpu.mempool import ingress as ing
     from tendermint_tpu.observability import trace as tr
     from tendermint_tpu.ops import epoch_cache as _epoch
     from tendermint_tpu.ops import pipeline as pl
-    from tendermint_tpu.ops._testing import drain_pool, slow_prepare
+    from tendermint_tpu.ops._testing import SlowReadback, drain_pool
     from tendermint_tpu.ops.entry_block import EntryBlock
 
     n_txs, n_senders, max_batch = 256, 8, 64
@@ -1364,26 +1366,48 @@ def run_ingress(args) -> int:
 
     _epoch.reset(4)
     real_prepare = pl.AsyncBatchVerifier._prepare
-    pl.AsyncBatchVerifier._prepare = staticmethod(
-        slow_prepare(real_prepare, resolve_delay)
-    )
+    # The staging below is driven by the pipeline's state, not by sleeps
+    # (a loaded host outran "50 ms, then 20 ms" either way): the first
+    # readback is held until a preemption has been counted, so the commit
+    # always meets an occupied pipeline, however late it arrives.
+    preempted = threading.Event()
+
+    class HeldReadback(SlowReadback):
+        def __array__(self, dtype=None):
+            preempted.wait(30)  # a FAIL below if nothing ever preempts
+            return super().__array__(dtype)
+
+    def held_prepare(entries):
+        f, args, rlc, bucket = real_prepare(entries)
+        return ((lambda *xs: HeldReadback(f(*xs), resolve_delay)),
+                args, rlc, bucket)
+
+    pl.AsyncBatchVerifier._prepare = staticmethod(held_prepare)
     tr.TRACER.clear()
     tr.configure(enabled=True)
     os.environ["TM_TPU_FORCE_DEVICE"] = "1"
     v = pl.AsyncBatchVerifier(depth=1, pool_depth=OVERLAP_POOL_DEPTH)
+    v.add_preempt_hook(lambda n: preempted.set())
     acc = ing.IngressAccumulator(verifier=v, max_batch=max_batch,
                                  window_ms=8.0)
+
+    def transferred(n_batches):
+        deadline = time.monotonic() + 60
+        while (v._pool.stats()["in_flight"] < n_batches
+               and time.monotonic() < deadline):
+            time.sleep(0.002)
+
     try:
-        # two waves: wave 1 launches and holds the single depth slot for
-        # resolve_delay; wave 2 transfers and parks on the semaphore.
-        # The commit then arrives against a genuinely occupied pipeline —
-        # the shape the preemption machinery exists for.
+        # two waves: wave 1 launches and holds the single depth slot;
+        # wave 2 transfers and parks on the semaphore. The commit then
+        # arrives against a genuinely occupied pipeline — the shape the
+        # preemption machinery exists for.
         futs = [acc.submit(s) for s in stxs[:max_batch]]
         acc.flush_now()
-        time.sleep(0.05)  # wave 1 is in flight on the device
+        transferred(1)  # wave 1 is on its way to the device
         futs += [acc.submit(s) for s in stxs[max_batch:]]
         acc.flush_now()
-        time.sleep(0.02)  # wave 2 transferred, parked on the depth sem
+        transferred(2)  # wave 2 transferred: parked on the depth sem
         cfut = v.submit(commit_block, priority=pl.PRIORITY_CONSENSUS)
         commit_ok = bool(all(cfut.result(timeout=300)))
         pending_at_commit = sum(1 for x in futs if not x.done())
