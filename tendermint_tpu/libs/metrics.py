@@ -688,6 +688,12 @@ class OpsMetrics:
             "Host bytes shipped to the device by the last dispatched "
             "batch, averaged over its coalesced commits.",
         )
+        # device_pool.transfer's device_put calls: h2d_ops / launches is 1
+        # on the warm-epoch RLC path (one packed buffer), 4 on the uncached
+        self.h2d_ops = registry.counter(
+            "ops", "h2d_ops_total",
+            "Host-to-device copy operations issued for launch arguments.",
+        )
         # overlapped device (ops/pipeline.py dispatcher + ops/device_pool):
         # transfer_overlap_ratio = fraction of H2D transfer time issued
         # while a kernel was in flight (hidden behind compute); the pool
@@ -931,6 +937,7 @@ def ops_stats() -> dict:
         "commit_decode_native": int(m.commit_decodes.value(path="native")),
         "commit_decode_python": int(m.commit_decodes.value(path="python")),
         "h2d_bytes_per_commit": float(m.h2d_bytes_per_commit.value()),
+        "h2d_ops": int(m.h2d_ops.total()),
         "transfer_overlap_ratio": float(m.transfer_overlap_ratio.value()),
         "buffer_pool_hits": int(m.buffer_pool_hits.total()),
         "buffer_pool_misses": int(m.buffer_pool_misses.total()),

@@ -111,21 +111,24 @@ def transfer(args, shardings=None) -> tuple:
         raise ValueError(
             f"{len(args)} args but {len(shardings)} transfer shardings"
         )
-    if _TRACER is not None and _TRACER.enabled:
-        # one span per put: is a launch's transfer cost per operation or
-        # per byte? (the dispatcher's thread args name the launch)
-        out = []
-        for a, s in zip(args, shardings):
-            if not isinstance(a, np.ndarray):
-                out.append(a)
-                continue
+    # one span per put: a launch's transfer cost is per operation, not
+    # per byte (the dispatcher's thread args name the launch); h2d_ops
+    # counts the same operations with the tracer off
+    traced = _TRACER is not None and _TRACER.enabled
+    out = []
+    puts = 0
+    for a, s in zip(args, shardings):
+        if not isinstance(a, np.ndarray):
+            out.append(a)
+            continue
+        puts += 1
+        if traced:
             with _span("pipeline.transfer.put", bytes=a.nbytes):
                 out.append(jax.device_put(a, s))
-        return tuple(out)
-    return tuple(
-        jax.device_put(a, s) if isinstance(a, np.ndarray) else a
-        for a, s in zip(args, shardings)
-    )
+        else:
+            out.append(jax.device_put(a, s))
+    _ops().h2d_ops.inc(puts)
+    return tuple(out)
 
 
 class PoolSlot:

@@ -415,16 +415,77 @@ def _jitted_rlc_verify(g: int, block: int, interpret: bool,
     return jax.jit(pipeline)
 
 
+# -- the warm-epoch launch's one argument buffer ------------------------------
+#
+# A host-to-device copy is priced per operation on the v5e (~0.25 ms
+# whether it carries 600 B or 650 kB), so the warm-epoch launch ships its
+# four per-signature arrays as ONE buffer of 32-bit words:
+#
+#     idx (bucket,) int32 | r_rows (bucket, 32) uint8
+#         | scal_rows (g, N_SCAL, 32) uint8 | sok_rows (g, M) int32
+#
+# Every offset is a static function of bucket, M and N_SCAL, and every
+# section is a whole number of words, so no section needs padding. int32
+# is the element type because that is what the v5e splits cheapest: idx
+# and sok_rows are plain slices, and the byte rows come back through one
+# bitcast each (a uint8 buffer with idx/sok bitcast up to int32 cost the
+# 10k launch 0.3 ms more on the device: PERF.md §6, PR 29).
+
+
+def packed_layout(bucket: int) -> tuple:
+    """Word offsets (r_rows, scal_rows, sok_rows, end) of the packed
+    buffer's sections; idx starts at 0."""
+    g = bucket // M
+    o_r = bucket
+    o_scal = o_r + 8 * bucket
+    o_sok = o_scal + 8 * N_SCAL * g
+    return o_r, o_scal, o_sok, o_sok + M * g
+
+
+def packed_views(packed: np.ndarray, bucket: int) -> tuple:
+    """The four host arrays of a packed buffer, as writable VIEWS of it:
+    (idx (bucket,) int32, r_rows (bucket, 32) uint8,
+    scal_rows (g, N_SCAL, 32) uint8, sok_rows (g, M) int32). The byte
+    rows lie in the words little-endian, as the host has them."""
+    g = bucket // M
+    o_r, o_scal, o_sok, end = packed_layout(bucket)
+    return (
+        packed[:o_r],
+        packed[o_r:o_scal].view(np.uint8).reshape(bucket, 32),
+        packed[o_scal:o_sok].view(np.uint8).reshape(g, N_SCAL, 32),
+        packed[o_sok:end].reshape(g, M),
+    )
+
+
+def split_packed(packed, bucket: int) -> tuple:
+    """packed_views on device, inside the jitted pipeline: static slices,
+    and each word of the byte rows bitcast back to its four bytes."""
+    g = bucket // M
+    o_r, o_scal, o_sok, end = packed_layout(bucket)
+    return (
+        packed[:o_r],
+        lax.bitcast_convert_type(packed[o_r:o_scal], jnp.uint8).reshape(
+            bucket, 32
+        ),
+        lax.bitcast_convert_type(packed[o_scal:o_sok], jnp.uint8).reshape(
+            g, N_SCAL, 32
+        ),
+        packed[o_sok:end].reshape(g, M),
+    )
+
+
 @functools.lru_cache(maxsize=None)
 def _jitted_rlc_verify_cached(g: int, block: int, vp: int, interpret: bool,
                               vma: frozenset | None = None,
                               donate: bool = False):
-    """The epoch-cached RLC pipeline: gathers the committee's
-    decompressed coords from the persistent (4*32, vp) device table,
-    rearranges them (and the raw row-major per-sig inputs) into the
-    slot-major kernel layout ON DEVICE, and runs K1-cached/K2/K3. The
-    host ships only val_idx + raw rows — prepare_rlc's slot-major
-    transposes (the bulk of its 31 ms at 10k sigs) become device work."""
+    """The epoch-cached RLC pipeline: splits the launch's ONE packed
+    argument buffer back into its four arrays (split_packed), gathers
+    the committee's decompressed coords from the persistent (4*32, vp)
+    device table, rearranges them (and the raw row-major per-sig inputs)
+    into the slot-major kernel layout ON DEVICE, and runs
+    K1-cached/K2/K3. The host ships only val_idx + raw rows —
+    prepare_rlc's slot-major transposes (the bulk of its 31 ms at 10k
+    sigs) become device work."""
     if g % block:
         raise ValueError(
             f"lane count {g} not a multiple of block {block} (size buckets "
@@ -475,7 +536,8 @@ def _jitted_rlc_verify_cached(g: int, block: int, vp: int, interpret: bool,
         interpret=interpret,
     )
 
-    def pipeline(coords_tbl, ok_tbl, idx, r_rows, scal_rows, sok_rows):
+    def pipeline(coords_tbl, ok_tbl, packed):
+        idx, r_rows, scal_rows, sok_rows = split_packed(packed, g * M)
         # idx is signature-major (i = lane*M + slot); the reshapes below
         # land every array in the kernels' slot-major layout
         ac = (
@@ -495,7 +557,7 @@ def _jitted_rlc_verify_cached(g: int, block: int, vp: int, interpret: bool,
     pipeline.__name__ = f"rlc_verify_cached_g{g}_b{block}_vp{vp}"
     if donate:
         # persistent epoch tables (argnums 0-1) are never donated
-        return jax.jit(pipeline, donate_argnums=(2, 3, 4, 5))
+        return jax.jit(pipeline, donate_argnums=(2,))
     return jax.jit(pipeline)
 
 
@@ -692,8 +754,9 @@ def prepare_rlc_cached(entries, bucket: int, ep):
     ROW-major — the slot-major transposes happen on device in the jitted
     cached pipeline. entries must be an EntryBlock with val_idx set.
 
-    Returns (idx (bucket,) int32, r_rows (bucket, 32) uint8,
-    scal_rows (g, N_SCAL, 32) uint8, sok_rows (g, M) int32)."""
+    Returns the 1-tuple (packed,): ONE int32 buffer per launch, filled
+    through its four views (packed_views: idx, r_rows, scal_rows,
+    sok_rows) — one host-to-device operation instead of four."""
     n = len(entries)
     if bucket % M:
         raise ValueError(f"bucket {bucket} not a multiple of M={M}")
@@ -702,16 +765,16 @@ def prepare_rlc_cached(entries, bucket: int, ep):
     live = g_live * M
     _pub, r_enc, scal, s_ok = _rlc_host_scalars(entries, live, g_live)
 
-    idx = np.full((bucket,), ep.vp - 1, dtype=np.int32)
+    packed = np.zeros((packed_layout(bucket)[-1],), dtype=np.int32)
+    idx, r_rows, scal_rows, sok_rows = packed_views(packed, bucket)
     idx[:n] = entries.val_idx
-    r_rows = np.zeros((bucket, 32), dtype=np.uint8)
+    idx[n:] = ep.vp - 1  # padding: the table's identity row
     r_rows[:live] = r_enc
     r_rows[live:, 0] = 1  # padding lanes: identity encoding
-    scal_rows = np.zeros((g, N_SCAL, 32), dtype=np.uint8)
     scal_rows[:g_live] = scal
-    sok_rows = np.ones((g, M), dtype=np.int32)
-    sok_rows[:g_live] = s_ok.reshape(g_live, M).astype(np.int32)
-    return idx, r_rows, scal_rows, sok_rows
+    sok_rows[:g_live] = s_ok.reshape(g_live, M)
+    sok_rows[g_live:] = 1
+    return (packed,)
 
 
 def verify_rlc_compact(a_t, r_t, scal_t, sok_t, block: int = 0,

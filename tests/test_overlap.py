@@ -344,3 +344,71 @@ class TestOverlapStructure:
         s = ops_stats()
         assert "transfer_overlap_ratio" in s
         assert s["buffer_pool_hits"] + s["buffer_pool_misses"] >= 1
+
+
+class TestOneTransferPerWarmRlcLaunch:
+    def test_warm_launch_is_one_put_uncached_is_four(self, monkeypatch):
+        """ISSUE 29: a warm-epoch RLC launch hands device_pool.transfer
+        ONE host array, so the dispatcher records one
+        `pipeline.transfer.put` inside its `pipeline.transfer` and
+        `h2d_ops` rises by 1; the uncached launch still ships its four.
+        The bytes are the old four arrays' sum, to the byte. The Pallas
+        pipelines are stood in for by all-accepting launches: this is the
+        dispatcher's accounting, tests/test_pallas_rlc.py has the kernels."""
+        import jax.numpy as jnp
+
+        from tendermint_tpu.libs.metrics import ops_stats
+        from tendermint_tpu.ops import pallas_rlc as pr
+
+        def accept_all(g, *_a, **_k):
+            return lambda *_args: jnp.ones((1, g), dtype=jnp.int32)
+
+        monkeypatch.setenv("TM_TPU_PALLAS", "1")
+        monkeypatch.setenv("TM_TPU_RLC", "1")
+        monkeypatch.setattr(pr, "_jitted_rlc_verify", accept_all)
+        monkeypatch.setattr(pr, "_jitted_rlc_verify_cached", accept_all)
+        monkeypatch.setattr(epoch_cache.EpochEntry, "coords_tables",
+                            lambda self: (None, None))
+        backend.engine.cache_clear()
+        epoch_cache.reset(depth=4)
+        n = 150
+        ep, warm = _warm_epoch(n, n)
+        # registered, then seen again: the epoch is warm for lookup()
+        for _ in range(2):
+            epoch_cache.cache().note(ep.key, ep.pub_rows[:n].copy())
+        cold = EntryBlock.from_entries(warm.to_entries())
+        bucket, g, _block = pr.plan_bucket(n)
+
+        def launch(v, block):
+            """(put spans inside the one transfer span, h2d_ops delta,
+            h2d_bytes_per_commit) of one launch of `block`."""
+            before = ops_stats()["h2d_ops"]
+            _tr.TRACER.clear()
+            _tr.configure(enabled=True)
+            try:
+                assert v.submit(block).result(timeout=300).all()
+            finally:
+                _tr.configure(enabled=False)
+            ev_ = _tr.TRACER.events()
+            (xfer,) = [e for e in ev_ if e[0] == "pipeline.transfer"]
+            puts = [e for e in ev_ if e[0] == "pipeline.transfer.put"]
+            assert all(e[3] == xfer[3] and xfer[1] <= e[1] and e[2] <= xfer[2]
+                       for e in puts)
+            s = ops_stats()
+            return puts, s["h2d_ops"] - before, s["h2d_bytes_per_commit"]
+
+        v = pl.AsyncBatchVerifier(depth=1)
+        try:
+            puts_w, ops_w, bytes_w = launch(v, warm)
+            puts_c, ops_c, bytes_c = launch(v, cold)
+        finally:
+            v.close()
+            epoch_cache.reset()
+            backend.engine.cache_clear()
+        assert (len(puts_w), ops_w) == (1, 1)
+        # idx 4 + r 32 + scal N_SCAL*32/M = 64 + sok 4 bytes a signature
+        assert puts_w[0][4]["bytes"] == bytes_w == 104 * bucket
+        assert (len(puts_c), ops_c) == (4, 4)
+        # slot-major a_t, r_t, scal_t (uint8) and sok_t (int32) per lane
+        assert sum(e[4]["bytes"] for e in puts_c) == bytes_c == g * (
+            2 * pr.M * 32 + pr.N_SCAL * 32 + 4 * pr.M)
