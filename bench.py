@@ -216,9 +216,9 @@ def worker() -> None:
                 pallas_verify.verify_compact(*args, interpret=not on_accel)
         else:
             with _tr.span("bench.host_prep", n=n_sigs, bucket=bucket):
-                args = backend.prepare_batch_device_hash(entries, bucket)
+                args = backend.prepare_batch(entries, bucket)
             prep_t += time.perf_counter() - p0
-            kern = backend.ed25519_verify.jitted_verify_device_hash()
+            kern = backend.ed25519_verify.jitted_verify()
             with _tr.span("bench.device", bucket=bucket):
                 _np.asarray(kern(*args))
         rep_times.append(time.perf_counter() - t0)

@@ -2218,10 +2218,10 @@ static PyObject *py_vote_sign_bytes_batch_buf(PyObject *, PyObject *args) {
 // commit_prep_fused(flags: n u8, sigs: n*64, ts_secs: n*8 LE i64,
 //                   ts_nanos: n*4 LE i32, pubs: n*32, power: n*8 LE i64,
 //                   prefix_commit, prefix_nil, suffix,
-//                   threshold, mode, ram_max_len)
+//                   threshold, mode)
 //   -> (sel (m*8 LE i64), tallied)                       when tally fails
-//   -> (sel, tallied, pub (m*32), sig (m*64), msgs, offs ((m+1)*8),
-//       ram_hi|None, ram_lo|None, counts|None)           otherwise
+//   -> (sel, tallied, pub (m*32), sig (m*64), msgs, offs ((m+1)*8))
+//                                                        otherwise
 //
 // The ENTIRE commit-side host prep of types.verify_commit in one
 // GIL-released call over CommitBlock + ValidatorSet columns
@@ -2229,9 +2229,7 @@ static PyObject *py_vote_sign_bytes_batch_buf(PyObject *, PyObject *args) {
 // threshold (validation.go:152 loop semantics, incl. early-stop keeping
 // the crossing lane), canonical sign-bytes composition into ONE
 // contiguous buffer (vote_sign_bytes_batch_buf layout, prefix chosen per
-// lane flag), pub/sig row gather, and — when ram_max_len > 0 and every
-// message fits — the device-hash kernel's padded R||A||M SHA blocks
-// word-packed per row (ops/sha512.pad_ram_block layout).
+// lane flag) and pub/sig row gather.
 //
 // mode bits: 1 = select COMMIT lanes only (else all non-ABSENT),
 //            2 = tally only COMMIT lanes, 4 = early-stop past threshold.
@@ -2246,10 +2244,10 @@ static size_t uvarint_len(uint64_t v) {
 
 static PyObject *py_commit_prep_fused(PyObject *, PyObject *args) {
   Py_buffer flags, sigs, tsec, tnan, pubs, power, pfxc, pfxn, sfx;
-  Py_ssize_t threshold, mode, ram_max_len;
-  if (!PyArg_ParseTuple(args, "y*y*y*y*y*y*y*y*y*nnn", &flags, &sigs, &tsec,
+  Py_ssize_t threshold, mode;
+  if (!PyArg_ParseTuple(args, "y*y*y*y*y*y*y*y*y*nn", &flags, &sigs, &tsec,
                         &tnan, &pubs, &power, &pfxc, &pfxn, &sfx, &threshold,
-                        &mode, &ram_max_len))
+                        &mode))
     return nullptr;
   Py_ssize_t n = flags.len;
   auto release_all = [&]() {
@@ -2264,7 +2262,7 @@ static PyObject *py_commit_prep_fused(PyObject *, PyObject *args) {
     PyBuffer_Release(&sfx);
   };
   if (sigs.len < 64 * n || tsec.len < 8 * n || tnan.len < 4 * n ||
-      pubs.len < 32 * n || power.len < 8 * n || ram_max_len < 0) {
+      pubs.len < 32 * n || power.len < 8 * n) {
     release_all();
     PyErr_SetString(PyExc_ValueError, "bad commit prep inputs");
     return nullptr;
@@ -2303,7 +2301,7 @@ static PyObject *py_commit_prep_fused(PyObject *, PyObject *args) {
     Py_DECREF(sel_out);
     return tup;
   }
-  // pass 2: per-record sign-bytes lengths -> offsets (+ ram feasibility)
+  // pass 2: per-record sign-bytes lengths -> offsets
   PyObject *offs_out = PyBytes_FromStringAndSize(nullptr, (m + 1) * 8);
   if (!offs_out) {
     Py_DECREF(sel_out);
@@ -2311,7 +2309,6 @@ static PyObject *py_commit_prep_fused(PyObject *, PyObject *args) {
     return nullptr;
   }
   int64_t *offs = (int64_t *)PyBytes_AS_STRING(offs_out);
-  int64_t max_msg = 0;
   Py_BEGIN_ALLOW_THREADS
   offs[0] = 0;
   for (Py_ssize_t j = 0; j < m; j++) {
@@ -2322,26 +2319,14 @@ static PyObject *py_commit_prep_fused(PyObject *, PyObject *args) {
                 (nanos ? 1 + uvarint_len(nanos) : 0);
     size_t plen = fp[i] == 3 ? (size_t)pfxn.len : (size_t)pfxc.len;
     size_t body = plen + 1 + uvarint_len(tn) + tn + (size_t)sfx.len;
-    int64_t rec = (int64_t)(uvarint_len(body) + body);
-    if (rec > max_msg) max_msg = rec;
-    offs[j + 1] = offs[j] + rec;
+    offs[j + 1] = offs[j] + (int64_t)(uvarint_len(body) + body);
   }
   Py_END_ALLOW_THREADS
-  bool want_ram = ram_max_len > 0 && 64 + max_msg <= (int64_t)ram_max_len;
-  Py_ssize_t nblock = want_ram ? (ram_max_len + 17 + 127) / 128 : 0;
   PyObject *pub_out = PyBytes_FromStringAndSize(nullptr, m * 32);
   PyObject *sig_out = PyBytes_FromStringAndSize(nullptr, m * 64);
   PyObject *msgs_out = PyBytes_FromStringAndSize(nullptr, offs[m]);
-  PyObject *hi_out = nullptr, *lo_out = nullptr, *cnt_out = nullptr;
-  if (want_ram) {
-    hi_out = PyBytes_FromStringAndSize(nullptr, m * nblock * 16 * 4);
-    lo_out = PyBytes_FromStringAndSize(nullptr, m * nblock * 16 * 4);
-    cnt_out = PyBytes_FromStringAndSize(nullptr, m * 4);
-  }
-  if (!pub_out || !sig_out || !msgs_out ||
-      (want_ram && (!hi_out || !lo_out || !cnt_out))) {
+  if (!pub_out || !sig_out || !msgs_out) {
     Py_XDECREF(pub_out); Py_XDECREF(sig_out); Py_XDECREF(msgs_out);
-    Py_XDECREF(hi_out); Py_XDECREF(lo_out); Py_XDECREF(cnt_out);
     Py_DECREF(sel_out); Py_DECREF(offs_out);
     release_all();
     return nullptr;
@@ -2349,13 +2334,8 @@ static PyObject *py_commit_prep_fused(PyObject *, PyObject *args) {
   uint8_t *pub_d = (uint8_t *)PyBytes_AS_STRING(pub_out);
   uint8_t *sig_d = (uint8_t *)PyBytes_AS_STRING(sig_out);
   uint8_t *msg_d = (uint8_t *)PyBytes_AS_STRING(msgs_out);
-  uint32_t *hi_d = want_ram ? (uint32_t *)PyBytes_AS_STRING(hi_out) : nullptr;
-  uint32_t *lo_d = want_ram ? (uint32_t *)PyBytes_AS_STRING(lo_out) : nullptr;
-  int32_t *cnt_d = want_ram ? (int32_t *)PyBytes_AS_STRING(cnt_out) : nullptr;
   Py_BEGIN_ALLOW_THREADS
   parallel_ranges(m, 1024, [&](Py_ssize_t lo_j, Py_ssize_t hi_j) {
-    std::vector<uint8_t> ram_row;
-    if (want_ram) ram_row.resize((size_t)nblock * 128);
     for (Py_ssize_t j = lo_j; j < hi_j; j++) {
       Py_ssize_t i = (Py_ssize_t)sel[(size_t)j];
       memcpy(pub_d + 32 * j, pp + 32 * i, 32);
@@ -2391,45 +2371,17 @@ static PyObject *py_commit_prep_fused(PyObject *, PyObject *args) {
       memcpy(p, mid, mn);
       p += mn;
       memcpy(p, sfx.buf, sfx.len);
-      if (want_ram) {
-        size_t mlen = (size_t)(offs[j + 1] - offs[j]);
-        size_t tot = 64 + mlen;
-        memset(ram_row.data(), 0, ram_row.size());
-        memcpy(ram_row.data(), gp + 64 * i, 32);       // R
-        memcpy(ram_row.data() + 32, pp + 32 * i, 32);  // A
-        memcpy(ram_row.data() + 64, msg_d + offs[j], mlen);
-        ram_row[tot] = 0x80;
-        size_t blocks = (tot + 17 + 127) / 128;
-        uint64_t bitlen = (uint64_t)tot * 8;
-        uint8_t *tail = ram_row.data() + blocks * 128 - 8;
-        for (int b = 0; b < 8; b++)
-          tail[b] = (uint8_t)(bitlen >> (8 * (7 - b)));
-        cnt_d[j] = (int32_t)blocks;
-        uint32_t *hi_row = hi_d + (size_t)j * nblock * 16;
-        uint32_t *lo_row = lo_d + (size_t)j * nblock * 16;
-        for (Py_ssize_t w = 0; w < nblock * 16; w++) {
-          const uint8_t *q = ram_row.data() + 8 * w;
-          hi_row[w] = ((uint32_t)q[0] << 24) | ((uint32_t)q[1] << 16) |
-                      ((uint32_t)q[2] << 8) | (uint32_t)q[3];
-          lo_row[w] = ((uint32_t)q[4] << 24) | ((uint32_t)q[5] << 16) |
-                      ((uint32_t)q[6] << 8) | (uint32_t)q[7];
-        }
-      }
     }
   });
   Py_END_ALLOW_THREADS
   release_all();
   PyObject *t = PyLong_FromLongLong((long long)tallied);
-  PyObject *none = Py_None;
   PyObject *tup =
-      t ? PyTuple_Pack(9, sel_out, t, pub_out, sig_out, msgs_out, offs_out,
-                       want_ram ? hi_out : none, want_ram ? lo_out : none,
-                       want_ram ? cnt_out : none)
+      t ? PyTuple_Pack(6, sel_out, t, pub_out, sig_out, msgs_out, offs_out)
         : nullptr;
   Py_XDECREF(t);
   Py_DECREF(sel_out); Py_DECREF(pub_out); Py_DECREF(sig_out);
   Py_DECREF(msgs_out); Py_DECREF(offs_out);
-  Py_XDECREF(hi_out); Py_XDECREF(lo_out); Py_XDECREF(cnt_out);
   return tup;
 }
 
@@ -2634,7 +2586,7 @@ static PyObject *py_commit_decode_columns(PyObject *, PyObject *arg) {
 static PyMethodDef Methods[] = {
     {"commit_prep_fused", py_commit_prep_fused, METH_VARARGS,
      "Fused columnar commit prep: selection + tally + sign-bytes + "
-     "pub/sig gather + device-hash RAM blocks, one GIL-released call"},
+     "pub/sig gather, one GIL-released call"},
     {"ed25519_batch_verify", py_ed25519_batch_verify, METH_VARARGS,
      "Host RLC batch ed25519 verification (Pippenger MSM); returns bool"},
     {"ed25519_rlc_scalars", py_ed25519_rlc_scalars, METH_VARARGS,

@@ -315,6 +315,10 @@ class _Span:
         self._flow = flow
         self._phase = phase
 
+    def note(self, **args) -> None:
+        """Args learned inside the span (a bucket the body chose)."""
+        self._args = {**self._args, **args} if self._args else args
+
     def __enter__(self) -> "_Span":
         if _devcheck.enabled():
             _devcheck.span_opened(self._name)
@@ -342,6 +346,9 @@ class _NullSpan:
     """Disabled-path context manager: shared, allocation-free."""
 
     __slots__ = ()
+
+    def note(self, **args) -> None:
+        pass
 
     def __enter__(self) -> "_NullSpan":
         return self

@@ -14,13 +14,16 @@ The challenge scalar k = SHA512(R||A||M) mod L is computed host-side via
 _challenges — the native batch helper (tm_native.ed25519_challenges,
 OpenSSL SHA-512 + fold-based mod L in one C call per batch) when built,
 else a hashlib loop. The per-sig Python loop it replaced measured ~50% of
-end-to-end batch time on a loaded host. A device SHA-512 path
-(ops.sha512 + prepare_batch_device_hash) exists for fixed-size
-sign-bytes workloads.
+end-to-end batch time on a loaded host.
+
+`select_kernel` is the one place that picks an engine for a batch: every
+path that launches (the dispatcher's `_prepare`, the direct chunk loop
+below, the mesh's segments) asks it.
 """
 
 from __future__ import annotations
 
+import contextlib
 import hashlib
 import os
 import time
@@ -74,15 +77,6 @@ BUCKETS = (128, 1024, 10240)
 # device win; use the host (OpenSSL) path. Mirrors the spirit of the
 # reference's batchVerifyThreshold (types/validation.go:12) at device scale.
 DEVICE_THRESHOLD = int(os.environ.get("TM_TPU_DEVICE_THRESHOLD", "64"))
-
-# Messages up to this size hash on-device (R||A||M padded buffers);
-# longer messages fall back to host hashlib for the challenge scalar.
-# 192 covers canonical vote sign-bytes (~120B + 50-char chain ids).
-# Defined in commit_prep (jax-free) so the types layer can size the fused
-# prep's RAM columns without importing the device stack.
-from .commit_prep import DEVICE_HASH_MAX_MSG  # noqa: E402
-
-HOST_HASH = bool(int(os.environ.get("TM_TPU_HOST_HASH", "0")))
 
 _L_BYTES = L.to_bytes(32, "little")
 
@@ -403,48 +397,14 @@ def prepare_batch_cached(entries: EntryBlock, bucket: int, ep) -> tuple:
     return args
 
 
-def prepare_batch_cached_device_hash(
-    entries: EntryBlock, bucket: int, ep
-) -> tuple:
-    """Warm-epoch device-hash prep: per-signature R||A||M SHA blocks (the
-    hash input — message data, shipped either way) + raw r/s rows +
-    val_idx. Drops prepare_batch_device_hash's pubkey limb pack and the
-    s-bit transpose entirely."""
-    from . import sha512 as _sha
-
-    n = len(entries)
-    t0 = time.perf_counter()
-    with _span("ops.host_prep", n=n, bucket=bucket, hash="device", cached=1):
-        idx, r_rows, s_rows, s_ok = _pack_sig_rows(entries, bucket, ep)
-        with _span("ops.sha_pad"):
-            ram = None
-            if entries.ram_hi is not None:
-                ram = _sha.pad_ram_rows(
-                    entries, bucket, 64 + DEVICE_HASH_MAX_MSG
-                )
-            if ram is None:
-                ram = _sha.pad_ram_block(
-                    entries, bucket, 64 + DEVICE_HASH_MAX_MSG
-                )
-            hi, lo, counts = ram
-    args = (idx, r_rows, s_rows, hi, lo, counts, s_ok)
-    _ops_m().host_prep_seconds.observe(
-        time.perf_counter() - t0, bucket=str(bucket)
-    )
-    return args
-
-
-def cached_kernel(ep, device_hash: bool, donate: bool = False):
+def cached_kernel(ep, donate: bool = False):
     """Kernel closure for a warm epoch: resolves the entry's device
     tables at CALL time — the caller is the pipeline's single
     dispatch-owner thread, so the one-time table upload happens on the
     only thread allowed to touch the device. The tables ride as the two
     leading (never-donated) arguments; `donate` applies only to the
     per-batch args."""
-    if device_hash:
-        base = ed25519_verify.jitted_verify_cached_device_hash(donate)
-    else:
-        base = ed25519_verify.jitted_verify_cached(donate)
+    base = ed25519_verify.jitted_verify_cached(donate)
 
     def call(*args):
         tbl_limbs, tbl_sign = ep.xla_tables()
@@ -535,35 +495,7 @@ def verify_batch_secp(entries) -> np.ndarray:
     returns (n,) bool. Direct device path — devcheck-exempt like
     verify_batch."""
     with _devcheck.exempt():
-        from . import epoch_cache as _epoch
-        from . import secp_verify as _sv
-
-        ep = _epoch.lookup(entries)
-        if ep is not None and ep.scheme != "secp256k1":
-            ep = None
-        out: List[np.ndarray] = []
-        i = 0
-        n_total = len(entries)
-        while i < n_total:
-            chunk = entries[i : i + BUCKETS[-1]]
-            bucket = _secp_bucket_for(len(chunk))
-            t0 = time.perf_counter()
-            if ep is not None:
-                args = prepare_batch_secp_cached(chunk, bucket, ep)
-                kern = secp_cached_kernel(ep)
-            else:
-                args = prepare_batch_secp(chunk, bucket)
-                kern = secp_kernel()
-            t1 = time.perf_counter()
-            with _span("ops.device_wait", bucket=bucket, scheme="secp256k1"):
-                res = np.array(kern(*args))
-            _note_device_batch(
-                len(chunk), bucket, prep_s=t1 - t0,
-                device_s=time.perf_counter() - t1,
-            )
-            out.append(res[: len(chunk)])
-            i += len(chunk)
-        return np.concatenate(out) if out else np.zeros((0,), dtype=bool)
+        return _verify_batch_direct(entries, BUCKETS[-1], "secp256k1")
 
 
 # -- bls12381 aggregation lane (ISSUE 20) ------------------------------------
@@ -673,37 +605,10 @@ def verify_batch_bls_codes(block) -> np.ndarray:
     """Run the aggregation lane over an AggBlock; returns the (k,) int32
     verdict-code row (ops/bls_verify code constants). Direct device path —
     devcheck-exempt like verify_batch."""
+    if len(block) == 0:
+        return np.zeros((0,), dtype=np.int32)
     with _devcheck.exempt():
-        from . import bls_verify as _bv
-
-        k = len(block)
-        if k == 0:
-            return np.zeros((0,), dtype=np.int32)
-        ep = _bls_epoch(block)
-        bad = _bls_bad_rows(block.pub48)
-        vp = ep.vp if ep is not None else block.pub48.shape[0] + 1
-        out: List[np.ndarray] = []
-        i = 0
-        while i < k:
-            chunk = block[i : i + BLS_BUCKETS[-1]]
-            bucket = _bls_bucket_for(len(chunk))
-            t0 = time.perf_counter()
-            masks, coeffs, ok, reasons = prepare_batch_bls(
-                chunk, bucket, vp, bad_rows=bad
-            )
-            kern = bls_kernel(chunk, ok, reasons, ep=ep)
-            t1 = time.perf_counter()
-            with _span("ops.device_wait", bucket=bucket, scheme="bls12381"):
-                # owning copy: np.asarray would alias the XLA buffer, and a
-                # donated later launch could mutate the slice we hand out
-                codes = np.array(kern(masks, coeffs))
-            _note_device_batch(
-                len(chunk), bucket, prep_s=t1 - t0,
-                device_s=time.perf_counter() - t1,
-            )
-            out.append(codes[: len(chunk)])
-            i += len(chunk)
-        return np.concatenate(out)
+        return _verify_batch_direct(block, BLS_BUCKETS[-1])
 
 
 def verify_batch_bls(block) -> np.ndarray:
@@ -711,61 +616,6 @@ def verify_batch_bls(block) -> np.ndarray:
     from . import bls_verify as _bv
 
     return verify_batch_bls_codes(block) == _bv.CODE_VALID
-
-
-def prepare_batch_device_hash(entries, bucket: int) -> tuple:
-    """Device-hash argument prep: no host SHA-512 — messages ship as padded
-    R||A||M SHA blocks. EntryBlock input pads columnar (pad_ram_block);
-    tuple lists build the per-message R||A||M bytes as before."""
-    from . import sha512 as _sha
-
-    n = len(entries)
-    t0 = time.perf_counter()
-    with _span("ops.host_prep", n=n, bucket=bucket, hash="device"):
-        with _span("ops.pack_rows"):
-            pub, r_enc, s_enc = _pack_rows(entries, bucket)
-        s_ok = _s_below_l(s_enc, n, bucket)
-        with _span("ops.sha_pad"):
-            if isinstance(entries, EntryBlock):
-                ram = None
-                if entries.ram_hi is not None:
-                    # fused commit prep already laid the R||A||M SHA
-                    # blocks per row — pad rows, skip the byte scatter
-                    ram = _sha.pad_ram_rows(
-                        entries, bucket, 64 + DEVICE_HASH_MAX_MSG
-                    )
-                if ram is not None:
-                    hi, lo, counts = ram
-                else:
-                    hi, lo, counts = _sha.pad_ram_block(
-                        entries, bucket, 64 + DEVICE_HASH_MAX_MSG
-                    )
-            else:
-                msgs = [sig[:32] + pk + msg for pk, msg, sig in entries]
-                msgs += [b"\x01" + bytes(31) + b"\x01" + bytes(31)] * (
-                    bucket - n
-                )
-                hi, lo, counts = _sha.pad_messages(
-                    msgs, 64 + DEVICE_HASH_MAX_MSG
-                )
-        a_sign = (pub[:, 31] >> 7).astype(np.int32)
-        r_sign = (r_enc[:, 31] >> 7).astype(np.int32)
-        with _span("ops.limb_pack"):
-            args = (
-                _pack_le_limbs(pub),
-                a_sign,
-                _pack_le_limbs(r_enc),
-                r_sign,
-                _bits_253(s_enc),
-                hi,
-                lo,
-                counts,
-                s_ok,
-            )
-    _ops_m().host_prep_seconds.observe(
-        time.perf_counter() - t0, bucket=str(bucket)
-    )
-    return args
 
 
 def _pallas_bucket(n: int) -> int:
@@ -795,14 +645,123 @@ def max_coalesce() -> int:
     return BUCKETS[-1]
 
 
-def _max_msg_len(entries) -> int:
-    """Longest message in a batch — O(1) columnar from an EntryBlock's
-    offset table, a generator scan for tuple lists."""
-    if isinstance(entries, EntryBlock):
-        if not len(entries):
-            return 0
-        return int(np.diff(entries.offsets).max())
-    return max((len(m) for _, m, _ in entries), default=0)
+def warm_epoch(entries, scheme: str = ""):
+    """The batch's device-resident committee (its ops/epoch_cache.py
+    entry, keyed by ValidatorSet.hash()), or None: no key, evicted, cache
+    off, or another scheme's. A warm batch ships per-signature data only
+    and the kernel gathers the committee's columns on the device."""
+    from . import epoch_cache as _epoch
+
+    if (scheme or getattr(entries, "scheme", "ed25519")) == "bls12381":
+        # AggBlocks carry no gather indices: the lane keys its epoch on
+        # the bitmap's committee
+        return _bls_epoch(entries)
+    return _epoch.lookup(entries)
+
+
+def select_kernel(entries, bucket: int = 0, lanes: int = 0, mesh=None,
+                  scheme: str = ""):
+    """The engine for ONE device batch, chosen here and nowhere else:
+    scheme, then the platform's ed25519 family (ops/engine.py: Pallas RLC
+    on a TPU, the XLA op graph elsewhere, which is also the tests'
+    reference), then warm epoch or not. Host prep runs here too.
+
+    Returns the dispatcher's `_prepare` contract, (launch_fn, args,
+    rlc_entries, bucket): `launch_fn(*args)` on the device-owner thread
+    gives the verdict row; `rlc_entries` is the batch itself when that
+    row holds one verdict per M-signature RLC lane (the caller expands
+    and re-verifies rejected lanes on the host, pallas_rlc.expand_lanes)
+    and None when it holds one per row; `bucket` is the padded width.
+
+    bucket  0 quantizes on the family's own ladder; the mesh forces its
+            superbatch's width, warmup() the shape to compile
+    lanes   > 0: a pack of that many lanes whose verdicts the caller
+            demuxes by row, so the per-signature kernel of the family
+            runs where the RLC one would (debt C1a)
+    mesh    a jax Mesh to shard the batch axis over: the launch is then
+            the family's shard_map twin (ops/sharded.py) and carries its
+            per-argument transfer placements as `launch_fn.shardings`.
+            secp256k1 and bls12381 have no twin and launch on one device
+    scheme  for tuple lists, which carry none (blocks carry their own)
+    """
+    n = len(entries)
+    scheme = scheme or getattr(entries, "scheme", "ed25519")
+    eng = engine()
+    # donation: launches consume their per-batch inputs so XLA recycles
+    # the pages; epoch tables stay exempt in every kernel's donate_argnums
+    donate = eng.donate
+    ep = warm_epoch(entries, scheme)
+    if scheme == "bls12381":
+        # one row = one aggregated commit
+        bucket = bucket or _bls_bucket_for(n)
+        vp = ep.vp if ep is not None else entries.pub48.shape[0] + 1
+        masks, coeffs, ok, reasons = prepare_batch_bls(
+            entries, bucket, vp, bad_rows=_bls_bad_rows(entries.pub48)
+        )
+        fn = bls_kernel(entries, ok, reasons, ep=ep, donate=donate)
+        return fn, (masks, coeffs), None, bucket
+    if scheme == "secp256k1":
+        # Strauss+GLV ECDSA: plain XLA jit on every platform
+        bucket = bucket or _secp_bucket_for(n)
+        if ep is not None:
+            return (secp_cached_kernel(ep, donate),
+                    prepare_batch_secp_cached(entries, bucket, ep),
+                    None, bucket)
+        return (secp_kernel(donate), prepare_batch_secp(entries, bucket),
+                None, bucket)
+    if mesh is not None:
+        from . import sharded as _sharded
+    if eng.pallas:
+        # the Pallas preps record no time of their own
+        t0 = time.perf_counter()
+        if eng.rlc and not lanes:
+            from . import pallas_rlc
+
+            fn, args, bucket = pallas_rlc.rlc_launch(
+                entries, ep, bucket=bucket, interpret=eng.interpret,
+                donate=donate,
+            )
+            rlc_entries = entries
+        else:
+            from . import pallas_verify as _pv
+
+            bucket = bucket or _pallas_bucket(n)
+            block = _pv.pick_block(bucket // max(lanes, 1))
+            rlc_entries = None
+            if ep is not None and not lanes:
+                args = _pv.prepare_compact_cached(entries, bucket, ep)
+                fn = _pv.cached_compact_fn(
+                    ep, bucket, block, eng.interpret, donate
+                )
+            else:
+                # a lane pack ships its pubs: no coords table per shard
+                args = _pv.prepare_compact(entries, bucket)
+                if mesh is not None:
+                    fn = _sharded.mesh_pallas_valid_fn(
+                        mesh, bucket // lanes, block, eng.interpret
+                    )
+                else:
+                    fn = _pv._jitted_pallas_verify(
+                        bucket, block, eng.interpret, donate=donate
+                    )
+        _ops_m().host_prep_seconds.observe(
+            time.perf_counter() - t0, bucket=str(bucket)
+        )
+        return fn, args, rlc_entries, bucket
+    bucket = bucket or _bucket_for(n)
+    if ep is not None:
+        args = prepare_batch_cached(entries, bucket, ep)
+        if mesh is not None:
+            fn = _sharded.mesh_valid_fn_cached(mesh, ep, donate)
+        else:
+            fn = cached_kernel(ep, donate)
+    else:
+        args = prepare_batch(entries, bucket)
+        if mesh is not None:
+            fn = _sharded.mesh_valid_fn(mesh, donate)
+        else:
+            fn = ed25519_verify.jitted_verify(donate)
+    return fn, args, None, bucket
 
 
 def verify_batch(entries) -> np.ndarray:
@@ -820,98 +779,39 @@ def verify_batch(entries) -> np.ndarray:
     if scheme == "bls12381":
         return verify_batch_bls(entries)
     with _devcheck.exempt():
-        return _verify_batch_direct(entries)
+        return _verify_batch_direct(entries, max_coalesce())
 
 
-def _verify_batch_direct(entries) -> np.ndarray:
-    eng = engine()
-    if eng.pallas:
-        from . import pallas_verify
-
-        interpret = eng.interpret
-        if eng.rlc:
-            from . import pallas_rlc
-
-            n = len(entries)
-            t0 = time.perf_counter()
-            with _span("ops.device_rlc", n=n):
-                res = pallas_rlc.verify_batch_rlc(entries, interpret=interpret)
-            elapsed = time.perf_counter() - t0
-            # verify_batch_rlc chunks internally at MAX_SIGS — account per
-            # chunk so batches/padded_lanes match what actually dispatched;
-            # elapsed (prep+device, coarse) is attributed to the first
-            # chunk only so device_seconds is not multiply counted
-            i = 0
-            while i < n:
-                c = min(n - i, pallas_rlc.MAX_SIGS)
-                _note_device_batch(
-                    c, pallas_rlc.plan_bucket(c)[0],
-                    device_s=elapsed if i == 0 else -1.0,
-                )
-                i += c
-            return res
-        out = []
-        i = 0
-        while i < len(entries):
-            chunk = entries[i : i + BUCKETS[-1]]
-            bucket = _pallas_bucket(len(chunk))
-            t0 = time.perf_counter()
-            with _span("ops.host_prep", n=len(chunk), bucket=bucket):
-                args = pallas_verify.prepare_compact(chunk, bucket)
-            t1 = time.perf_counter()
-            with _span("ops.device_wait", bucket=bucket):
-                res = pallas_verify.verify_compact(*args, interpret=interpret)
-            _note_device_batch(
-                len(chunk), bucket, prep_s=t1 - t0,
-                device_s=time.perf_counter() - t1,
-            )
-            out.append(res[: len(chunk)])
-            i += len(chunk)
-        return np.concatenate(out) if out else np.zeros((0,), dtype=bool)
-
-    device_hash = not HOST_HASH and _max_msg_len(entries) <= DEVICE_HASH_MAX_MSG
-    from . import epoch_cache as _epoch
-
-    ep = _epoch.lookup(entries)
+def _verify_batch_direct(entries, step: int, scheme: str = "") -> np.ndarray:
+    """The chunk loop around select_kernel: `step` rows at a time, each
+    chunk prepared, launched and waited for on the caller's thread."""
     out: List[np.ndarray] = []
-    i = 0
-    while i < len(entries):
-        chunk = entries[i : i + BUCKETS[-1]]
-        bucket = _bucket_for(len(chunk))
-        # same donate flag as the pipeline's _prepare: the jitted-wrapper
-        # caches key on it, so defaulting here would compile every bucket
-        # twice (and de-warm warmup())
-        donate = eng.donate
-        if ep is not None:
-            # warm epoch: committee gathers from the device-resident
-            # table, per-sig rows ship raw and unpack on device
-            kern = cached_kernel(ep, device_hash, donate)
-            if device_hash:
-                args = prepare_batch_cached_device_hash(chunk, bucket, ep)
-            else:
-                args = prepare_batch_cached(chunk, bucket, ep)
-        elif device_hash:
-            kern = ed25519_verify.jitted_verify_device_hash(donate)
-            args = prepare_batch_device_hash(chunk, bucket)
-        else:
-            kern = ed25519_verify.jitted_verify(donate)
-            args = prepare_batch(chunk, bucket)
+    for i in range(0, len(entries), step):
+        chunk = entries[i : i + step]
+        fn, args, rlc_entries, bucket = select_kernel(chunk, scheme=scheme)
         # dispatch vs wait split: jax dispatch returns before the device
-        # finishes; the np.asarray blocks until the result materializes
+        # finishes; materializing the result blocks until it has
         t0 = time.perf_counter()
-        with _span("ops.device_dispatch", bucket=bucket):
-            dev = kern(*args)
-        with _span("ops.device_wait", bucket=bucket):
-            # owned copy, not a view: under donation a later chunk's
-            # launch recycles the output page and would mutate earlier
-            # chunks' verdicts still sitting in `out` (the PR-7 bug
-            # class, here across the chunks of ONE oversized batch)
-            res = np.asarray(dev)[: len(chunk)].copy()
+        with (_span("ops.device_rlc", n=len(chunk))
+              if rlc_entries is not None else contextlib.nullcontext()):
+            with _span("ops.device_dispatch", bucket=bucket):
+                dev = fn(*args)
+            with _span("ops.device_wait", bucket=bucket):
+                # owned copy, not a view: under donation a later chunk's
+                # launch recycles the output page and would mutate earlier
+                # chunks' verdicts still sitting in `out` (the PR-7 bug
+                # class, here across the chunks of ONE oversized batch)
+                res = np.array(dev)
+            if res.ndim == 2:  # pallas rows are (1, N) int32
+                res = res[0].astype(bool)
+            if rlc_entries is not None:
+                from . import pallas_rlc
+
+                res = pallas_rlc.expand_lanes(res, rlc_entries)
         _note_device_batch(
             len(chunk), bucket, device_s=time.perf_counter() - t0
         )
-        out.append(res)
-        i += len(chunk)
+        out.append(res[: len(chunk)])
     return np.concatenate(out) if out else np.zeros((0,), dtype=bool)
 
 
@@ -1020,9 +920,7 @@ class Ed25519DeviceBatchVerifier(BatchVerifier):
 
 
 def warmup(bucket: int = BUCKETS[0]) -> None:
-    """Pre-compile the kernel for a bucket (first XLA compile is slow)."""
-    verify_batch([])  # no-op; keeps import light
-    args = prepare_batch([], bucket)
-    # the donate flag keys the jitted-wrapper cache — warm the variant
-    # the pipeline will actually launch
-    np.asarray(ed25519_verify.jitted_verify(engine().donate)(*args))
+    """Pre-compile the platform's kernel for a bucket (the first compile
+    of a shape is slow)."""
+    fn, args, _rlc, _bucket = select_kernel(EntryBlock.empty(), bucket=bucket)
+    np.asarray(fn(*args))

@@ -80,7 +80,7 @@ LayoutKey = Tuple
 def layout_key(bucket: int, args) -> LayoutKey:
     """Compiled-layout key for a prepared argument tuple: the bucket plus
     every host array's (shape, dtype). Distinct preps (cached/uncached,
-    host-hash/device-hash, RLC) of the same bucket get distinct keys —
+    per-signature, RLC) of the same bucket get distinct keys —
     a slot only ever recycles buffers of identical layout."""
     return (bucket,) + tuple(
         (a.shape, a.dtype.str) for a in args if isinstance(a, np.ndarray)

@@ -1,8 +1,8 @@
-"""Batched ZIP-215 ed25519 verification kernel (JAX/XLA, TPU-first).
+"""Batched ZIP-215 ed25519 verification kernel as a plain XLA op graph.
 
-The device replacement for the reference's batch verifier
-(crypto/ed25519/ed25519.go:192-227, curve25519-voi ZIP-215 config) and the
-compute half of SURVEY.md §7 stage 1. Semantics are *per-signature*
+The engine of every platform that is not a TPU (ops/engine.py: Pallas
+could only interpret there) and the tests' reference for the Pallas
+kernels the chip runs (ops/pallas_rlc.py). Semantics are *per-signature*
 cofactored verification — exactly the oracle in
 tendermint_tpu.crypto._edwards.verify_zip215:
 
@@ -10,11 +10,11 @@ tendermint_tpu.crypto._edwards.verify_zip215:
                 0 <= s < L (checked host-side), and
                 [8]([s]B - R - [k]A) == O,  k = SHA512(R||A||M) mod L.
 
-Per-signature evaluation (vs the reference's random-linear-combination
-batch) is the right shape for TPU: it is embarrassingly parallel over the
-batch axis, needs no host-side randomness, and directly yields the per-sig
-valid[] vector that types/validation.go:242-248 needs for blame assignment
-— the reference has to re-verify one-by-one on batch failure to get it.
+The challenge k is hashed on the host (backend._challenges: one native
+SHA-512 pass per batch) and ships as a scalar; the kernel is the ladder.
+Per-signature evaluation yields the valid[] vector that
+types/validation.go:242-248 needs for blame assignment directly, with no
+host-side randomness.
 
 Control flow is branchless (complete twisted-Edwards formulas, masked
 selects), shapes are static per bucket: everything jits to one XLA
@@ -32,7 +32,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from . import fe, sc, sha512 as _sha
+from . import fe
 from ..crypto import _edwards
 
 # Curve constants in limb form (host-computed Python ints -> 20-limb arrays).
@@ -287,36 +287,6 @@ def verify_kernel_cached(
     return verify_kernel(a_y, a_sign, r_y, r_sign, s_bits_t, k_bits_t, s_ok)
 
 
-def verify_kernel_cached_device_hash(
-    a_tbl_limbs, a_tbl_sign, val_idx, r_enc, s_enc,
-    blocks_hi, blocks_lo, n_blocks, s_ok
-):
-    """verify_kernel_device_hash on the epoch-cached committee: k hashes
-    on-chip from the shipped R||A||M blocks (per-signature message data),
-    A limbs gather from the device table, r/s unpack on device."""
-    digest = _sha.sha512_blocks(blocks_hi, blocks_lo, n_blocks)
-    k_limbs = sc.mod_l_from_bits(sc.digest_to_le_bits(digest))
-    k_bits_t = sc.limbs_to_bits(k_limbs, SCALAR_BITS)
-    a_y = a_tbl_limbs[val_idx]
-    a_sign = a_tbl_sign[val_idx]
-    r_y, r_sign = unpack_limbs_rows(r_enc.astype(jnp.int32))
-    s_bits_t = bits253_rows(s_enc.astype(jnp.int32))
-    return verify_kernel(a_y, a_sign, r_y, r_sign, s_bits_t, k_bits_t, s_ok)
-
-
-def verify_kernel_device_hash(
-    a_y, a_sign, r_y, r_sign, s_bits_t, blocks_hi, blocks_lo, n_blocks, s_ok
-):
-    """Fully-device path: the challenge k = SHA512(R||A||M) mod L is
-    computed on-chip (ops.sha512 + ops.sc) before the ladder — no host
-    hashing in the hot loop (SURVEY.md §7 hard-part #2 resolved on
-    device)."""
-    digest = _sha.sha512_blocks(blocks_hi, blocks_lo, n_blocks)
-    k_limbs = sc.mod_l_from_bits(sc.digest_to_le_bits(digest))
-    k_bits_t = sc.limbs_to_bits(k_limbs, SCALAR_BITS)
-    return verify_kernel(a_y, a_sign, r_y, r_sign, s_bits_t, k_bits_t, s_ok)
-
-
 # Donation (ISSUE 7): with donate=True the jitted wrapper donates every
 # PER-BATCH input buffer to XLA, so a launch consumes its inputs and their
 # pages return to the allocator for the next batch's device_put — the
@@ -335,24 +305,8 @@ def jitted_verify(donate: bool = False):
 
 
 @functools.lru_cache(maxsize=None)
-def jitted_verify_device_hash(donate: bool = False):
-    if donate:
-        return jax.jit(verify_kernel_device_hash,
-                       donate_argnums=tuple(range(9)))
-    return jax.jit(verify_kernel_device_hash)
-
-
-@functools.lru_cache(maxsize=None)
 def jitted_verify_cached(donate: bool = False):
     if donate:
         return jax.jit(verify_kernel_cached,
                        donate_argnums=tuple(range(2, 7)))
     return jax.jit(verify_kernel_cached)
-
-
-@functools.lru_cache(maxsize=None)
-def jitted_verify_cached_device_hash(donate: bool = False):
-    if donate:
-        return jax.jit(verify_kernel_cached_device_hash,
-                       donate_argnums=tuple(range(2, 9)))
-    return jax.jit(verify_kernel_cached_device_hash)

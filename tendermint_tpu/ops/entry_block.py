@@ -37,27 +37,13 @@ _EMPTY_OFFSETS = np.zeros(1, dtype=np.int64)
 
 
 class EntryBlock:
-    """Columnar (pub, msg, sig) batch; see module docstring.
-
-    Optional `ram_*` columns carry each row's R||A||M message already
-    padded into SHA-512 blocks and packed into the device-hash kernel's
-    big-endian 32-bit word layout (ops/sha512.pad_ram_block output, but
-    per ROW instead of per padded bucket): ram_hi/ram_lo (n, W) uint32
-    with W = nblock*16, ram_counts (n,) int32 blocks-used. The fused
-    commit prep (ops/commit_prep.py) fills them while composing the sign
-    bytes — the bytes are in cache anyway — so prepare_batch_device_hash
-    skips its big scatter and just pads rows. They ride through concat
-    and slicing like every other column; blocks without them (tuple-list
-    conversions, mixed sources) simply fall back to the generic pad."""
+    """Columnar (pub, msg, sig) batch; see module docstring."""
 
     __slots__ = ("pub", "sig", "msgs", "offsets",
-                 "ram_hi", "ram_lo", "ram_counts",
                  "val_idx", "epoch_key", "scheme", "pub_aux")
 
     def __init__(self, pub: np.ndarray, sig: np.ndarray,
                  msgs: Union[bytes, memoryview], offsets: np.ndarray,
-                 ram_hi: "np.ndarray" = None, ram_lo: "np.ndarray" = None,
-                 ram_counts: "np.ndarray" = None,
                  val_idx: "np.ndarray" = None, epoch_key: bytes = None,
                  scheme: str = "ed25519", pub_aux: "np.ndarray" = None):
         n = pub.shape[0]
@@ -74,16 +60,6 @@ class EntryBlock:
         self.sig = sig
         self.msgs = msgs
         self.offsets = offsets
-        if ram_hi is not None:
-            if (
-                ram_lo is None or ram_counts is None
-                or ram_hi.shape != ram_lo.shape or ram_hi.shape[0] != n
-                or ram_counts.shape != (n,)
-            ):
-                raise ValueError("ram columns must be (n, W) hi/lo + (n,) counts")
-        self.ram_hi = ram_hi
-        self.ram_lo = ram_lo
-        self.ram_counts = ram_counts
         # Epoch-cache metadata (ops/epoch_cache.py): val_idx (n,) int32 —
         # each lane's row in its validator set's cached device pub table;
         # epoch_key — the ValidatorSet.hash() the table is keyed by. When
@@ -224,15 +200,11 @@ class EntryBlock:
         o = self.offsets
         base = int(o[start])
         mv = memoryview(self.msgs)[base : int(o[stop])]
-        ram = self.ram_hi is not None
         return EntryBlock(
             self.pub[start:stop],
             self.sig[start:stop],
             mv,
             o[start : stop + 1] - base,
-            ram_hi=self.ram_hi[start:stop] if ram else None,
-            ram_lo=self.ram_lo[start:stop] if ram else None,
-            ram_counts=self.ram_counts[start:stop] if ram else None,
             val_idx=(
                 self.val_idx[start:stop] if self.val_idx is not None else None
             ),
@@ -275,13 +247,6 @@ class EntryBlock:
             offsets[pos + 1 : pos + len(b) + 1] = o[1:] + base
             pos += len(b)
             base += int(o[-1])
-        ram_hi = ram_lo = ram_counts = None
-        if all(b.ram_hi is not None for b in blocks) and len(
-            {b.ram_hi.shape[1] for b in blocks}
-        ) == 1:
-            ram_hi = np.concatenate([b.ram_hi for b in blocks])
-            ram_lo = np.concatenate([b.ram_lo for b in blocks])
-            ram_counts = np.concatenate([b.ram_counts for b in blocks])
         # epoch metadata survives only a SAME-epoch merge: gather indices
         # are rows of one valset's device table, so a mixed-key concat
         # (the coalescer's mixed-valset fallback) drops to the uncached
@@ -298,8 +263,6 @@ class EntryBlock:
         if all(b.pub_aux is not None for b in blocks):
             pub_aux = np.concatenate([b.pub_aux for b in blocks])
         return EntryBlock(pub, sig, msgs, offsets,
-                          ram_hi=ram_hi, ram_lo=ram_lo,
-                          ram_counts=ram_counts,
                           val_idx=val_idx, epoch_key=epoch_key,
                           scheme=scheme, pub_aux=pub_aux)
 

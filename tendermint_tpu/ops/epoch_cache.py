@@ -13,7 +13,7 @@ invalidated (with `ed25519_columns`) by `_update_with_change_set`, so a
 membership or power change yields a NEW key and the stale entry ages out
 of the LRU. On first sight of a valset the cache registers its pubkey
 column; from the SECOND commit on, batches carry only per-signature data
-(sig rows, sign-bytes/RAM blocks, `val_idx` gather indices) and the
+(sig rows, sign-bytes, `val_idx` gather indices) and the
 kernels gather the committee from persistent device arrays:
 
     xla_tables()    (vp, 20) int32 limb rows + (vp,) sign bits — the

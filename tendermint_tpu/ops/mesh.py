@@ -81,8 +81,8 @@ except ImportError:  # pragma: no cover — standalone file load (crypto-less
     block_concat = _eb.block_concat
 
 # single-device bucket ladder (ops/backend.BUCKETS, duplicated here so the
-# packing layer stays importable without the device stack; backend asserts
-# they agree at prepare_superbatch time)
+# packing layer stays importable without the device stack;
+# tests/test_ops.py holds the two copies equal)
 _BUCKETS = (128, 1024, 10240)
 # smallest lane bucket an operator may force via TM_TPU_MESH_LANE_BUCKET:
 # the secp256k1 ladder's fine bucket floor (backend.SECP_BUCKETS) — its
@@ -91,7 +91,7 @@ _BUCKETS = (128, 1024, 10240)
 _LANE_BUCKET_FLOOR = 16
 
 # BLS12-381 aggregation lanes (ISSUE 20) quantize to their OWN tiny
-# ladder (backend.BLS_BUCKETS, asserted in sync at prep time): one row
+# ladder (backend.BLS_BUCKETS, held equal by the same test): one row
 # is one whole aggregated commit costing two Miller loops, so padding an
 # agg lane out to `lane_bucket` per-signature rows would burn orders of
 # magnitude more kernel time than the live work. Superbatch row offsets
@@ -495,74 +495,32 @@ def build_superblock(plan: MeshPlan) -> Tuple[object, List[Tuple]]:
 
 
 # ---------------------------------------------------------------------------
-# Device-facing half: superbatch prep + kernel selection. Runs on the
-# pipeline's prep pool; the returned launch fn runs ONLY on the
-# dispatch-owner thread (which also owns the transfer and any lazy
-# epoch-table upload inside the cached closures).
+# Device-facing half: superbatch prep. Runs on the pipeline's prep pool;
+# the returned launch fn runs ONLY on the dispatch-owner thread (which
+# also owns the transfer and any lazy epoch-table upload inside the
+# cached closures).
 # ---------------------------------------------------------------------------
 
 
-def _prepare_mixed_superbatch(sb: SchemeSuperBlock, donate: bool,
-                              bucket: int):
-    """Prep a mixed-scheme superbatch: each scheme segment gets its own
-    kernel + args, fused behind ONE launch fn that slices the flat arg
-    tuple back per segment and concatenates the verdict rows in segment
-    order — a single dispatch event for the whole commit. Per-segment
-    epoch entries still engage the cached gather prep when a segment
-    shares one warm key. XLA per-sig kernels only: the mixed face never
-    routes through pallas or shard_map (follow-up, ROADMAP 3a)."""
+def _prepare_mixed_superbatch(sb: SchemeSuperBlock, bucket: int):
+    """Prep a mixed-scheme superbatch: each scheme segment asks
+    backend.select_kernel for its kernel + args at its own width, fused
+    behind ONE launch fn that slices the flat arg tuple back per segment
+    and concatenates the verdict rows in segment order — a single
+    dispatch event for the whole commit. A segment that shares one warm
+    key still engages the cached gather prep. No shard_map here: every
+    segment launches on one device (follow-up, ROADMAP 3a)."""
     from . import backend as _backend
-    from . import ed25519_verify as _kernel
-    from . import epoch_cache as _epoch
 
     seg_fns: List[Tuple] = []
     flat_args: List = []
-    for scheme, blk, _off in sb.parts:
-        n = len(blk)
-        if scheme == "bls12381":
-            # aggregation lane (ISSUE 20): the whole segment is one
-            # committee's AggBlock at its exact quantized width — no
-            # lane_bucket padding (each pad row costs two Miller loops).
-            # masks/coeffs ship to the device; ok/reasons ride the
-            # closure for the host-side verdict-code fold.
-            bep = _backend._bls_epoch(blk)
-            vp = bep.vp if bep is not None else blk.pub48.shape[0] + 1
-            masks, coeffs, ok, reasons = _backend.prepare_batch_bls(
-                blk, n, vp, bad_rows=_backend._bls_bad_rows(blk.pub48)
-            )
-            args = (masks, coeffs)
-            fn = _backend.bls_kernel(blk, ok, reasons, ep=bep,
-                                     donate=donate)
-            seg_fns.append((fn, len(flat_args), len(flat_args) + len(args)))
-            flat_args.extend(args)
-            continue
-        ep = _epoch.lookup(blk)
-        if scheme == "secp256k1":
-            if ep is not None:
-                args = _backend.prepare_batch_secp_cached(blk, n, ep)
-                fn = _backend.secp_cached_kernel(ep, donate)
-            else:
-                args = _backend.prepare_batch_secp(blk, n)
-                fn = _backend.secp_kernel(donate)
-        else:
-            device_hash = (
-                not _backend.HOST_HASH
-                and _backend._max_msg_len(blk) <= _backend.DEVICE_HASH_MAX_MSG
-            )
-            if ep is not None:
-                if device_hash:
-                    args = _backend.prepare_batch_cached_device_hash(
-                        blk, n, ep
-                    )
-                else:
-                    args = _backend.prepare_batch_cached(blk, n, ep)
-                fn = _backend.cached_kernel(ep, device_hash, donate)
-            elif device_hash:
-                args = _backend.prepare_batch_device_hash(blk, n)
-                fn = _kernel.jitted_verify_device_hash(donate)
-            else:
-                args = _backend.prepare_batch(blk, n)
-                fn = _kernel.jitted_verify(donate)
+    for _scheme, blk, _off in sb.parts:
+        # the segment's exact width: a BLS lane is already quantized (a
+        # pad row there costs two Miller loops), the others are whole
+        # lane buckets
+        fn, args, _rlc, _b = _backend.select_kernel(
+            blk, bucket=len(blk), lanes=1
+        )
         seg_fns.append((fn, len(flat_args), len(flat_args) + len(args)))
         flat_args.extend(args)
 
@@ -570,7 +528,10 @@ def _prepare_mixed_superbatch(sb: SchemeSuperBlock, donate: bool,
         import jax.numpy as jnp
 
         outs = [fn(*flat[lo:hi]) for fn, lo, hi in seg_fns]
-        return jnp.concatenate(outs)
+        # a Pallas row is (1, N) int32; the others are flat
+        return jnp.concatenate(
+            [o[0].astype(bool) if o.ndim == 2 else o for o in outs]
+        )
 
     return _launch, tuple(flat_args), None, bucket, None
 
@@ -586,91 +547,26 @@ def prepare_superbatch(block: EntryBlock, plan: MeshPlan):
     `device_pool.transfer` places each array lane-per-device), or None
     on the single-device / simulated-lanes fallback.
 
-    Kernel selection mirrors `_prepare`: pallas compact on the pallas
-    backend (uncached — per-mesh coords tables are follow-up work, a
-    warm pack ships pubs); otherwise the XLA family with the same
-    device-hash choice `_prepare` makes (short messages hash on-chip)
-    and the cached gather prep when the WHOLE pack shares one warm
-    epoch. The RLC fast-accept kernel is per-lane-group incompatible
-    with row demux and stays single-device (ops/pallas_rlc)."""
+    What is the mesh's own stays here: the plan's width, whether a real
+    mesh serves its lanes, the per-segment fusing of a mixed pack. Which
+    kernel verifies a segment is backend.select_kernel's answer for a
+    lane pack: the platform's per-signature family (verdicts demux by
+    row, which RLC lane verdicts cannot), cached when the whole pack
+    shares one warm epoch."""
     from . import backend as _backend
     from . import sharded as _sharded
 
-    assert _BUCKETS == _backend.BUCKETS, "bucket ladders diverged"
-    assert _BLS_LANE_BUCKETS == _backend.BLS_BUCKETS, (
-        "BLS bucket ladders diverged"
-    )
     bucket = plan.bucket
     if len(block) != bucket:
         raise ValueError(
             f"superblock is {len(block)} rows, plan says {bucket}"
         )
-    eng = _backend.engine()
-    donate = eng.donate
     if isinstance(block, SchemeSuperBlock):
-        return _prepare_mixed_superbatch(block, donate, bucket)
-    ep = _warm_entry(plan) if block.epoch_key is not None else None
-    if getattr(block, "scheme", "ed25519") == "secp256k1":
-        # secp lane-group: the Strauss+GLV kernel (ops/secp_verify).
-        # Plain jit only — no pallas/shard_map face yet (ROADMAP 3a);
-        # the single-device XLA kernel still fuses all lanes into one
-        # launch, which is what the mesh demux contract needs.
-        if ep is not None and ep.scheme == "secp256k1":
-            args = _backend.prepare_batch_secp_cached(block, bucket, ep)
-            return (_backend.secp_cached_kernel(ep, donate), args, None,
-                    bucket, None)
-        args = _backend.prepare_batch_secp(block, bucket)
-        return _backend.secp_kernel(donate), args, None, bucket, None
-    use_mesh = plan.n_lanes > 1 and _sharded.mesh_ready(plan.n_lanes)
-    if eng.pallas:
-        from . import pallas_verify as _pv
-
-        interpret = eng.interpret
-        blk = _pv.pick_block(plan.lane_bucket)
-        args = _pv.prepare_compact(block, bucket)
-        if use_mesh:
-            m = _sharded.dispatch_mesh(plan.n_lanes)
-            fn = _sharded.mesh_pallas_valid_fn(
-                m, bucket // plan.n_lanes, blk, interpret
-            )
-            shardings = _sharded.mesh_arg_shardings(m, "pallas", len(args))
-            return fn, args, None, bucket, shardings
-        fn = _pv._jitted_pallas_verify(bucket, blk, interpret, donate=donate)
-        return fn, args, None, bucket, None
-    device_hash = (
-        not _backend.HOST_HASH
-        and _backend._max_msg_len(block) <= _backend.DEVICE_HASH_MAX_MSG
-    )
-    if ep is not None:
-        if device_hash:
-            args = _backend.prepare_batch_cached_device_hash(
-                block, bucket, ep
-            )
-            kind = "cached_device_hash"
-        else:
-            args = _backend.prepare_batch_cached(block, bucket, ep)
-            kind = "cached"
-        if use_mesh:
-            m = _sharded.dispatch_mesh(plan.n_lanes)
-            fn = _sharded.mesh_valid_fn_cached(m, ep, donate, device_hash)
-            shardings = _sharded.mesh_arg_shardings(m, kind, len(args))
-            return fn, args, None, bucket, shardings
-        return (_backend.cached_kernel(ep, device_hash, donate), args,
-                None, bucket, None)
-    if device_hash:
-        args = _backend.prepare_batch_device_hash(block, bucket)
-        kind = "device_hash"
-    else:
-        args = _backend.prepare_batch(block, bucket)
-        kind = "host_hash"
-    if use_mesh:
+        return _prepare_mixed_superbatch(block, bucket)
+    m = None
+    if plan.n_lanes > 1 and _sharded.mesh_ready(plan.n_lanes):
         m = _sharded.dispatch_mesh(plan.n_lanes)
-        fn = _sharded.mesh_valid_fn(m, donate, device_hash)
-        shardings = _sharded.mesh_arg_shardings(m, kind, len(args))
-        return fn, args, None, bucket, shardings
-    from . import ed25519_verify as _kernel
-
-    if device_hash:
-        return (_kernel.jitted_verify_device_hash(donate), args, None,
-                bucket, None)
-    return _kernel.jitted_verify(donate), args, None, bucket, None
+    fn, args, _rlc, bucket = _backend.select_kernel(
+        block, bucket=bucket, lanes=plan.n_lanes, mesh=m
+    )
+    return fn, args, None, bucket, getattr(fn, "shardings", None)

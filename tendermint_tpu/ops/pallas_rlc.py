@@ -818,6 +818,22 @@ def expand_lanes(lane_valid: np.ndarray, entries) -> np.ndarray:
     return per_sig
 
 
+def rlc_launch(entries, ep=None, bucket: int = 0, block: int = 0,
+               interpret: bool = False, donate: bool = False) -> tuple:
+    """One RLC launch for at most MAX_SIGS signatures: (launch_fn, args,
+    bucket), sized for max(len(entries), bucket) signatures. With a warm
+    epoch entry the committee gathers from the device-resident table
+    (prepare_rlc_cached + rlc_cached_fn); without one the batch ships its
+    pubs. backend.select_kernel's RLC arm and verify_batch_rlc are both
+    this."""
+    bucket, g, blk = plan_bucket(max(len(entries), bucket), block)
+    if ep is not None:
+        return (rlc_cached_fn(ep, g, blk, interpret, donate),
+                prepare_rlc_cached(entries, bucket, ep), bucket)
+    return (_jitted_rlc_verify(g, blk, interpret, donate=donate),
+            prepare_rlc(entries, bucket), bucket)
+
+
 def verify_batch_rlc(entries, block: int = 0, interpret: bool = False) -> np.ndarray:
     """Arbitrary-size batch through the RLC fast-accept path; returns
     per-signature (n,) bool with exact per-sig ZIP-215 blame. Warm-epoch
@@ -826,23 +842,13 @@ def verify_batch_rlc(entries, block: int = 0, interpret: bool = False) -> np.nda
     from . import epoch_cache as _epoch
 
     ep = _epoch.lookup(entries)
-    sigs_per_call = MAX_SIGS
     out = []
-    i = 0
-    while i < len(entries):
-        chunk = entries[i : i + sigs_per_call]
-        bucket, g, blk = plan_bucket(len(chunk), block)
-        if ep is not None:
-            args = prepare_rlc_cached(chunk, bucket, ep)
-            dev = rlc_cached_fn(ep, g, blk, interpret)(*args)
-            lane_valid = np.asarray(dev)[0].astype(bool)
-        else:
-            args = prepare_rlc(chunk, bucket)
-            lane_valid = verify_rlc_compact(
-                *args, block=blk, interpret=interpret
-            )
+    for i in range(0, len(entries), MAX_SIGS):
+        chunk = entries[i : i + MAX_SIGS]
+        fn, args, _bucket = rlc_launch(chunk, ep, block=block,
+                                       interpret=interpret)
+        lane_valid = np.asarray(fn(*args))[0].astype(bool)
         out.append(expand_lanes(lane_valid, chunk))
-        i += len(chunk)
     return (
         np.concatenate(out) if out else np.zeros((0,), dtype=bool)
     )

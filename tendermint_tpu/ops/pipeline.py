@@ -37,7 +37,6 @@ from ..observability import trace as _trace
 from ..types.validation import ErrNotEnoughVotingPowerSigned
 from . import backend as _backend
 from . import device_pool as _dpool
-from . import ed25519_verify as _kernel
 from . import mesh as _mesh
 from .entry_block import EntryBlock, as_block, block_concat
 
@@ -517,129 +516,21 @@ class AsyncBatchVerifier:
         it is the entry list _resolve needs to expand lane verdicts to
         per-sig verdicts (and re-verify rejected lanes for blame). bucket
         is the padded device batch size (signature lanes) for metric
-        labels."""
-        from . import epoch_cache as _epoch
-
-        # warm-epoch fast path: the committee is device-resident (keyed
-        # by ValidatorSet.hash()) — prep ships only per-signature data
-        # and the kernels gather cached A columns on device
-        ep = _epoch.lookup(entries)
-        # donation (ISSUE 7): launches consume their per-batch inputs so
-        # XLA recycles the pages; epoch tables stay exempt in every
-        # kernel's donate_argnums
-        eng = _backend.engine()
-        donate = eng.donate
-        if getattr(entries, "scheme", "ed25519") == "bls12381":
-            # aggregation lane (ISSUE 20): one row = one whole commit.
-            # `ep` above is None by construction (AggBlocks carry no
-            # gather indices); the lane keys its epoch on the bitmap's
-            # committee directly.
-            ep = _backend._bls_epoch(entries)
-            bucket = _backend._bls_bucket_for(len(entries))
-            vp = ep.vp if ep is not None else entries.pub48.shape[0] + 1
-            with _span("pipeline.prep", n=len(entries), bucket=bucket,
-                       cached=int(ep is not None), scheme="bls12381"):
-                masks, coeffs, ok, reasons = _backend.prepare_batch_bls(
-                    entries, bucket, vp,
-                    bad_rows=_backend._bls_bad_rows(entries.pub48),
+        labels. Which kernel, and its argument layout, is
+        backend.select_kernel's business."""
+        n = len(entries)
+        with _span("pipeline.prep", n=n) as sp:
+            res = _backend.select_kernel(entries)
+            if _trace.TRACER.enabled:
+                # what was chosen, for whoever reads the span
+                scheme = getattr(entries, "scheme", "ed25519")
+                sp.note(
+                    bucket=res[3],
+                    cached=int(_backend.warm_epoch(entries) is not None),
+                    **({"scheme": scheme} if scheme != "ed25519" else {}),
                 )
-                kern = _backend.bls_kernel(
-                    entries, ok, reasons, ep=ep, donate=donate
-                )
-            _backend._note_device_batch(len(entries), bucket)
-            return kern, (masks, coeffs), None, bucket
-        if getattr(entries, "scheme", "ed25519") == "secp256k1":
-            # scheme lane (ISSUE 19): the Strauss+GLV ECDSA kernel.
-            # Plain XLA jit only — no pallas/RLC face for secp yet
-            # (ROADMAP 3a); `ep` is already scheme-guarded by
-            # epoch_cache.lookup so a warm secp committee gathers its
-            # decompressed affine Q columns on device.
-            bucket = _backend._secp_bucket_for(len(entries))
-            with _span("pipeline.prep", n=len(entries), bucket=bucket,
-                       cached=int(ep is not None), scheme="secp256k1"):
-                if ep is not None:
-                    args = _backend.prepare_batch_secp_cached(
-                        entries, bucket, ep
-                    )
-                    kern = _backend.secp_cached_kernel(ep, donate)
-                else:
-                    args = _backend.prepare_batch_secp(entries, bucket)
-                    kern = _backend.secp_kernel(donate)
-            _backend._note_device_batch(len(entries), bucket)
-            return kern, args, None, bucket
-        if eng.pallas:
-            from . import pallas_verify
-
-            interpret = eng.interpret
-            if eng.rlc:
-                from . import pallas_rlc
-
-                bucket, g, block = pallas_rlc.plan_bucket(len(entries))
-                t0 = time.perf_counter()
-                with _span("pipeline.prep", n=len(entries), bucket=bucket,
-                           cached=int(ep is not None)):
-                    if ep is not None:
-                        args = pallas_rlc.prepare_rlc_cached(
-                            entries, bucket, ep
-                        )
-                        f = pallas_rlc.rlc_cached_fn(
-                            ep, g, block, interpret, donate
-                        )
-                    else:
-                        args = pallas_rlc.prepare_rlc(entries, bucket)
-                        f = pallas_rlc._jitted_rlc_verify(
-                            g, block, interpret, donate=donate
-                        )
-                _backend._note_device_batch(
-                    len(entries), bucket, prep_s=time.perf_counter() - t0
-                )
-                return f, args, entries, bucket
-            bucket = _backend._pallas_bucket(len(entries))
-            blk = min(pallas_verify.BLOCK, bucket)
-            t0 = time.perf_counter()
-            with _span("pipeline.prep", n=len(entries), bucket=bucket,
-                       cached=int(ep is not None)):
-                if ep is not None:
-                    args = pallas_verify.prepare_compact_cached(
-                        entries, bucket, ep
-                    )
-                    f = pallas_verify.cached_compact_fn(
-                        ep, bucket, blk, interpret, donate
-                    )
-                else:
-                    args = pallas_verify.prepare_compact(entries, bucket)
-                    f = pallas_verify._jitted_pallas_verify(
-                        bucket, blk, interpret, donate=donate
-                    )
-            _backend._note_device_batch(
-                len(entries), bucket, prep_s=time.perf_counter() - t0
-            )
-            return f, args, None, bucket
-        device_hash = (
-            not _backend.HOST_HASH
-            and _backend._max_msg_len(entries) <= _backend.DEVICE_HASH_MAX_MSG
-        )
-        bucket = _backend._bucket_for(len(entries))
-        # prep timing histograms are recorded inside prepare_batch*;
-        # only the dispatch counters are noted here
-        with _span("pipeline.prep", n=len(entries), bucket=bucket,
-                   cached=int(ep is not None)):
-            if ep is not None:
-                kern = _backend.cached_kernel(ep, device_hash, donate)
-                if device_hash:
-                    args = _backend.prepare_batch_cached_device_hash(
-                        entries, bucket, ep
-                    )
-                else:
-                    args = _backend.prepare_batch_cached(entries, bucket, ep)
-            elif device_hash:
-                args = _backend.prepare_batch_device_hash(entries, bucket)
-                kern = _kernel.jitted_verify_device_hash(donate)
-            else:
-                args = _backend.prepare_batch(entries, bucket)
-                kern = _kernel.jitted_verify(donate)
-        _backend._note_device_batch(len(entries), bucket)
-        return kern, args, None, bucket
+        _backend._note_device_batch(n, res[3])
+        return res
 
     @classmethod
     def _prepare_timed(cls, entries, launch: int = 0):
@@ -1476,8 +1367,8 @@ def commit_entries(
 
     Columnar commits (CommitBlock from wire decode, or built+cached on
     first use) with all-ed25519 validator columns take the FUSED path:
-    selection, tally, sign-bytes, gather, and the device-hash RAM blocks
-    in one call (native GIL-released when built)."""
+    selection, tally, sign-bytes and gather in one call (native
+    GIL-released when built)."""
     from . import commit_prep as _cp
 
     with _span("pipeline.commit_prep_fused", n=len(commit.signatures)):
@@ -1503,8 +1394,8 @@ def commit_entries_legacy(
 ) -> Tuple[EntryBlock, int]:
     """The PR-2 columnar path, object-walking selection + per-stage
     composition: the fallback for non-columnar commits/valsets, and the
-    pinned baseline the fused path is gated against (tools/prep_bench.py
-    --fused, tests/test_gil_budget.py)."""
+    baseline the fused path is held equal to (tools/prep_bench.py
+    --fused, tests/test_commit_block.py)."""
     idxs = []
     tallied = 0
     for idx, cs in enumerate(commit.signatures):

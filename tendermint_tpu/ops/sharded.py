@@ -521,74 +521,74 @@ def dispatch_mesh(n_lanes: int) -> Mesh:
     return m
 
 
-def mesh_valid_fn(mesh: Mesh, donate: bool = False,
-                  device_hash: bool = False):
+# per-batch argument placement of each mesh launch, batch axis sharded
+# lane-per-device: the shard_map in_specs and the NamedShardings
+# device_pool.transfer places the host arrays by are the same table
+_MESH_SPECS = {
+    # uncached XLA args: limbs/sign/bits/s_ok (backend.prepare_batch)
+    "xla": (P(AXIS), P(AXIS), P(AXIS), P(AXIS),
+            P(None, AXIS), P(None, AXIS), P(AXIS)),
+    # warm-epoch gather args: idx + raw r/s/k rows + s_ok
+    "xla_cached": (P(AXIS),) * 5,
+    # compact pallas: batch-minor, shard the last axis
+    "pallas": (P(None, AXIS),) * 5,
+}
+
+
+class MeshLaunch:
+    """One shard_map launch of the mesh dispatcher: the function, and in
+    `shardings` where each per-batch argument goes — batch k+1's H2D
+    copies land lane-per-device through device_pool.transfer, overlapping
+    mesh kernel k exactly like the single-device overlap path."""
+
+    __slots__ = ("_fn", "shardings")
+
+    def __init__(self, fn, mesh: Mesh, kind: str):
+        self._fn = fn
+        self.shardings = tuple(
+            NamedSharding(mesh, p) for p in _MESH_SPECS[kind]
+        )
+
+    def __call__(self, *args):
+        return self._fn(*args)
+
+
+def mesh_valid_fn(mesh: Mesh, donate: bool = False) -> MeshLaunch:
     """Jitted shard_map of the bare per-sig verify kernel: uncached args
-    sharded lane-per-device, (B,) bool verdicts out. `device_hash` picks
-    the on-chip-SHA kernel (R||A||M block rows ship instead of host
-    challenges — the same selection the classic `_prepare` makes)."""
-    key = ("mesh_valid", tuple(d.id for d in mesh.devices.flat), donate,
-           device_hash)
+    sharded lane-per-device, (B,) bool verdicts out."""
+    key = ("mesh_valid", tuple(d.id for d in mesh.devices.flat), donate)
     if key not in _mesh_cache:
         from jax import shard_map
 
-        if device_hash:
-            body = _kernel.verify_kernel_device_hash
-            # a_limbs/sign, r_limbs/sign, s_bits, hi, lo, counts, s_ok —
-            # the SHA block rows are (B, NBLOCK, 16): batch axis leads
-            specs = (P(AXIS), P(AXIS), P(AXIS), P(AXIS), P(None, AXIS),
-                     P(AXIS), P(AXIS), P(AXIS), P(AXIS))
-            n_args = 9
-        else:
-            body = _kernel.verify_kernel
-            specs = (P(AXIS), P(AXIS), P(AXIS), P(AXIS),
-                     P(None, AXIS), P(None, AXIS), P(AXIS))
-            n_args = 7
-        # check_vma off for the on-chip-SHA body only: its compression
-        # and mod-L loops start from constant carries (IV words, zero
-        # limbs), which jax 0.9's varying-axes check rejects inside
-        # shard_map. These are valid-bits-only kernels with no collective
-        # for the check to protect.
-        fn = shard_map(body, mesh=mesh, in_specs=specs, out_specs=P(AXIS),
-                       check_vma=not device_hash)
-        _mesh_cache[key] = (
-            jax.jit(fn, donate_argnums=tuple(range(n_args))) if donate
-            else jax.jit(fn)
+        specs = _MESH_SPECS["xla"]
+        fn = shard_map(_kernel.verify_kernel, mesh=mesh, in_specs=specs,
+                       out_specs=P(AXIS))
+        _mesh_cache[key] = MeshLaunch(
+            jax.jit(fn, donate_argnums=tuple(range(len(specs))))
+            if donate else jax.jit(fn),
+            mesh, "xla",
         )
     return _mesh_cache[key]
 
 
-def mesh_valid_fn_cached(mesh: Mesh, ep, donate: bool = False,
-                         device_hash: bool = False):
+def mesh_valid_fn_cached(mesh: Mesh, ep, donate: bool = False) -> MeshLaunch:
     """Cached-epoch mesh kernel closure: each shard gathers committee
     rows from its replicated table copy (epoch_tables_sharded — resident
     per device, owned by the epoch LRU) and unpacks the raw per-sig rows
     on device. The table resolves at CALL time, on the dispatch-owner
     thread, exactly like backend.cached_kernel."""
     key = ("mesh_valid_cached",
-           tuple(d.id for d in mesh.devices.flat), donate, device_hash)
+           tuple(d.id for d in mesh.devices.flat), donate)
     if key not in _mesh_cache:
         from jax import shard_map
 
-        if device_hash:
-            body = _kernel.verify_kernel_cached_device_hash
-            # idx, r, s, hi (B, NB, 16), lo, counts, s_ok
-            specs = (P(None, None), P(None),
-                     P(AXIS), P(AXIS), P(AXIS),
-                     P(AXIS), P(AXIS), P(AXIS), P(AXIS))
-            n_args = 9
-        else:
-            body = _kernel.verify_kernel_cached
-            specs = (P(None, None), P(None),              # tables
-                     P(AXIS), P(AXIS), P(AXIS), P(AXIS),  # idx, r, s, k
-                     P(AXIS))                             # s_ok
-            n_args = 7
-        # same check_vma rationale as mesh_valid_fn
-        fn = shard_map(body, mesh=mesh, in_specs=specs, out_specs=P(AXIS),
-                       check_vma=not device_hash)
+        specs = (P(None, None), P(None)) + _MESH_SPECS["xla_cached"]
+        fn = shard_map(_kernel.verify_kernel_cached, mesh=mesh,
+                       in_specs=specs, out_specs=P(AXIS))
+        # the tables (argnums 0-1) are shared across batches: not donated
         _mesh_cache[key] = (
-            jax.jit(fn, donate_argnums=tuple(range(2, n_args))) if donate
-            else jax.jit(fn)
+            jax.jit(fn, donate_argnums=tuple(range(2, len(specs))))
+            if donate else jax.jit(fn)
         )
     base = _mesh_cache[key]
 
@@ -596,11 +596,11 @@ def mesh_valid_fn_cached(mesh: Mesh, ep, donate: bool = False,
         tbl_limbs, tbl_sign = epoch_tables_sharded(ep, mesh)
         return base(tbl_limbs, tbl_sign, *args)
 
-    return call
+    return MeshLaunch(call, mesh, "xla_cached")
 
 
 def mesh_pallas_valid_fn(mesh: Mesh, n_per_shard: int, block: int,
-                         interpret: bool):
+                         interpret: bool) -> MeshLaunch:
     """Compact-pallas mesh kernel, valid bits only: batch-minor args
     shard on their LAST axis (one lane per device), verdict row out."""
     key = ("mesh_pallas_valid", tuple(d.id for d in mesh.devices.flat),
@@ -623,41 +623,10 @@ def mesh_pallas_valid_fn(mesh: Mesh, n_per_shard: int, block: int,
         fn = shard_map(
             _step,
             mesh=mesh,
-            in_specs=(
-                P(None, AXIS), P(None, AXIS), P(None, AXIS),
-                P(None, AXIS), P(None, AXIS),
-            ),
+            in_specs=_MESH_SPECS["pallas"],
             out_specs=P(AXIS),
             # same vma rationale as sharded_pallas_verifier above
             check_vma=not interpret,
         )
-        _mesh_cache[key] = jax.jit(fn)
+        _mesh_cache[key] = MeshLaunch(jax.jit(fn), mesh, "pallas")
     return _mesh_cache[key]
-
-
-_MESH_SPECS = {
-    # host-hash uncached: limbs/sign/bits/s_ok (backend.prepare_batch)
-    "host_hash": (P(AXIS), P(AXIS), P(AXIS), P(AXIS),
-                  P(None, AXIS), P(None, AXIS), P(AXIS)),
-    # device-hash uncached: limbs/sign/s_bits + (B, NB, 16) SHA rows
-    "device_hash": (P(AXIS), P(AXIS), P(AXIS), P(AXIS), P(None, AXIS),
-                    P(AXIS), P(AXIS), P(AXIS), P(AXIS)),
-    # warm-epoch gather args: idx + raw r/s/k rows + s_ok
-    "cached": (P(AXIS), P(AXIS), P(AXIS), P(AXIS), P(AXIS)),
-    # warm-epoch device-hash: idx + raw r/s + SHA rows + s_ok
-    "cached_device_hash": (P(AXIS),) * 7,
-    # compact pallas: batch-minor, shard the last axis
-    "pallas": (P(None, AXIS),) * 5,
-}
-
-
-def mesh_arg_shardings(mesh: Mesh, kind: str, n_args: int):
-    """Per-arg NamedShardings for device_pool.transfer — batch k+1's H2D
-    copies land lane-per-device (overlapping the mesh kernel k exactly
-    like the single-device overlap path; ISSUE 9 tentpole piece c)."""
-    specs = _MESH_SPECS[kind]
-    if len(specs) != n_args:
-        raise ValueError(
-            f"{kind} superbatch has {n_args} args, specs cover {len(specs)}"
-        )
-    return tuple(NamedSharding(mesh, p) for p in specs)

@@ -200,8 +200,8 @@ def compose_vote_sign_bytes_cols(
     the output buffer — no scatter at all.
 
     with_groups=True appends a [(rows, (g, rec_len) uint8 array)] list so
-    callers laying the same bytes into a second destination (the fused
-    prep's SHA RAM blocks) can reuse the 2-D record matrices; the buffer
+    a caller merging two compositions into lane order (the fused prep's
+    COMMIT and NIL groups) can reuse the 2-D record matrices; the buffer
     then comes back as a 1-D uint8 ndarray (no bytes copy) instead of
     bytes."""
     import numpy as np

@@ -23,7 +23,6 @@ except ModuleNotFoundError:
 
 from tendermint_tpu.ops import backend, commit_prep as cp
 from tendermint_tpu.ops import pipeline as pl
-from tendermint_tpu.ops import sha512 as sha
 from tendermint_tpu.ops.entry_block import CommitBlock, EntryBlock
 from tendermint_tpu.types import validation
 from tendermint_tpu.types.block import (
@@ -241,8 +240,7 @@ class TestFusedPrepDifferential:
         cp.MODE_SELECT_COMMIT_ONLY | cp.MODE_COUNT_FOR_BLOCK
         | cp.MODE_EARLY_STOP,
     ])
-    @pytest.mark.parametrize("ram", [0, 256])
-    def test_numpy_matches_object_sign_bytes(self, mode, ram):
+    def test_numpy_matches_object_sign_bytes(self, mode):
         vset, commit = _random_commit(120, nil=(0, 7, 33), absent=(5, 60))
         dec = Commit.decode(commit.encode())
         cb = dec.commit_block()
@@ -251,7 +249,7 @@ class TestFusedPrepDifferential:
         pn = dec.sign_bytes_template(CHAIN_ID, BLOCK_ID_FLAG_NIL)
         needed = vset.total_voting_power() * 2 // 3
         sel, tallied, blk = cp._prep_commit_numpy(
-            cb, cols[0], cols[1], pc[0], pn[0], pc[1], needed, mode, ram
+            cb, cols[0], cols[1], pc[0], pn[0], pc[1], needed, mode
         )
         assert blk is not None
         # per-lane parity with the object-path sign bytes + columns
@@ -260,16 +258,6 @@ class TestFusedPrepDifferential:
             assert blk.msg(j) == dec.vote_sign_bytes(CHAIN_ID, i)
             assert blk.pub[j].tobytes() == vset.validators[i].pub_key.bytes()
             assert blk.sig[j].tobytes() == dec.signatures[i].signature
-        if ram:
-            assert blk.ram_hi is not None
-            hi, lo, counts = sha.pad_ram_block(
-                blk[0 : len(blk)], len(blk), ram
-            )
-            got = sha.pad_ram_rows(blk, len(blk), ram)
-            assert got is not None
-            assert np.array_equal(got[0], hi)
-            assert np.array_equal(got[1], lo)
-            assert np.array_equal(got[2], counts)
 
     @pytest.mark.native_required
     @pytest.mark.parametrize("mode", [
@@ -305,27 +293,19 @@ class TestFusedPrepDifferential:
         pc = dec.sign_bytes_template(CHAIN_ID, BLOCK_ID_FLAG_COMMIT)
         pn = dec.sign_bytes_template(CHAIN_ID, BLOCK_ID_FLAG_NIL)
         for thr in (100, vset.total_voting_power() * 2 // 3, 10 ** 12):
-            for ram in (0, 256):
-                a = cp.prep_commit(cb, cols[0], cols[1], pc[0], pn[0],
-                                   pc[1], thr, mode, ram)
-                b = cp._prep_commit_numpy(cb, cols[0], cols[1], pc[0],
-                                          pn[0], pc[1], thr, mode, ram)
-                assert np.array_equal(a[0], b[0])
-                assert a[1] == b[1]
-                assert (a[2] is None) == (b[2] is None)
-                if a[2] is None:
-                    continue
-                assert np.array_equal(a[2].pub, b[2].pub)
-                assert np.array_equal(a[2].sig, b[2].sig)
-                assert np.array_equal(a[2].offsets, b[2].offsets)
-                assert bytes(a[2].msgs) == bytes(b[2].msgs)
-                for x, y in ((a[2].ram_hi, b[2].ram_hi),
-                             (a[2].ram_lo, b[2].ram_lo),
-                             (a[2].ram_counts, b[2].ram_counts)):
-                    assert (x is None) == (y is None)
-                    if x is not None:
-                        assert np.array_equal(np.asarray(x, dtype=np.uint32),
-                                              np.asarray(y, dtype=np.uint32))
+            a = cp.prep_commit(cb, cols[0], cols[1], pc[0], pn[0],
+                               pc[1], thr, mode)
+            b = cp._prep_commit_numpy(cb, cols[0], cols[1], pc[0],
+                                      pn[0], pc[1], thr, mode)
+            assert np.array_equal(a[0], b[0])
+            assert a[1] == b[1]
+            assert (a[2] is None) == (b[2] is None)
+            if a[2] is None:
+                continue
+            assert np.array_equal(a[2].pub, b[2].pub)
+            assert np.array_equal(a[2].sig, b[2].sig)
+            assert np.array_equal(a[2].offsets, b[2].offsets)
+            assert bytes(a[2].msgs) == bytes(b[2].msgs)
 
     def test_commit_entries_fused_matches_legacy(self):
         vset, commit = _random_commit(90, absent=(4,))
@@ -408,51 +388,14 @@ class TestVerifyCommitFused:
         assert str(e1.value) == str(e2.value)
 
 
-class TestEntryBlockRamColumns:
-    def _block_with_ram(self, n=20, seed=3):
-        vset, commit = _random_commit(n, seed=seed)
+class TestEntryBlockConcat:
+    def test_concat_single_block_passes_through_by_identity(self):
+        vset, commit = _random_commit(8, seed=3)
         dec = Commit.decode(commit.encode())
         needed = vset.total_voting_power() * 2 // 3
         blk, _ = pl.commit_entries(CHAIN_ID, vset, dec, needed)
-        assert blk.ram_hi is not None
-        return blk
-
-    def test_slice_and_concat_preserve_ram(self):
-        blk = self._block_with_ram(24)
-        a, b = blk[:10], blk[10:]
-        assert a.ram_hi is not None and b.ram_hi is not None
-        back = EntryBlock.concat([a, b])
-        assert np.array_equal(
-            np.asarray(back.ram_hi, dtype=np.uint32),
-            np.asarray(blk.ram_hi, dtype=np.uint32),
-        )
-        assert np.array_equal(back.ram_counts, blk.ram_counts)
-        assert bytes(back.msgs_contiguous()[0]) == bytes(
-            blk.msgs_contiguous()[0]
-        )
-
-    def test_concat_drops_ram_when_any_block_lacks_it(self):
-        blk = self._block_with_ram(12)
-        plain = EntryBlock(blk.pub.copy(), blk.sig.copy(),
-                           bytes(blk.msgs_contiguous()[0]),
-                           np.asarray(blk.offsets).copy())
-        out = EntryBlock.concat([blk, plain])
-        assert out.ram_hi is None
-
-    def test_concat_single_block_passes_through_by_identity(self):
-        blk = self._block_with_ram(8)
         assert EntryBlock.concat([blk]) is blk
         assert EntryBlock.concat([EntryBlock.empty(), blk]) is blk
-
-    def test_prepare_device_hash_ram_fast_path_matches_generic(self):
-        blk = self._block_with_ram(30)
-        bucket = 128
-        fast = backend.prepare_batch_device_hash(blk, bucket)
-        plain = EntryBlock(blk.pub, blk.sig,
-                           bytes(blk.msgs_contiguous()[0]),
-                           np.asarray(blk.offsets))
-        generic = backend.prepare_batch_device_hash(plain, bucket)
-        assert all(np.array_equal(a, b) for a, b in zip(fast, generic))
 
 
 class TestDispatchOwnerThread:

@@ -172,9 +172,7 @@ class TestPrepParity:
     with and without the native module (native-absent fallback parity)."""
 
     @pytest.mark.parametrize("use_native", [True, False])
-    @pytest.mark.parametrize(
-        "prep", ["prepare_batch", "prepare_batch_device_hash", "prepare_compact"]
-    )
+    @pytest.mark.parametrize("prep", ["prepare_batch", "prepare_compact"])
     def test_args_match(self, monkeypatch, prep, use_native):
         if not use_native:
             _no_native(monkeypatch)
@@ -223,20 +221,6 @@ class TestPrepParity:
         expected = np.ones(2 * M, dtype=bool)
         expected[[1, M + 2]] = False
         assert np.array_equal(per_block, expected)
-
-    def test_pad_ram_block_matches_list_path(self, monkeypatch):
-        _no_native(monkeypatch)
-        # empty-message and max-length edges
-        sk = ed25519.gen_priv_key(b"\x09" * 32)
-        ents = [
-            (sk.pub_key().bytes(), b"", sk.sign(b"")),
-            (sk.pub_key().bytes(), b"y" * backend.DEVICE_HASH_MAX_MSG,
-             sk.sign(b"y" * backend.DEVICE_HASH_MAX_MSG)),
-        ] + _entries(3)
-        a = backend.prepare_batch_device_hash(ents, 8)
-        b = backend.prepare_batch_device_hash(EntryBlock.from_entries(ents), 8)
-        for x, y in zip(a, b):
-            assert np.array_equal(np.asarray(x), np.asarray(y))
 
 
 class TestKernelVerdictParity:

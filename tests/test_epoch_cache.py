@@ -351,25 +351,10 @@ class TestCachedVerdictParity:
         res_u = np.asarray(ev.jitted_verify()(*args_u))[: len(blk)]
         args_c = backend.prepare_batch_cached(blk, bucket, ep)
         res_c = np.asarray(
-            backend.cached_kernel(ep, device_hash=False)(*args_c)
+            backend.cached_kernel(ep)(*args_c)
         )[: len(blk)]
         assert np.array_equal(res_u, res_c)
         assert not res_c.all()  # the bad lanes really reject
-
-    @pytest.mark.parametrize("n", [90, 150])  # buckets 128 and 1024
-    def test_device_hash_parity(self, n):
-        vset, commit, _, _ = _signed_commit(n, bad=(n - 2,), nil=(1,))
-        blk = _warm_block(vset, commit, 100 * (n - 1) - 1)
-        ep = epoch_cache.lookup(blk)
-        bucket = backend._bucket_for(len(blk))
-        args_u = backend.prepare_batch_device_hash(blk, bucket)
-        res_u = np.asarray(ev.jitted_verify_device_hash()(*args_u))[: len(blk)]
-        args_c = backend.prepare_batch_cached_device_hash(blk, bucket, ep)
-        res_c = np.asarray(
-            backend.cached_kernel(ep, device_hash=True)(*args_c)
-        )[: len(blk)]
-        assert np.array_equal(res_u, res_c)
-        assert not res_c.all()
         # warm-epoch transfer really shrinks (acceptance: <= 0.5x)
         assert backend.h2d_arg_bytes(args_c) <= 0.5 * (
             backend.h2d_arg_bytes(args_u)

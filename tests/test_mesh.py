@@ -202,11 +202,10 @@ class TestMeshParity:
             assert block.epoch_key == b"mesh-warm"
             res = ms.prepare_superbatch(block, plan)
             args = res[1]
-            # cached arg shape: these short messages select the
-            # device-hash family (mirroring _prepare), so the warm args
-            # are (idx, r, s, hi, lo, counts, s_ok) — structurally
-            # pub-free (the --transfer gate's invariant, mesh face)
-            assert len(args) == 7 and args[0].dtype == np.int32
+            # cached arg shape: the warm args are (idx, r, s, k, s_ok)
+            # — structurally pub-free (the --transfer gate's invariant,
+            # mesh face)
+            assert len(args) == 5 and args[0].dtype == np.int32
             arr, spans = _run_plan(plan)
             flat = np.zeros(48, dtype=bool)
             for job, off, n in spans:

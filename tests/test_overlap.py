@@ -121,39 +121,39 @@ class TestDonationParity:
     per call, exactly the pipeline's usage)."""
 
     @pytest.mark.parametrize("bucket,n", [(128, 100), (1024, 1000)])
-    def test_cold_epoch_device_hash_parity(self, bucket, n):
+    def test_cold_epoch_parity(self, bucket, n):
         entries = (
             _signed_entries(16, tag=1, bad=(3, 7)) + _random_entries(n - 16)
             if bucket == 128
             else _random_entries(n, tag=2)
         )
         block = EntryBlock.from_entries(entries)
-        plain = ev.jitted_verify_device_hash(False)(
-            *backend.prepare_batch_device_hash(block, bucket)
+        plain = ev.jitted_verify(False)(
+            *backend.prepare_batch(block, bucket)
         )
-        donated = ev.jitted_verify_device_hash(True)(
-            *backend.prepare_batch_device_hash(block, bucket)
+        donated = ev.jitted_verify(True)(
+            *backend.prepare_batch(block, bucket)
         )
         _assert_verdict_blame_parity(
             np.asarray(plain)[:n], np.asarray(donated)[:n]
         )
 
     @pytest.mark.parametrize("bucket,n", [(128, 100), (1024, 1000)])
-    def test_warm_epoch_device_hash_parity(self, bucket, n):
+    def test_warm_epoch_parity(self, bucket, n):
         ep, block = _warm_epoch(100, n, bad=(5,))
-        plain = backend.cached_kernel(ep, True, donate=False)(
-            *backend.prepare_batch_cached_device_hash(block, bucket, ep)
+        plain = backend.cached_kernel(ep, donate=False)(
+            *backend.prepare_batch_cached(block, bucket, ep)
         )
-        donated = backend.cached_kernel(ep, True, donate=True)(
-            *backend.prepare_batch_cached_device_hash(block, bucket, ep)
+        donated = backend.cached_kernel(ep, donate=True)(
+            *backend.prepare_batch_cached(block, bucket, ep)
         )
         p, d = np.asarray(plain)[:n], np.asarray(donated)[:n]
         _assert_verdict_blame_parity(p, d)
         assert not p[5]  # the corrupted lane is blamed on both paths
         # the epoch tables survived the donated launch (donation exempt):
         # a second donated call over fresh args still verifies
-        again = backend.cached_kernel(ep, True, donate=True)(
-            *backend.prepare_batch_cached_device_hash(block, bucket, ep)
+        again = backend.cached_kernel(ep, donate=True)(
+            *backend.prepare_batch_cached(block, bucket, ep)
         )
         assert np.array_equal(np.asarray(again)[:n], p)
 

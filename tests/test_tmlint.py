@@ -211,7 +211,7 @@ class TestDeviceOwnership:
     def test_positive_entry_points(self):
         src = """
             def f(backend, args):
-                k = backend.cached_kernel(None, True, True)
+                k = backend.cached_kernel(None, True)
                 return k(*args)
         """
         assert rules_of(lint(src, REACTOR_PATH)) == ["device-ownership"]

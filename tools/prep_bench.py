@@ -15,9 +15,10 @@ this machine) — for both representations:
 
 Runs on the CPU backend with NO device work (prep only). By default the
 native module is DISABLED (TM_TPU_NO_NATIVE=1) so the numbers isolate the
-representation change itself — the pure-Python fallback path, which is
-also the acceptance gate (ISSUE 2: >= 2x). Pass --native to keep the
-native module and measure the fused-call path instead.
+representation change itself — the pure-Python fallback path. The gate
+is argument parity (ISSUE 2's >= 2x floor was the device-hash prep's and
+went with it, ISSUE 30). Pass --native to keep the native module and
+measure the fused-call path instead.
 
 Usage:
     JAX_PLATFORMS=cpu python tools/prep_bench.py [--sigs 10000] [--reps 5]
@@ -36,7 +37,6 @@ os.environ.setdefault("TM_TPU_PUREPY_CRYPTO", "1")
 if "--native" not in sys.argv:
     os.environ["TM_TPU_NO_NATIVE"] = "1"
 
-FUSED_SPEEDUP_GATE = 1.3  # --fused: decode->kernel-args vs the PR-4 path
 TRANSFER_RATIO_GATE = 0.5  # --transfer: warm-epoch H2D vs cold-epoch H2D
 TRANSFER_SPEEDUP_GATE = 1.3  # --transfer: cached prep vs the PR-4 prep
 OVERLAP_POOL_DEPTH = 2  # --overlap: double-buffered input slots
@@ -112,10 +112,10 @@ def commit_entries_tuples(chain_id, vals, commit, voting_power_needed):
 def run_fused(args) -> int:
     """--fused: the round-6 columnar-from-decode gate. Measures the full
     decode-to-kernel-args path — wire-decoded commit (CommitBlock
-    columns) -> fused prep (ops/commit_prep.py) -> device-hash kernel
+    columns) -> fused prep (ops/commit_prep.py) -> XLA kernel
     args — against the PR-2 columnar path (commit_entries_legacy object
-    walk + generic pad), enforces bit-identical kernel args, and gates
-    the speedup at >= FUSED_SPEEDUP_GATE on CPU."""
+    walk + generic pad), enforces bit-identical kernel args and reports
+    the speedup."""
     import statistics as stats
 
     from tendermint_tpu.native import load as _load_native
@@ -140,14 +140,14 @@ def run_fused(args) -> int:
     def fused():
         dec._sb_tpl = None
         blk, _ = pipeline.commit_entries(chain_id, vset, dec, needed)
-        return backend.prepare_batch_device_hash(blk, bucket)
+        return backend.prepare_batch(blk, bucket)
 
     def pr2():
         commit._sb_tpl = None
         blk, _ = pipeline.commit_entries_legacy(
             chain_id, vset, commit, needed
         )
-        return backend.prepare_batch_device_hash(blk, bucket)
+        return backend.prepare_batch(blk, bucket)
 
     # interleave reps so machine noise hits both paths equally
     fused()
@@ -170,23 +170,16 @@ def run_fused(args) -> int:
     print(f"  fused columnar-from-decode  : {f_ms:9.2f} ms")
     print(f"  speedup                     : {speedup:9.2f}x")
     print(f"  arg parity                  : {'OK' if parity else 'MISMATCH'}")
-    if not parity:
-        return 2
-    if speedup < FUSED_SPEEDUP_GATE:
-        print(
-            f"  FAIL: expected >= {FUSED_SPEEDUP_GATE}x decode->kernel-args "
-            "speedup",
-            file=sys.stderr,
-        )
-        return 1
-    return 0
+    # no speed floor: the 1.3x one was the RAM-block prep's, which went
+    # with the device-hash kernels (ISSUE 30); arg parity is the gate
+    return 0 if parity else 2
 
 
 def run_transfer(args) -> int:
     """--transfer: the round-7 epoch-cache gate. A validator set seen for
     the SECOND time is device-resident (ops/epoch_cache.py), so a warm
-    commit ships only per-signature data — this gate asserts, on both the
-    device-hash and host-hash XLA preps:
+    commit ships only per-signature data — this gate asserts, on the XLA
+    preps:
 
       bytes    steady-state (warm) H2D bytes <= TRANSFER_RATIO_GATE x the
                cold-epoch bytes (the uncached batch args PLUS the one-time
@@ -233,11 +226,6 @@ def run_transfer(args) -> int:
     table_b = ep.nbytes_host()
     for name, uncached, cached in (
         (
-            "device-hash",
-            lambda b=blk_cold: backend.prepare_batch_device_hash(b, bucket),
-            lambda: backend.prepare_batch_cached_device_hash(blk, bucket, ep),
-        ),
-        (
             "host-hash",
             lambda b=blk_cold: backend.prepare_batch(b, bucket),
             lambda: backend.prepare_batch_cached(blk, bucket, ep),
@@ -248,7 +236,7 @@ def run_transfer(args) -> int:
         warm_b = backend.h2d_arg_bytes(warm_args)
         ratio = warm_b / cold_b
         # interleaved min-of-reps (this box's allocator noise drifts
-        # medians +-30%; see tests/test_gil_budget.py)
+        # medians +-30%)
         uncached(); cached()
         t_u, t_c = [], []
         for _ in range(args.reps):
@@ -2530,16 +2518,11 @@ def main() -> int:
             times.append(time.perf_counter() - t0)
         return statistics.median(times)
 
-    # The pipeline's prep selection on this (CPU/XLA) config: canonical
-    # vote sign-bytes fit DEVICE_HASH_MAX_MSG, so the worker preps via
-    # prepare_batch_device_hash — no host SHA-512 (pipeline._prepare).
-    # That is the PRIMARY measured path and the acceptance gate; the
-    # host-hash prep (what the TPU pallas/RLC paths pay for challenges)
-    # is reported as a secondary figure.
+    # The pipeline's prep on this (CPU/XLA) config (backend.select_kernel):
+    # prepare_batch, challenges hashed on the host. The acceptance gate.
     results = {}
     for name, prep in (
-        ("pipeline prep (device-hash)", backend.prepare_batch_device_hash),
-        ("host-hash prep", backend.prepare_batch),
+        ("pipeline prep", backend.prepare_batch),
     ):
         t_tuple = run(
             lambda p=prep: p(
@@ -2568,15 +2551,8 @@ def main() -> int:
         print(f"    speedup             : {speedup:9.2f}x")
         print(f"    arg parity          : {'OK' if parity else 'MISMATCH'}")
 
-    if not all(r[3] for r in results.values()):
-        return 2
-    # acceptance gate (ISSUE 2): >= 2x on the pure-Python fallback for
-    # the path the pipeline actually selects under JAX_PLATFORMS=cpu
-    gate = results["pipeline prep (device-hash)"][2]
-    if native is None and gate < 2.0:
-        print("  FAIL: expected >= 2x host prep reduction", file=sys.stderr)
-        return 1
-    return 0
+    # no speed floor: the 2x one was the RAM-block prep's (ISSUE 30)
+    return 0 if all(r[3] for r in results.values()) else 2
 
 
 if __name__ == "__main__":

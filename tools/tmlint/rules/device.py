@@ -45,7 +45,9 @@ ENTRY_POINTS = frozenset({
     "copy_to_host_async",
     "block_until_ready",
     "jitted_verify",
-    "jitted_verify_device_hash",
+    "jitted_verify_cached",
+    "select_kernel",
+    "rlc_launch",
     "cached_kernel",
     "rlc_cached_fn",
     "cached_compact_fn",
