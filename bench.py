@@ -196,10 +196,10 @@ def worker() -> None:
         if use_pallas and backend.engine().rlc:
             from tendermint_tpu.ops import pallas_rlc
 
-            _b, _g, _blk = pallas_rlc.plan_bucket(n_sigs)
+            _b, _g, _blk, _m = pallas_rlc.plan_bucket(n_sigs)
             pad_bucket = _b
             with _tr.span("bench.host_prep", n=n_sigs, bucket=_b):
-                args = pallas_rlc.prepare_rlc(entries, _b)
+                args = pallas_rlc.prepare_rlc(entries, _b, _m)
             prep_t += time.perf_counter() - p0
             with _tr.span("bench.device", bucket=_b):
                 lanes = pallas_rlc.verify_rlc_compact(
@@ -306,7 +306,7 @@ def worker() -> None:
         from tendermint_tpu.ops import pallas_rlc as _prw
 
         for _b in _prw.RLC_BUCKETS:
-            _wargs = _prw.prepare_rlc([], _b)
+            _wargs = _prw.prepare_rlc([], _b, _prw.lane_width(_b))
             _prw.verify_rlc_compact(*_wargs)
     if on_accel and use_pallas:
         from concurrent.futures import ThreadPoolExecutor
@@ -320,8 +320,8 @@ def worker() -> None:
             k_entries = (entries * ((_pk.MAX_SIGS + n_sigs - 1) // n_sigs))[
                 : _pk.MAX_SIGS
             ]
-            rlc_bucket, g, blk = _pk.plan_bucket(len(k_entries))
-            f = _pk._jitted_rlc_verify(g, blk, False)
+            rlc_bucket, g, blk, rlc_m = _pk.plan_bucket(len(k_entries))
+            f = _pk._jitted_rlc_verify(rlc_m, g, blk, False)
             # kernel_stream is the DEVICE capability figure (transfer +
             # execute steady state); host prep at this scale (~230 ms
             # GIL-mixed) is the headline's cost, not the kernel's — so
@@ -330,7 +330,8 @@ def worker() -> None:
             # execute-only) and keep prep out of the timed loop
             n_batches = 4
             pre = [
-                _pk.prepare_rlc(k_entries, rlc_bucket) for _ in range(n_batches)
+                _pk.prepare_rlc(k_entries, rlc_bucket, rlc_m)
+                for _ in range(n_batches)
             ]
             prep_fn = None
             kern_sigs = len(k_entries)

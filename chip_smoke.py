@@ -253,22 +253,25 @@ def early_stop_count(n_vals: int, num: int = 2, den: int = 3) -> int:
     return min(needed // POWER + 1, n_vals)
 
 
-def rlc_lane() -> int:
-    """Signatures per RLC lane: what the host re-verifies when a lane
-    rejects (ops/pallas_rlc.py expand_lanes)."""
-    from tendermint_tpu.ops import pallas_rlc
-
-    return pallas_rlc.M
-
-
 def forged_position(rng: random.Random, n: int) -> int:
     """Where to forge one of a batch's n signatures: anywhere but the
-    last few. An RLC lane holds M consecutive signatures of the LAUNCHED
-    batch (ops/pallas_rlc.py), and on a reject the host re-verifies the
+    last few. An RLC lane holds m consecutive signatures of the LAUNCHED
+    batch, m being the width that launch's size gave it (ops/pallas_rlc.py
+    plan_bucket: 2, 4 or 8), and on a reject the host re-verifies the
     lane's live ones; several jobs may share a launch at any offset, so
-    only a position at least M short of the job's end is certain to sit
-    in a full lane — and cost exactly M host re-verifies."""
-    return rng.randrange(n - rlc_lane())
+    only a position at least the widest lane short of the job's end is
+    certain to sit in a full lane — and cost exactly m host re-verifies."""
+    from tendermint_tpu.ops import pallas_rlc
+
+    return rng.randrange(n - pallas_rlc.WIDTHS[-1])
+
+
+def reverified(before: dict, after: dict) -> int:
+    """Signatures the host re-verified for the RLC lanes the device
+    rejected between two counters(): each lane its launch's width."""
+    was = before["rlc_rejected_lanes_by_width"]
+    return sum(int(m) * (k - was.get(m, 0))
+               for m, k in after["rlc_rejected_lanes_by_width"].items())
 
 
 # -- the sequential reference ------------------------------------------------
@@ -324,17 +327,13 @@ class Ledger:
     def __init__(self):
         self.device = 0
         self.host = 0
+        self.forged = 0  # jobs with one forged signature: one lane each
         self.notes = []
         self.steps = []  # (stage, step, seconds, first_use)
 
     def submitted(self, n: int, forged: bool = False) -> None:
         self.device += n
-        if forged:
-            self.host += rlc_lane()
-            self.notes.append(
-                f"{rlc_lane()} host re-verifies for the rejected RLC lane "
-                f"of a forged {n}-signature job"
-            )
+        self.forged += forged
 
     def run(self, stage: str, step: str, fn, sigs: int = 0,
             first_use: bool = False, forged: bool = False):
@@ -360,6 +359,8 @@ def counters() -> dict:
         "dispatch_errors": s["dispatch_errors"],
         "ingress_fallbacks": dict(s["ingress_fallbacks"]),
         "batches_by_bucket": dict(s["batches_by_bucket"]),
+        "rlc_launches_by_width": dict(s["rlc_launches_by_width"]),
+        "rlc_rejected_lanes_by_width": dict(s["rlc_rejected_lanes_by_width"]),
         "epoch_cache_hits": s["epoch_cache_hits"],
         "epoch_cache_misses": s["epoch_cache_misses"],
     }
@@ -519,7 +520,7 @@ def stage_server(led: Ledger, reqs, now, want_forged) -> dict:
     cfg.base.home = home
     cfg.rpc.laddr = "tcp://127.0.0.1:0"
     cfg.p2p.laddr = "tcp://127.0.0.1:0"
-    host_before = counters()["host"]
+    before = counters()
     node = make_node(cfg, app=KVStoreApplication(), with_rpc=True)
     node.start()
     try:
@@ -562,8 +563,9 @@ def stage_server(led: Ledger, reqs, now, want_forged) -> dict:
     # signatures, validation.go:12). Block h+1 may have been validated
     # but not yet stored when the node stopped.
     height = node.block_store.height()
-    consensus_host = (counters()["host"] - host_before
-                      - rlc_lane() * sum(1 for *_r, f in reqs if f))
+    after = counters()
+    consensus_host = (after["host"] - before["host"]
+                      - reverified(before, after))
     check(max(height - 1, 0) <= consensus_host <= height,
           f"the node's consensus host-verified {consensus_host} signatures "
           f"at chain height {height}")
@@ -588,6 +590,18 @@ def stage_accounts(led: Ledger, base: dict) -> dict:
     check_engine(eng, "ops.engine")
     dev = c["device"] - base["device"]
     host = c["host"] - base["host"]
+    lanes = {m: k - base["rlc_rejected_lanes_by_width"].get(m, 0)
+             for m, k in c["rlc_rejected_lanes_by_width"].items()}
+    check(sum(lanes.values()) == led.forged,
+          f"{led.forged} forged jobs, each one signature in a full lane, "
+          f"but the device rejected {lanes} lanes (by width)")
+    blamed = reverified(base, c)
+    led.host += blamed
+    led.notes.append(
+        f"{blamed} host re-verifies for the rejected RLC lanes of "
+        f"{led.forged} forged jobs (lanes by width: {lanes})"
+    )
+    say(f"  RLC launches by lane width: {c['rlc_launches_by_width']}")
     check(dev == led.device,
           f"sigs_verified device rose by {dev}, submitted {led.device}")
     check(host == led.host,
@@ -601,7 +615,8 @@ def stage_accounts(led: Ledger, base: dict) -> dict:
         say(f"  host path, stated: {note}")
     return {"engine": eng, "sigs_verified_device": dev,
             "sigs_verified_host": host, "host_notes": led.notes,
-            "batches_by_bucket": c["batches_by_bucket"]}
+            "batches_by_bucket": c["batches_by_bucket"],
+            "rlc_launches_by_width": c["rlc_launches_by_width"]}
 
 
 def report(led: Ledger) -> dict:

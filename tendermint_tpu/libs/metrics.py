@@ -613,6 +613,22 @@ class OpsMetrics:
         self.batches = registry.counter(
             "ops", "batches_total", "Device batches dispatched, by bucket label."
         )
+        # the RLC kernel's lane width follows the batch size
+        # (ops/pallas_rlc.plan_bucket): which widths a deployment's own
+        # traffic runs at, counted where the width is chosen
+        self.rlc_launches = registry.counter(
+            "ops", "rlc_launches_total",
+            "RLC launches prepared, by lane width label m (signatures a lane).",
+        )
+        self.rlc_sigs = registry.counter(
+            "ops", "rlc_sigs_total",
+            "Live signatures of RLC launches, by lane width label m.",
+        )
+        self.rlc_rejected_lanes = registry.counter(
+            "ops", "rlc_rejected_lanes_total",
+            "RLC lanes the device rejected and the host re-verified "
+            "signature by signature, by lane width label m.",
+        )
         self.padded_lanes = registry.counter(
             "ops", "padded_lanes_total",
             "Padding lanes dispatched (bucket size minus live signatures).",
@@ -899,6 +915,12 @@ def blocksync_stats() -> dict:
     }
 
 
+def _by_width(counter) -> dict:
+    """An RLC counter's values keyed by its lane width label."""
+    return {dict(k).get("m", ""): int(v)
+            for k, v in counter.by_label().items()}
+
+
 def ops_stats() -> dict:
     """Verify-engine snapshot for /status — no jax import, cheap reads."""
     m = ops_metrics()
@@ -916,6 +938,9 @@ def ops_stats() -> dict:
             (dict(k).get("bucket", "") or "unbucketed"): int(v)
             for k, v in m.batches.by_label().items()
         },
+        "rlc_launches_by_width": _by_width(m.rlc_launches),
+        "rlc_sigs_by_width": _by_width(m.rlc_sigs),
+        "rlc_rejected_lanes_by_width": _by_width(m.rlc_rejected_lanes),
         "pad_waste_ratio": (padded / dispatched) if dispatched else 0.0,
         "host_fallback_batches": int(m.host_fallback.total()),
         "dispatch_errors": int(m.dispatch_errors.total()),

@@ -669,9 +669,10 @@ def select_kernel(entries, bucket: int = 0, lanes: int = 0, mesh=None,
     Returns the dispatcher's `_prepare` contract, (launch_fn, args,
     rlc_entries, bucket): `launch_fn(*args)` on the device-owner thread
     gives the verdict row; `rlc_entries` is the batch itself when that
-    row holds one verdict per M-signature RLC lane (the caller expands
-    and re-verifies rejected lanes on the host, pallas_rlc.expand_lanes)
-    and None when it holds one per row; `bucket` is the padded width.
+    row holds one verdict per RLC lane of bucket // len(row) signatures,
+    the width that launch ran at (the caller expands by it and
+    re-verifies rejected lanes on the host, pallas_rlc.expand_lanes) and
+    None when it holds one per row; `bucket` is the padded width.
 
     bucket  0 quantizes on the family's own ladder; the mesh forces its
             superbatch's width, warmup() the shape to compile
@@ -717,7 +718,7 @@ def select_kernel(entries, bucket: int = 0, lanes: int = 0, mesh=None,
         if eng.rlc and not lanes:
             from . import pallas_rlc
 
-            fn, args, bucket = pallas_rlc.rlc_launch(
+            fn, args, bucket, _m = pallas_rlc.rlc_launch(
                 entries, ep, bucket=bucket, interpret=eng.interpret,
                 donate=donate,
             )
@@ -807,7 +808,8 @@ def _verify_batch_direct(entries, step: int, scheme: str = "") -> np.ndarray:
             if rlc_entries is not None:
                 from . import pallas_rlc
 
-                res = pallas_rlc.expand_lanes(res, rlc_entries)
+                res = pallas_rlc.expand_lanes(
+                    res, rlc_entries, bucket // len(res))
         _note_device_batch(
             len(chunk), bucket, device_s=time.perf_counter() - t0
         )

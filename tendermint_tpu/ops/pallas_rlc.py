@@ -1,13 +1,13 @@
-"""Per-lane RLC fast-accept verification — M signatures per kernel lane.
+"""Per-lane RLC fast-accept verification — m signatures per kernel lane.
 
 The per-signature kernel (ops.pallas_verify) spends ~70% of its ladder on
 point doubles: every lane doubles its own accumulator 254 times to verify
-ONE signature. This module amortizes those doubles over M signatures by
+ONE signature. This module amortizes those doubles over m signatures by
 verifying a random-linear-combination equation per lane (the same
 construction Go's crypto/ed25519 batch path uses across a whole batch —
 crypto/ed25519/ed25519.go:192-227 — applied at lane granularity):
 
-    lane g covers sigs j = 0..M-1 with coefficients c_0 = 1,
+    lane g covers sigs j = 0..m-1 with coefficients c_0 = 1,
     c_j = z_j (random 128-bit, host CSPRNG, fresh per batch):
 
     acc = [S]B - sum_j [u_j]A_j - sum_{j>=1} [z_j]R_j
@@ -18,21 +18,31 @@ crypto/ed25519/ed25519.go:192-227 — applied at lane granularity):
 Soundness: [8] of each per-sig residual e_j = [s_j]B - [k_j]A_j - R_j
 lies in the prime-order subgroup, so if any [8]e_j != O the combination
 [8]acc = sum c_j [8]e_j vanishes with probability <= 2^-125 over the
-z_j. Valid batches ALWAYS accept ([8]e_j = O for all j implies
-[8]acc = O identically — torsion components cancel under the cofactor
-exactly as in per-sig ZIP-215). On lane reject the caller re-verifies
-that lane's M signatures individually for blame (the reference's own
-accept/reject asymmetry, types/validation.go:242-248); per-sig
-accept/reject semantics are therefore preserved exactly, up to the
-negligible false-accept probability every RLC batch verifier carries.
+z_j, whatever m is. Valid batches ALWAYS accept ([8]e_j = O for all j
+implies [8]acc = O identically — torsion components cancel under the
+cofactor exactly as in per-sig ZIP-215). On lane reject the caller
+re-verifies that lane's m signatures individually for blame (the
+reference's own accept/reject asymmetry, types/validation.go:242-248);
+per-sig accept/reject semantics are therefore preserved exactly, up to
+the negligible false-accept probability every RLC batch verifier carries.
 
-The ladder processes 2M scalars (1 + M full 253-bit, M-1 half 128-bit)
-through M joint 16-entry Straus tables — 2 doubles + ~(M/2+1..M) adds
-per iteration for M signatures, vs 2 doubles + 1 add per signature in
-the per-sig kernel. At M=4 that is ~1.9x fewer field muls per signature
-with the SAME per-block VMEM footprint (per-lane table bytes x4, lanes
-/4). Layouts, point ops, and Mosaic constraints are shared with
-ops.pallas_verify.
+The ladder processes 2m scalars (1 + m full 253-bit, m-1 half 128-bit)
+through m joint 16-entry Straus tables: 2 doubles + (m/2+1 .. m) adds per
+iteration for m signatures, vs 2 doubles + 1 add per signature in the
+per-sig kernel. Field multiplications and squarings of the ladder, per
+lane and per signature:
+
+    m   per lane   per signature
+    2     3 810        1 905
+    4     5 338        1 334
+    8     8 394        1 049
+
+A wider lane does less arithmetic per signature and more per lane, so the
+width follows the batch (plan_bucket): where one kernel block holds the
+whole batch the time is the per-lane chain and the narrowest width that
+fits wins; where blocks run one after another the time is per signature
+and the widest does. Layouts, point ops, and Mosaic constraints are
+shared with ops.pallas_verify.
 """
 
 from __future__ import annotations
@@ -54,16 +64,18 @@ from ..crypto import _edwards
 
 NL = fe_t.NLIMBS
 
-# Signatures per lane. 2 scalars pair per joint table, so M tables serve
-# 2M scalars; M=4 is the measured sweet spot (M=8 halves the remaining
-# doubles but the z-lane adds start to dominate).
-M = int(os.environ.get("TM_TPU_RLC_M", "4"))
-if M not in (2, 4, 8):
-    raise ValueError(f"TM_TPU_RLC_M={M} must be 2, 4 or 8")
+# Signatures per lane a launch can run at. Two scalars pair per joint
+# table, so a lane of m signatures has m tables for its 2m scalars:
+#   scalar q: 0 -> S, 1..m -> u_{q-1}, m+1..2m-1 -> z_{q-m}
+#   table t pairs scalar lo=2t (low 2 bits of the entry index) with
+#   hi=2t+1. Tables whose BOTH scalars are z's (lo index > m) carry zero
+#   digits above bit 128 and are skipped in the top half of the ladder,
+#   which leaves m//2 + 1 tables there.
+WIDTHS = (2, 4, 8)
 
-# Lanes per kernel block (block covers BLOCK_LANES * M signatures). The
-# per-block table is M x 16 entries x 4 coords — the same VMEM bytes as
-# the per-sig kernel's 16-entry table at M x the lane count.
+# Lanes per kernel block (a block covers BLOCK_LANES * m signatures). The
+# per-block table is m x 16 entries x 4 coords of 32-row slots: 1 MiB per
+# unit of m at 128 lanes.
 BLOCK_LANES = int(os.environ.get("TM_TPU_RLC_BLOCK", "128"))
 
 # Max signatures per device batch: the async pipeline coalesces
@@ -71,345 +83,367 @@ BLOCK_LANES = int(os.environ.get("TM_TPU_RLC_BLOCK", "128"))
 # HBM at 81920 is ~900 MB of intermediates on a 16 GB part. Value not
 # measured on this machine.
 #
-# Validated at import: every bucket plan_bucket can select —
-# the cap included — must divide into whole kernel blocks (M * BLOCK_LANES
-# signatures each) or the truncated pallas grid would leave trailing
-# lanes' verdicts uninitialized, and a cap below the smallest quantized
-# bucket would make plan_bucket select ABOVE it.
+# Validated at import: every multi-block bucket plan_bucket can select —
+# the cap included — runs at the widest lane and must divide into whole
+# kernel blocks (WIDTHS[-1] * BLOCK_LANES signatures each) or the
+# truncated pallas grid would leave trailing lanes' verdicts
+# uninitialized, and a cap below the smallest quantized bucket would make
+# plan_bucket select ABOVE it.
 MAX_SIGS = int(os.environ.get("TM_TPU_RLC_MAX_SIGS", "81920"))
-if MAX_SIGS < 512 or MAX_SIGS % (M * BLOCK_LANES):
+if MAX_SIGS <= 0 or MAX_SIGS % (WIDTHS[-1] * BLOCK_LANES):
     raise ValueError(
-        f"TM_TPU_RLC_MAX_SIGS={MAX_SIGS} must be >= 512 and a multiple of "
-        f"M*BLOCK_LANES={M * BLOCK_LANES}"
+        f"TM_TPU_RLC_MAX_SIGS={MAX_SIGS} must be a positive multiple of "
+        f"{WIDTHS[-1]}*BLOCK_LANES={WIDTHS[-1] * BLOCK_LANES}"
     )
 
-# Scalar q: 0 -> S, 1..M -> u_{q-1}, M+1..2M-1 -> z_{q-M}.
-N_SCAL = 2 * M
-# Table t pairs scalar lo=2t (low 2 bits of the entry index) with
-# hi=2t+1. Tables whose BOTH scalars are z's (lo index > M) carry zero
-# digits above bit 128 and are skipped in the top half of the ladder.
-N_FULL_TABLES = M // 2 + 1
+# Rows of one point (4 coords) and of one 16-entry table in the 32-row
+# slot layout both kernels' refs use
+_POINT_ROWS = 4 * 32
+_TABLE_ROWS = 16 * _POINT_ROWS
+
+
+def _coord_rows(c: int) -> slice:
+    """Rows of coord c inside one point's 32-row slots."""
+    return slice(c * 32, c * 32 + NL)
 
 
 def _point_rows(p: int, c: int) -> slice:
     """Rows of coord c of point p in the coords ref (32-row slots)."""
-    base = (p * 4 + c) * 32
+    base = p * _POINT_ROWS + c * 32
     return slice(base, base + NL)
 
 
-def _tbl_rows(t: int, e: int, c: int) -> slice:
-    base = ((t * 16 + e) * 4 + c) * 32
-    return slice(base, base + NL)
+# -- K1: byte unpack + decompression of 2m points ---------------------------
 
 
-# -- K1: byte unpack + decompression of 2M points ---------------------------
+def _k1_rlc_kernel(m: int, cached: bool):
+    """Unpack 2m scalars' base-4 digits and jointly decompress the points
+    of each lane's m signatures.
+
+    coords: (2m * 128, G) 32-row coordinate slots, A's then R's.
+    ok:     (2m, G) decompression flags.
+    dig:    (2m * 128, G) shift-grouped digits, scalar-major.
+
+    cached=False: refs (a, r, scal | coords, ok, dig); all 2m points
+    (A_0..A_{m-1}, R_0..R_{m-1}) decompress here.
+    cached=True, a WARM epoch: refs (ac, aok, r, scal | coords, ok, dig);
+    the m committee points arrive pre-decompressed (gathered on device
+    from the epoch cache's persistent coords table: ac (m * 128, G) int32
+    slot-major A coords, aok (m, G) flags), so only the m R's decompress
+    — K1 was ~half committee work by construction."""
+
+    def kernel(*refs):
+        *a_refs, r_ref, scal_ref, coords_ref, ok_ref, dig_ref = refs
+        for q in range(2 * m):
+            enc = scal_ref[q * 32 : (q + 1) * 32].astype(jnp.int32)
+            dig_ref[q * 128 : (q + 1) * 128] = pv._unpack_digits2_grouped(enc)
+
+        encs = [r_ref[j * 32 : (j + 1) * 32] for j in range(m)]
+        if cached:
+            ac_ref, aok_ref = a_refs
+            ok_ref[0:m] = aok_ref[...]
+            for p in range(m):
+                for c in range(4):
+                    coords_ref[_point_rows(p, c)] = ac_ref[_point_rows(p, c)]
+        else:
+            (a_ref,) = a_refs
+            encs = [a_ref[j * 32 : (j + 1) * 32] for j in range(m)] + encs
+        first = 2 * m - len(encs)  # coords slot of the first point unpacked
+        ys, signs = zip(*(pv._unpack_limbs(e.astype(jnp.int32)) for e in encs))
+        G = ys[0].shape[-1]
+        ok_all, pts = pv.decompress(pv._cat(ys), pv._cat(signs))
+        for j in range(len(encs)):
+            p = first + j
+            ok_ref[p : p + 1] = ok_all[:, j * G : (j + 1) * G].astype(jnp.int32)
+            for c in range(4):
+                coords_ref[_point_rows(p, c)] = pts[c][:, j * G : (j + 1) * G]
+
+    return kernel
 
 
-def _k1_rlc_kernel(a_ref, r_ref, scal_ref, coords_ref, ok_ref, dig_ref):
-    """Unpack 2M scalars' base-4 digits and jointly decompress the 2M
-    points (A_0..A_{M-1}, R_0..R_{M-1}) of each lane's M signatures.
-
-    coords: ((2M*4)*32, G) 32-row coordinate slots, A's then R's.
-    ok:     (2M, G) decompression flags.
-    dig:    (2M*128, G) shift-grouped digits, scalar-major."""
-    for q in range(N_SCAL):
-        enc = scal_ref[q * 32 : (q + 1) * 32].astype(jnp.int32)
-        dig_ref[q * 128 : (q + 1) * 128] = pv._unpack_digits2_grouped(enc)
-
-    ys = []
-    signs = []
-    for j in range(M):
-        y, s = pv._unpack_limbs(a_ref[j * 32 : (j + 1) * 32].astype(jnp.int32))
-        ys.append(y)
-        signs.append(s)
-    for j in range(M):
-        y, s = pv._unpack_limbs(r_ref[j * 32 : (j + 1) * 32].astype(jnp.int32))
-        ys.append(y)
-        signs.append(s)
-    G = ys[0].shape[-1]
-    ok_all, pts = pv.decompress(pv._cat(ys), pv._cat(signs))
-    for p in range(2 * M):
-        ok_ref[p : p + 1] = ok_all[:, p * G : (p + 1) * G].astype(jnp.int32)
-        for c in range(4):
-            coords_ref[_point_rows(p, c)] = pts[c][:, p * G : (p + 1) * G]
+# -- K2: m joint Straus tables ----------------------------------------------
 
 
-def _k1_rlc_kernel_cached(ac_ref, aok_ref, r_ref, scal_ref, coords_ref,
-                          ok_ref, dig_ref):
-    """_k1_rlc_kernel for a WARM epoch: the M committee points per lane
-    arrive pre-decompressed (gathered on device from the epoch cache's
-    persistent coords table), so this variant decompresses M points (the
-    R's) instead of 2M — K1 was ~half committee work by construction.
-
-    ac: (M*4*32, B) int32 — slot-major A coords, point p coord c at rows
-    (p*4 + c)*32; aok: (M, B) int32 per-slot decompression flags."""
-    for q in range(N_SCAL):
-        enc = scal_ref[q * 32 : (q + 1) * 32].astype(jnp.int32)
-        dig_ref[q * 128 : (q + 1) * 128] = pv._unpack_digits2_grouped(enc)
-
-    for p in range(M):
-        ok_ref[p : p + 1] = aok_ref[p : p + 1]
-        for c in range(4):
-            coords_ref[_point_rows(p, c)] = ac_ref[
-                (p * 4 + c) * 32 : (p * 4 + c) * 32 + NL
-            ]
-
-    ys = []
-    signs = []
-    for j in range(M):
-        y, s = pv._unpack_limbs(r_ref[j * 32 : (j + 1) * 32].astype(jnp.int32))
-        ys.append(y)
-        signs.append(s)
-    G = ys[0].shape[-1]
-    ok_all, pts = pv.decompress(pv._cat(ys), pv._cat(signs))
-    for j in range(M):
-        p = M + j
-        ok_ref[p : p + 1] = ok_all[:, j * G : (j + 1) * G].astype(jnp.int32)
-        for c in range(4):
-            coords_ref[_point_rows(p, c)] = pts[c][:, j * G : (j + 1) * G]
+def _table_points(m: int, t):
+    """Coords slots (P, Q) of table t's two points, t static or traced:
+    scalars (2t, 2t+1), where scalar q <= m is u_{q-1} on A_{q-1} (slot
+    q-1) and q > m is z_{q-m} on R_{q-m} (slot q). Table 0's P is the
+    base point, which has no slot: the kernel substitutes it and slot 0
+    stands in."""
+    p = jnp.where(2 * t <= m, jnp.maximum(2 * t - 1, 0), 2 * t)
+    q = jnp.where(2 * t + 1 <= m, 2 * t, 2 * t + 1)
+    return p, q
 
 
-# -- K2: M joint Straus tables ----------------------------------------------
-
-
-def _k2_rlc_kernel(coords_ref, tbl_ref):
-    """Build the M 16-entry joint tables. Table t holds
-    [lo]P_t + [hi]Q_t for digits lo, hi in 0..3 at entry lo + 4*hi, where
-    (P_t, Q_t) are the points of scalars (2t, 2t+1): B for S, -A_j for
-    u_j, -R_j for z_j. Same lane-folded dbl/tri/cross construction as
-    pallas_verify._k2_table_kernel, folded across all M tables."""
-    pts = []
-    for p in range(2 * M):
-        pt = tuple(coords_ref[_point_rows(p, c)] for c in range(4))
-        pts.append(pv.point_neg(pt))
-    G = pts[0][0].shape[-1]
+def _k2_rlc_kernel(p_ref, q_ref, tbl_ref):
+    """Build ONE 16-entry joint table per grid step (lane block i, table
+    t): [lo]P + [hi]Q for digits lo, hi in 0..3 at entry lo + 4*hi, where
+    (P, Q) are the points of scalars (2t, 2t+1): B for S, -A_j for u_j,
+    -R_j for z_j. The block specs hand the step its two points
+    (_table_points), so the body is traced once whatever the lane width.
+    Same lane-folded dbl/tri/cross construction as
+    pallas_verify._k2_table_kernel."""
+    P = pv.point_neg(tuple(p_ref[_coord_rows(c)] for c in range(4)))
+    Q = pv.point_neg(tuple(q_ref[_coord_rows(c)] for c in range(4)))
+    G = P[0].shape[-1]
     zero = jnp.zeros((NL, G), dtype=jnp.int32)
     one = fe_t.limbs_from_int_t(1)
-    bx = fe_t.limbs_from_int_t(_edwards.BASE[0])
-    by = fe_t.limbs_from_int_t(_edwards.BASE[1])
-    bt = fe_t.limbs_from_int_t(_edwards.BASE[3])
-    base = (bx + zero, by + zero, one + zero, bt + zero)
     ident = (zero, one + zero, one + zero, zero)
-
-    def point_of(q):
-        if q == 0:
-            return base
-        if q <= M:
-            return pts[q - 1]  # -A_{q-1}
-        return pts[M + (q - M)]  # -R_{q-M}
-
-    P = [point_of(2 * t) for t in range(M)]
-    Q = [point_of(2 * t + 1) for t in range(M)]
-    # one fold for all 2M doubles, one for all 2M triples
-    pair = pv._catp(P + Q)
+    first = pl.program_id(1) == 0
+    base = (_edwards.BASE[0], _edwards.BASE[1], 1, _edwards.BASE[3])
+    P = tuple(
+        jnp.where(first, fe_t.limbs_from_int_t(b) + zero, p)
+        for b, p in zip(base, P)
+    )
+    pair = pv._catp([P, Q])
     dbl = pv.point_double(pair)
     tri = pv.point_add(dbl, pair)
-    rows = []  # rows[t] = [O, P, 2P, 3P]; cols[t] = [O, Q, 2Q, 3Q]
-    cols = []
-    for t in range(M):
-        rows.append([ident, P[t], pv._slicep(dbl, t, G), pv._slicep(tri, t, G)])
-        cols.append(
-            [ident, Q[t], pv._slicep(dbl, M + t, G), pv._slicep(tri, M + t, G)]
-        )
-    # 9 cross entries per table, folded PER TABLE (a single M*9-wide fold
-    # overruns scoped VMEM at 128 lanes: the (20, 20, 9*M*G) mul transient
-    # alone is ~7 MB)
-    crosses = [
-        pv.point_add(
-            pv._catp([rows[t][lo] for hi in (1, 2, 3) for lo in (1, 2, 3)]),
-            pv._catp([cols[t][hi] for hi in (1, 2, 3) for lo in (1, 2, 3)]),
-        )
-        for t in range(M)
-    ]
-    entries = []  # (t, e, point)
-    for t in range(M):
-        for hi in range(4):
-            for lo in range(4):
-                if hi == 0:
-                    pt = rows[t][lo]
-                elif lo == 0:
-                    pt = cols[t][hi]
-                else:
-                    pt = pv._slicep(crosses[t], (hi - 1) * 3 + (lo - 1), G)
-                entries.append((t, lo + 4 * hi, pt))
+    row = [ident, P, pv._slicep(dbl, 0, G), pv._slicep(tri, 0, G)]
+    col = [ident, Q, pv._slicep(dbl, 1, G), pv._slicep(tri, 1, G)]
+    # the 9 cross entries in one fold
+    cross = pv.point_add(
+        pv._catp([row[lo] for hi in (1, 2, 3) for lo in (1, 2, 3)]),
+        pv._catp([col[hi] for hi in (1, 2, 3) for lo in (1, 2, 3)]),
+    )
+    entries = []  # entry e = lo + 4*hi, in order
+    for hi in range(4):
+        for lo in range(4):
+            if hi == 0:
+                entries.append(row[lo])
+            elif lo == 0:
+                entries.append(col[hi])
+            else:
+                entries.append(pv._slicep(cross, (hi - 1) * 3 + (lo - 1), G))
     # Niels-form store, folded 8 entries at a time (keeps the (20,20,B)
     # mul transient within VMEM; see pallas_verify._k2_table_kernel)
-    for half in range(len(entries) // 8):
-        chunk = entries[half * 8 : half * 8 + 8]
-        niels = pv.to_niels(pv._catp([pt for _, _, pt in chunk]))
-        for j, (t, e, _) in enumerate(chunk):
+    for half in range(2):
+        niels = pv.to_niels(pv._catp(entries[half * 8 : half * 8 + 8]))
+        for j in range(8):
             ent = pv._slicep(niels, j, G)
             for c in range(4):
-                tbl_ref[_tbl_rows(t, e, c)] = ent[c]
+                tbl_ref[_point_rows(half * 8 + j, c)] = ent[c]
 
 
 # -- K3: the shared-doubles ladder ------------------------------------------
 
 
-def _k3_rlc_kernel(tbl_ref, dig_ref, coords_ref, ok_ref, sok_ref, out_ref):
+def _k3_rlc_kernel(m: int):
     """127-iteration ladder with 2 doubles + n_tables adds per iteration
     (vs 2 doubles + 1 add PER SIGNATURE in the per-sig kernel). The top
     63 iterations skip the all-z tables (digits structurally zero: z_j <
-    2^128). Final test: [8]acc == [8]R_0 by doubles-only projective
-    cross-multiplication, identical to pallas_verify._k3_ladder_kernel."""
-    G = sok_ref.shape[-1]
-    zero = jnp.zeros((NL, G), dtype=jnp.int32)
-    one = fe_t.limbs_from_int_t(1)
-    ident = (zero, one + zero, one + zero, zero)
+    2^128). The adds of an iteration are an unrolled chain: on the v5e a
+    loop over the tables cost the 8-wide 10 240-signature launch 3.9 %
+    (12.35 against 11.88 ms: PERF.md §5, PR 31), and what it saves the
+    host in tracing K2 already saved. Final test: [8]acc == [8]R_0 by
+    doubles-only projective cross-multiplication, identical to
+    pallas_verify._k3_ladder_kernel."""
 
-    def select(t, idx):
-        out = [tbl_ref[_tbl_rows(t, 0, c)] for c in range(4)]
-        for e in range(1, 16):
-            m = (idx == e)[None, :]
-            for c in range(4):
-                out[c] = jnp.where(m, tbl_ref[_tbl_rows(t, e, c)], out[c])
-        return tuple(out)
+    def kernel(tbl_ref, dig_ref, r0_ref, ok_ref, sok_ref, out_ref):
+        G = sok_ref.shape[-1]
+        zero = jnp.zeros((NL, G), dtype=jnp.int32)
+        one = fe_t.limbs_from_int_t(1)
+        ident = (zero, one + zero, one + zero, zero)
 
-    def make_body(n_tables):
-        def body(i, acc):
-            j = pv._digit_row(126 - i)
-            acc = pv.point_double(pv.point_double(acc, need_t=False))
-            for t in range(n_tables):
-                idx = dig_ref[2 * t * 128 + j] + 4 * dig_ref[(2 * t + 1) * 128 + j]
-                # intermediate adds feed the next add's t1*T2d term; only
-                # the last add before the wrap-around doubles skips T
-                acc = pv.point_add_niels(acc, select(t, idx), need_t=t + 1 < n_tables)
-            return acc
+        def select(t, idx):
+            out = [tbl_ref[_point_rows(t * 16, c)] for c in range(4)]
+            for e in range(1, 16):
+                hit = (idx == e)[None, :]
+                for c in range(4):
+                    out[c] = jnp.where(
+                        hit, tbl_ref[_point_rows(t * 16 + e, c)], out[c])
+            return tuple(out)
 
-        return body
+        def make_body(n_tables):
+            def body(i, acc):
+                j = pv._digit_row(126 - i)
+                acc = pv.point_double(pv.point_double(acc, need_t=False))
+                for t in range(n_tables):
+                    idx = (dig_ref[2 * t * 128 + j]
+                           + 4 * dig_ref[(2 * t + 1) * 128 + j])
+                    # intermediate adds feed the next add's t1*T2d term;
+                    # only the last add before the wrap-around doubles
+                    # skips T
+                    acc = pv.point_add_niels(
+                        acc, select(t, idx), need_t=t + 1 < n_tables)
+                return acc
 
-    # positions 126..64: z digits are all zero — all-z tables skipped
-    acc = lax.fori_loop(0, 63, make_body(N_FULL_TABLES), ident)
-    acc = lax.fori_loop(63, 127, make_body(M), acc)
+            return body
 
-    # [8]acc == [8]R_0, doubles-only (complete for small-order inputs)
-    R0 = tuple(coords_ref[_point_rows(M, c)] for c in range(4))
-    acc8, r8 = acc, R0
-    for _ in range(3):
-        acc8 = pv.point_double(acc8, need_t=False)
-        r8 = pv.point_double(r8, need_t=False)
-    eq_x = fe_t.is_zero(
-        fe_t.sub(fe_t.mul(acc8[0], r8[2]), fe_t.mul(r8[0], acc8[2]))
-    )
-    eq_y = fe_t.is_zero(
-        fe_t.sub(fe_t.mul(acc8[1], r8[2]), fe_t.mul(r8[1], acc8[2]))
-    )
-    valid = eq_x & eq_y
-    for p in range(2 * M):
-        valid = valid & (ok_ref[p : p + 1] != 0)
-    for j in range(M):
-        valid = valid & (sok_ref[j : j + 1] != 0)
-    out_ref[:] = valid.astype(jnp.int32)
+        # positions 126..64: z digits are all zero — all-z tables skipped
+        acc = lax.fori_loop(0, 63, make_body(m // 2 + 1), ident)
+        acc = lax.fori_loop(63, 127, make_body(m), acc)
+
+        # [8]acc == [8]R_0, doubles-only (complete for small-order inputs)
+        acc8 = acc
+        r8 = tuple(r0_ref[_coord_rows(c)] for c in range(4))
+        for _ in range(3):
+            acc8 = pv.point_double(acc8, need_t=False)
+            r8 = pv.point_double(r8, need_t=False)
+        eq_x = fe_t.is_zero(
+            fe_t.sub(fe_t.mul(acc8[0], r8[2]), fe_t.mul(r8[0], acc8[2]))
+        )
+        eq_y = fe_t.is_zero(
+            fe_t.sub(fe_t.mul(acc8[1], r8[2]), fe_t.mul(r8[1], acc8[2]))
+        )
+        valid = eq_x & eq_y
+        for p in range(2 * m):
+            valid = valid & (ok_ref[p : p + 1] != 0)
+        for j in range(m):
+            valid = valid & (sok_ref[j : j + 1] != 0)
+        out_ref[:] = valid.astype(jnp.int32)
+
+    return kernel
 
 
 # -- pipeline ----------------------------------------------------------------
 
 
-# Quantized bucket ladder (in signatures): XLA compiles one executable
-# per shape, and the coalescing pipeline would otherwise produce a fresh
-# shape (and a fresh trace + Mosaic compile) for every distinct batch total.
-# Built as a sorted tuple filtered to <= MAX_SIGS (and to whole kernel
-# blocks) so plan_bucket can never select above the cap or hand the
-# jitted kernel a lane count that truncates its grid.
+# Quantized ladder of the multi-block buckets (in signatures): XLA
+# compiles one executable per shape, and the coalescing pipeline would
+# otherwise produce a fresh shape (and a fresh trace + Mosaic compile) for
+# every distinct batch total. Every bucket here is more than one block of
+# any width, so all of them run at the widest lane. A sorted tuple
+# filtered to <= MAX_SIGS (and to whole kernel blocks) so plan_bucket can
+# never select above the cap or hand the jitted kernel a lane count that
+# truncates its grid.
 RLC_BUCKETS = tuple(
     sorted(
         b
-        for b in {512, 2048, 10240, 20480, 40960, 81920, MAX_SIGS}
-        if b <= MAX_SIGS and b % (M * BLOCK_LANES) == 0
+        for b in {2048, 10240, 20480, 40960, 81920, MAX_SIGS}
+        if b <= MAX_SIGS and b % (WIDTHS[-1] * BLOCK_LANES) == 0
     )
 )
 assert RLC_BUCKETS and RLC_BUCKETS[-1] == MAX_SIGS
 
 
-def plan_bucket(n: int, block: int = 0) -> tuple:
-    """(bucket_sigs, g_lanes, block) covering n signatures such that the
-    lane count divides evenly into kernel blocks. EVERY caller that feeds
-    _jitted_rlc_verify must size via this: a g not divisible by block
-    would truncate the pallas grid and leave trailing lanes' verdicts
-    uninitialized — read back as garbage 'valid' bits.
-
-    Buckets quantize to RLC_BUCKETS (pow2 single-block below 512 sigs) so
-    the compiled-shape set stays small under arbitrary coalesced sizes."""
+def lane_width(n: int, block: int = 0) -> int:
+    """Signatures a lane for a launch of n signatures: the narrowest
+    width whose single block holds the batch (at 128-lane blocks: n <=
+    256 -> 2, <= 512 -> 4) and the widest for everything larger. One
+    block's time is its per-lane chain, the same for 1 lane or 128 (a
+    (20, 64) and a (20, 128) limb array are the same vector registers),
+    so a batch that fits one block runs the shortest chain that still
+    fits; past that, blocks run one after another, time is per signature,
+    and the widest lane does the least arithmetic per signature (module
+    docstring). The rule reads nothing but n, and a bucket has the width
+    of every n it pads: lane_width(plan_bucket(n)[0]) == lane_width(n)."""
     block = block or BLOCK_LANES
-    lanes = max((n + M - 1) // M, 1)
+    return next((w for w in WIDTHS if n <= w * block), WIDTHS[-1])
+
+
+def plan_bucket(n: int, block: int = 0) -> tuple:
+    """(bucket_sigs, g_lanes, block, m) covering n signatures: the lane
+    width m (lane_width), and a lane count that divides evenly into
+    kernel blocks. EVERY caller that feeds _jitted_rlc_verify must size
+    via this: a g not divisible by block would truncate the pallas grid
+    and leave trailing lanes' verdicts uninitialized — read back as
+    garbage 'valid' bits.
+
+    Buckets quantize to RLC_BUCKETS (pow2 single-block below one block's
+    worth) so the compiled-shape set stays small under arbitrary
+    coalesced sizes."""
+    block = block or BLOCK_LANES
+    m = lane_width(n, block)
+    lanes = max((n + m - 1) // m, 1)
     if block < BLOCK_LANES or lanes <= block:
-        # explicit small blocks (tests) or tiny batches: pow2 single/multi
+        # explicit small blocks (tests) or single-block batches: pow2
         # block, lane count padded to a multiple of the block
         block = min(block, 1 << (lanes - 1).bit_length())
         g = ((lanes + block - 1) // block) * block
-        return g * M, g, block
-    for b in RLC_BUCKETS:
-        if n <= b:
-            return b, b // M, block
-    return RLC_BUCKETS[-1], RLC_BUCKETS[-1] // M, block
+        return g * m, g, block, m
+    bucket = next((b for b in RLC_BUCKETS if n <= b), RLC_BUCKETS[-1])
+    return bucket, bucket // m, block, m
 
 
-@functools.lru_cache(maxsize=None)
-def _jitted_rlc_verify(g: int, block: int, interpret: bool,
-                       vma: frozenset | None = None,
-                       donate: bool = False):
-    """g lanes (g*M signatures), block lanes per kernel invocation.
-    donate=True donates the per-batch inputs (ISSUE 7; see
-    ed25519_verify's donation note)."""
+def _rlc_kernels(m: int, g: int, block: int, interpret: bool, vma,
+                 cached: bool) -> tuple:
+    """The three pallas_calls (K1, K2, K3) of one RLC pipeline: g lanes
+    of m signatures, block lanes per kernel invocation."""
+    if m not in WIDTHS:
+        raise ValueError(f"lane width {m} not one of {WIDTHS}")
     if g % block:
         raise ValueError(
             f"lane count {g} not a multiple of block {block} (size buckets "
             "via plan_bucket — a truncated grid silently skips lanes)"
         )
     # Mosaic requires the minor block dim divisible by 128 (or the full
-    # array dim); K2's working set at 128 lanes fits because its folds
-    # are chunked (see _k2_rlc_kernel)
+    # array dim)
     k2_block = min(block, 128)
 
-    def mkspec(b):
-        def spec(rows):
-            return pl.BlockSpec((rows, b), lambda i: (0, i), memory_space=pltpu.VMEM)
-
-        return spec
+    def spec(rows, at=0):
+        return pl.BlockSpec((rows, block), lambda i: (at, i),
+                            memory_space=pltpu.VMEM)
 
     def out(rows):
         return jax.ShapeDtypeStruct((rows, g), jnp.int32, vma=vma)
 
-    spec = mkspec(block)
-    spec2 = mkspec(k2_block)
-    coords_rows = 2 * M * 4 * 32
-    tbl_rows = M * 16 * 4 * 32
-    dig_rows = N_SCAL * 128
+    coords_rows = 2 * m * _POINT_ROWS
+    tbl_rows = m * _TABLE_ROWS
+    dig_rows = 2 * m * 128
+    a_specs = ([spec(m * _POINT_ROWS), spec(m)] if cached
+               else [spec(m * 32)])
 
     k1 = pl.pallas_call(
-        _k1_rlc_kernel,
+        _k1_rlc_kernel(m, cached),
         grid=(g // block,),
-        in_specs=[spec(M * 32), spec(M * 32), spec(N_SCAL * 32)],
-        out_specs=[spec(coords_rows), spec(2 * M), spec(dig_rows)],
-        out_shape=[out(coords_rows), out(2 * M), out(dig_rows)],
+        in_specs=a_specs + [spec(m * 32), spec(2 * m * 32)],
+        out_specs=[spec(coords_rows), spec(2 * m), spec(dig_rows)],
+        out_shape=[out(coords_rows), out(2 * m), out(dig_rows)],
         interpret=interpret,
     )
     k2 = pl.pallas_call(
         _k2_rlc_kernel,
-        grid=(g // k2_block,),
-        in_specs=[spec2(coords_rows)],
-        out_specs=spec2(tbl_rows),
+        grid=(g // k2_block, m),
+        in_specs=[
+            pl.BlockSpec((_POINT_ROWS, k2_block),
+                         lambda i, t, which=which: (
+                             _table_points(m, t)[which], i),
+                         memory_space=pltpu.VMEM)
+            for which in (0, 1)
+        ],
+        out_specs=pl.BlockSpec((_TABLE_ROWS, k2_block), lambda i, t: (t, i),
+                               memory_space=pltpu.VMEM),
         out_shape=out(tbl_rows),
         interpret=interpret,
     )
     k3 = pl.pallas_call(
-        _k3_rlc_kernel,
+        _k3_rlc_kernel(m),
         grid=(g // block,),
-        in_specs=[spec(tbl_rows), spec(dig_rows), spec(coords_rows),
-                  spec(2 * M), spec(M)],
+        # of the coords only R_0 (slot m) is read, for the final check
+        in_specs=[spec(tbl_rows), spec(dig_rows), spec(_POINT_ROWS, at=m),
+                  spec(2 * m), spec(m)],
         out_specs=spec(1),
         out_shape=out(1),
+        # scoped VMEM: the blocks double-buffered, plus room for the
+        # ladder's transients. The default limit (16 MiB) is what the
+        # widest lane's table alone takes: 8 MiB a buffer at 128 lanes
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=(
+                2 * 4 * block * (tbl_rows + dig_rows + _POINT_ROWS)
+                + (8 << 20)
+            ),
+        ),
         interpret=interpret,
     )
+    return k1, lambda coords: k2(coords, coords), k3
+
+
+@functools.lru_cache(maxsize=None)
+def _jitted_rlc_verify(m: int, g: int, block: int, interpret: bool,
+                       vma: frozenset | None = None,
+                       donate: bool = False):
+    """g lanes of m signatures, block lanes per kernel invocation.
+    donate=True donates the per-batch inputs (ISSUE 7; see
+    ed25519_verify's donation note)."""
+    k1, k2, k3 = _rlc_kernels(m, g, block, interpret, vma, cached=False)
 
     def pipeline(a_t, r_t, scal_t, sok_t):
         coords, ok, dig = k1(a_t, r_t, scal_t)
-        tbl = k2(coords)
-        return k3(tbl, dig, coords, ok, sok_t)
+        return k3(k2(coords), dig, coords, ok, sok_t)
 
     # a stable, shape-bearing name: it is what compile logs, the
     # persistent-cache counters and profiler traces show for this launch
-    pipeline.__name__ = f"rlc_verify_g{g}_b{block}"
+    pipeline.__name__ = f"rlc_verify_g{g}_m{m}_b{block}"
     if donate:
         return jax.jit(pipeline, donate_argnums=(0, 1, 2, 3))
     return jax.jit(pipeline)
@@ -422,60 +456,62 @@ def _jitted_rlc_verify(g: int, block: int, interpret: bool,
 # four per-signature arrays as ONE buffer of 32-bit words:
 #
 #     idx (bucket,) int32 | r_rows (bucket, 32) uint8
-#         | scal_rows (g, N_SCAL, 32) uint8 | sok_rows (g, M) int32
+#         | scal_rows (g, 2m, 32) uint8 | sok_rows (g, m) int32
 #
-# Every offset is a static function of bucket, M and N_SCAL, and every
-# section is a whole number of words, so no section needs padding. int32
-# is the element type because that is what the v5e splits cheapest: idx
-# and sok_rows are plain slices, and the byte rows come back through one
-# bitcast each (a uint8 buffer with idx/sok bitcast up to int32 cost the
-# 10k launch 0.3 ms more on the device: PERF.md §6, PR 29).
+# Every offset is a static function of bucket and m, every section is a
+# whole number of words, so no section needs padding, and the whole is
+# 104 bytes a slot at any width. int32 is the element type because that
+# is what the v5e splits cheapest: idx and sok_rows are plain slices, and
+# the byte rows come back through one bitcast each (a uint8 buffer with
+# idx/sok bitcast up to int32 cost the 10k launch 0.3 ms more on the
+# device: PERF.md §6, PR 29).
 
 
-def packed_layout(bucket: int) -> tuple:
+def packed_layout(bucket: int, m: int) -> tuple:
     """Word offsets (r_rows, scal_rows, sok_rows, end) of the packed
     buffer's sections; idx starts at 0."""
-    g = bucket // M
+    g = bucket // m
     o_r = bucket
     o_scal = o_r + 8 * bucket
-    o_sok = o_scal + 8 * N_SCAL * g
-    return o_r, o_scal, o_sok, o_sok + M * g
+    o_sok = o_scal + 8 * 2 * m * g
+    return o_r, o_scal, o_sok, o_sok + m * g
 
 
-def packed_views(packed: np.ndarray, bucket: int) -> tuple:
+def packed_views(packed: np.ndarray, bucket: int, m: int) -> tuple:
     """The four host arrays of a packed buffer, as writable VIEWS of it:
     (idx (bucket,) int32, r_rows (bucket, 32) uint8,
-    scal_rows (g, N_SCAL, 32) uint8, sok_rows (g, M) int32). The byte
+    scal_rows (g, 2m, 32) uint8, sok_rows (g, m) int32). The byte
     rows lie in the words little-endian, as the host has them."""
-    g = bucket // M
-    o_r, o_scal, o_sok, end = packed_layout(bucket)
+    g = bucket // m
+    o_r, o_scal, o_sok, end = packed_layout(bucket, m)
     return (
         packed[:o_r],
         packed[o_r:o_scal].view(np.uint8).reshape(bucket, 32),
-        packed[o_scal:o_sok].view(np.uint8).reshape(g, N_SCAL, 32),
-        packed[o_sok:end].reshape(g, M),
+        packed[o_scal:o_sok].view(np.uint8).reshape(g, 2 * m, 32),
+        packed[o_sok:end].reshape(g, m),
     )
 
 
-def split_packed(packed, bucket: int) -> tuple:
+def split_packed(packed, bucket: int, m: int) -> tuple:
     """packed_views on device, inside the jitted pipeline: static slices,
     and each word of the byte rows bitcast back to its four bytes."""
-    g = bucket // M
-    o_r, o_scal, o_sok, end = packed_layout(bucket)
+    g = bucket // m
+    o_r, o_scal, o_sok, end = packed_layout(bucket, m)
     return (
         packed[:o_r],
         lax.bitcast_convert_type(packed[o_r:o_scal], jnp.uint8).reshape(
             bucket, 32
         ),
         lax.bitcast_convert_type(packed[o_scal:o_sok], jnp.uint8).reshape(
-            g, N_SCAL, 32
+            g, 2 * m, 32
         ),
-        packed[o_sok:end].reshape(g, M),
+        packed[o_sok:end].reshape(g, m),
     )
 
 
 @functools.lru_cache(maxsize=None)
-def _jitted_rlc_verify_cached(g: int, block: int, vp: int, interpret: bool,
+def _jitted_rlc_verify_cached(m: int, g: int, block: int, vp: int,
+                              interpret: bool,
                               vma: frozenset | None = None,
                               donate: bool = False):
     """The epoch-cached RLC pipeline: splits the launch's ONE packed
@@ -486,86 +522,37 @@ def _jitted_rlc_verify_cached(g: int, block: int, vp: int, interpret: bool,
     K1-cached/K2/K3. The host ships only val_idx + raw rows —
     prepare_rlc's slot-major transposes (the bulk of its 31 ms at 10k
     sigs) become device work."""
-    if g % block:
-        raise ValueError(
-            f"lane count {g} not a multiple of block {block} (size buckets "
-            "via plan_bucket — a truncated grid silently skips lanes)"
-        )
-    k2_block = min(block, 128)
-
-    def mkspec(b):
-        def spec(rows):
-            return pl.BlockSpec((rows, b), lambda i: (0, i), memory_space=pltpu.VMEM)
-
-        return spec
-
-    def out(rows):
-        return jax.ShapeDtypeStruct((rows, g), jnp.int32, vma=vma)
-
-    spec = mkspec(block)
-    spec2 = mkspec(k2_block)
-    coords_rows = 2 * M * 4 * 32
-    acoords_rows = M * 4 * 32
-    tbl_rows = M * 16 * 4 * 32
-    dig_rows = N_SCAL * 128
-
-    k1 = pl.pallas_call(
-        _k1_rlc_kernel_cached,
-        grid=(g // block,),
-        in_specs=[spec(acoords_rows), spec(M), spec(M * 32),
-                  spec(N_SCAL * 32)],
-        out_specs=[spec(coords_rows), spec(2 * M), spec(dig_rows)],
-        out_shape=[out(coords_rows), out(2 * M), out(dig_rows)],
-        interpret=interpret,
-    )
-    k2 = pl.pallas_call(
-        _k2_rlc_kernel,
-        grid=(g // k2_block,),
-        in_specs=[spec2(coords_rows)],
-        out_specs=spec2(tbl_rows),
-        out_shape=out(tbl_rows),
-        interpret=interpret,
-    )
-    k3 = pl.pallas_call(
-        _k3_rlc_kernel,
-        grid=(g // block,),
-        in_specs=[spec(tbl_rows), spec(dig_rows), spec(coords_rows),
-                  spec(2 * M), spec(M)],
-        out_specs=spec(1),
-        out_shape=out(1),
-        interpret=interpret,
-    )
+    k1, k2, k3 = _rlc_kernels(m, g, block, interpret, vma, cached=True)
 
     def pipeline(coords_tbl, ok_tbl, packed):
-        idx, r_rows, scal_rows, sok_rows = split_packed(packed, g * M)
-        # idx is signature-major (i = lane*M + slot); the reshapes below
+        idx, r_rows, scal_rows, sok_rows = split_packed(packed, g * m, m)
+        # idx is signature-major (i = lane*m + slot); the reshapes below
         # land every array in the kernels' slot-major layout
         ac = (
             coords_tbl[:, idx]
-            .reshape(4 * 32, g, M)
+            .reshape(_POINT_ROWS, g, m)
             .transpose(2, 0, 1)
-            .reshape(acoords_rows, g)
+            .reshape(m * _POINT_ROWS, g)
         )
-        aok = ok_tbl[:, idx].reshape(g, M).T
-        r_t = r_rows.reshape(g, M, 32).transpose(1, 2, 0).reshape(M * 32, g)
-        scal_t = scal_rows.transpose(1, 2, 0).reshape(N_SCAL * 32, g)
-        sok_t = sok_rows.T
+        aok = ok_tbl[:, idx].reshape(g, m).T
+        r_t = r_rows.reshape(g, m, 32).transpose(1, 2, 0).reshape(m * 32, g)
+        scal_t = scal_rows.transpose(1, 2, 0).reshape(2 * m * 32, g)
         coords, ok, dig = k1(ac, aok, r_t, scal_t)
-        tbl = k2(coords)
-        return k3(tbl, dig, coords, ok, sok_t)
+        return k3(k2(coords), dig, coords, ok, sok_rows.T)
 
-    pipeline.__name__ = f"rlc_verify_cached_g{g}_b{block}_vp{vp}"
+    pipeline.__name__ = f"rlc_verify_cached_g{g}_m{m}_b{block}_vp{vp}"
     if donate:
         # persistent epoch tables (argnums 0-1) are never donated
         return jax.jit(pipeline, donate_argnums=(2,))
     return jax.jit(pipeline)
 
 
-def rlc_cached_fn(ep, g: int, block: int, interpret: bool,
+def rlc_cached_fn(ep, m: int, g: int, block: int, interpret: bool,
                   donate: bool = False):
     """Kernel closure for the warm-epoch RLC pipeline; coords tables
     resolve at CALL time on the dispatch-owner thread."""
-    f = _jitted_rlc_verify_cached(g, block, ep.vp, interpret, donate=donate)
+    f = _jitted_rlc_verify_cached(m, g, block, ep.vp, interpret,
+                                  donate=donate)
 
     def call(*args):
         coords_tbl, ok_tbl = ep.coords_tables()
@@ -648,7 +635,7 @@ def _gen_z(bucket: int) -> np.ndarray:
     return z
 
 
-def _rlc_host_scalars(entries, live: int, g_live: int):
+def _rlc_host_scalars(entries, live: int, g_live: int, m: int):
     """Shared host scalar stage for both RLC preps: packs the live rows,
     draws the z coefficients, and computes the lane scalars. For an
     EntryBlock with the native module built, challenges + scalar mul-adds
@@ -656,7 +643,7 @@ def _rlc_host_scalars(entries, live: int, g_live: int):
     buffers (tm_native.ed25519_rlc_prep); tuple lists and native-absent
     builds keep the split numpy/Python path with identical outputs.
 
-    Returns (pub (live, 32), r_enc (live, 32), scal (g_live, N_SCAL, 32),
+    Returns (pub (live, 32), r_enc (live, 32), scal (g_live, 2m, 32),
     s_ok (live,) bool)."""
     from .backend import _challenges_any, _pack_rows, _s_below_l
     from .entry_block import EntryBlock
@@ -680,7 +667,7 @@ def _rlc_host_scalars(entries, live: int, g_live: int):
             buf,
             np.ascontiguousarray(offs).tobytes(),
             z.tobytes(),
-            M,
+            m,
             live,
         )
         s_ok = np.frombuffer(sok_raw, dtype=np.uint8).astype(bool)
@@ -692,62 +679,68 @@ def _rlc_host_scalars(entries, live: int, g_live: int):
             k_enc[:n] = np.frombuffer(ks, dtype=np.uint8).reshape(n, 32)
         s_b, k_b, z_b = s_enc.tobytes(), k_enc.tobytes(), z.tobytes()
         if native is not None and hasattr(native, "ed25519_rlc_scalars"):
-            raw = native.ed25519_rlc_scalars(s_b, k_b, z_b, M)
+            raw = native.ed25519_rlc_scalars(s_b, k_b, z_b, m)
         else:
-            raw = _rlc_scalars_py(s_b, k_b, z_b, M)
+            raw = _rlc_scalars_py(s_b, k_b, z_b, m)
     S = np.frombuffer(raw[: 32 * g_live], dtype=np.uint8).reshape(g_live, 32)
-    U = np.frombuffer(raw[32 * g_live :], dtype=np.uint8).reshape(g_live, M, 32)
+    U = np.frombuffer(raw[32 * g_live :], dtype=np.uint8).reshape(g_live, m, 32)
 
-    scal = np.zeros((g_live, N_SCAL, 32), dtype=np.uint8)
+    scal = np.zeros((g_live, 2 * m, 32), dtype=np.uint8)
     scal[:, 0] = S
-    scal[:, 1 : M + 1] = U
-    scal[:, M + 1 :] = z.reshape(g_live, M, 32)[:, 1:]
+    scal[:, 1 : m + 1] = U
+    scal[:, m + 1 :] = z.reshape(g_live, m, 32)[:, 1:]
     return pub, r_enc, scal, s_ok
 
 
-def prepare_rlc(entries, bucket: int):
+def _live_lanes(n: int, bucket: int, m: int) -> tuple:
+    """(lanes, live lanes) of n signatures in a bucket of width-m lanes.
+    All host work runs over the LIVE lanes only; padding lanes get their
+    constant pattern (identity-point A/R encodings, zero scalars, s_ok
+    true) via broadcast assigns. A coalesced total just past a quantized
+    bucket would otherwise pay the full bucket's packing on the host."""
+    if m not in WIDTHS or bucket % m:
+        raise ValueError(
+            f"bucket {bucket} is not a whole number of lanes of width {m} "
+            f"(one of {WIDTHS})"
+        )
+    g = bucket // m
+    return g, min((n + m - 1) // m, g)
+
+
+def prepare_rlc(entries, bucket: int, m: int):
     """EntryBlock or (pub32, msg, sig64) triples -> RLC kernel args,
-    padded to `bucket` signatures (bucket % M == 0, bucket // M lanes).
+    padded to `bucket` signatures in bucket // m lanes of width m.
     Host work on top of the per-sig prep (pack + SHA-512 challenges +
     s<L): one 128x256-bit mod-L mul-add per signature (see
     _rlc_host_scalars), then the slot-major transposes the kernel layout
     needs — warm epochs skip those via prepare_rlc_cached."""
-    n = len(entries)
-    if bucket % M:
-        raise ValueError(f"bucket {bucket} not a multiple of M={M}")
-    g = bucket // M
-    # All host work runs over the LIVE lanes only; padding lanes get
-    # their constant pattern (identity-point A/R encodings, zero scalars,
-    # s_ok true) via broadcast assigns. A coalesced total just past a
-    # quantized bucket would otherwise pay the full bucket's packing and
-    # transposes on the host.
-    g_live = min((n + M - 1) // M, g)
-    live = g_live * M
-    pub, r_enc, scal, s_ok = _rlc_host_scalars(entries, live, g_live)
+    g, g_live = _live_lanes(len(entries), bucket, m)
+    live = g_live * m
+    pub, r_enc, scal, s_ok = _rlc_host_scalars(entries, live, g_live, m)
 
-    def slotmajor(arr):  # (live, 32) -> (M*32, g_live)
+    def slotmajor(arr):  # (live, 32) -> (m*32, g_live)
         return np.ascontiguousarray(
-            arr.reshape(g_live, M, 32).transpose(1, 2, 0).reshape(M * 32, g_live)
+            arr.reshape(g_live, m, 32).transpose(1, 2, 0).reshape(m * 32, g_live)
         )
 
-    a_t = np.zeros((M * 32, g), dtype=np.uint8)
-    r_t = np.zeros((M * 32, g), dtype=np.uint8)
-    scal_t = np.zeros((N_SCAL * 32, g), dtype=np.uint8)
-    sok_t = np.ones((M, g), dtype=np.int32)
+    a_t = np.zeros((m * 32, g), dtype=np.uint8)
+    r_t = np.zeros((m * 32, g), dtype=np.uint8)
+    scal_t = np.zeros((2 * m * 32, g), dtype=np.uint8)
+    sok_t = np.ones((m, g), dtype=np.int32)
     # padding lanes: identity encoding = byte 0 of each slot set to 1
-    a_t[np.arange(M) * 32, g_live:] = 1
-    r_t[np.arange(M) * 32, g_live:] = 1
+    a_t[np.arange(m) * 32, g_live:] = 1
+    r_t[np.arange(m) * 32, g_live:] = 1
     if g_live:
         a_t[:, :g_live] = slotmajor(pub)
         r_t[:, :g_live] = slotmajor(r_enc)
         scal_t[:, :g_live] = np.ascontiguousarray(
-            scal.transpose(1, 2, 0).reshape(N_SCAL * 32, g_live)
+            scal.transpose(1, 2, 0).reshape(2 * m * 32, g_live)
         )
-        sok_t[:, :g_live] = s_ok.reshape(g_live, M).T.astype(np.int32)
+        sok_t[:, :g_live] = s_ok.reshape(g_live, m).T.astype(np.int32)
     return a_t, r_t, scal_t, sok_t
 
 
-def prepare_rlc_cached(entries, bucket: int, ep):
+def prepare_rlc_cached(entries, bucket: int, ep, m: int):
     """Warm-epoch RLC prep: same host scalar stage as prepare_rlc, but
     the committee ships as val_idx gather indices (the kernel gathers the
     cached decompressed A coords on device) and every per-sig array ships
@@ -758,21 +751,18 @@ def prepare_rlc_cached(entries, bucket: int, ep):
     through its four views (packed_views: idx, r_rows, scal_rows,
     sok_rows) — one host-to-device operation instead of four."""
     n = len(entries)
-    if bucket % M:
-        raise ValueError(f"bucket {bucket} not a multiple of M={M}")
-    g = bucket // M
-    g_live = min((n + M - 1) // M, g)
-    live = g_live * M
-    _pub, r_enc, scal, s_ok = _rlc_host_scalars(entries, live, g_live)
+    _g, g_live = _live_lanes(n, bucket, m)
+    live = g_live * m
+    _pub, r_enc, scal, s_ok = _rlc_host_scalars(entries, live, g_live, m)
 
-    packed = np.zeros((packed_layout(bucket)[-1],), dtype=np.int32)
-    idx, r_rows, scal_rows, sok_rows = packed_views(packed, bucket)
+    packed = np.zeros((packed_layout(bucket, m)[-1],), dtype=np.int32)
+    idx, r_rows, scal_rows, sok_rows = packed_views(packed, bucket, m)
     idx[:n] = entries.val_idx
     idx[n:] = ep.vp - 1  # padding: the table's identity row
     r_rows[:live] = r_enc
     r_rows[live:, 0] = 1  # padding lanes: identity encoding
     scal_rows[:g_live] = scal
-    sok_rows[:g_live] = s_ok.reshape(g_live, M)
+    sok_rows[:g_live] = s_ok.reshape(g_live, m)
     sok_rows[g_live:] = 1
     return (packed,)
 
@@ -780,58 +770,69 @@ def prepare_rlc_cached(entries, bucket: int, ep):
 def verify_rlc_compact(a_t, r_t, scal_t, sok_t, block: int = 0,
                        interpret: bool = False) -> np.ndarray:
     """Run the RLC kernel; returns (g,) bool LANE validity (a lane is
-    valid iff the RLC equation holds and every slot's flags pass)."""
+    valid iff the RLC equation holds and every slot's flags pass). The
+    lane width is the arguments' own: sok_t is (m, g)."""
     block = block or BLOCK_LANES
-    g = a_t.shape[-1]
-    if g % block:
-        raise ValueError(f"lane count {g} not a multiple of block {block}")
-    out = _jitted_rlc_verify(g, block, interpret)(a_t, r_t, scal_t, sok_t)
+    m, g = sok_t.shape
+    # rlc_launch's own call, keyword for keyword: one cache entry, one trace
+    out = _jitted_rlc_verify(m, g, block, interpret, donate=False)(
+        a_t, r_t, scal_t, sok_t)
     return np.asarray(out)[0].astype(bool)
 
 
-def expand_lanes(lane_valid: np.ndarray, entries) -> np.ndarray:
-    """Lane verdicts -> per-signature verdicts (entries: EntryBlock or
-    tuple list). Valid lanes accept all M slots; rejected lanes re-verify
-    their live signatures individually on the host for blame
-    (types/validation.go:242-248 asymmetry — rejects are the rare path,
-    and M host verifies cost ~0.5 ms). The blame path is the ONLY place a
-    per-signature tuple is materialized from an EntryBlock — M lanes at a
-    time, never the whole batch. Every re-verified signature is counted
+def expand_lanes(lane_valid: np.ndarray, entries, m: int) -> np.ndarray:
+    """Lane verdicts of a launch that ran at width m -> per-signature
+    verdicts (entries: EntryBlock or tuple list). Valid lanes accept all
+    m slots; rejected lanes re-verify their live signatures individually
+    on the host for blame (types/validation.go:242-248 asymmetry —
+    rejects are the rare path, and m host verifies cost ~0.1 ms each).
+    The blame path is the ONLY place a per-signature tuple is
+    materialized from an EntryBlock — one lane at a time, never the whole
+    batch. Every re-verified signature is counted
     in sigs_verified{path="host"}: it was checked on the host, on top of
     the device lane that rejected it."""
     from ..crypto import ed25519 as _ed25519
     from .entry_block import EntryBlock
 
     n = len(entries)
-    per_sig = np.repeat(lane_valid, M)[:n].copy()
+    per_sig = np.repeat(lane_valid, m)[:n].copy()
     if not lane_valid.all():
         is_block = isinstance(entries, EntryBlock)
         reverified = 0
-        for lane in np.nonzero(~lane_valid)[0]:
-            for i in range(lane * M, min((lane + 1) * M, n)):
+        rejected = np.nonzero(~lane_valid)[0]
+        for lane in rejected:
+            for i in range(lane * m, min((lane + 1) * m, n)):
                 pk, msg, sig = entries.entry(i) if is_block else entries[i]
                 per_sig[i] = _ed25519.verify_zip215_fast(pk, msg, sig)
                 reverified += 1
         from ..libs import metrics as _metrics
 
-        _metrics.ops_metrics().sigs_verified.inc(reverified, path="host")
+        om = _metrics.ops_metrics()
+        om.sigs_verified.inc(reverified, path="host")
+        om.rlc_rejected_lanes.inc(len(rejected), m=str(m))
     return per_sig
 
 
 def rlc_launch(entries, ep=None, bucket: int = 0, block: int = 0,
                interpret: bool = False, donate: bool = False) -> tuple:
     """One RLC launch for at most MAX_SIGS signatures: (launch_fn, args,
-    bucket), sized for max(len(entries), bucket) signatures. With a warm
-    epoch entry the committee gathers from the device-resident table
-    (prepare_rlc_cached + rlc_cached_fn); without one the batch ships its
-    pubs. backend.select_kernel's RLC arm and verify_batch_rlc are both
-    this."""
-    bucket, g, blk = plan_bucket(max(len(entries), bucket), block)
+    bucket, m), sized for max(len(entries), bucket) signatures in lanes
+    of the width plan_bucket gives that size. With a warm epoch entry the
+    committee gathers from the device-resident table (prepare_rlc_cached
+    + rlc_cached_fn); without one the batch ships its pubs.
+    backend.select_kernel's RLC arm and verify_batch_rlc are both this."""
+    from ..libs import metrics as _metrics
+
+    n = len(entries)
+    bucket, g, blk, m = plan_bucket(max(n, bucket), block)
+    om = _metrics.ops_metrics()
+    om.rlc_launches.inc(m=str(m))
+    om.rlc_sigs.inc(n, m=str(m))
     if ep is not None:
-        return (rlc_cached_fn(ep, g, blk, interpret, donate),
-                prepare_rlc_cached(entries, bucket, ep), bucket)
-    return (_jitted_rlc_verify(g, blk, interpret, donate=donate),
-            prepare_rlc(entries, bucket), bucket)
+        return (rlc_cached_fn(ep, m, g, blk, interpret, donate),
+                prepare_rlc_cached(entries, bucket, ep, m), bucket, m)
+    return (_jitted_rlc_verify(m, g, blk, interpret, donate=donate),
+            prepare_rlc(entries, bucket, m), bucket, m)
 
 
 def verify_batch_rlc(entries, block: int = 0, interpret: bool = False) -> np.ndarray:
@@ -845,10 +846,10 @@ def verify_batch_rlc(entries, block: int = 0, interpret: bool = False) -> np.nda
     out = []
     for i in range(0, len(entries), MAX_SIGS):
         chunk = entries[i : i + MAX_SIGS]
-        fn, args, _bucket = rlc_launch(chunk, ep, block=block,
-                                       interpret=interpret)
+        fn, args, _bucket, m = rlc_launch(chunk, ep, block=block,
+                                          interpret=interpret)
         lane_valid = np.asarray(fn(*args))[0].astype(bool)
-        out.append(expand_lanes(lane_valid, chunk))
+        out.append(expand_lanes(lane_valid, chunk, m))
     return (
         np.concatenate(out) if out else np.zeros((0,), dtype=bool)
     )

@@ -69,6 +69,17 @@ def _d2h_async_supported() -> bool:
     return supported
 
 
+def _rlc_width_arg(rlc_entries, bucket: int) -> dict:
+    """The `m` a launch's spans carry: the lane width of an RLC launch of
+    this bucket, nothing for the per-signature kernels or with the
+    tracer off."""
+    if rlc_entries is None or not _trace.TRACER.enabled:
+        return {}
+    from . import pallas_rlc
+
+    return {"m": pallas_rlc.lane_width(bucket)}
+
+
 class _Readback:
     """Structured async verdict readback (ISSUE 7 tentpole piece 4): the
     launched device result plus its D2H copy, started at construction
@@ -528,6 +539,7 @@ class AsyncBatchVerifier:
                     bucket=res[3],
                     cached=int(_backend.warm_epoch(entries) is not None),
                     **({"scheme": scheme} if scheme != "ed25519" else {}),
+                    **_rlc_width_arg(res[2], res[3]),
                 )
         _backend._note_device_batch(n, res[3])
         return res
@@ -611,7 +623,10 @@ class AsyncBatchVerifier:
             if rlc_entries is not None:
                 from . import pallas_rlc
 
-                arr = pallas_rlc.expand_lanes(arr, rlc_entries)
+                # one verdict per lane: the launch's width is its bucket
+                # over its lanes (select_kernel's contract)
+                arr = pallas_rlc.expand_lanes(
+                    arr, rlc_entries, bucket // len(arr))
             if _devcheck.inject_lintbug("alias"):
                 # AFTER the 2-D/RLC reductions (they mint fresh owned
                 # arrays that would neutralize the seam): the DELIVERED
@@ -1217,7 +1232,8 @@ class AsyncBatchVerifier:
                         {"bucket": bucket},
                     )
                 try:
-                    with _span("pipeline.dispatch", bucket=bucket):
+                    with _span("pipeline.dispatch", bucket=bucket,
+                               **_rlc_width_arg(rlc_entries, bucket)):
                         dev = f(*dev_args)
                     if _trace.TRACER.enabled:
                         # one launch serves many coalesced jobs: step each

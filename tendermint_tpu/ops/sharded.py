@@ -384,19 +384,25 @@ def _bucket_pow2(n: int, nd: int) -> int:
 # ---------------------------------------------------------------------------
 
 
+# Signatures a lane of the sharded RLC program: the mesh plans its own
+# shapes (per-shard lane counts from the mesh size), so it names its width
+# itself and does not follow pallas_rlc.plan_bucket's single-chip rule.
+RLC_M = 4
+
+
 def sharded_rlc_verifier(mesh: Mesh, g_per_shard: int, block: int,
                          interpret: bool):
     from jax import shard_map
 
     from . import pallas_rlc as _pr
 
+    m = RLC_M
     if interpret:
-        kern = _pr._jitted_rlc_verify(g_per_shard, block, interpret)
+        kern = _pr._jitted_rlc_verify(m, g_per_shard, block, interpret)
     else:
         kern = _pr._jitted_rlc_verify(
-            g_per_shard, block, interpret, vma=frozenset({AXIS})
+            m, g_per_shard, block, interpret, vma=frozenset({AXIS})
         )
-    m = _pr.M
 
     def _step(a_t, r_t, scal_t, sok_t, power, live):
         lane_valid = kern(a_t, r_t, scal_t, sok_t)[0].astype(bool)
@@ -439,7 +445,7 @@ def verify_commit_sharded_rlc(
 
     n = len(entries)
     nd = int(np.prod(mesh.devices.shape))
-    m = _pr.M
+    m = RLC_M
     lanes_needed = max((n + m - 1) // m, 1)
     # per-shard lane count: pow2, >= 1, such that total lanes covers n
     g_shard = 1
@@ -450,7 +456,7 @@ def verify_commit_sharded_rlc(
     bucket = g * m
 
     with _span("sharded.host_prep", n=n, bucket=bucket):
-        a_t, r_t, scal_t, sok_t = _pr.prepare_rlc(entries, bucket)
+        a_t, r_t, scal_t, sok_t = _pr.prepare_rlc(entries, bucket, m)
         live = np.zeros((bucket,), dtype=bool)
         live[:n] = True
         pw = np.zeros((bucket, POWER_LANES), dtype=np.int32)
@@ -470,7 +476,7 @@ def verify_commit_sharded_rlc(
     # lane verdicts -> per-sig verdicts + host re-verify of rejected
     # lanes (shared with the single-chip path), then add the rescued
     # signatures' power back into the device tally
-    per_sig = _pr.expand_lanes(lane_valid, entries)
+    per_sig = _pr.expand_lanes(lane_valid, entries, m)
     rescued = per_sig & ~np.repeat(lane_valid, m)[:n]
     tallied += sum(int(powers[i]) for i in np.nonzero(rescued)[0])
     return per_sig, tallied, bool(per_sig.all()) if n else bool(all_valid)

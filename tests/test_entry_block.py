@@ -192,8 +192,9 @@ class TestPrepParity:
         for x, y in zip(a, b):
             assert np.array_equal(np.asarray(x), np.asarray(y))
 
+    @pytest.mark.parametrize("M", pallas_rlc.WIDTHS)
     @pytest.mark.parametrize("use_native", [True, False])
-    def test_prepare_rlc_args_match(self, monkeypatch, use_native):
+    def test_prepare_rlc_args_match(self, monkeypatch, use_native, M):
         if not use_native:
             _no_native(monkeypatch)
         elif __import__("tendermint_tpu.native", fromlist=["load"]).load() is None:
@@ -201,25 +202,24 @@ class TestPrepParity:
         # deterministic z so tuple and block runs draw identical
         # coefficients (CPU backend: seed is honored)
         monkeypatch.setenv("TM_TPU_RLC_SEED", "7")
-        M = pallas_rlc.M
         ents = _entries(2 * M + 1, bad=(1,))
         bucket = ((len(ents) + M - 1) // M + 1) * M  # one padding lane
-        a = pallas_rlc.prepare_rlc(ents, bucket)
-        b = pallas_rlc.prepare_rlc(EntryBlock.from_entries(ents), bucket)
+        a = pallas_rlc.prepare_rlc(ents, bucket, M)
+        b = pallas_rlc.prepare_rlc(EntryBlock.from_entries(ents), bucket, M)
         for x, y in zip(a, b):
             assert np.array_equal(np.asarray(x), np.asarray(y))
 
-    def test_expand_lanes_blame_parity(self):
-        M = pallas_rlc.M
-        ents = _entries(2 * M, bad=(1, M + 2))
+    @pytest.mark.parametrize("M", pallas_rlc.WIDTHS)
+    def test_expand_lanes_blame_parity(self, M):
+        ents = _entries(2 * M, bad=(1, M + 1))
         lane_valid = np.array([False, False])
-        per_tuple = pallas_rlc.expand_lanes(lane_valid, ents)
+        per_tuple = pallas_rlc.expand_lanes(lane_valid, ents, M)
         per_block = pallas_rlc.expand_lanes(
-            lane_valid, EntryBlock.from_entries(ents)
+            lane_valid, EntryBlock.from_entries(ents), M
         )
         assert np.array_equal(per_tuple, per_block)
         expected = np.ones(2 * M, dtype=bool)
-        expected[[1, M + 2]] = False
+        expected[[1, M + 1]] = False
         assert np.array_equal(per_block, expected)
 
 
@@ -321,16 +321,18 @@ class TestRlcEnvHardening:
     def test_rlc_buckets_respect_cap(self):
         assert pallas_rlc.RLC_BUCKETS == tuple(sorted(pallas_rlc.RLC_BUCKETS))
         assert pallas_rlc.RLC_BUCKETS[-1] == pallas_rlc.MAX_SIGS
-        step = pallas_rlc.M * pallas_rlc.BLOCK_LANES
+        # every multi-block bucket runs at the widest lane
+        step = pallas_rlc.WIDTHS[-1] * pallas_rlc.BLOCK_LANES
         assert all(b % step == 0 and b <= pallas_rlc.MAX_SIGS
+                   and pallas_rlc.lane_width(b) == pallas_rlc.WIDTHS[-1]
                    for b in pallas_rlc.RLC_BUCKETS)
 
     def test_plan_bucket_never_exceeds_cap(self):
         for n in (1, 511, 512, 513, 10240, pallas_rlc.MAX_SIGS,
                   pallas_rlc.MAX_SIGS + 1):
-            bucket, g, block = pallas_rlc.plan_bucket(n)
+            bucket, g, block, m = pallas_rlc.plan_bucket(n)
             assert bucket <= pallas_rlc.MAX_SIGS
-            assert g % block == 0
+            assert g % block == 0 and bucket == g * m
 
     def test_max_sigs_validated_at_import(self):
         import subprocess
@@ -451,7 +453,7 @@ class TestInterpretKernels:
 
     def test_rlc_interpret_parity(self, monkeypatch):
         monkeypatch.setenv("TM_TPU_RLC_SEED", "3")
-        M = pallas_rlc.M
+        M = 4
         ents = _entries(2 * M, bad=(1,))
         ra = pallas_rlc.verify_batch_rlc(ents, interpret=True)
         rb = pallas_rlc.verify_batch_rlc(

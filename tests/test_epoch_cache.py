@@ -608,17 +608,17 @@ class TestCachedPallasInterpret:
         monkeypatch.setenv("TM_TPU_RLC_SEED", "7")
         monkeypatch.setenv("TM_TPU_RLC_SEED_UNSAFE", "1")
         blk, ep = self._blk(6)
-        bucket, g, b = pr.plan_bucket(len(blk))
+        bucket, g, b, m = pr.plan_bucket(len(blk))
         lanes_u = pr.verify_rlc_compact(
-            *pr.prepare_rlc(blk, bucket), block=b, interpret=True
+            *pr.prepare_rlc(blk, bucket, m), block=b, interpret=True
         )
-        dev = pr.rlc_cached_fn(ep, g, b, True)(
-            *pr.prepare_rlc_cached(blk, bucket, ep)
+        dev = pr.rlc_cached_fn(ep, m, g, b, True)(
+            *pr.prepare_rlc_cached(blk, bucket, ep, m)
         )
         lanes_c = np.asarray(dev)[0].astype(bool)
         assert np.array_equal(lanes_u, lanes_c)
         assert np.array_equal(
-            pr.expand_lanes(lanes_u, blk), pr.expand_lanes(lanes_c, blk)
+            pr.expand_lanes(lanes_u, blk, m), pr.expand_lanes(lanes_c, blk, m)
         )
 
     def test_compact_cached_parity(self):

@@ -360,7 +360,7 @@ class TestOneTransferPerWarmRlcLaunch:
         from tendermint_tpu.libs.metrics import ops_stats
         from tendermint_tpu.ops import pallas_rlc as pr
 
-        def accept_all(g, *_a, **_k):
+        def accept_all(_m, g, *_a, **_k):
             return lambda *_args: jnp.ones((1, g), dtype=jnp.int32)
 
         monkeypatch.setenv("TM_TPU_PALLAS", "1")
@@ -377,7 +377,7 @@ class TestOneTransferPerWarmRlcLaunch:
         for _ in range(2):
             epoch_cache.cache().note(ep.key, ep.pub_rows[:n].copy())
         cold = EntryBlock.from_entries(warm.to_entries())
-        bucket, g, _block = pr.plan_bucket(n)
+        bucket, g, _block, m = pr.plan_bucket(n)
 
         def launch(v, block):
             """(put spans inside the one transfer span, h2d_ops delta,
@@ -406,9 +406,9 @@ class TestOneTransferPerWarmRlcLaunch:
             epoch_cache.reset()
             backend.engine.cache_clear()
         assert (len(puts_w), ops_w) == (1, 1)
-        # idx 4 + r 32 + scal N_SCAL*32/M = 64 + sok 4 bytes a signature
+        # idx 4 + r 32 + scal 2m*32/m = 64 + sok 4 bytes a signature
         assert puts_w[0][4]["bytes"] == bytes_w == 104 * bucket
         assert (len(puts_c), ops_c) == (4, 4)
         # slot-major a_t, r_t, scal_t (uint8) and sok_t (int32) per lane
         assert sum(e[4]["bytes"] for e in puts_c) == bytes_c == g * (
-            2 * pr.M * 32 + pr.N_SCAL * 32 + 4 * pr.M)
+            2 * m * 32 + 2 * m * 32 + 4 * m)

@@ -17,33 +17,16 @@ import pytest
 pytest.importorskip("jax")
 
 from tendermint_tpu.ops import backend, pallas_rlc as pr  # noqa: E402
-from _rlc import _deterministic_z, _sign_batch  # noqa: E402,F401
+from _rlc import (  # noqa: E402,F401
+    CachedSuite, KernelSuite, _deterministic_z, _sign_batch, _warm_block,
+)
 
 
-class TestRlcKernel:
-    @pytest.mark.time_limit(600)  # 191-242 s on a cold cache (first trace + XLA:CPU compile of the bucket-16 shape): the maximum
-    def test_valid_batch_with_straddling_padding(self):
-        # 14 live sigs in a 16-sig bucket: one lane straddles live/padding
-        entries = _sign_batch(14)
-        res = pr.verify_batch_rlc(entries, block=4, interpret=True)
-        assert res.tolist() == [True] * 14
+class TestRlcKernel(KernelSuite):
+    M, N, FORGED = 4, 14, 6
 
-    def test_lane_reject_falls_back_per_sig(self):
-        entries = _sign_batch(14, tamper={6})
-        res = pr.verify_batch_rlc(entries, block=4, interpret=True)
-        assert res.tolist() == [i != 6 for i in range(14)]
-
-    def test_all_valid_small_order_lane_fast_accepts(self):
-        """A lane of entirely-valid small-order signatures must accept
-        WITHOUT the fallback: [8]e_j = O for each, so the combination
-        [8]acc = O identically (torsion cancels under the cofactor)."""
-        ident_pk = (1).to_bytes(32, "little")
-        entries = [(ident_pk, b"m%d" % i, bytes(64)) for i in range(pr.M)]
-        args = pr.prepare_rlc(entries, 4 * pr.M)  # shape shared with above
-        lanes = pr.verify_rlc_compact(*args, block=4, interpret=True)
-        assert lanes.tolist() == [True] * 4  # lane 0 small-order, 1-3 padding
-
-    def test_scalar_prep_native_matches_python(self):
+    @pytest.mark.parametrize("m", pr.WIDTHS)
+    def test_scalar_prep_native_matches_python(self, m):
         entries = _sign_batch(8)
         from tendermint_tpu.ops.backend import _challenges, _pack_rows
         from tendermint_tpu.native import load as _load_native
@@ -56,10 +39,10 @@ class TestRlcKernel:
         k_enc = np.frombuffer(ks, dtype=np.uint8).reshape(8, 32)
         z = pr._gen_z(8)
         a = native.ed25519_rlc_scalars(
-            s_enc.tobytes(), k_enc.tobytes(), z.tobytes(), pr.M
+            s_enc.tobytes(), k_enc.tobytes(), z.tobytes(), m
         )
-        b = pr._rlc_scalars_py(s_enc.tobytes(), k_enc.tobytes(), z.tobytes(), pr.M)
-        assert a == b
+        b = pr._rlc_scalars_py(s_enc.tobytes(), k_enc.tobytes(), z.tobytes(), m)
+        assert a == b and len(a) == 32 * (8 // m) * (1 + m)
 
     def test_seeded_z_deterministic(self):
         assert (pr._gen_z(8) == pr._gen_z(8)).all()
@@ -75,7 +58,8 @@ class TestRlcKernel:
         monkeypatch.setenv("TM_TPU_PALLAS", "1")
         monkeypatch.setenv("TM_TPU_RLC", "1")
         # tiny lane blocks so interpret mode stays fast (env var is read
-        # at module import; patch the module attribute)
+        # at module import; patch the module attribute); 10 signatures in
+        # 4-lane blocks are width 4, the shape traced above
         monkeypatch.setattr(pr, "BLOCK_LANES", 4)
         backend.engine.cache_clear()
         try:
@@ -86,49 +70,99 @@ class TestRlcKernel:
             backend.engine.cache_clear()
 
 
-def _four_arrays(entries, bucket, ep):
+# what plan_bucket owes a batch of n at the default 128-lane blocks:
+# (n, width, bucket)
+PLANS = [
+    (64, 2, 64), (150, 2, 256), (256, 2, 256), (257, 4, 512),
+    (512, 4, 512), (513, 8, 1024), (1_024, 8, 1024), (1_025, 8, 2048),
+    (10_000, 8, 10_240), (40_000, 8, 40_960), (81_920, 8, 81_920),
+]
+
+
+class TestLaneWidthRule:
+    """The width follows the batch size and nothing else: the narrowest
+    whose single block holds the batch, the widest past that."""
+
+    @pytest.mark.parametrize("n,m,bucket", PLANS)
+    def test_plan_bucket(self, n, m, bucket):
+        got_bucket, g, block, got_m = pr.plan_bucket(n)
+        assert (got_m, got_bucket) == (m, bucket)
+        # whole lanes, whole blocks: never a truncated grid
+        assert got_bucket == g * m >= n
+        assert g % block == 0 and 0 < block <= pr.BLOCK_LANES
+        assert got_bucket % (m * block) == 0
+        # a single block wherever one block can hold the batch
+        assert (g == block) == (n <= pr.WIDTHS[-1] * pr.BLOCK_LANES)
+        # a bucket has the width of every size it pads
+        assert pr.lane_width(got_bucket) == m
+        assert pr.plan_bucket(got_bucket) == (got_bucket, g, block, m)
+
+    def test_width_is_monotone_in_n(self):
+        widths = [pr.lane_width(n) for n in range(1, 3000)]
+        assert widths == sorted(widths) and set(widths) == set(pr.WIDTHS)
+        assert pr.plan_bucket(pr.MAX_SIGS + 1)[0] == pr.MAX_SIGS
+
+    @pytest.mark.parametrize("m", [0, 1, 3, 16])
+    def test_unknown_width_refused(self, m):
+        with pytest.raises(ValueError, match="width"):
+            pr.prepare_rlc([], 16 * max(m, 1), m)
+        with pytest.raises(ValueError, match="width"):
+            pr._jitted_rlc_verify(m, 4, 4, True)
+
+    def test_launches_counted_by_width(self, monkeypatch):
+        from tendermint_tpu.libs.metrics import ops_stats
+
+        monkeypatch.setattr(pr, "_jitted_rlc_verify",
+                            lambda m, g, *_a, **_k: (m, g))
+        before = ops_stats()
+        shapes = [pr.rlc_launch(_sign_batch(n), block=4)
+                  for n in (3, 5, 9, 20, 21)]
+        # (kernel for (m, g), its four arrays, bucket, m)
+        assert [(fn, bucket, m) for fn, _args, bucket, m in shapes] == [
+            ((2, 2), 4, 2), ((2, 4), 8, 2), ((4, 4), 16, 4),
+            ((8, 4), 32, 8), ((8, 4), 32, 8)]
+        after = ops_stats()
+
+        def moved(key):
+            return {m: after[key].get(m, 0) - before[key].get(m, 0)
+                    for m in ("2", "4", "8")}
+
+        assert moved("rlc_launches_by_width") == {"2": 2, "4": 1, "8": 2}
+        assert moved("rlc_sigs_by_width") == {"2": 8, "4": 9, "8": 41}
+
+
+def _four_arrays(entries, bucket, ep, m):
     """The four arrays the warm-epoch prep shipped, one device_put each,
     before they became views of one buffer (PR 29): what the packed
     buffer's sections have to hold, byte for byte."""
     n = len(entries)
-    g = bucket // pr.M
-    g_live = min((n + pr.M - 1) // pr.M, g)
-    live = g_live * pr.M
-    _pub, r_enc, scal, s_ok = pr._rlc_host_scalars(entries, live, g_live)
+    g = bucket // m
+    g_live = min((n + m - 1) // m, g)
+    live = g_live * m
+    _pub, r_enc, scal, s_ok = pr._rlc_host_scalars(entries, live, g_live, m)
     idx = np.full((bucket,), ep.vp - 1, dtype=np.int32)
     idx[:n] = entries.val_idx
     r_rows = np.zeros((bucket, 32), dtype=np.uint8)
     r_rows[:live] = r_enc
     r_rows[live:, 0] = 1
-    scal_rows = np.zeros((g, pr.N_SCAL, 32), dtype=np.uint8)
+    scal_rows = np.zeros((g, 2 * m, 32), dtype=np.uint8)
     scal_rows[:g_live] = scal
-    sok_rows = np.ones((g, pr.M), dtype=np.int32)
-    sok_rows[:g_live] = s_ok.reshape(g_live, pr.M).astype(np.int32)
+    sok_rows = np.ones((g, m), dtype=np.int32)
+    sok_rows[:g_live] = s_ok.reshape(g_live, m).astype(np.int32)
     return idx, r_rows, scal_rows, sok_rows
 
 
-def _warm_block(entries):
-    """(EntryBlock with gather indices, its epoch entry): the block as
-    the pipeline sees it once the validator set's tables are resident."""
-    from tendermint_tpu.ops import epoch_cache
-    from tendermint_tpu.ops.entry_block import EntryBlock
-
-    blk = EntryBlock.from_entries(entries)
-    # a permutation: gather indices are the commit's order, not 0..n-1
-    blk.val_idx = np.random.RandomState(len(entries)).permutation(
-        len(entries)).astype(np.int32)
-    ep = epoch_cache.EpochEntry(b"k" * 32, blk.pub[np.argsort(blk.val_idx)])
-    blk.epoch_key = ep.key
-    return blk, ep
-
-
-class TestPackedLaunchBuffer:
+class TestPackedLaunchBuffer(CachedSuite):
     """The warm-epoch launch ships ONE buffer (ISSUE 29): its sections,
     as the host wrote them and as the jitted prologue splits them, are
-    the four arrays of the old layout; the kernels' verdicts follow."""
+    the four arrays of the old layout, at every lane width; the kernels'
+    verdicts follow (CachedSuite, here at width 4)."""
 
+    M, N, FORGED = 4, 14, 6
+
+    @pytest.mark.parametrize("m", pr.WIDTHS)
     @pytest.mark.parametrize("n", [1, 3, 150, 255, 256, 10_000])
-    def test_split_equals_the_four_arrays(self, n):
+    def test_split_equals_the_four_arrays(self, n, m):
         import jax
 
         rng = np.random.RandomState(n)
@@ -136,14 +170,20 @@ class TestPackedLaunchBuffer:
         # random s halves give s_ok both values (s < L one time in 16)
         blk, ep = _warm_block(
             [(rng.bytes(32), b"rlc-%d" % i, rng.bytes(64)) for i in range(n)])
-        bucket, g, _block = pr.plan_bucket(n)
-        want = _four_arrays(blk, bucket, ep)
-        (packed,) = pr.prepare_rlc_cached(blk, bucket, ep)
+        # plan_bucket's own bucket where m is its width for n, else the
+        # lanes of n at the forced width padded to whole 8-lane groups
+        bucket, _g, _block, planned = pr.plan_bucket(n)
+        if planned != m:
+            bucket = -(-n // (8 * m)) * 8 * m
+        want = _four_arrays(blk, bucket, ep, m)
+        (packed,) = pr.prepare_rlc_cached(blk, bucket, ep, m)
         assert packed.ndim == 1 and packed.flags.c_contiguous
-        # no padding: the launch ships the bytes it always shipped
+        # no padding: the launch ships the bytes it always shipped, 104 a
+        # slot at any width
         assert packed.nbytes == sum(a.nbytes for a in want) == 104 * bucket
-        host = pr.packed_views(packed, bucket)
-        dev = jax.jit(pr.split_packed, static_argnums=1)(packed, bucket)
+        host = pr.packed_views(packed, bucket, m)
+        dev = jax.jit(pr.split_packed, static_argnums=(1, 2))(
+            packed, bucket, m)
         for name, w, h, d in zip(("idx", "r_rows", "scal_rows", "sok_rows"),
                                  want, host, dev):
             d = np.asarray(d)
@@ -152,24 +192,9 @@ class TestPackedLaunchBuffer:
             assert w.shape == h.shape == d.shape, name
             assert w.tobytes() == h.tobytes() == d.tobytes(), name
         idx, r_rows, scal_rows, sok_rows = (np.asarray(d) for d in dev)
-        live = -(-n // pr.M) * pr.M
+        live = -(-n // m) * m
         assert (idx[:n] == blk.val_idx).all() and (idx[n:] == ep.vp - 1).all()
         assert (r_rows[live:, 0] == 1).all() and not r_rows[live:, 1:].any()
-        assert not scal_rows[live // pr.M:].any()
-        assert (sok_rows[live // pr.M:] == 1).all()
-        assert n < 16 or 0 < sok_rows[: live // pr.M].sum() < live
-
-    @pytest.mark.time_limit(600)  # first trace of the cached bucket-16 shape
-    @pytest.mark.parametrize("tamper", [(), (6,)], ids=["valid", "forged"])
-    def test_cached_lanes_equal_uncached(self, tamper):
-        blk, ep = _warm_block(_sign_batch(14, tamper=set(tamper)))
-        bucket, g, block = pr.plan_bucket(len(blk), 4)
-        lanes_u = pr.verify_rlc_compact(
-            *pr.prepare_rlc(blk, bucket), block=block, interpret=True)
-        dev = pr.rlc_cached_fn(ep, g, block, True)(
-            *pr.prepare_rlc_cached(blk, bucket, ep))
-        lanes_c = np.asarray(dev)[0].astype(bool)
-        assert lanes_c.tolist() == lanes_u.tolist()
-        assert lanes_c.tolist() == [True, not tamper, True, True]
-        assert pr.expand_lanes(lanes_c, blk).tolist() == [
-            i not in tamper for i in range(14)]
+        assert not scal_rows[live // m:].any()
+        assert (sok_rows[live // m:] == 1).all()
+        assert n < 16 or 0 < sok_rows[: live // m].sum() < live

@@ -30,3 +30,61 @@ class TestRlcPipelineDispatch:
         finally:
             v.close()
             backend.engine.cache_clear()
+
+    def test_each_launch_expands_by_its_own_width(self, monkeypatch):
+        """Launches of different sizes run at different lane widths, and
+        the resolver expands each one's lane verdicts by the width THAT
+        launch ran at: the rejected lane's signatures, and only they, are
+        re-verified on the host, and the forged one is blamed. The
+        kernels are stood in for by a launch that rejects one lane (the
+        kernels themselves: test_pallas_rlc*.py); the spans of each
+        launch carry its width."""
+        import jax.numpy as jnp
+
+        from tendermint_tpu.libs.metrics import ops_stats
+        from tendermint_tpu.observability import trace as _tr
+        from tendermint_tpu.ops.pipeline import AsyncBatchVerifier
+
+        reject = {}  # lane width -> the lane the stand-in rejects
+
+        def lanes_but_one(m, g, *_a, **_k):
+            def launch(a_t, r_t, scal_t, sok_t):
+                assert sok_t.shape == (m, g) and a_t.shape == (m * 32, g)
+                return jnp.ones((1, g), jnp.int32).at[0, reject[m]].set(0)
+
+            return launch
+
+        monkeypatch.setenv("TM_TPU_PALLAS", "1")
+        monkeypatch.setenv("TM_TPU_RLC", "1")
+        monkeypatch.setattr(pr, "BLOCK_LANES", 4)
+        monkeypatch.setattr(pr, "_jitted_rlc_verify", lanes_but_one)
+        backend.engine.cache_clear()
+        v = AsyncBatchVerifier()
+        _tr.TRACER.clear()
+        _tr.configure(enabled=True)
+        try:
+            # (signatures, forged one, width at 4-lane blocks, bucket)
+            for n, forged, m, bucket in [(6, 3, 2, 8), (12, 5, 4, 16),
+                                         (20, 9, 8, 32), (7, 6, 2, 8)]:
+                reject[m] = forged // m
+                before = ops_stats()
+                res = v.submit(_sign_batch(n, tamper={forged})).result(
+                    timeout=120)
+                after = ops_stats()
+                assert res.tolist() == [i != forged for i in range(n)], m
+                # the lane's live signatures: m, or fewer in the last lane
+                lane_live = min((forged // m + 1) * m, n) - forged // m * m
+                assert (after["sigs_verified_host"]
+                        - before["sigs_verified_host"]) == lane_live, m
+                assert (after["rlc_launches_by_width"][str(m)]
+                        - before["rlc_launches_by_width"].get(str(m), 0)) == 1
+                assert (after["batches_by_bucket"][str(bucket)]
+                        - before["batches_by_bucket"].get(str(bucket), 0)) == 1
+        finally:
+            _tr.configure(enabled=False)
+            v.close()
+            backend.engine.cache_clear()
+        for name in ("pipeline.prep", "pipeline.dispatch"):
+            args = [e[4] for e in _tr.TRACER.events() if e[0] == name]
+            assert [(a["bucket"], a["m"]) for a in args] == [
+                (8, 2), (16, 4), (32, 8), (8, 2)], name

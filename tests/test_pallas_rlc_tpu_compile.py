@@ -1,0 +1,60 @@
+"""The RLC pipelines at their REAL shapes through the TPU's own compiler,
+for a v5e that is described and not attached (no chip, nothing runs):
+what interpret mode cannot refuse — a block that overflows the scoped
+VMEM, a slice off the tiling. One shape per lane width, the ones the
+benchmark's cells launch. All in this one file: only one process at a
+time may hold the TPU's library (tests/_rlc.py has the kernels' verdicts)."""
+
+import pytest
+
+pytest.importorskip("jax")
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    import jax
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — whatever keeps libtpu away
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # an executable compiled for an absent chip is written to the
+    # persistent cache and can never be read back: keep it out
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", True)
+    cc.reset_cache()
+
+
+# (signatures of the launch, validator-table columns): hub150's commit,
+# a 400-signature one, max10k's commit
+SHAPES = [(150, 256), (400, 512), (10_000, 16_384)]
+
+
+@pytest.mark.time_limit(420)
+@pytest.mark.parametrize("n,vp", SHAPES, ids=["m2", "m4", "m8"])
+def test_cached_pipeline_compiles_for_v5e(one_chip, n, vp):
+    import jax
+    import jax.numpy as jnp
+
+    from tendermint_tpu.ops import pallas_rlc as pr
+
+    bucket, g, block, m = pr.plan_bucket(n)
+    assert block == pr.BLOCK_LANES == 128
+
+    def arg(shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+
+    f = pr._jitted_rlc_verify_cached(m, g, block, vp, False)
+    compiled = f.lower(
+        arg((4 * 32, vp)), arg((1, vp)),
+        arg((pr.packed_layout(bucket, m)[-1],)),
+    ).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") >= 3  # K1, K2, K3 are kernels
+    assert f"rlc_verify_cached_g{g}_m{m}_b128_vp{vp}" in text
