@@ -21,10 +21,16 @@ commit; a 128-validator header chain):
                 coalesced bucket has launched
   5. server     an in-process node: GET /status, then a few dozen
                 /light_verify requests over HTTP (one forged)
-  6. accounts   sigs_verified{device} rose by exactly what stages 2-5
+  6. churn      30 heights of a 100-validator chain that replaces one
+                key a height, each light block from its wire bytes
+                through light.verifier.verify_adjacent: tables built,
+                sets mapped onto a resident table, rows patched and the
+                kernels launched are printed and held to what one key a
+                height into a 128-row table implies
+  7. accounts   sigs_verified{device} rose by exactly what stages 2-6
                 submitted; host / fallback / dispatch-error counters
                 moved only by what the smoke states
-  7. report     per-step wall time (first-use set-up apart from
+  8. report     per-step wall time (first-use set-up apart from
                 repeats), every compile, one JSON line last
 
 Any stage failing fails the run: nothing is caught and skipped, and no
@@ -54,6 +60,8 @@ LIGHT_VALS = 128      # BASELINE config #5's header-chain shape
 STREAM = 8            # concurrent big commits (verify_commit_stream8)
 N_ADJACENT = 24       # /light_verify requests h -> h+1
 N_SKIPPING = 11       # /light_verify requests 0 -> k (two stages each)
+CHURN_VALS = 100      # the light-client sequence benchmark's set
+N_CHURN = 30          # adjacent steps, one key replaced at each
 POWER = 100           # every validator's voting power
 CHAIN_ID = "chip-smoke"
 T0 = 1_600_000_000
@@ -227,6 +235,43 @@ def build_header_chain(seed: int, n_headers: int, n_vals: int):
     return chain
 
 
+def build_churn_chain(seed: int, n_headers: int, n_vals: int):
+    """[wire bytes of the LightBlock at height h]: at every height the
+    oldest key leaves the set and a new one joins (light/helpers_test.go
+    genLightBlocksWithKeys with valVariation 1)."""
+    from tendermint_tpu.light.provider import LightBlock
+    from tendermint_tpu.types import SignedHeader
+    from tendermint_tpu.types.block import (
+        BlockID, Header, PartSetHeader, Version,
+    )
+    from tendermint_tpu.wire.canonical import Timestamp
+
+    sks = _keys(seed, "churn", n_vals + n_headers)
+    sets = [_valset(sks[h:h + n_vals]) for h in range(n_headers + 1)]
+    out, prev = [], b""
+    for h in range(1, n_headers + 1):
+        vset, ordered = sets[h - 1]
+        hdr = Header(
+            version=Version(block=11, app=0), chain_id=CHAIN_ID, height=h,
+            time=Timestamp(seconds=T0 + h),
+            last_block_id=BlockID(
+                hash=prev, part_set_header=PartSetHeader(total=1, hash=prev)
+            ) if prev else BlockID(),
+            validators_hash=vset.hash(),
+            next_validators_hash=sets[h][0].hash(),
+            consensus_hash=b"\x01" * 32,
+            proposer_address=vset.validators[0].address,
+        )
+        prev = hdr.hash()
+        bid = BlockID(hash=prev,
+                      part_set_header=PartSetHeader(total=1, hash=prev))
+        out.append(LightBlock(
+            signed_header=SignedHeader(
+                header=hdr, commit=_signed_commit(vset, ordered, h, bid)),
+            validators=vset).encode())
+    return out
+
+
 def forge(commit, idx: int):
     """The commit with one bit of signature #idx flipped."""
     sigs = list(commit.signatures)
@@ -363,6 +408,9 @@ def counters() -> dict:
         "rlc_rejected_lanes_by_width": dict(s["rlc_rejected_lanes_by_width"]),
         "epoch_cache_hits": s["epoch_cache_hits"],
         "epoch_cache_misses": s["epoch_cache_misses"],
+        "epoch_tables_built": s["epoch_tables_built"],
+        "epoch_tables_shared": s["epoch_tables_shared"],
+        "epoch_rows_patched": s["epoch_rows_patched"],
     }
 
 
@@ -580,6 +628,51 @@ def stage_server(led: Ledger, reqs, now, want_forged) -> dict:
 # -- stage 6/7 -----------------------------------------------------------------
 
 
+def stage_churn(led: Ledger, wires) -> dict:
+    """A validator set never seen at every step: the light client's own
+    sequential entry, from wire bytes."""
+    from tendermint_tpu.light import verifier
+    from tendermint_tpu.light.provider import LightBlock
+    from tendermint_tpu.wire.canonical import Timestamp
+
+    now = Timestamp(seconds=T0 + len(wires) + 1)
+    n_sigs = early_stop_count(CHURN_VALS)
+    c0 = counters()
+    trusted = LightBlock.decode(wires[0]).signed_header
+
+    def step(k):
+        nonlocal trusted
+        lb = LightBlock.decode(wires[k])
+        verifier.verify_adjacent(trusted, lb.signed_header, lb.validators,
+                                 3600.0, now, 10.0)
+        trusted = lb.signed_header
+
+    for k in range(1, len(wires)):
+        led.run("churn", f"verify_adjacent height {k + 1}",
+                lambda k=k: step(k), sigs=n_sigs, first_use=k <= 3)
+    c1 = counters()
+    rise = {k: c1[k] - c0[k] for k in (
+        "epoch_cache_hits", "epoch_cache_misses", "epoch_tables_built",
+        "epoch_tables_shared", "epoch_rows_patched")}
+    launched = {m: n - c0["rlc_launches_by_width"].get(m, 0)
+                for m, n in c1["rlc_launches_by_width"].items()
+                if n != c0["rlc_launches_by_width"].get(m, 0)}
+    say(f"  {len(wires) - 1} steps x {n_sigs} signatures: {rise}; RLC "
+        f"launches by lane width {launched}")
+    # one key a height into a 128-row table of 100: a set maps until the
+    # table's 27 free rows are used, then one cold build, and so on
+    steps = len(wires) - 1
+    built = -(-steps // (128 - CHURN_VALS))
+    check(rise["epoch_cache_misses"] == steps and rise["epoch_cache_hits"] == 0,
+          f"every step carries a set never seen: {rise}")
+    check(rise["epoch_tables_built"] == built
+          and rise["epoch_tables_shared"] == steps - built
+          and rise["epoch_rows_patched"] == steps - built,
+          f"{steps} steps of one-key churn imply {built} tables built and "
+          f"{steps - built} sets mapped, one row each: {rise}")
+    return dict(rise, steps=steps, rlc_launches_by_width=launched)
+
+
 def stage_accounts(led: Ledger, base: dict) -> dict:
     from tendermint_tpu.ops.engine import engine
 
@@ -663,6 +756,7 @@ def main() -> None:
     big = build_commit_jobs(args.seed, "big", BIG_VALS, STREAM)
     chain = build_header_chain(args.seed, N_ADJACENT + 2, LIGHT_VALS)
     reqs, now = light_requests(chain, rng)
+    churn = build_churn_chain(args.seed, N_CHURN + 1, CHURN_VALS)
 
     def variant(job, commit):
         return job[:4] + (commit,)
@@ -705,10 +799,13 @@ def main() -> None:
     stage_stream(led, big)
     say("stage 5: server entry")
     server = stage_server(led, reqs, now, want_forged)
-    say("stage 6: accounts")
+    say("stage 6: a validator set that changes every height")
+    churned = stage_churn(led, churn)
+    say("stage 7: accounts")
     accounts = stage_accounts(led, base)
-    say("stage 7: report")
-    summary = dict(install=info, server=server, accounts=accounts,
+    say("stage 8: report")
+    summary = dict(install=info, server=server, churn=churned,
+                   accounts=accounts,
                    **report(led), seed=args.seed,
                    wall_s=round(time.perf_counter() - _START, 1), claim=None)
 

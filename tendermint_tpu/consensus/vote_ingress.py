@@ -130,6 +130,23 @@ def vote_ingress_stats() -> dict:
     return out
 
 
+class _SetRows:
+    """A validator set's rows in its device table, as part of a window
+    key: equal and hashed by the set's hash."""
+
+    __slots__ = ("set_hash", "rows")
+
+    def __init__(self, set_hash: bytes, rows: np.ndarray):
+        self.set_hash = set_hash
+        self.rows = rows
+
+    def __eq__(self, other) -> bool:
+        return isinstance(other, _SetRows) and other.set_hash == self.set_hash
+
+    def __hash__(self) -> int:
+        return hash(self.set_hash)
+
+
 class VoteIngress:
     """Window/size-batched live-vote signature verification — a `votes`
     lane on the shared ingress fabric (ops/ingress.py).
@@ -162,7 +179,8 @@ class VoteIngress:
         self.metrics = metrics
         self.memo_hits = 0
         self.apply_drops = 0    # consensus/state.py bumps this directly
-        self._epoch_keys: Dict[Tuple, Optional[bytes]] = {}
+        # (height, id(valset)) -> (table key, the set's rows there) or None
+        self._epoch_keys: Dict[Tuple, Optional[Tuple]] = {}
         self._lane = _fabric.shared_engine().register(_fabric.LaneSpec(
             name="votes",
             priority=_fabric.PRIORITY_CONSENSUS,
@@ -210,13 +228,13 @@ class VoteIngress:
     @staticmethod
     def _attach(block, key: Tuple, batch: List[PendingVote]) -> None:
         """Warm-epoch windows carry val_idx + epoch_key so kernels
-        gather A on device (key[1] is the epoch key iff warm)."""
-        ek = key[1] if isinstance(key[1], bytes) else None
-        if ek is not None:
-            block.val_idx = np.array(
+        gather A on device: key[1] is the table's key iff warm, key[2]
+        the rows of the set's validators in that table."""
+        if isinstance(key[1], bytes):
+            block.val_idx = key[2].rows[np.array(
                 [p.vote.validator_index for p in batch], dtype=np.int32
-            )
-            block.epoch_key = ek
+            )]
+            block.epoch_key = key[1]
 
     @staticmethod
     def _trace(batch: List[PendingVote], flow: int) -> None:
@@ -280,24 +298,27 @@ class VoteIngress:
                           dedup_key=dkey, t_enq=pend.t_enq or None)
 
     def _window_key(self, height: int, val_set) -> Tuple:
-        """(height, epoch key) when the epoch cache knows this valset
-        (warm windows attach val_idx so kernels gather A on device);
+        """(height, table key, the set's rows) when the epoch cache knows
+        this valset (warm windows attach val_idx so kernels gather A on
+        device; the rows tell two sets of one table apart);
         (height, id(valset)) cold — still coalesces same-valset votes,
         never fuses rows from different tables."""
         vkey = (height, id(val_set))
-        ek = self._epoch_keys.get(vkey)
-        if ek is None and vkey not in self._epoch_keys:
+        warm = self._epoch_keys.get(vkey)
+        if warm is None and vkey not in self._epoch_keys:
             try:
                 from ..ops import epoch_cache as _epoch
 
-                ek = _epoch.note_valset(val_set)
+                ek, rows = _epoch.table_rows(val_set, np.arange(
+                    len(val_set.validators), dtype=np.int32))
+                warm = (ek, _SetRows(val_set.hash(), rows)) if ek else None
             except Exception:  # noqa: BLE001 — cache is an optimization
-                ek = None
-            self._epoch_keys[vkey] = ek
+                warm = None
+            self._epoch_keys[vkey] = warm
             if len(self._epoch_keys) > 64:
                 self._epoch_keys.clear()
-                self._epoch_keys[vkey] = ek
-        return (height, ek) if ek is not None else vkey
+                self._epoch_keys[vkey] = warm
+        return (height,) + warm if warm is not None else vkey
 
     def flush_now(self) -> None:
         self._lane.flush_now()

@@ -691,6 +691,21 @@ class OpsMetrics:
             "ops", "epoch_cache_evictions_total",
             "Validator-set epochs evicted from the device cache (LRU).",
         )
+        # a set never seen may still gather from a resident table
+        # (ops/epoch_cache.py map_rows): shared = such misses, patched =
+        # the keys they appended to it, built = cold builds of a table
+        self.epoch_tables_shared = registry.counter(
+            "ops", "epoch_tables_shared_total",
+            "Validator sets never seen that mapped onto a resident table.",
+        )
+        self.epoch_rows_patched = registry.counter(
+            "ops", "epoch_rows_patched_total",
+            "Public keys appended to resident tables by mapped sets.",
+        )
+        self.epoch_tables_built = registry.counter(
+            "ops", "epoch_tables_built_total",
+            "Device tables registered for a set no resident table could take.",
+        )
         # wire decode (types/block.py Commit.decode): which path parsed
         # the commit — native = commit_decode_columns took the bytes,
         # python = it answered None (off the canonical shape) or the
@@ -959,6 +974,9 @@ def ops_stats() -> dict:
         "epoch_cache_hits": int(m.epoch_cache_hits.total()),
         "epoch_cache_misses": int(m.epoch_cache_misses.total()),
         "epoch_cache_evictions": int(m.epoch_cache_evictions.total()),
+        "epoch_tables_shared": int(m.epoch_tables_shared.total()),
+        "epoch_rows_patched": int(m.epoch_rows_patched.total()),
+        "epoch_tables_built": int(m.epoch_tables_built.total()),
         "commit_decode_native": int(m.commit_decodes.value(path="native")),
         "commit_decode_python": int(m.commit_decodes.value(path="python")),
         "h2d_bytes_per_commit": float(m.h2d_bytes_per_commit.value()),

@@ -14,7 +14,11 @@ import abc
 from dataclasses import dataclass
 from typing import Optional
 
+from ..observability import trace as _trace
 from ..types import Commit, Header, SignedHeader, ValidatorSet
+from ..wire.proto import ProtoWriter, decode_message, field_bytes
+
+_span = _trace.span
 
 
 @dataclass
@@ -30,6 +34,29 @@ class LightBlock:
 
     def hash(self) -> bytes:
         return self.signed_header.header.hash()
+
+    def encode(self) -> bytes:
+        """tendermint.types.LightBlock: 1 signed_header, 2 validator_set."""
+        w = ProtoWriter()
+        w.write_message(1, self.signed_header.encode(), always=True)
+        w.write_message(2, self.validators.encode(), always=True)
+        return w.bytes()
+
+    @classmethod
+    def decode(cls, data: bytes) -> "LightBlock":
+        """What a light client is handed for a height: the signed header
+        and the validator set that signed it. Both parts are required
+        (types/light.go LightBlockFromProto); the set's decode validates
+        it, the header's checks are verify_adjacent's."""
+        f = decode_message(data)
+        if 1 not in f:
+            raise ValueError("missing signed header")
+        if 2 not in f:
+            raise ValueError("missing validator set")
+        signed_header = SignedHeader.decode(field_bytes(f, 1))
+        with _span("light.decode.valset"):
+            validators = ValidatorSet.decode(field_bytes(f, 2))
+        return cls(signed_header=signed_header, validators=validators)
 
 
 class ErrLightBlockNotFound(KeyError):

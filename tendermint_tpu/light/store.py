@@ -10,8 +10,6 @@ import struct
 from typing import Optional
 
 from ..db import DB
-from ..types import Commit, Header, SignedHeader, ValidatorSet
-from ..wire.proto import ProtoWriter, decode_message, field_bytes
 from .provider import LightBlock
 
 _PREFIX = b"lb/"
@@ -26,27 +24,11 @@ class LightStore:
         self._db = db
 
     def save_light_block(self, lb: LightBlock) -> None:
-        w = ProtoWriter()
-        sh = ProtoWriter()
-        sh.write_message(1, lb.signed_header.header.encode(), always=True)
-        sh.write_message(2, lb.signed_header.commit.encode(), always=True)
-        w.write_message(1, sh.bytes(), always=True)
-        w.write_message(2, lb.validators.encode(), always=True)
-        self._db.set(_key(lb.height), w.bytes())
+        self._db.set(_key(lb.height), lb.encode())
 
     def light_block(self, height: int) -> Optional[LightBlock]:
         raw = self._db.get(_key(height))
-        if raw is None:
-            return None
-        f = decode_message(raw)
-        sh = decode_message(field_bytes(f, 1))
-        return LightBlock(
-            signed_header=SignedHeader(
-                header=Header.decode(field_bytes(sh, 1)),
-                commit=Commit.decode(field_bytes(sh, 2)),
-            ),
-            validators=ValidatorSet.decode(field_bytes(f, 2)),
-        )
+        return None if raw is None else LightBlock.decode(raw)
 
     def first_light_block_height(self) -> int:
         for k, _ in self._db.iterator(_key(0), _key((1 << 62))):

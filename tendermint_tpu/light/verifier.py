@@ -17,7 +17,10 @@ from ..types.validation import (
     verify_commit_light,
     verify_commit_light_trusting,
 )
+from ..observability import trace as _trace
 from ..wire.canonical import Timestamp
+
+_span = _trace.span
 
 # light.DefaultTrustLevel (light/verifier.go:20)
 DEFAULT_TRUST_LEVEL = Fraction(1, 3)
@@ -210,19 +213,20 @@ def prepare_adjacent(
 ) -> List[SigCheck]:
     """verifier.go:103-150 host checks; returns the sig work (one +2/3
     commit check) instead of running it."""
-    if untrusted_header.header.height != trusted_header.header.height + 1:
-        raise ValueError("headers must be adjacent in height")
-    if header_expired(trusted_header, trusting_period, now):
-        raise ErrOldHeaderExpired(f"old header has expired at {now}")
-    verify_new_header_and_vals(
-        untrusted_header, untrusted_vals, trusted_header, now, max_clock_drift
-    )
-    # valhash continuity (verifier.go:134-142)
-    if untrusted_header.header.validators_hash != trusted_header.header.next_validators_hash:
-        raise ErrInvalidHeader(
-            f"expected old header next validators ({trusted_header.header.next_validators_hash.hex()}) "
-            f"to match those from new header ({untrusted_header.header.validators_hash.hex()})"
+    with _span("light.header_checks"):
+        if untrusted_header.header.height != trusted_header.header.height + 1:
+            raise ValueError("headers must be adjacent in height")
+        if header_expired(trusted_header, trusting_period, now):
+            raise ErrOldHeaderExpired(f"old header has expired at {now}")
+        verify_new_header_and_vals(
+            untrusted_header, untrusted_vals, trusted_header, now, max_clock_drift
         )
+        # valhash continuity (verifier.go:134-142)
+        if untrusted_header.header.validators_hash != trusted_header.header.next_validators_hash:
+            raise ErrInvalidHeader(
+                f"expected old header next validators ({trusted_header.header.next_validators_hash.hex()}) "
+                f"to match those from new header ({untrusted_header.header.validators_hash.hex()})"
+            )
     return [
         _light_check(
             trusted_header.header.chain_id,
@@ -304,11 +308,12 @@ def verify_adjacent(
     """verifier.go:103-150: the prepare seam driven synchronously —
     full commit verification on the device engine (verifier.go:143-148);
     any commit defect surfaces as ErrInvalidHeader."""
-    for chk in prepare_adjacent(
-        trusted_header, untrusted_header, untrusted_vals,
-        trusting_period, now, max_clock_drift,
-    ):
-        chk.run_sync()
+    with _span("light.verify_adjacent"):
+        for chk in prepare_adjacent(
+            trusted_header, untrusted_header, untrusted_vals,
+            trusting_period, now, max_clock_drift,
+        ):
+            chk.run_sync()
 
 
 def verify_non_adjacent(

@@ -160,15 +160,16 @@ def prep_commit_from(
         mode,
     )
     if block is not None:
-        # epoch-cache metadata: sel IS the valset row of each lane, and
-        # the key is only attached for WARM epochs (ops/epoch_cache.py) —
-        # downstream preps then ship gather indices instead of
-        # pubkey-derived arrays. A disabled cache returns None and the
-        # block is exactly what PR 4 produced.
+        # epoch-cache metadata: sel IS the valset row of each lane;
+        # table_rows names the device table the set gathers from and the
+        # lanes' rows there. The key is only attached for WARM sets
+        # (ops/epoch_cache.py) — downstream preps then ship gather
+        # indices instead of pubkey-derived arrays. A disabled cache
+        # returns None and the block is exactly what PR 4 produced.
         from . import epoch_cache as _epoch
 
-        block.val_idx = sel.astype(np.int32)
-        block.epoch_key = _epoch.note_valset(vals)
+        block.epoch_key, block.val_idx = _epoch.table_rows(
+            vals, sel.astype(np.int32))
     return sel, tallied, block
 
 

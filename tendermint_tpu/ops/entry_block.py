@@ -60,11 +60,14 @@ class EntryBlock:
         self.sig = sig
         self.msgs = msgs
         self.offsets = offsets
-        # Epoch-cache metadata (ops/epoch_cache.py): val_idx (n,) int32 —
-        # each lane's row in its validator set's cached device pub table;
-        # epoch_key — the ValidatorSet.hash() the table is keyed by. When
-        # set, warm-epoch preps ship val_idx instead of pubkey-derived
-        # arrays and the kernels gather A on device.
+        # Epoch-cache metadata (ops/epoch_cache.py): epoch_key names the
+        # device TABLE of public-key rows the lanes gather from (the
+        # hash of the set that built it and, for ed25519, the table's
+        # own serial: a name never leads to other rows than it did;
+        # later sets that share its keys map onto it), val_idx (n,)
+        # int32 — each lane's row of THAT table. When set, warm-epoch preps ship val_idx instead of
+        # pubkey-derived arrays and the kernels gather A on device;
+        # blocks of different sets of one table fuse (concat).
         if val_idx is not None and val_idx.shape != (n,):
             raise ValueError("val_idx must be (n,)")
         self.val_idx = val_idx

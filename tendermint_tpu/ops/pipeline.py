@@ -1428,7 +1428,7 @@ def commit_entries_legacy(
         raise ValueError("invalid signature length")
     buf, offsets = commit.vote_sign_bytes_block(chain_id, idxs)
     n = len(idxs)
-    idx_arr = np.asarray(idxs, dtype=np.int32)
+    idx_arr = val_idx = np.asarray(idxs, dtype=np.int32)
     cols = vals.ed25519_columns()
     epoch_key = None
     scheme = "ed25519"
@@ -1441,7 +1441,7 @@ def commit_entries_legacy(
         pub = cols[0][idx_arr]
         from . import epoch_cache as _epoch
 
-        epoch_key = _epoch.note_valset(vals)
+        epoch_key, val_idx = _epoch.table_rows(vals, idx_arr)
     elif (scols := vals.secp256k1_columns()) is not None:
         # all-secp256k1 committee (ISSUE 19): gather the 33-byte SEC1
         # rows and route the block through the scheme lane — the prefix
@@ -1452,7 +1452,7 @@ def commit_entries_legacy(
         pub_aux = np.ascontiguousarray(raw[:, 0])
         pub = np.ascontiguousarray(raw[:, 1:])
         scheme = "secp256k1"
-        epoch_key = _epoch.note_valset(vals)
+        epoch_key, val_idx = _epoch.table_rows(vals, idx_arr)
     else:
         pub_b = b"".join(vals.validators[i].pub_key.bytes() for i in idxs)
         if len(pub_b) != 32 * n:
@@ -1465,7 +1465,7 @@ def commit_entries_legacy(
         b"".join(sigs[i].signature for i in idxs), dtype=np.uint8
     ).reshape(n, 64)
     return EntryBlock(pub, sig, buf, offsets,
-                      val_idx=idx_arr, epoch_key=epoch_key,
+                      val_idx=val_idx, epoch_key=epoch_key,
                       scheme=scheme, pub_aux=pub_aux), tallied
 
 

@@ -830,7 +830,8 @@ class Cluster:
         now = self._epoch_stats()
         d = {
             k: now[k] - self._epoch_stats0.get(k, 0)
-            for k in ("hits", "misses", "evictions")
+            for k in ("hits", "misses", "evictions", "tables_shared",
+                      "tables_built")
         }
         d["enabled"] = now["enabled"]
         d["depth"] = now["depth"]
@@ -900,12 +901,21 @@ class Cluster:
                     out.append(
                         "epoch-cache: warm re-verifications recorded no hits"
                     )
-                expect_evict = distinct - ec["depth"]
+                # sets that differ by a key map onto one table, so it
+                # is the TABLES built that the depth evicts, and a
+                # rotation of one key must not have built one each
+                expect_evict = ec["tables_built"] - ec["depth"]
                 if expect_evict > 0 and ec["evictions"] < expect_evict:
                     out.append(
-                        f"epoch-cache: {distinct} epochs through depth "
-                        f"{ec['depth']} implies >= {expect_evict} evictions, "
-                        f"saw {ec['evictions']}"
+                        f"epoch-cache: {ec['tables_built']} tables through "
+                        f"depth {ec['depth']} implies >= {expect_evict} "
+                        f"evictions, saw {ec['evictions']}"
+                    )
+                if ec["tables_built"] + ec["tables_shared"] < distinct:
+                    out.append(
+                        f"epoch-cache: {distinct} distinct valsets but "
+                        f"{ec['tables_built']} tables built and "
+                        f"{ec['tables_shared']} sets mapped"
                     )
         return out
 

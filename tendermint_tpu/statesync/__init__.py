@@ -86,28 +86,6 @@ def _enc(kind: int, fields: Optional[dict] = None) -> bytes:
     return w.bytes()
 
 
-def _encode_light_block(lb: LightBlock) -> bytes:
-    sh = ProtoWriter()
-    sh.write_message(1, lb.signed_header.header.encode(), always=True)
-    sh.write_message(2, lb.signed_header.commit.encode(), always=True)
-    w = ProtoWriter()
-    w.write_message(1, sh.bytes(), always=True)
-    w.write_message(2, lb.validators.encode(), always=True)
-    return w.bytes()
-
-
-def _decode_light_block(raw: bytes) -> LightBlock:
-    f = decode_message(raw)
-    sh = decode_message(field_bytes(f, 1))
-    return LightBlock(
-        signed_header=SignedHeader(
-            header=Header.decode(field_bytes(sh, 1)),
-            commit=Commit.decode(field_bytes(sh, 2)),
-        ),
-        validators=ValidatorSet.decode(field_bytes(f, 2)),
-    )
-
-
 @dataclass
 class _SnapshotInfo:
     height: int
@@ -259,10 +237,10 @@ class StateSyncReactor:
             height = to_signed64(field_int(r, 1))
             lb = self._load_local_light_block(height)
             if lb is not None:
-                self._lb_ch.send(env.from_id, _enc(2, {1: _encode_light_block(lb)}))
+                self._lb_ch.send(env.from_id, _enc(2, {1: lb.encode()}))
         elif 2 in f:  # light_block_response
             r = decode_message(field_bytes(f, 2))
-            lb = _decode_light_block(field_bytes(r, 1))
+            lb = LightBlock.decode(field_bytes(r, 1))
             with self._mtx:
                 self._light_blocks[lb.height] = lb
 

@@ -1070,6 +1070,26 @@ class SignedHeader:
     header: Optional[Header] = None
     commit: Optional[Commit] = None
 
+    def encode(self) -> bytes:
+        """tendermint.types.SignedHeader: 1 header, 2 commit."""
+        w = ProtoWriter()
+        if self.header is not None:
+            w.write_message(1, self.header.encode(), always=True)
+        if self.commit is not None:
+            w.write_message(2, self.commit.encode(), always=True)
+        return w.bytes()
+
+    @classmethod
+    def decode(cls, data: bytes) -> "SignedHeader":
+        """A field the bytes lack decodes to None (a nil pointer in Go),
+        which validate_basic names; the commit rides Commit.decode's
+        native column pass."""
+        f = decode_message(data)
+        return cls(
+            header=Header.decode(field_bytes(f, 1)) if 1 in f else None,
+            commit=Commit.decode(field_bytes(f, 2)) if 2 in f else None,
+        )
+
     def validate_basic(self, chain_id: str) -> None:
         if self.header is None:
             raise ValueError("missing header")

@@ -360,8 +360,9 @@ def prepare_commit_batch(
     # gather pub rows from the cached columns (key TYPE safety is
     # structural: ed25519_columns is None for any mixed set) and attach
     # the epoch metadata so warm epochs ship only per-sig data —
-    # val_idx rows are VALIDATOR-SET rows (the device-table gather key),
-    # which differ from signature indexes on the by-address path
+    # `rows` are VALIDATOR-SET rows (they differ from signature indexes
+    # on the by-address path); table_rows turns them into the rows of
+    # the device table the set gathers from
     rows = _np.asarray([r for _, r, _ in selected], dtype=_np.int32)
     if cols is not None:
         scheme, pub, pub_aux = "ed25519", cols[0][rows], None
@@ -372,14 +373,14 @@ def prepare_commit_batch(
         scheme = "secp256k1"
         pub_aux = _np.ascontiguousarray(raw[:, 0])
         pub = _np.ascontiguousarray(raw[:, 1:])
-    epoch_key = _epoch.note_valset(vals)
+    epoch_key, val_idx = _epoch.table_rows(vals, rows)
     sigs_list = commit.signatures
     sig = _np.frombuffer(
         b"".join(sigs_list[i].signature for i in batch_sig_idxs),
         dtype=_np.uint8,
     ).reshape(len(selected), 64)
     eblk = EntryBlock(pub, sig, buf, offsets,
-                      val_idx=rows, epoch_key=epoch_key,
+                      val_idx=val_idx, epoch_key=epoch_key,
                       scheme=scheme, pub_aux=pub_aux)
     return eblk, _blame_conclude(batch_sig_idxs, commit)
 

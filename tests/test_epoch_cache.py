@@ -57,9 +57,12 @@ def _block_id():
     )
 
 
-def _signed_commit(n, height=7, bad=(), nil=(), absent=(), power=None):
-    """A REAL signed commit over n validators (index-aligned set)."""
-    sks = [ed25519.gen_priv_key(bytes([i + 1]) * 32) for i in range(n)]
+def _signed_commit(n, height=7, bad=(), nil=(), absent=(), power=None,
+                   first=0):
+    """A REAL signed commit over n validators (index-aligned set); sets
+    of different `first` (16 apart) share no key."""
+    sks = [ed25519.gen_priv_key(bytes([first + i + 1]) * 32)
+           for i in range(n)]
     vals = [
         Validator.new(sk.pub_key(), (power or [100] * n)[i])
         for i, sk in enumerate(sks)
@@ -107,7 +110,9 @@ class TestEpochCacheCore:
         key1 = epoch_cache.note_valset(vset)
         assert key1 is None  # first sight: cold, registers only
         key2 = epoch_cache.note_valset(vset)
-        assert key2 == vset.hash()  # second sight: warm
+        # second sight: warm; a table's name is the hash of the set that
+        # built it and a serial of its own
+        assert key2[:32] == vset.hash() and len(key2) == 40
         ep = epoch_cache.cache().get(key2)
         assert ep is not None
         assert ep.n_vals == 6
@@ -121,7 +126,8 @@ class TestEpochCacheCore:
             m.epoch_cache_misses.total(),
             m.epoch_cache_evictions.total(),
         )
-        sets = [_signed_commit(4 + i)[0] for i in range(5)]
+        # five sets that share no key: nothing maps, each builds a table
+        sets = [_signed_commit(4 + i, first=16 * i)[0] for i in range(5)]
         for vs in sets:
             assert epoch_cache.note_valset(vs) is None  # 5 misses
         # depth=4: registering the 5th evicted the 1st (LRU)
@@ -134,40 +140,61 @@ class TestEpochCacheCore:
         assert m.epoch_cache_misses.total() - m0 == 6
 
     def test_lru_ordering(self):
-        sets = [_signed_commit(4 + i)[0] for i in range(4)]
+        sets = [_signed_commit(4 + i, first=16 * i)[0] for i in range(4)]
         for vs in sets:
             epoch_cache.note_valset(vs)
         # touch the oldest so it is no longer the LRU victim
         assert epoch_cache.note_valset(sets[0]) is not None
-        epoch_cache.note_valset(_signed_commit(12)[0])  # evicts sets[1]
+        epoch_cache.note_valset(_signed_commit(12, first=64)[0])  # evicts sets[1]
         assert epoch_cache.note_valset(sets[0]) is not None
         assert epoch_cache.note_valset(sets[1]) is None  # was evicted
 
-    def test_power_change_invalidates(self):
+    def test_power_change_is_a_new_set_of_the_same_table(self):
+        m = _ops()
         vset, _, _, sks = _signed_commit(5)
         epoch_cache.note_valset(vset)
         key_a = epoch_cache.note_valset(vset)
         assert key_a is not None
+        m0, s0, p0, b0 = (m.epoch_cache_misses.total(),
+                          m.epoch_tables_shared.total(),
+                          m.epoch_rows_patched.total(),
+                          m.epoch_tables_built.total())
         vset.update_with_change_set(
             [Validator.new(sks[0].pub_key(), 999)]
         )
         # _update_with_change_set cleared _hash and _ed_cols: the changed
-        # set keys to a NEW epoch (cold), never the stale table
+        # set has a NEW hash (a miss) and a new order, but its keys are
+        # the table's: it maps onto it and appends nothing
         assert vset.hash() != key_a
+        key_b, idx = epoch_cache.table_rows(vset, np.arange(5, dtype=np.int32))
+        assert key_b == key_a
+        ep = epoch_cache.cache().get(key_a)
+        assert (ep.pub_rows[idx] == vset.ed25519_columns()[0]).all()
+        assert ep.n_rows == 5 and len(epoch_cache.cache()) == 1
+        assert (m.epoch_cache_misses.total() - m0,
+                m.epoch_tables_shared.total() - s0,
+                m.epoch_rows_patched.total() - p0,
+                m.epoch_tables_built.total() - b0) == (1, 1, 0, 0)
+        # a caller that would attach its own set rows is told "uncached"
+        assert list(idx) != list(range(5))
         assert epoch_cache.note_valset(vset) is None
-        key_b = epoch_cache.note_valset(vset)
-        assert key_b is not None and key_b != key_a
 
-    def test_membership_change_invalidates(self):
+    def test_membership_change_appends_a_row(self):
+        m = _ops()
         vset, _, _, _ = _signed_commit(5)
         epoch_cache.note_valset(vset)
         key_a = epoch_cache.note_valset(vset)
+        p0 = m.epoch_rows_patched.total()
         new_sk = ed25519.gen_priv_key(b"\x77" * 32)
         vset.update_with_change_set([Validator.new(new_sk.pub_key(), 50)])
         assert vset.hash() != key_a
-        assert epoch_cache.note_valset(vset) is None  # cold under new key
-        ep = epoch_cache.cache().get(vset.hash())
-        assert ep.n_vals == 6
+        key_b, idx = epoch_cache.table_rows(vset, np.arange(6, dtype=np.int32))
+        assert key_b == key_a  # the same table, one key appended
+        ep = epoch_cache.cache().get(key_a)
+        assert ep.n_vals == 5 and ep.n_rows == 6
+        assert (ep.pub_rows[idx] == vset.ed25519_columns()[0]).all()
+        assert m.epoch_rows_patched.total() - p0 == 1
+        assert len(epoch_cache.cache()) == 1  # no table of its own
 
     def test_non_ed25519_set_not_cached(self):
         class FakeKey:
@@ -196,7 +223,7 @@ class TestEpochCacheCore:
         epoch_cache.note_valset(vset)
         c = vset.copy()
         # copy preserves (pub, power): same hash, same (warm) epoch
-        assert epoch_cache.note_valset(c) == vset.hash()
+        assert epoch_cache.note_valset(c)[:32] == vset.hash()
 
 
 # ---------------------------------------------------------------------------
@@ -312,9 +339,11 @@ class TestDeviceUnpackParity:
         assert np.array_equal(
             np.asarray(limbs), backend._pack_le_limbs(ep.pub_rows)
         )
-        # identity pad rows: limb0 = 1, rest 0, sign 0
+        # the pad lane's identity row: limb0 = 1, rest 0, sign 0; the
+        # free rows before it hold y = 2, which is no point
         pad = np.asarray(limbs)[ep.n_vals:]
-        assert (pad[:, 0] == 1).all() and (pad[:, 1:] == 0).all()
+        assert (pad[:-1, 0] == 2).all() and pad[-1, 0] == 1
+        assert (pad[:, 1:] == 0).all()
 
 
 # ---------------------------------------------------------------------------
@@ -476,36 +505,46 @@ class TestChurnLifecycle:
             validation.verify_commit(CHAIN_ID, vset, bid, h, dec)
 
         h0, m0, e0 = deltas()
-        key_a = vset.hash()
-        epoch_a = vset.copy()  # pre-rotation snapshot: same hash/key
+        epoch_a = vset.copy()  # pre-rotation snapshot: same hash
         verify(7)  # cold: registers epoch A
         h1, m1, e1 = deltas()
         assert (m1 - m0, e1 - e0) == (1, 0)
         verify(8)  # warm: hits epoch A
         h2, m2, _ = deltas()
         assert h2 - h1 >= 1 and m2 == m1
+        key_a = epoch_cache.note_valset(epoch_a)
+        assert key_a[:32] == epoch_a.hash()
 
-        _rotate(vset, by_pub, 200)  # epoch B: structural invalidation
-        assert vset.hash() != key_a
-        verify(9)   # cold under the NEW key (depth 2: A + B resident)
-        verify(10)  # warm B
-        _, m3, e3 = deltas()
-        assert m3 - m2 == 1 and e3 - e1 == 0
-        assert len(epoch_cache.cache()) == 2
+        _rotate(vset, by_pub, 200)  # epoch B: one key joins, one leaves
+        assert vset.hash() != epoch_a.hash()
+        verify(9)   # a NEW set hash (miss) that maps onto A's table
+        verify(10)  # seen: a hit
+        h3, m3, e3 = deltas()
+        assert m3 - m2 == 1 and h3 - h2 >= 1 and e3 - e1 == 0
+        assert len(epoch_cache.cache()) == 1
+        assert epoch_cache.cache().get(key_a).n_rows == 91
 
-        _rotate(vset, by_pub, 201)  # epoch C: LRU depth 2 evicts A
-        verify(11)
+        # two committees that share no key with A: depth 2 evicts A
+        for first in (100, 200):
+            other, _ = _vset_with_sks(8, first_byte=first)
+            assert epoch_cache.note_valset(other) is None
         _, m4, e4 = deltas()
-        assert m4 - m3 == 1 and e4 - e3 == 1
+        assert m4 - m3 == 2 and e4 - e3 == 1
         assert epoch_cache.cache().get(key_a) is None  # A really evicted
 
-        # re-register: the SAME membership (content-derived hash == key_a)
-        # returning after eviction is a fresh cold registration, then warm
-        assert epoch_a.hash() == key_a
+        # re-register: the SAME membership returning after eviction is a
+        # fresh cold registration, then warm — in ANOTHER table, under
+        # another name: what the old name's later rows held followed from
+        # the sets mapped onto it, not from the set that built it
         assert epoch_cache.note_valset(epoch_a) is None       # cold again
-        assert epoch_cache.note_valset(epoch_a) == key_a      # warm again
+        key_a2 = epoch_cache.note_valset(epoch_a)             # warm again
+        assert key_a2[:32] == key_a[:32] and key_a2 != key_a
+        assert epoch_cache.cache().get(key_a) is None
         _, m5, _ = deltas()
         assert m5 - m4 == 1
+        # and B, whose mapping went with the table, maps onto the new one
+        verify(11)
+        assert epoch_cache.cache().get(key_a2).n_rows == 91
 
     def test_evicted_epoch_verdict_and_blame_bit_identical(self):
         """The satellite's parity leg: a commit verified WARM (cached
@@ -569,9 +608,10 @@ class TestShardedCached:
         # to the cached variant (replicated table, per-shard gather)
         key = b"E" * 32
         epoch_cache.cache().note(key, blk.pub.copy())
-        assert epoch_cache.cache().note(key, blk.pub.copy()) is not None
+        got = epoch_cache.cache().note(key, blk.pub.copy())
+        assert got is not None
         blk.val_idx = np.arange(n, dtype=np.int32)
-        blk.epoch_key = key
+        blk.epoch_key = got[0].key
         assert epoch_cache.lookup(blk) is not None
         v_c, t_c, a_c = sharded.verify_commit_sharded(
             blk, powers, mesh, bucket=n

@@ -579,13 +579,18 @@ class TestSimnetE2E:
             want = want_forged if i % 2 == 0 else want_conf
             assert (r["error_type"], r["error"]) == want
         # the fleet amortized: far fewer unique verifications than
-        # requests, across MULTIPLE epoch groups (the rotation's work)
+        # requests, across the rotation's several validator sets
         assert stats["requests"] == len(honest) + len(bad)
         assert stats["unique"] < stats["requests"] // 4
         assert stats["memo_hits"] + stats["inflight_joins"] > 0
         plans = [prepare_request(req_for(1 + k % 3, tip - k % 2), now)
                  for k in range(6)]
-        assert len(group_stats(plans)) >= 2, "expected multiple epochs"
+        # the rotation's validator sets differ by a key or two: they map
+        # onto ONE device table (ops/epoch_cache.py), so their stages fuse
+        # where they used to form a group a set
+        assert len(group_stats(plans)) >= 1
+        assert _epoch.stats()["tables_shared"] >= 1, \
+            "the rotation's sets share no table"
 
         # merged flight recorder: cluster doc + service doc share flow
         # namespaces; every unique verification's chain is COMPLETE

@@ -186,11 +186,13 @@ class TestMeshParity:
             ).reshape(48, 32)
             c = epoch_cache.cache()
             assert c.note(b"mesh-warm", pub_col) is None  # cold register
-            assert c.note(b"mesh-warm", pub_col) is not None  # warm
+            warm = c.note(b"mesh-warm", pub_col)  # warm
+            assert warm is not None
+            name = warm[0].key      # the table's own name
 
             def jb(lo, hi, tag):
                 blk = EntryBlock.from_entries(entries[lo:hi])
-                blk.epoch_key = b"mesh-warm"
+                blk.epoch_key = name
                 blk.val_idx = np.arange(lo, hi, dtype=np.int32)
                 return _J(blk)
 
@@ -199,7 +201,7 @@ class TestMeshParity:
             # gather from the same table rows)
             assert not held and len(plan.lanes) == 1
             block, _ = ms.build_superblock(plan)
-            assert block.epoch_key == b"mesh-warm"
+            assert block.epoch_key == name
             res = ms.prepare_superbatch(block, plan)
             args = res[1]
             # cached arg shape: the warm args are (idx, r, s, k, s_ok)

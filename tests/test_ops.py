@@ -302,6 +302,8 @@ def _choice_block(scheme, warm):
             blk.epoch_key = key
     if warm:
         epoch_cache.cache().note(key, pub, scheme)
+        if scheme == "ed25519":    # an ed25519 table has a name of its own
+            blk.epoch_key = epoch_cache.cache().note(key, pub)[0].key
     return blk
 
 
@@ -429,7 +431,7 @@ def _forged_batch(n, forged, warm):
         key = b"\x32" * 32
         epoch_cache.cache().note(key, blk.pub.copy())
         blk.val_idx = np.arange(n, dtype=np.int32)
-        blk.epoch_key = key
+        blk.epoch_key = epoch_cache.cache().note(key, blk.pub.copy())[0].key
     return blk
 
 

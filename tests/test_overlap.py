@@ -375,7 +375,8 @@ class TestOneTransferPerWarmRlcLaunch:
         ep, warm = _warm_epoch(n, n)
         # registered, then seen again: the epoch is warm for lookup()
         for _ in range(2):
-            epoch_cache.cache().note(ep.key, ep.pub_rows[:n].copy())
+            got = epoch_cache.cache().note(ep.key, ep.pub_rows[:n].copy())
+        warm.epoch_key = got[0].key     # the resident table's own name
         cold = EntryBlock.from_entries(warm.to_entries())
         bucket, g, _block, m = pr.plan_bucket(n)
 

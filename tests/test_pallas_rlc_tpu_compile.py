@@ -58,3 +58,52 @@ def test_cached_pipeline_compiles_for_v5e(one_chip, n, vp):
     text = compiled.as_text()
     assert text.count("tpu_custom_call") >= 3  # K1, K2, K3 are kernels
     assert f"rlc_verify_cached_g{g}_m{m}_b128_vp{vp}" in text
+
+
+@pytest.mark.time_limit(420)
+def test_table_patch_compiles_for_v5e_and_leaves_its_arguments(one_chip):
+    """The program that places keys appended to a resident table
+    (ops/epoch_cache.py, the churning chain's every request): a 128-row
+    table and ONE packed buffer of MIN_PATCH_ROWS rows to scatter. No
+    argument is donated, so a launch in flight keeps the table value it
+    was given."""
+    import jax
+    import jax.numpy as jnp
+
+    from tendermint_tpu.ops import epoch_cache as ec
+
+    vp, k = 128, ec.MIN_PATCH_ROWS
+
+    def arg(shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+
+    compiled = ec._coords_patch_fn().lower(
+        arg((4 * 32, vp)), arg((1, vp)), arg((k * (4 * 32 + 2),))).compile()
+    text = compiled.as_text()
+    assert "epoch_coords_patch" in text
+    assert "input_output_alias" not in text.split("ENTRY")[0], \
+        "the patch donates a table"
+    assert "while" not in text, "a patch decompresses nothing on the device"
+    out_coords, out_ok = compiled.out_info
+    assert out_coords.shape == (4 * 32, vp) and out_ok.shape == (1, vp)
+
+
+@pytest.mark.time_limit(420)
+def test_churn_cells_cached_pipeline_compiles_for_v5e(one_chip):
+    """67 signatures of a 100-validator set: bucket 128 at m=2, 64 lanes
+    in one block, over a 128-column table."""
+    import jax
+    import jax.numpy as jnp
+
+    from tendermint_tpu.ops import pallas_rlc as pr
+
+    bucket, g, block, m = pr.plan_bucket(67)
+    assert (bucket, g, block, m) == (128, 64, 64, 2)
+
+    def arg(shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+
+    compiled = pr._jitted_rlc_verify_cached(m, g, block, 128, False).lower(
+        arg((4 * 32, 128)), arg((1, 128)),
+        arg((pr.packed_layout(bucket, m)[-1],))).compile()
+    assert "rlc_verify_cached_g64_m2_b64_vp128" in compiled.as_text()

@@ -248,9 +248,10 @@ class TestValsetRotation:
 
     def test_epoch_cache_cycles_cold_warm_evict_under_churn(self):
         """Rotation drives the device epoch cache through its whole
-        lifecycle: every distinct valset cold-registers (miss), warm
-        re-verifies hit, and an LRU depth below the epoch count forces
-        evictions — asserted live by the harness invariants."""
+        lifecycle: every distinct valset is a miss once, warm
+        re-verifies hit, and a rotated set maps onto the resident table
+        instead of building (and evicting) one of its own — asserted
+        live by the harness invariants."""
         from tendermint_tpu.ops import epoch_cache
 
         epoch_cache.reset(depth=2)
@@ -266,10 +267,13 @@ class TestValsetRotation:
             assert rep.ok, rep.reason  # includes the epoch-cache invariants
             ec = rep.epoch_cache
             assert ec["enabled"] and ec["depth"] == 2
-            # genesis + 3 rotations = 4 distinct epochs
+            # genesis + 3 rotations = 4 distinct validator sets, each a
+            # miss once; a rotation swaps one key of four, so the sets map
+            # onto the table the first one built and nothing is evicted
             assert ec["misses"] >= 4
             assert ec["hits"] > 0
-            assert ec["evictions"] >= 2
+            assert ec["tables_shared"] >= 3
+            assert ec["evictions"] >= ec["tables_built"] - ec["depth"]
         finally:
             epoch_cache.reset()
 

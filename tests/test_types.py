@@ -349,3 +349,118 @@ class TestVerifyCommit:
         assert cs.for_block()
         back = vote_from_commit_sig(cs, v.block_id, 5, 0, v.validator_index)
         assert back.sign_bytes(CHAIN_ID) == v.sign_bytes(CHAIN_ID)
+
+
+# ---------------------------------------------------------------------------
+# PR 32: a validator set and a commit arrive as wire bytes inside every
+# light-client request — what decode gives is what the encoder was given,
+# and validate_basic walks decoded signatures as it walks objects.
+# ---------------------------------------------------------------------------
+
+
+def _wire_valset(n=7, powers=None, priorities=None):
+    from tendermint_tpu.crypto import ed25519
+    from tendermint_tpu.types.validator_set import Validator, ValidatorSet
+
+    vals = []
+    for i in range(n):
+        pk = ed25519.gen_priv_key(bytes([40 + i]) * 32).pub_key()
+        vals.append(Validator(pk.address(), pk,
+                              (powers or [100] * n)[i],
+                              (priorities or [0] * n)[i]))
+    return ValidatorSet(validators=vals, proposer=vals[0])
+
+
+class TestValidatorSetFromWire:
+    @pytest.mark.parametrize("powers,priorities", [
+        (None, None),
+        ([1, 2 ** 40, 7, 100, 100, 3, 9], [5, -5, 0, -(2 ** 50), 1, 2, 3]),
+    ], ids=["equal", "wide"])
+    def test_decode_gives_the_set_its_hash_and_its_columns(self, powers,
+                                                           priorities):
+        import numpy as np
+
+        from tendermint_tpu.crypto import merkle
+        from tendermint_tpu.types.validator_set import ValidatorSet
+
+        src = _wire_valset(powers=powers, priorities=priorities)
+        got = ValidatorSet.decode(src.encode())
+        assert got.validators == src.validators
+        assert got.encode() == src.encode()
+        assert got.hash() == merkle.hash_from_byte_slices(
+            [v.bytes() for v in src.validators])
+        want = src.ed25519_columns()
+        cols = got.ed25519_columns()
+        assert np.array_equal(cols[0], want[0]) and \
+            np.array_equal(cols[1], want[1])
+
+    @pytest.mark.parametrize("mangle", [
+        lambda r: r[:57],                              # a short key
+        lambda r: r[:30],                              # cut inside the key
+    ], ids=["short_key", "truncated"])
+    def test_a_mangled_validator_record_is_refused(self, mangle):
+        from tendermint_tpu.types.validator_set import Validator
+
+        raw = mangle(_wire_valset(1).validators[0].encode())
+        with pytest.raises(ValueError):
+            Validator.decode(raw)
+
+    def test_a_changed_set_drops_the_decoded_columns(self):
+        from tendermint_tpu.crypto import ed25519
+        from tendermint_tpu.types.validator_set import Validator, ValidatorSet
+
+        got = ValidatorSet.decode(_wire_valset().encode())
+        before = got.hash()
+        pk = ed25519.gen_priv_key(b"\x66" * 32).pub_key()
+        got.update_with_change_set([Validator.new(pk, 5)])
+        assert got._ed_cols is None and got.hash() != before
+        assert got.ed25519_columns()[0].shape == (8, 32)
+
+
+class TestCommitValidateBasicFromWire:
+    def _commit(self, n=5):
+        from tendermint_tpu.types.block import (
+            BLOCK_ID_FLAG_COMMIT, BlockID, Commit, CommitSig, PartSetHeader,
+        )
+        from tendermint_tpu.wire.canonical import Timestamp
+
+        bid = BlockID(hash=b"\x11" * 32,
+                      part_set_header=PartSetHeader(total=1, hash=b"\x22" * 32))
+        sigs = [CommitSig.absent()] + [
+            CommitSig(block_id_flag=BLOCK_ID_FLAG_COMMIT,
+                      validator_address=bytes([i]) * 20,
+                      timestamp=Timestamp(seconds=1_700_000_000 + i),
+                      signature=bytes([i]) * 64) for i in range(1, n)]
+        return Commit(height=3, round=0, block_id=bid, signatures=sigs)
+
+    def test_decoded_columns_pass_as_the_objects_do(self):
+        from tendermint_tpu.types.block import Commit, CommitSigs
+
+        dec = Commit.decode(self._commit().encode())
+        assert isinstance(dec.signatures, CommitSigs)
+        dec.validate_basic()
+        self._commit().validate_basic()
+        assert list(dec.signatures) == self._commit().signatures
+
+    def test_a_tampered_view_is_walked_again(self):
+        from dataclasses import replace
+
+        from tendermint_tpu.types.block import Commit
+
+        dec = Commit.decode(self._commit().encode())
+        dec.signatures[2] = replace(dec.signatures[2], signature=b"")
+        with pytest.raises(ValueError, match="wrong CommitSig #2: signature is missing"):
+            dec.validate_basic()
+
+    def test_objects_and_odd_records_are_walked(self):
+        from dataclasses import replace
+
+        from tendermint_tpu.types.block import Commit
+
+        c = self._commit()
+        c.signatures[1] = replace(c.signatures[1], validator_address=b"\x01" * 19)
+        with pytest.raises(ValueError, match="expected ValidatorAddress size"):
+            c.validate_basic()
+        # off the canonical shape the decode keeps objects, and the walk
+        with pytest.raises(ValueError, match="expected ValidatorAddress size"):
+            Commit.decode(c.encode()).validate_basic()

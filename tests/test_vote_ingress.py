@@ -521,3 +521,88 @@ class TestSimnetIngress:
         assert fp1 == fp2
         assert b1 == b2
         assert b1 > 0, "votes never windowed through the accumulator"
+
+
+class TestWindowsOnASharedTable:
+    """PR 32: a validator set that maps onto another set's device table
+    (ops/epoch_cache.py) attaches the TABLE's rows, and two sets of one
+    table at one height never share a window."""
+
+    def test_a_mapped_sets_window_carries_the_tables_rows(self):
+        from tendermint_tpu.ops.entry_block import EntryBlock
+        from tendermint_tpu.types.validator_set import Validator, ValidatorSet
+
+        _epoch.reset(8)
+        try:
+            sks, vset = make_validators(6)
+            joiner = make_validators(7)[0][6]
+            later = ValidatorSet.new(
+                [Validator.new(sk.pub_key(), 10) for sk in sks[1:] + [joiner]])
+            acc = vi.VoteIngress(_Collector(), stepped=True)
+            try:
+                assert acc._window_key(HEIGHT, vset) == (HEIGHT, id(vset))  # cold
+                k_old = acc._window_key(HEIGHT + 1, vset)
+                k_new = acc._window_key(HEIGHT + 1, later)
+            finally:
+                acc.close()
+            assert k_old[1] == k_new[1], "one table"
+            assert k_old[1][:32] == vset.hash()
+            assert k_old != k_new and hash(k_old) != hash(k_new)
+            table = _epoch.cache().get(k_old[1])
+            assert table.n_rows == 7
+            cols = later.ed25519_columns()[0]
+            assert (table.pub_rows[k_new[2].rows] == cols).all()
+            # a window of the later set's validators 0, 3, 5
+            bid = make_block_id()
+            by_addr = {sk.pub_key().address(): sk for sk in sks + [joiner]}
+            batch = []
+            for i in (0, 3, 5):
+                sk = by_addr[later.validators[i].address]
+                batch.append(_pend(sign_vote(sk, later, PREVOTE_TYPE,
+                                             HEIGHT + 1, 0, bid), sk))
+            blk = EntryBlock.from_entries(
+                [(p.pub, p.msg, p.vote.signature) for p in batch])
+            vi.VoteIngress._attach(blk, k_new, batch)
+            assert blk.epoch_key == k_old[1]
+            assert (table.pub_rows[blk.val_idx] == blk.pub).all()
+            assert list(blk.val_idx) != [0, 3, 5], "not the set's own rows"
+        finally:
+            _epoch.reset()
+
+    def test_a_windows_cached_name_goes_stale_with_its_table(self):
+        """The window key is cached for the height; when the table is
+        evicted and built again meanwhile, the cached (name, rows) must
+        lead nowhere: the window's block rides the uncached path."""
+        from tendermint_tpu.ops.entry_block import EntryBlock
+        from tendermint_tpu.types.validator_set import Validator, ValidatorSet
+
+        _epoch.reset(1)
+        try:
+            sks, vset = make_validators(6)
+            joiner = make_validators(7)[0][6]
+            later = ValidatorSet.new(
+                [Validator.new(sk.pub_key(), 10) for sk in sks[1:] + [joiner]])
+            _epoch.note_valset(vset)
+            acc = vi.VoteIngress(_Collector(), stepped=True)
+            try:
+                key = acc._window_key(HEIGHT, later)      # mapped: row 6
+                assert int(key[2].rows.max()) == 6
+                stranger = ValidatorSet.new([Validator.new(
+                    ed.gen_priv_key(bytes([200 + i]) * 32).pub_key(), 100)
+                    for i in range(6)])
+                assert _epoch.note_valset(stranger) is None   # depth 1: evicts
+                assert _epoch.note_valset(vset) is None       # built again
+                assert _epoch.note_valset(vset) != key[1]
+                assert acc._window_key(HEIGHT, later) == key, "cached"
+            finally:
+                acc.close()
+            bid = make_block_id()
+            batch = [_pend(sign_vote(joiner, later, PREVOTE_TYPE, HEIGHT, 0,
+                                     bid), joiner)]
+            blk = EntryBlock.from_entries(
+                [(p.pub, p.msg, p.vote.signature) for p in batch])
+            vi.VoteIngress._attach(blk, key, batch)
+            assert blk.epoch_key == key[1] and list(blk.val_idx) == [6]
+            assert _epoch.lookup(blk) is None
+        finally:
+            _epoch.reset()
