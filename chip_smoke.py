@@ -26,7 +26,8 @@ commit; a 128-validator header chain):
                 through light.verifier.verify_adjacent: tables built,
                 sets mapped onto a resident table, rows patched and the
                 kernels launched are printed and held to what one key a
-                height into a 128-row table implies
+                height into a 128-row table implies; every set must have
+                been decoded by the native pass
   7. accounts   sigs_verified{device} rose by exactly what stages 2-6
                 submitted; host / fallback / dispatch-error counters
                 moved only by what the smoke states
@@ -411,6 +412,8 @@ def counters() -> dict:
         "epoch_tables_built": s["epoch_tables_built"],
         "epoch_tables_shared": s["epoch_tables_shared"],
         "epoch_rows_patched": s["epoch_rows_patched"],
+        "valset_decode_native": s["valset_decode_native"],
+        "valset_decode_python": s["valset_decode_python"],
     }
 
 
@@ -653,7 +656,8 @@ def stage_churn(led: Ledger, wires) -> dict:
     c1 = counters()
     rise = {k: c1[k] - c0[k] for k in (
         "epoch_cache_hits", "epoch_cache_misses", "epoch_tables_built",
-        "epoch_tables_shared", "epoch_rows_patched")}
+        "epoch_tables_shared", "epoch_rows_patched",
+        "valset_decode_native", "valset_decode_python")}
     launched = {m: n - c0["rlc_launches_by_width"].get(m, 0)
                 for m, n in c1["rlc_launches_by_width"].items()
                 if n != c0["rlc_launches_by_width"].get(m, 0)}
@@ -670,6 +674,12 @@ def stage_churn(led: Ledger, wires) -> dict:
           and rise["epoch_rows_patched"] == steps - built,
           f"{steps} steps of one-key churn imply {built} tables built and "
           f"{steps - built} sets mapped, one row each: {rise}")
+    # a build that lost valset_decode_columns must fail here, not read as
+    # "no gain" in the benchmark
+    check(rise["valset_decode_native"] == len(wires)
+          and rise["valset_decode_python"] == 0,
+          f"{len(wires)} all-ed25519 sets decoded from wire bytes must all "
+          f"take the native pass: {rise}")
     return dict(rise, steps=steps, rlc_launches_by_width=launched)
 
 

@@ -4,8 +4,12 @@
 // native runtime seam around it (SURVEY.md §2: the batch verification
 // engine's host half): the per-batch packing that turns 10k signature
 // triples into kernel input arrays, and RFC-6962 merkle hashing for part
-// sets / block data. CPython C API (no pybind11 in this image), built by
-// native/build.py via setuptools.
+// sets / block data. The wire decodes that sit on a request's path have a
+// single-pass, GIL-released fast path here too, each answering None where
+// the Python walk that specifies it must decide: commit_decode_columns
+// (Commit.decode) and valset_decode_columns (ValidatorSet.decode).
+// CPython C API (no pybind11 in this image), built by g++ on the first
+// tendermint_tpu.native.load().
 
 #define PY_SSIZE_T_CLEAN
 #include <Python.h>
@@ -2583,6 +2587,143 @@ static PyObject *py_commit_decode_columns(PyObject *, PyObject *arg) {
   return tup;
 }
 
+// --------------------------------------------------------------------------
+// valset_decode_columns(data: bytes)
+//   -> None
+//   -> (n, addr (n*20), pub (n*32), power (n*8 LE i64),
+//       priority (n*8 LE i64), proposer_addr (20), proposer_pub (32),
+//       proposer_power, proposer_priority)
+//
+// The wire bytes of a ValidatorSet (proto fields 1 repeated Validator,
+// 2 proposer, 3 total_voting_power — parsed and dropped: the set's total
+// is recomputed, never trusted) into columns, in one GIL-released walk.
+// The fast path of types/validator_set.py ValidatorSet.decode, whose
+// Python walk (decode_message + Validator.decode + pubkey_from_proto) is
+// the specification, as Commit.decode's is for commit_decode_columns: a
+// strict subset of what that walk decodes WITHOUT raising, the same
+// values for it, None for everything else. Taken:
+//   outer      one-byte tags only: field 1 (bytes) once or more, field 2
+//              (bytes) exactly once, field 3 (varint) at most once, in any
+//              order;
+//   validator  fields 1 address (bytes, exactly 20), 2 pub_key (bytes),
+//              3 voting_power, 4 proposer_priority (varints) at most once
+//              each in any order; address and key present; power >= 0;
+//   pub_key    exactly one field: 1 ed25519 (bytes, exactly 32) — any
+//              other member of the PublicKey oneof keeps the walk;
+//   varint     commitdec's: at most 10 bytes holding at most 64 bits,
+//              read as int64 like wire/proto.to_signed64.
+// No state is kept between calls.
+namespace valsetdec {
+
+using commitdec::delimited;
+using commitdec::uvarint;
+
+static bool parse_validator(const uint8_t *p, const uint8_t *end,
+                            uint8_t *addr, uint8_t *pub, int64_t *power,
+                            int64_t *priority) {
+  const uint8_t *a = nullptr, *k = nullptr;
+  size_t alen = 0, klen = 0;
+  uint64_t pw = 0, pr = 0;
+  unsigned seen = 0;
+  while (p < end) {
+    uint8_t tag = *p++;
+    unsigned bit;
+    bool ok;
+    switch (tag) {
+      case 0x0a: bit = 1; ok = delimited(p, end, a, alen); break;
+      case 0x12: bit = 2; ok = delimited(p, end, k, klen); break;
+      case 0x18: bit = 4; ok = uvarint(p, end, pw); break;
+      case 0x20: bit = 8; ok = uvarint(p, end, pr); break;
+      default: return false;
+    }
+    if (!ok || (seen & bit)) return false;
+    seen |= bit;
+  }
+  // PublicKey{1: ed25519}: tag, length 32, the key — and nothing else
+  if (alen != 20 || klen != 34 || k[0] != 0x0a || k[1] != 32) return false;
+  if ((int64_t)pw < 0) return false;
+  memcpy(addr, a, 20);
+  memcpy(pub, k + 2, 32);
+  *power = (int64_t)pw;
+  *priority = (int64_t)pr;
+  return true;
+}
+
+// one walk of the outer message. The first (`cols` null) checks the outer
+// shape and counts the validators into `n`; the second is given that `n`
+// and a block of n * 68 bytes, and parses every validator and the proposer
+static bool walk(const uint8_t *p, const uint8_t *end, size_t &n,
+                 uint8_t *cols, uint8_t *prop, int64_t *prop_nums) {
+  size_t total = n, i = 0;
+  unsigned seen = 0;
+  const uint8_t *body;
+  size_t len;
+  uint64_t v;
+  while (p < end) {
+    uint8_t tag = *p++;
+    if (tag == 0x0a) {
+      if (!delimited(p, end, body, len)) return false;
+      if (cols &&
+          !parse_validator(body, body + len, cols + 48 * total + 20 * i,
+                           cols + 16 * total + 32 * i, (int64_t *)cols + i,
+                           (int64_t *)cols + total + i))
+        return false;
+      i++;
+    } else if (tag == 0x12 && !(seen & 1)) {
+      seen |= 1;
+      if (!delimited(p, end, body, len)) return false;
+      if (cols && !parse_validator(body, body + len, prop, prop + 20,
+                                   prop_nums, prop_nums + 1))
+        return false;
+    } else if (tag == 0x18 && !(seen & 2)) {
+      seen |= 2;
+      if (!uvarint(p, end, v)) return false;
+    } else {
+      return false;
+    }
+  }
+  n = i;
+  return i > 0 && (seen & 1);
+}
+
+}  // namespace valsetdec
+
+static PyObject *py_valset_decode_columns(PyObject *, PyObject *arg) {
+  // bytes only, as commit_decode_columns: the walk's addresses are slices
+  // of its input, of the input's own type
+  if (!PyBytes_Check(arg)) Py_RETURN_NONE;
+  const uint8_t *data = (const uint8_t *)PyBytes_AS_STRING(arg);
+  const uint8_t *end = data + PyBytes_GET_SIZE(arg);
+  // columns in one block: power | priority | pub | addr, so the 8-byte
+  // lanes are aligned; copied out under the GIL below
+  uint8_t *cols = nullptr;
+  uint8_t prop[52];
+  int64_t prop_nums[2] = {0, 0};
+  size_t n = 0;
+  bool ok;
+  Py_BEGIN_ALLOW_THREADS
+  ok = valsetdec::walk(data, end, n, nullptr, nullptr, nullptr);
+  if (ok) {
+    cols = (uint8_t *)malloc(n * 68);
+    ok = cols != nullptr &&
+         valsetdec::walk(data, end, n, cols, prop, prop_nums);
+  }
+  Py_END_ALLOW_THREADS
+  if (!ok) {
+    free(cols);
+    Py_RETURN_NONE;
+  }
+  Py_ssize_t k = (Py_ssize_t)n;
+  const char *c = (const char *)cols;
+  PyObject *tup = Py_BuildValue(
+      "(ny#y#y#y#y#y#LL)", k, c + k * 48, k * 20, c + k * 16, k * 32, c,
+      k * 8, c + k * 8, k * 8, (const char *)prop, (Py_ssize_t)20,
+      (const char *)prop + 20, (Py_ssize_t)32, (long long)prop_nums[0],
+      (long long)prop_nums[1]);
+  free(cols);
+  return tup;
+}
+
 static PyMethodDef Methods[] = {
     {"commit_prep_fused", py_commit_prep_fused, METH_VARARGS,
      "Fused columnar commit prep: selection + tally + sign-bytes + "
@@ -2618,6 +2759,9 @@ static PyMethodDef Methods[] = {
     {"commit_decode_columns", py_commit_decode_columns, METH_O,
      "Commit wire bytes -> CommitBlock columns in one GIL-released walk; "
      "None for any input off the canonical shape"},
+    {"valset_decode_columns", py_valset_decode_columns, METH_O,
+     "ValidatorSet wire bytes -> address/key/power/priority columns in one "
+     "GIL-released walk; None for any input off the canonical shape"},
     {nullptr, nullptr, 0, nullptr}};
 
 static struct PyModuleDef moduledef = {PyModuleDef_HEAD_INIT, "tm_native",

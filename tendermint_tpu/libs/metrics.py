@@ -714,6 +714,13 @@ class OpsMetrics:
             "ops", "commit_decodes_total",
             "Commits decoded from wire bytes, by path label (native|python).",
         )
+        # the same for a validator set (types/validator_set.py
+        # ValidatorSet.decode; native = valset_decode_columns)
+        self.valset_decodes = registry.counter(
+            "ops", "valset_decodes_total",
+            "Validator sets decoded from wire bytes, by path label "
+            "(native|python).",
+        )
         self.h2d_bytes_per_commit = registry.gauge(
             "ops", "h2d_bytes_per_commit",
             "Host bytes shipped to the device by the last dispatched "
@@ -979,6 +986,8 @@ def ops_stats() -> dict:
         "epoch_tables_built": int(m.epoch_tables_built.total()),
         "commit_decode_native": int(m.commit_decodes.value(path="native")),
         "commit_decode_python": int(m.commit_decodes.value(path="python")),
+        "valset_decode_native": int(m.valset_decodes.value(path="native")),
+        "valset_decode_python": int(m.valset_decodes.value(path="python")),
         "h2d_bytes_per_commit": float(m.h2d_bytes_per_commit.value()),
         "h2d_ops": int(m.h2d_ops.total()),
         "transfer_overlap_ratio": float(m.transfer_overlap_ratio.value()),

@@ -94,3 +94,13 @@ def load():
             return None
         _module = mod
         return _module
+
+
+def columns(entry: str, data):
+    """`entry(data)` of the module — one of the single-pass wire parses
+    (commit_decode_columns, valset_decode_columns): its column tuple, or
+    None where the parse answers None, the module is absent, or the module
+    was built before `entry` existed (native/_build is not tracked: a stale
+    shared object reads as absent, never as an error)."""
+    fn = getattr(load(), entry, None)
+    return fn(data) if fn is not None else None

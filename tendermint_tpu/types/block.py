@@ -612,12 +612,9 @@ def _native_commit_columns(data):
     input is off the canonical shape. Counts the commit under the path
     that decodes it."""
     global _OPS
-    from ..native import load as _load_native
+    from .. import native as _native
 
-    native = _load_native()
-    cols = None
-    if native is not None and hasattr(native, "commit_decode_columns"):
-        cols = native.commit_decode_columns(data)
+    cols = _native.columns("commit_decode_columns", data)
     if _OPS is None:
         from ..libs import metrics as _metrics
 

@@ -568,13 +568,80 @@ class ValidatorSet:
 
     @classmethod
     def decode(cls, data: bytes) -> "ValidatorSet":
-        f = decode_message(data)
-        vals = [Validator.decode(raw) for raw in field_repeated_bytes(f, 1)]
-        proposer = Validator.decode(field_bytes(f, 2)) if 2 in f else None
-        vs = cls(validators=vals, proposer=proposer)
+        """Two paths, one result. The FAST path is native/tm_native.cpp
+        valset_decode_columns: the whole message walked once with the GIL
+        released, every validator parsed and shape-checked on every call,
+        nothing kept between calls; the Validator objects are then built
+        from its columns, which also become the set's ed25519_columns().
+        It answers None for any input off the canonical all-ed25519 shape
+        (and is absent under TM_TPU_NO_NATIVE or a failed build); then the
+        Python walk below runs — the SPECIFICATION, which alone decides
+        tolerance and raises every exception of the wire. Either way the
+        power total and validate_basic() run here, on the objects.
+        ops_stats() counts the sets each path decoded
+        (valset_decode_native / valset_decode_python)."""
+        cols = _native_valset_columns(data)
+        if cols is not None:
+            vs = _valset_from_columns(cls, *cols)
+        else:
+            f = decode_message(data)
+            vals = [Validator.decode(raw) for raw in field_repeated_bytes(f, 1)]
+            proposer = Validator.decode(field_bytes(f, 2)) if 2 in f else None
+            vs = cls(validators=vals, proposer=proposer)
         vs.total_voting_power()  # recompute, never trust the wire
         vs.validate_basic()
         return vs
+
+
+_OPS = None
+
+
+def _native_valset_columns(data):
+    """The native parse of a ValidatorSet's wire bytes (decode's fast
+    path): its column tuple, or None where the module is absent or the
+    input is off the canonical shape. Counts the set under the path that
+    decodes it."""
+    global _OPS
+    from .. import native as _native
+
+    cols = _native.columns("valset_decode_columns", data)
+    if _OPS is None:
+        from ..libs import metrics as _metrics
+
+        _OPS = _metrics.ops_metrics()
+    _OPS.valset_decodes.inc(path="python" if cols is None else "native")
+    return cols
+
+
+def _valset_from_columns(
+    cls, n, addr, pub, power, priority, p_addr, p_pub, p_power, p_priority
+) -> "ValidatorSet":
+    """valset_decode_columns' buffers as the set the Python walk builds:
+    a Validator and an ed25519 PubKey a row, the proposer an object of its
+    own, and (pub, power) kept as the set's ed25519 columns (the arrays
+    ed25519_columns() would gather from the objects; read-only views of
+    the buffers)."""
+    import numpy as np
+
+    from ..crypto.ed25519 import PubKey as _EdPubKey
+
+    power_col = np.frombuffer(power, dtype=np.int64)
+    powers = power_col.tolist()
+    priorities = np.frombuffer(priority, dtype=np.int64).tolist()
+    vs = cls(
+        validators=[
+            Validator(
+                addr[20 * i : 20 * i + 20],
+                _EdPubKey(pub[32 * i : 32 * i + 32]),
+                powers[i],
+                priorities[i],
+            )
+            for i in range(n)
+        ],
+        proposer=Validator(p_addr, _EdPubKey(p_pub), p_power, p_priority),
+    )
+    vs._ed_cols = (np.frombuffer(pub, dtype=np.uint8).reshape(n, 32), power_col)
+    return vs
 
 
 class ErrNotEnoughVotingPowerSigned(ValueError):

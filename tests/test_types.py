@@ -464,3 +464,145 @@ class TestCommitValidateBasicFromWire:
         # off the canonical shape the decode keeps objects, and the walk
         with pytest.raises(ValueError, match="expected ValidatorAddress size"):
             Commit.decode(c.encode()).validate_basic()
+
+
+# ---------------------------------------------------------------------------
+# PR 33: the set inside a light block is decoded by a native pass where the
+# module is there and by the Python walk where it is not — to equal blocks
+# and equal verdicts.
+# ---------------------------------------------------------------------------
+
+_LIGHT_CHAIN = dict(seed=33, n_headers=4, n_vals=8)
+
+_LIGHT_SCRIPT = """
+import json, sys
+import chip_smoke
+from tendermint_tpu import native
+from tendermint_tpu.libs.metrics import ops_stats
+from tendermint_tpu.light import verifier
+from tendermint_tpu.light.provider import LightBlock
+from tendermint_tpu.wire.canonical import Timestamp
+
+wires = chip_smoke.build_churn_chain(**json.loads(sys.argv[1]))
+blocks = [LightBlock.decode(w) for w in wires]
+# the last step twice: as it is, then under the validator set of the height below
+steps = [(k, blocks[k].validators) for k in range(1, len(blocks))]
+steps.append((len(blocks) - 1, blocks[-2].validators))
+now = Timestamp(seconds=chip_smoke.T0 + len(wires) + 1)
+verdicts = []
+for k, vals in steps:
+    try:
+        verifier.verify_adjacent(blocks[k - 1].signed_header,
+                                 blocks[k].signed_header, vals, 3600.0, now, 10.0)
+        verdicts.append(["ok", ""])
+    except Exception as e:
+        verdicts.append([type(e).__name__, str(e)])
+stats = ops_stats()
+print(json.dumps({
+    "module": native.load() is not None,
+    "native": stats["valset_decode_native"],
+    "python": stats["valset_decode_python"],
+    "roundtrip": [b.encode() == w for b, w in zip(blocks, wires)],
+    "valset_hashes": [b.validators.hash().hex() for b in blocks],
+    "verdicts": verdicts,
+}))
+"""
+
+
+class TestLightBlockFromWire:
+    @staticmethod
+    def _wires():
+        import chip_smoke
+
+        return chip_smoke.build_churn_chain(**_LIGHT_CHAIN)
+
+    @staticmethod
+    def _decode(wire, path):
+        from unittest import mock
+
+        from tendermint_tpu import native as native_mod
+        from tendermint_tpu.light.provider import LightBlock
+
+        if path == "native":
+            return LightBlock.decode(wire)
+        with mock.patch.object(native_mod, "load", lambda: None):
+            return LightBlock.decode(wire)
+
+    @pytest.mark.parametrize("path", ["native", "walk"])
+    def test_decode_of_encode_is_the_block(self, path):
+        for wire in self._wires():
+            lb = self._decode(wire, path)
+            assert lb.encode() == wire
+            assert lb.validators.hash() == lb.signed_header.header.validators_hash
+            again = self._decode(lb.encode(), path)
+            assert again.signed_header == lb.signed_header
+            assert again.validators.validators == lb.validators.validators
+            assert again.validators.proposer == lb.validators.proposer
+
+    @pytest.mark.native_required
+    def test_both_paths_decode_equal_blocks(self):
+        from tendermint_tpu.libs.metrics import ops_stats
+
+        for wire in self._wires():
+            before = ops_stats()
+            a, b = self._decode(wire, "native"), self._decode(wire, "walk")
+            after = ops_stats()
+            assert [after[k] - before[k] for k in
+                    ("valset_decode_native", "valset_decode_python")] == [1, 1]
+            assert a.signed_header == b.signed_header
+            assert a.validators.validators == b.validators.validators
+            assert a.validators.proposer == b.validators.proposer
+            assert a.validators.total_voting_power() == \
+                b.validators.total_voting_power()
+            assert a.validators.hash() == b.validators.hash()
+            assert a.encode() == b.encode() == wire
+
+    @pytest.mark.parametrize("no_native", [False, True],
+                             ids=["native", "TM_TPU_NO_NATIVE"])
+    def test_a_process_decodes_and_verifies_alike_with_and_without_the_module(
+            self, no_native):
+        import json
+        import os
+        import subprocess
+        import sys
+
+        from tendermint_tpu import native as native_mod
+
+        if not no_native and native_mod.load() is None:
+            pytest.skip("tm_native module not built")
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        env = dict(os.environ, JAX_PLATFORMS="cpu")
+        env.pop("TM_TPU_NO_NATIVE", None)
+        if no_native:
+            env["TM_TPU_NO_NATIVE"] = "1"
+        r = subprocess.run(
+            [sys.executable, "-c", _LIGHT_SCRIPT, json.dumps(_LIGHT_CHAIN)],
+            capture_output=True, env=env, cwd=repo, timeout=240)
+        assert r.returncode == 0, \
+            (r.stderr or b"").decode(errors="replace")[-3000:]
+        out = json.loads(r.stdout.decode().strip().splitlines()[-1])
+        n = _LIGHT_CHAIN["n_headers"]
+        wires = self._wires()
+        assert out == {
+            "module": not no_native,
+            "native": 0 if no_native else n,
+            "python": n if no_native else 0,
+            "roundtrip": [True] * n,
+            "valset_hashes": [
+                self._decode(w, "walk").validators.hash().hex() for w in wires],
+            "verdicts": [["ok", ""]] * (n - 1) + [self._refusal(wires)],
+        }
+
+    def _refusal(self, wires):
+        """What this process says of the last step under the set of the
+        height below: the header's validators_hash refuses it."""
+        import chip_smoke
+        from tendermint_tpu.light import verifier
+
+        lbs = [self._decode(w, "walk") for w in wires[-2:]]
+        now = Timestamp(seconds=chip_smoke.T0 + len(wires) + 1)
+        with pytest.raises(ValueError, match="validators") as e:
+            verifier.verify_adjacent(
+                lbs[0].signed_header, lbs[1].signed_header, lbs[0].validators,
+                3600.0, now, 10.0)
+        return [type(e.value).__name__, str(e.value)]
