@@ -28,10 +28,16 @@ commit; a 128-validator header chain):
                 kernels launched are printed and held to what one key a
                 height into a 128-row table implies; every set must have
                 been decoded by the native pass
-  7. accounts   sigs_verified{device} rose by exactly what stages 2-6
+  7. skipping   one catch-up of a fresh light.Client over 150 heights of
+                such a chain (benchmark/lightchain.py builds it), by
+                skipping at trust level 1/3: hops, refusals, fetches and
+                signatures are held to the plain reference
+                (benchmark/reference_bisect.py); the by-address third of
+                every hop is under the device threshold and is stated
+  8. accounts   sigs_verified{device} rose by exactly what stages 2-7
                 submitted; host / fallback / dispatch-error counters
                 moved only by what the smoke states
-  8. report     per-step wall time (first-use set-up apart from
+  9. report     per-step wall time (first-use set-up apart from
                 repeats), every compile, one JSON line last
 
 Any stage failing fails the run: nothing is caught and skipped, and no
@@ -63,6 +69,7 @@ N_ADJACENT = 24       # /light_verify requests h -> h+1
 N_SKIPPING = 11       # /light_verify requests 0 -> k (two stages each)
 CHURN_VALS = 100      # the light-client sequence benchmark's set
 N_CHURN = 30          # adjacent steps, one key replaced at each
+N_SKIP = 150          # heights the skipping client catches up across
 POWER = 100           # every validator's voting power
 CHAIN_ID = "chip-smoke"
 T0 = 1_600_000_000
@@ -374,6 +381,7 @@ class Ledger:
         self.device = 0
         self.host = 0
         self.forged = 0  # jobs with one forged signature: one lane each
+        self.under_threshold = 0  # batches stated to be verified on the host
         self.notes = []
         self.steps = []  # (stage, step, seconds, first_use)
 
@@ -392,6 +400,11 @@ class Ledger:
         if sigs:
             self.submitted(sigs, forged)
         return out
+
+
+LIGHT_COUNTERS = ("light_hops_verified", "light_hops_refused",
+                  "light_blocks_fetched", "light_trusting_sigs_host",
+                  "light_trusting_sigs_device")
 
 
 def counters() -> dict:
@@ -414,6 +427,7 @@ def counters() -> dict:
         "epoch_rows_patched": s["epoch_rows_patched"],
         "valset_decode_native": s["valset_decode_native"],
         "valset_decode_python": s["valset_decode_python"],
+        **{k: s[k] for k in LIGHT_COUNTERS},
     }
 
 
@@ -683,6 +697,87 @@ def stage_churn(led: Ledger, wires) -> dict:
     return dict(rise, steps=steps, rlc_launches_by_width=launched)
 
 
+def build_skip_chain(seed: int):
+    """(wire bytes of the light block at height h, the chain's records,
+    now, what the plain reference does on a catch-up 1 -> N_SKIP)."""
+    from benchmark import lightchain, reference_bisect
+
+    cfg = {"name": "smoke-skip", "validators": CHURN_VALS,
+           "voting_power": POWER, "chain_id": CHAIN_ID + "-skip",
+           "headers": N_SKIP, "keys_replaced_per_height": 1,
+           "block_interval_s": 60}
+    _keys_of, blocks = lightchain.chain(cfg, seed)
+    now = (lightchain.T0 + 60 * (N_SKIP + 1), 0)
+    want = reference_bisect.catch_up(
+        lambda h: (blocks[h - 1], blocks[h - 1].vals), cfg["chain_id"], 1,
+        blocks[0].block_hash, N_SKIP, 86400, now, 10)
+    return [lightchain.light_block_wire(b) for b in blocks], blocks, now, want
+
+
+def stage_skipping(led: Ledger, wires, blocks, now, want) -> dict:
+    """The light client's default mode, through light.Client: the far
+    header against the trusted set by address (a third, under the device
+    threshold), then against its own (+2/3, on the chip), bisecting."""
+    from tendermint_tpu.db import MemDB
+    from tendermint_tpu.light.client import Client, TrustOptions
+    from tendermint_tpu.light.provider import LightBlock, Provider
+    from tendermint_tpu.light.store import LightStore
+    from tendermint_tpu.types import Fraction
+    from tendermint_tpu.wire.canonical import Timestamp
+
+    class Node(Provider):
+        def light_block(self, height: int):
+            return LightBlock.decode(wires[(height or len(wires)) - 1])
+
+    check(want.error is None and len(want.refused) >= 2,
+          f"the reference on the honest chain: {want}")
+    hops = len(want.trace) - 1
+    third, two_thirds = (early_stop_count(CHURN_VALS, 1, 3),
+                         early_stop_count(CHURN_VALS))
+    check(want.sigs == two_thirds + hops * (third + two_thirds),
+          f"the reference counts {want.sigs} signatures in {hops} hops")
+    at = Timestamp(*now)
+    c0 = counters()
+
+    def catch_up():
+        node = Node()
+        client = Client(
+            blocks[0].header.chain_id,
+            TrustOptions(86400.0, 1, blocks[0].block_hash), node, [node],
+            LightStore(MemDB()), trust_level=Fraction(1, 3),
+            max_clock_drift=10.0, now_fn=lambda: at)
+        return client.verify_light_block_at_height(N_SKIP, at)
+
+    lb = led.run("skipping", f"catch-up 1 -> {N_SKIP}: {want.trace}",
+                 catch_up, sigs=two_thirds * (hops + 1), first_use=True)
+    check(lb.height == N_SKIP and lb.hash() == blocks[-1].block_hash,
+          f"the client verified {lb.height} {lb.hash().hex()}")
+    c1 = counters()
+    rise = {k: c1[k] - c0[k] for k in LIGHT_COUNTERS + (
+        "device", "host", "host_fallback_batches", "epoch_tables_built")}
+    say(f"  {hops} hops, {len(want.refused)} refused: {rise}")
+    check((rise["light_hops_verified"], rise["light_hops_refused"],
+           rise["light_blocks_fetched"]) == (hops, len(want.refused),
+                                             len(want.fetched)),
+          f"the reference makes {hops} hops, {len(want.refused)} refusals "
+          f"and {len(want.fetched)} fetches: {rise}")
+    check(rise["device"] + rise["host"] == want.sigs,
+          f"the reference looks at {want.sigs} signatures: {rise}")
+    where = (rise["light_trusting_sigs_host"],
+             rise["light_trusting_sigs_device"])
+    check(sum(where) == hops * third and rise["host"] == where[0],
+          f"{hops} trusting checks of {third}: {rise}")
+    led.host += where[0]
+    led.device += where[1]
+    led.under_threshold += rise["host_fallback_batches"]
+    check(rise["host_fallback_batches"] * third == where[0],
+          f"batches under the device threshold are trusting thirds: {rise}")
+    led.notes.append(
+        f"{where[0]} signatures of {rise['host_fallback_batches']} by-address "
+        f"trusting checks under the device threshold ({third} each)")
+    return dict(rise, trace=want.trace, refused=len(want.refused))
+
+
 def stage_accounts(led: Ledger, base: dict) -> dict:
     from tendermint_tpu.ops.engine import engine
 
@@ -710,8 +805,13 @@ def stage_accounts(led: Ledger, base: dict) -> dict:
     check(host == led.host,
           f"sigs_verified host rose by {host}, expected {led.host} "
           f"({led.notes})")
-    for key in ("host_fallback_batches", "dispatch_errors"):
-        check(c[key] == base[key], f"{key} moved: {base[key]} -> {c[key]}")
+    check(c["host_fallback_batches"] - base["host_fallback_batches"]
+          == led.under_threshold,
+          f"host_fallback_batches moved {base['host_fallback_batches']} -> "
+          f"{c['host_fallback_batches']}, stated {led.under_threshold}")
+    check(c["dispatch_errors"] == base["dispatch_errors"],
+          f"dispatch_errors moved: {base['dispatch_errors']} -> "
+          f"{c['dispatch_errors']}")
     check(c["ingress_fallbacks"] == base["ingress_fallbacks"],
           f"ingress fallbacks moved: {c['ingress_fallbacks']}")
     for note in led.notes:
@@ -767,6 +867,7 @@ def main() -> None:
     chain = build_header_chain(args.seed, N_ADJACENT + 2, LIGHT_VALS)
     reqs, now = light_requests(chain, rng)
     churn = build_churn_chain(args.seed, N_CHURN + 1, CHURN_VALS)
+    skip = build_skip_chain(args.seed)
 
     def variant(job, commit):
         return job[:4] + (commit,)
@@ -811,11 +912,13 @@ def main() -> None:
     server = stage_server(led, reqs, now, want_forged)
     say("stage 6: a validator set that changes every height")
     churned = stage_churn(led, churn)
-    say("stage 7: accounts")
+    say("stage 7: a light client that catches up by skipping")
+    skipped = stage_skipping(led, *skip)
+    say("stage 8: accounts")
     accounts = stage_accounts(led, base)
-    say("stage 8: report")
+    say("stage 9: report")
     summary = dict(install=info, server=server, churn=churned,
-                   accounts=accounts,
+                   skipping=skipped, accounts=accounts,
                    **report(led), seed=args.seed,
                    wall_s=round(time.perf_counter() - _START, 1), claim=None)
 

@@ -34,6 +34,8 @@ class Ed25519HostBatchVerifier(BatchVerifier):
     explicit no-device choice): native RLC batch, else per-signature
     ZIP-215 via the OpenSSL fast path."""
 
+    on_device = False      # the device verifier's attribute, for its readers
+
     def __init__(self):
         self._entries: List[Tuple[bytes, bytes, bytes]] = []
 

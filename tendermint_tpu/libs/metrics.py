@@ -721,6 +721,25 @@ class OpsMetrics:
             "Validator sets decoded from wire bytes, by path label "
             "(native|python).",
         )
+        # the light client catching up by skipping (light/client.py): hops
+        # = verifier.verify calls of the bisection, by outcome; fetched =
+        # provider calls that answered; trusting sigs = where the
+        # by-address check against the trusted set was verified
+        # (types/validation.py verify_commit_light_trusting)
+        self.light_hops = registry.counter(
+            "ops", "light_hops_total",
+            "Bisection attempts of the light client, by outcome label "
+            "(verified|refused: not enough trusted power signed).",
+        )
+        self.light_blocks_fetched = registry.counter(
+            "ops", "light_blocks_fetched_total",
+            "Light blocks the light client was handed by its providers.",
+        )
+        self.light_trusting_sigs = registry.counter(
+            "ops", "light_trusting_sigs_total",
+            "Signatures verified by address against a trusted validator "
+            "set, by path label (device|host).",
+        )
         self.h2d_bytes_per_commit = registry.gauge(
             "ops", "h2d_bytes_per_commit",
             "Host bytes shipped to the device by the last dispatched "
@@ -988,6 +1007,13 @@ def ops_stats() -> dict:
         "commit_decode_python": int(m.commit_decodes.value(path="python")),
         "valset_decode_native": int(m.valset_decodes.value(path="native")),
         "valset_decode_python": int(m.valset_decodes.value(path="python")),
+        "light_hops_verified": int(m.light_hops.value(outcome="verified")),
+        "light_hops_refused": int(m.light_hops.value(outcome="refused")),
+        "light_blocks_fetched": int(m.light_blocks_fetched.total()),
+        "light_trusting_sigs_device": int(
+            m.light_trusting_sigs.value(path="device")),
+        "light_trusting_sigs_host": int(
+            m.light_trusting_sigs.value(path="host")),
         "h2d_bytes_per_commit": float(m.h2d_bytes_per_commit.value()),
         "h2d_ops": int(m.h2d_ops.total()),
         "transfer_overlap_ratio": float(m.transfer_overlap_ratio.value()),

@@ -14,7 +14,9 @@ import http.client as _http
 from dataclasses import dataclass
 from typing import Callable, List, Optional
 
+from ..libs import metrics as _metrics
 from ..libs.timeutil import now_ts as _now_ts
+from ..observability import trace as _trace
 from ..types import Fraction
 from ..wire.canonical import Timestamp
 from . import verifier
@@ -27,6 +29,9 @@ DEFAULT_MAX_CLOCK_DRIFT = 10.0  # seconds (light/client.go:56)
 # bisection pivot: 9/16 (light/client.go:44-45)
 _BISECT_NUM = 9
 _BISECT_DEN = 16
+
+_span = _trace.span
+_ops = _metrics.ops_metrics      # the process-wide set, cached there
 
 
 @dataclass
@@ -123,7 +128,7 @@ class Client:
         existing = self._store.latest_light_block()
         if existing is not None:
             return  # already bootstrapped (checkTrustedHeaderUsingOptions simplified)
-        lb = self._primary.light_block(opts.height)
+        lb = self._fetch(self._primary, opts.height, "primary")
         if lb.hash() != opts.hash:
             raise ValueError(
                 f"expected header's hash {opts.hash.hex()}, but got {lb.hash().hex()}"
@@ -161,7 +166,7 @@ class Client:
         compared = 0
         for i, w in enumerate(self._witnesses):
             try:
-                wlb = w.light_block(root.height)
+                wlb = self._fetch(w, root.height, "witness")
             except (OSError, ValueError, KeyError, TimeoutError,
                     ConnectionError, RuntimeError, _http.HTTPException):
                 continue  # unreachable / missing block: ignore this witness
@@ -261,7 +266,7 @@ class Client:
 
     def update(self, now: Optional[Timestamp] = None) -> Optional[LightBlock]:
         """client.go Update: verify the primary's latest header."""
-        latest = self._primary.light_block(0)
+        latest = self._fetch(self._primary, 0, "primary")
         trusted = self._store.latest_light_block()
         if trusted is not None and latest.height <= trusted.height:
             return None
@@ -274,17 +279,19 @@ class Client:
         if height <= 0:
             raise ValueError("height must be positive")
         now = now or self._now_ts()
-        existing = self._store.light_block(height)
-        if existing is not None:
-            return existing
-        latest_trusted = self._store.latest_light_block()
-        if latest_trusted is None:
-            raise RuntimeError("no trusted state — client not initialized")
-        if height < latest_trusted.height:
-            return self._backwards(latest_trusted, height, now)
-        new_block = self._light_block_from_primary(height)
-        self._verify_light_block(new_block, now)
-        return new_block
+        with _span("light.client.verify_at_height", to=height) as sp:
+            existing = self._store.light_block(height)
+            if existing is not None:
+                return existing
+            latest_trusted = self._store.latest_light_block()
+            if latest_trusted is None:
+                raise RuntimeError("no trusted state — client not initialized")
+            sp.note(**{"from": latest_trusted.height})
+            if height < latest_trusted.height:
+                return self._backwards(latest_trusted, height, now)
+            new_block = self._light_block_from_primary(height)
+            self._verify_light_block(new_block, now)
+            return new_block
 
     # -- verification strategies -----------------------------------------
 
@@ -322,13 +329,46 @@ class Client:
     def _verify_skipping(
         self, source: Provider, trusted: LightBlock, new_block: LightBlock, now: Timestamp
     ) -> List[LightBlock]:
-        """client.go:639-720 verifySkipping: bisection with 9/16 pivot."""
-        blocks_to_verify = [new_block]
-        depth = 0
+        """client.go:639-720 verifySkipping: bisection with 9/16 pivot. The
+        pivots are kept as a stack: after a pivot verifies, the pivot fetched
+        before it is tried next (upstream starts again from the far target
+        and walks its cache down to the same header: the same hops, more
+        refused attempts)."""
+        blocks_to_verify = [new_block]  # the far target, then each pivot
         verified = [trusted]
         current = trusted
         while True:
-            target = blocks_to_verify[depth]
+            target = blocks_to_verify[-1]
+            try:
+                self._attempt(current, target, now)
+                verified.append(target)
+                # a verified pivot leaves the stack and is the new lower
+                # bound; the pivot fetched before it is tried next
+                blocks_to_verify.pop()
+                if not blocks_to_verify:
+                    return verified
+                current = target
+            except verifier.ErrNotEnoughTrust:
+                # bisect: pivot at 9/16 between current and target
+                pivot = (
+                    current.height
+                    + (target.height - current.height) * _BISECT_NUM // _BISECT_DEN
+                )
+                if pivot <= current.height:
+                    pivot = current.height + 1
+                if pivot >= target.height:
+                    raise
+                blocks_to_verify.append(self._light_block_from(source, pivot))
+
+    def _attempt(self, current: LightBlock, target: LightBlock,
+                 now: Timestamp) -> None:
+        """One verifier.verify of the bisection, as span
+        light.bisect.attempt (args from, to, outcome) and in the
+        light_hops counters; an attempt refused for lack of trusted power
+        is also a span of its own name, light.bisect.refused, because a
+        reader of span names cannot tell the outcomes apart."""
+        with _span("light.bisect.attempt", **{"from": current.height,
+                                              "to": target.height}) as sp:
             try:
                 verifier.verify(
                     current.signed_header,
@@ -340,24 +380,13 @@ class Client:
                     self._max_clock_drift,
                     self._trust_level,
                 )
-                verified.append(target)
-                if depth == 0:
-                    return verified
-                current = target
-                depth -= 1
             except verifier.ErrNotEnoughTrust:
-                # bisect: pivot at 9/16 between current and target
-                pivot = (
-                    current.height
-                    + (target.height - current.height) * _BISECT_NUM // _BISECT_DEN
-                )
-                if pivot <= current.height:
-                    pivot = current.height + 1
-                if pivot >= target.height:
-                    raise
-                interim = self._light_block_from(source, pivot)
-                blocks_to_verify.append(interim)
-                depth += 1
+                sp.note(outcome="not_enough_trust")
+                sp.also("light.bisect.refused")
+                _ops().light_hops.inc(outcome="refused")
+                raise
+            sp.note(outcome="verified")
+        _ops().light_hops.inc(outcome="verified")
 
     def _verify_skipping_against_witnesses(
         self, trusted: LightBlock, new_block: LightBlock, now: Timestamp
@@ -365,7 +394,8 @@ class Client:
         """client.go:722-780 + detector.go: verify against the primary,
         then cross-check the verified trace with every witness."""
         trace = self._verify_skipping(self._primary, trusted, new_block, now)
-        self._detect_divergence(trace, now)
+        with _span("light.detect_divergence", hops=len(trace) - 1):
+            self._detect_divergence(trace, now)
 
     # -- divergence detector (detector.go) --------------------------------
 
@@ -385,7 +415,7 @@ class Client:
         to_remove: List[int] = []
         for i, witness in enumerate(self._witnesses):
             try:
-                w_block = witness.light_block(last.height)
+                w_block = self._fetch(witness, last.height, "witness")
             except (ErrLightBlockNotFound, ConnectionError):
                 continue  # witness doesn't have it (yet) — tolerated
             if w_block.hash() != last.hash():
@@ -478,7 +508,9 @@ class Client:
             if trace_block.height == target_block.height:
                 source_block = target_block
             else:
-                source_block = source.light_block(trace_block.height)
+                source_block = self._fetch(
+                    source, trace_block.height,
+                    "primary" if source is self._primary else "witness")
             if idx == 0:
                 if source_block.hash() != trace_block.hash():
                     raise ValueError(
@@ -515,14 +547,22 @@ class Client:
 
     # -- provider plumbing (client.go:935-1035) ---------------------------
 
+    def _fetch(self, provider: Provider, height: int, source: str) -> LightBlock:
+        """Every provider call of the client: span light.fetch, and the
+        light_blocks_fetched counter for the calls that answered."""
+        with _span("light.fetch", height=height, source=source):
+            lb = provider.light_block(height)
+        _ops().light_blocks_fetched.inc()
+        return lb
+
     def _light_block_from_primary(self, height: int) -> LightBlock:
         try:
-            lb = self._primary.light_block(height)
+            lb = self._fetch(self._primary, height, "primary")
         except (ErrLightBlockNotFound, ConnectionError):
             # primary failed: promote a witness (client.go findNewPrimary)
             for i, w in enumerate(self._witnesses):
                 try:
-                    lb = w.light_block(height)
+                    lb = self._fetch(w, height, "witness")
                 except (ErrLightBlockNotFound, ConnectionError):
                     continue
                 self._witnesses.pop(i)
@@ -535,4 +575,4 @@ class Client:
     def _light_block_from(self, source: Provider, height: int) -> LightBlock:
         if source is self._primary:
             return self._light_block_from_primary(height)
-        return source.light_block(height)
+        return self._fetch(source, height, "witness")

@@ -10,7 +10,10 @@ import struct
 from typing import Optional
 
 from ..db import DB
+from ..observability import trace as _trace
 from .provider import LightBlock
+
+_span = _trace.span
 
 _PREFIX = b"lb/"
 
@@ -24,7 +27,8 @@ class LightStore:
         self._db = db
 
     def save_light_block(self, lb: LightBlock) -> None:
-        self._db.set(_key(lb.height), lb.encode())
+        with _span("light.store.save", height=lb.height):
+            self._db.set(_key(lb.height), lb.encode())
 
     def light_block(self, height: int) -> Optional[LightBlock]:
         raw = self._db.get(_key(height))

@@ -116,12 +116,14 @@ class SigCheck:
                   as does the sub-threshold single-signature path.
     """
 
-    __slots__ = ("kind", "_run", "_prep", "_wrap")
+    __slots__ = ("kind", "_span_name", "_run", "_prep", "_wrap")
 
     def __init__(self, kind: str, run: Callable[[], None],
                  prep: Callable[[], tuple],
                  wrap: Callable[[BaseException], BaseException]):
         self.kind = kind
+        # light.trusting_check / light.light_check
+        self._span_name = f"light.{kind}_check"
         self._run = run
         self._prep = prep
         self._wrap = wrap
@@ -133,10 +135,11 @@ class SigCheck:
         raise w from e
 
     def run_sync(self) -> None:
-        try:
-            self._run()
-        except Exception as e:  # noqa: BLE001 — wrap decides
-            self._raise(e)
+        with _span(self._span_name):
+            try:
+                self._run()
+            except Exception as e:  # noqa: BLE001 — wrap decides
+                self._raise(e)
 
     def prepare(self):
         try:
@@ -252,14 +255,16 @@ def prepare_non_adjacent(
     trust-level check against the OLD set, then the full +2/3 of the NEW
     set, IN ORDER (the service applies verdicts in stage order so error
     precedence matches the sequential path)."""
-    if untrusted_header.header.height == trusted_header.header.height + 1:
-        raise ValueError("headers must be non adjacent in height")
-    validate_trust_level(trust_level)
-    if header_expired(trusted_header, trusting_period, now):
-        raise ErrOldHeaderExpired(f"old header has expired at {now}")
-    verify_new_header_and_vals(
-        untrusted_header, untrusted_vals, trusted_header, now, max_clock_drift
-    )
+    with _span("light.header_checks"):
+        if untrusted_header.header.height == trusted_header.header.height + 1:
+            raise ValueError("headers must be non adjacent in height")
+        validate_trust_level(trust_level)
+        if header_expired(trusted_header, trusting_period, now):
+            raise ErrOldHeaderExpired(f"old header has expired at {now}")
+        verify_new_header_and_vals(
+            untrusted_header, untrusted_vals, trusted_header, now,
+            max_clock_drift
+        )
     chain_id = trusted_header.header.chain_id
     return [
         _trusting_check(

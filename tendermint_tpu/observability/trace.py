@@ -305,7 +305,8 @@ class SpanTracer:
 class _Span:
     """Active span: records on exit. Only built when tracing is enabled."""
 
-    __slots__ = ("_tr", "_name", "_args", "_flow", "_phase", "_t0", "_ann")
+    __slots__ = ("_tr", "_name", "_args", "_flow", "_phase", "_t0", "_ann",
+                 "_also")
 
     def __init__(self, tracer: "SpanTracer", name: str, args: Optional[dict],
                  flow: Optional[int] = None, phase: Optional[str] = None):
@@ -314,10 +315,17 @@ class _Span:
         self._args = args
         self._flow = flow
         self._phase = phase
+        self._also = None
 
     def note(self, **args) -> None:
         """Args learned inside the span (a bucket the body chose)."""
         self._args = {**self._args, **args} if self._args else args
+
+    def also(self, name: str) -> None:
+        """A second name learned inside the span (how its body ended): the
+        interval is recorded under both, for readers that select spans by
+        name and cannot see args."""
+        self._also = name
 
     def __enter__(self) -> "_Span":
         if _devcheck.enabled():
@@ -329,8 +337,11 @@ class _Span:
 
     def __exit__(self, *exc) -> bool:
         tr = self._tr
-        tr.record(self._name, self._t0, tr._now(), self._args,
+        t1 = tr._now()
+        tr.record(self._name, self._t0, t1, self._args,
                   flow=self._flow, flow_phase=self._phase)
+        if self._also is not None:
+            tr.record(self._also, self._t0, t1, self._args)
         if self._ann is not None:
             self._ann.__exit__(*exc)
         # unconditional (like DevLock.release): devcheck disabled between
@@ -348,6 +359,9 @@ class _NullSpan:
     __slots__ = ()
 
     def note(self, **args) -> None:
+        pass
+
+    def also(self, name: str) -> None:
         pass
 
     def __enter__(self) -> "_NullSpan":

@@ -828,6 +828,7 @@ class Ed25519DeviceBatchVerifier(BatchVerifier):
     def __init__(self, force_device: bool = False):
         self._entries: List[Tuple[bytes, bytes, bytes]] = []
         self._blocks: List[EntryBlock] = []
+        self.on_device = False     # where the last verify() ran
         self._force = force_device or bool(
             int(os.environ.get("TM_TPU_FORCE_DEVICE", "0"))
         )
@@ -895,6 +896,7 @@ class Ed25519DeviceBatchVerifier(BatchVerifier):
                 ]
             return all(valid), valid
         block = self._collect()
+        self.on_device = True
         # Default path is the shared async pipeline:
         # one worker thread owns every device dispatch, so concurrent
         # commit verifies coalesce into full buckets and overlap host prep

@@ -24,17 +24,28 @@ _span = _trace.span
 _OPS = None
 
 
-def _note_host_verified(n: int) -> None:
-    """Per-signature host verifications (the sub-threshold single path)
-    count toward the ops sigs_verified series like every other path."""
+def _ops():
     global _OPS
-    if not n:
-        return
     if _OPS is None:
         from ..libs import metrics as _metrics
 
         _OPS = _metrics.ops_metrics()
-    _OPS.sigs_verified.inc(n, path="host")
+    return _OPS
+
+
+def _note_host_verified(n: int) -> None:
+    """Per-signature host verifications (the sub-threshold single path)
+    count toward the ops sigs_verified series like every other path."""
+    if n:
+        _ops().sigs_verified.inc(n, path="host")
+
+
+def _note_trusting(n: int, on_device: bool) -> None:
+    """Where a by-address check against a trusted set (the light client's
+    skipping third) verified its n signatures: the `light_trusting_sigs`
+    series, beside sigs_verified, which counts them too."""
+    _ops().light_trusting_sigs.inc(n, path="device" if on_device else "host")
+
 
 BATCH_VERIFY_THRESHOLD = 2  # validation.go:12
 
@@ -382,6 +393,9 @@ def prepare_commit_batch(
     eblk = EntryBlock(pub, sig, buf, offsets,
                       val_idx=val_idx, epoch_key=epoch_key,
                       scheme=scheme, pub_aux=pub_aux)
+    if not look_up_by_index and cols is not None:
+        # the seam's callers submit the block to the shared pipeline
+        _note_trusting(len(selected), True)
     return eblk, _blame_conclude(batch_sig_idxs, commit)
 
 
@@ -862,6 +876,10 @@ def _verify_commit_batch(
                 bv.add(val.pub_key, sb, commit.signatures[idx].signature)
     with _span("verify_commit.verify", n=len(selected)):
         ok, valid_sigs = bv.verify()
+    if not look_up_by_index:
+        on_device = getattr(bv, "on_device", None)
+        if on_device is not None:   # a verifier that says where it ran
+            _note_trusting(len(selected), on_device)
     if ok:
         return
     import numpy as _np
@@ -913,9 +931,10 @@ def _verify_commit_single(
         if count_sig(commit_sig):
             tallied += val.voting_power
         if not count_all_signatures and tallied > voting_power_needed:
-            _note_host_verified(checked)
-            return
+            break
     _note_host_verified(checked)
+    if checked and not look_up_by_index:
+        _note_trusting(checked, False)
     if tallied <= voting_power_needed:
         raise ErrNotEnoughVotingPowerSigned(got=tallied, needed=voting_power_needed)
 
