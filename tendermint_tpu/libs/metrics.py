@@ -731,6 +731,11 @@ class OpsMetrics:
             "Bisection attempts of the light client, by outcome label "
             "(verified|refused: not enough trusted power signed).",
         )
+        self.light_hops_fused = registry.counter(
+            "ops", "light_hops_fused_total",
+            "Skipping hops whose trusting and +2/3 checks went to the "
+            "device as one submission (light.verifier.verify_non_adjacent).",
+        )
         self.light_blocks_fetched = registry.counter(
             "ops", "light_blocks_fetched_total",
             "Light blocks the light client was handed by its providers.",
@@ -1009,6 +1014,7 @@ def ops_stats() -> dict:
         "valset_decode_python": int(m.valset_decodes.value(path="python")),
         "light_hops_verified": int(m.light_hops.value(outcome="verified")),
         "light_hops_refused": int(m.light_hops.value(outcome="refused")),
+        "light_hops_fused": int(m.light_hops_fused.total()),
         "light_blocks_fetched": int(m.light_blocks_fetched.total()),
         "light_trusting_sigs_device": int(
             m.light_trusting_sigs.value(path="device")),

@@ -11,20 +11,15 @@ into one device batch (mesh lanes when enabled); `conclude_request`
 applies the device verdict rows back in sequential stage order so error
 precedence — and every error string — matches light/verifier.py exactly.
 
-Error-precedence contract (what makes verdicts byte-identical to the
-sequential path): verify_non_adjacent raises the trusting-stage error
-before the +2/3 stage runs at all, so
-
-  * a host-side failure while preparing stage k is recorded ON stage k
-    and later stages are not prepared (sequential never reached them);
-  * verdicts are applied in stage order — stage k's sig failure masks
-    anything recorded for stage k+1.
+The error-precedence contract that makes that so, and its one
+implementation, are verifier.prepare_stages / conclude_stages: the
+synchronous verify_non_adjacent runs a hop's two checks through them too.
 """
 
 from __future__ import annotations
 
 from dataclasses import dataclass, field
-from typing import Callable, Dict, List, Optional
+from typing import Dict, List, Optional
 
 from ..types import Fraction
 from ..wire.canonical import Timestamp
@@ -85,23 +80,11 @@ def fingerprint(req: HeaderRequest, now: Timestamp) -> Optional[tuple]:
 
 
 @dataclass
-class StagePlan:
-    """One prepared sig-check stage: exactly one of {entries+conclude,
-    error, neither} — `neither` means the stage completed synchronously
-    at prepare time (sub-threshold commit) and passed."""
-
-    kind: str
-    entries: object = None
-    conclude: Optional[Callable] = None
-    error: Optional[BaseException] = None
-
-
-@dataclass
 class RequestPlan:
-    stages: List[StagePlan] = field(default_factory=list)
+    stages: List[verifier.StagePlan] = field(default_factory=list)
     error: Optional[BaseException] = None  # host-check failure (pre-sig)
 
-    def entry_stages(self) -> List[StagePlan]:
+    def entry_stages(self) -> List[verifier.StagePlan]:
         return [s for s in self.stages if s.entries is not None]
 
 
@@ -117,17 +100,7 @@ def prepare_request(req: HeaderRequest, now: Timestamp) -> RequestPlan:
         )
     except Exception as e:  # noqa: BLE001 — any host-check error is the verdict
         return RequestPlan(error=e)
-    plan = RequestPlan()
-    for chk in checks:
-        try:
-            entries, conclude = chk.prepare()
-        except Exception as e:  # noqa: BLE001
-            plan.stages.append(StagePlan(chk.kind, error=e))
-            break  # sequential surfaces this before later stages run
-        plan.stages.append(
-            StagePlan(chk.kind, entries=entries, conclude=conclude)
-        )
-    return plan
+    return RequestPlan(stages=verifier.prepare_stages(checks))
 
 
 def conclude_request(plan: RequestPlan, verdicts) -> Optional[BaseException]:
@@ -138,21 +111,7 @@ def conclude_request(plan: RequestPlan, verdicts) -> Optional[BaseException]:
     path's) or None on acceptance."""
     if plan.error is not None:
         return plan.error
-    vi = 0
-    for st in plan.stages:
-        if st.error is not None:
-            return st.error
-        if st.entries is None:
-            continue  # verified synchronously at prepare time
-        v = verdicts[vi]
-        vi += 1
-        if isinstance(v, BaseException):
-            return v  # pipeline-level failure (DispatchError): not parity
-        try:
-            st.conclude(v)
-        except Exception as e:  # noqa: BLE001 — the wrapped stage error
-            return e
-    return None
+    return verifier.conclude_stages(plan.stages, verdicts)
 
 
 def group_stats(plans) -> Dict[Optional[bytes], int]:

@@ -9,8 +9,12 @@ pipelined 1k-header sync workload of BASELINE config #5.
 
 from __future__ import annotations
 
-from typing import Callable, List
+import itertools
+from dataclasses import dataclass
+from typing import Callable, Iterable, List, Optional
 
+from ..crypto import batch as _batch
+from ..libs import metrics as _metrics
 from ..types import ErrNotEnoughVotingPowerSigned, Fraction, SignedHeader, ValidatorSet
 from ..types import validation as _validation
 from ..types.validation import (
@@ -100,20 +104,28 @@ class SigCheck:
     The prepare_* functions below run every NON-sig check host-side
     (heights, trust level, expiry, hash chaining, clock drift — exactly
     the lines the old verify_* bodies ran) and return the sig work as
-    SigCheck objects instead of verifying in place. Two consumers:
+    SigCheck objects instead of verifying in place. Two ways to run one:
 
-      run_sync()  the sequential path — calls the SAME types.validation
-                  entry point the old code called, with the identical
-                  error wrapping, so verify_adjacent/verify_non_adjacent
-                  keep their byte-for-byte behavior;
-      prepare()   the batched light service — returns (entries, conclude)
-                  where `entries` is the check's EntryBlock (epoch
-                  metadata attached) to ship through the shared device
-                  pipeline and `conclude(valid)` raises the identical
-                  (wrapped) error over the device verdict row. A check
-                  the async seam cannot represent falls back to
-                  run_sync() inside prepare() and returns (None, None),
-                  as does the sub-threshold single-signature path.
+      run_sync()  calls the types.validation entry point the sequential
+                  code always called, with the identical error wrapping
+                  (verify_adjacent's one check; a hop whose sets are not
+                  all-ed25519);
+      prepare()   returns (entries, conclude) where `entries` is the
+                  check's EntryBlock (epoch metadata attached) and
+                  `conclude(valid, on_device=True)` raises the identical
+                  (wrapped) error over the verdict row: the batched light
+                  service ships the block through the shared device
+                  pipeline, verify_non_adjacent hands a hop's two blocks
+                  to one batch verifier (ISSUE 36). A check the seam
+                  cannot represent falls back to run_sync() inside
+                  prepare() and returns (None, None), as does the
+                  sub-threshold single-signature path.
+
+    Either way a check is ONE span of its name (light.trusting_check /
+    light.light_check): around run_sync(), or around prepare()'s host
+    work — selection, tally, sign bytes — wherever the signatures are
+    then verified (conclude, an argmin over the verdict row, runs inside
+    the verification's span).
     """
 
     __slots__ = ("kind", "_span_name", "_run", "_prep", "_wrap")
@@ -128,37 +140,32 @@ class SigCheck:
         self._prep = prep
         self._wrap = wrap
 
-    def _raise(self, e: BaseException):
-        w = self._wrap(e)
-        if w is e:
-            raise
-        raise w from e
+    def _wrapped(self, fn, *args):
+        """fn(*args), its errors through the wrap (which leaves a
+        PrepareUnsupported, like any non-ValueError, as it is)."""
+        try:
+            return fn(*args)
+        except Exception as e:  # noqa: BLE001 — wrap decides
+            w = self._wrap(e)
+            if w is e:
+                raise
+            raise w from e
 
     def run_sync(self) -> None:
         with _span(self._span_name):
-            try:
-                self._run()
-            except Exception as e:  # noqa: BLE001 — wrap decides
-                self._raise(e)
+            self._wrapped(self._run)
 
     def prepare(self):
         try:
-            entries, conclude = self._prep()
+            with _span(self._span_name):
+                entries, conclude = self._wrapped(self._prep)
         except _validation.PrepareUnsupported:
             self.run_sync()
             return None, None
-        except Exception as e:  # noqa: BLE001 — wrap decides
-            self._raise(e)
         if conclude is None:
             return None, None
-
-        def _conclude(valid) -> None:
-            try:
-                conclude(valid)
-            except Exception as e:  # noqa: BLE001 — wrap decides
-                self._raise(e)
-
-        return entries, _conclude
+        return entries, lambda valid, on_device=True: self._wrapped(
+            conclude, valid, on_device)
 
 
 def _wrap_trusting(e: BaseException) -> BaseException:
@@ -195,12 +202,12 @@ def _light_check(chain_id: str, vals: ValidatorSet, block_id, height: int,
 
 
 def _trusting_check(chain_id: str, vals: ValidatorSet, commit,
-                    trust_level: Fraction) -> SigCheck:
+                    trust_level: Fraction, epoch_lookup: bool) -> SigCheck:
     return SigCheck(
         "trusting",
         run=lambda: verify_commit_light_trusting(chain_id, vals, commit, trust_level),
         prep=lambda: _validation.prepare_commit_light_trusting(
-            chain_id, vals, commit, trust_level
+            chain_id, vals, commit, trust_level, epoch_lookup
         ),
         wrap=_wrap_trusting,
     )
@@ -250,11 +257,14 @@ def prepare_non_adjacent(
     now: Timestamp,
     max_clock_drift: float,
     trust_level: Fraction,
+    epoch_lookup: bool = True,
 ) -> List[SigCheck]:
     """verifier.go:33-101 host checks; returns the sig work — the
     trust-level check against the OLD set, then the full +2/3 of the NEW
     set, IN ORDER (the service applies verdicts in stage order so error
-    precedence matches the sequential path)."""
+    precedence matches the sequential path). `epoch_lookup` False leaves
+    the old set's device table to the caller (verify_non_adjacent, which
+    knows only after the +2/3 check's prepare whether one serves both)."""
     with _span("light.header_checks"):
         if untrusted_header.header.height == trusted_header.header.height + 1:
             raise ValueError("headers must be non adjacent in height")
@@ -268,7 +278,8 @@ def prepare_non_adjacent(
     chain_id = trusted_header.header.chain_id
     return [
         _trusting_check(
-            chain_id, trusted_vals, untrusted_header.commit, trust_level
+            chain_id, trusted_vals, untrusted_header.commit, trust_level,
+            epoch_lookup,
         ),
         _light_check(
             chain_id,
@@ -302,6 +313,117 @@ def prepare_verify(
     )
 
 
+@dataclass
+class StagePlan:
+    """One prepared sig-check stage: exactly one of {entries+conclude,
+    error, neither} — `neither` means the stage completed synchronously
+    at prepare time (sub-threshold commit) and passed."""
+
+    kind: str
+    entries: object = None
+    conclude: Optional[Callable] = None
+    error: Optional[BaseException] = None
+
+
+def prepare_stages(checks: Iterable[SigCheck]) -> List[StagePlan]:
+    """The host half of a verification's sig checks, in order. Never
+    raises — with conclude_stages, the ONE implementation of the
+    error-precedence contract that makes a verification whose checks are
+    verified together (the light service: many requests' blocks in one
+    device batch; verify_non_adjacent: a hop's two blocks in one
+    submission) byte-identical to checks run one after the other, where
+    the trusting stage raises before the +2/3 stage runs at all:
+
+      * a host-side failure while preparing stage k is recorded ON stage
+        k and later stages are not prepared (sequential never reached
+        them);
+      * verdicts are applied in stage order — stage k's sig failure masks
+        anything recorded for stage k+1."""
+    stages: List[StagePlan] = []
+    for chk in checks:
+        try:
+            entries, conclude = chk.prepare()
+        except Exception as e:  # noqa: BLE001 — the stage's verdict
+            stages.append(StagePlan(chk.kind, error=e))
+            break
+        stages.append(StagePlan(chk.kind, entries=entries, conclude=conclude))
+    return stages
+
+
+def conclude_stages(stages: List[StagePlan], verdicts,
+                    on_device: bool = True) -> Optional[BaseException]:
+    """Apply verdicts in SEQUENTIAL stage order. `verdicts` has one item
+    per stage that has entries, in that order — each a bool validity row
+    or the exception its pipeline future resolved with; `on_device` says
+    where the rows were computed. Returns the error the sequential path
+    raises (byte-identical) or None on acceptance."""
+    vi = 0
+    for st in stages:
+        if st.error is not None:
+            return st.error
+        if st.entries is None:
+            continue  # verified synchronously at prepare time
+        v = verdicts[vi]
+        vi += 1
+        if isinstance(v, BaseException):
+            return v  # pipeline-level failure (DispatchError): not parity
+        try:
+            st.conclude(v, on_device)
+        except Exception as e:  # noqa: BLE001 — the wrapped stage error
+            return e
+    return None
+
+
+def _verify_together(checks: List[SigCheck], sets) -> None:
+    """checks[i], against sets[i], in order — their signatures as ONE
+    submission to crypto.batch's ed25519 verifier where their total
+    reaches the device (ops.backend DEVICE_THRESHOLD). Under it each
+    check stays a host batch of its own, as where checks run one after
+    the other: the host verifies one signature at a time either way, and
+    whoever counts host batches keeps reading whole checks. That verifier
+    takes the leading checks whose sets are all-ed25519 (asked of a set
+    only once the checks before it have prepared: a refused attempt
+    touches nothing of the new set); the rest run_sync() after them."""
+    def ed25519_first():
+        for chk, vals in zip(checks, sets):
+            if vals.ed25519_columns() is None:
+                return
+            yield chk
+
+    stages = prepare_stages(ed25519_first())
+    live = [st for st in stages if st.entries is not None]
+    verdicts, on_device = [], False
+    if live:
+        from ..ops import backend as _backend
+
+        n = sum(len(st.entries) for st in live)
+        together = n >= _backend.DEVICE_THRESHOLD
+        if together and len(live) == 2 and live[1].entries.epoch_key is not None:
+            # the new set is warm: the old one rides its table if it maps
+            # onto it (a set that moves slowly), else ships its keys
+            live[0].entries = _validation.on_table_of(
+                live[0].entries, sets[0], live[1].entries)
+        with _span("light.hop_verify", n=n, stages=len(live)) as sp:
+            for group in [live] if together else [[st] for st in live]:
+                bv = _batch.create_batch_verifier(sets[0].validators[0].pub_key)
+                for st in group:
+                    bv.add_block(st.entries)
+                rows = iter(bv.verify()[1])
+                verdicts += [list(itertools.islice(rows, len(st.entries)))
+                             for st in group]
+                on_device = bv.on_device
+                if on_device and len(group) > 1:
+                    _metrics.ops_metrics().light_hops_fused.inc()
+            sp.note(on_device=on_device)
+            err = conclude_stages(stages, verdicts, on_device)
+    else:
+        err = conclude_stages(stages, verdicts)
+    if err is not None:
+        raise err
+    for chk in checks[len(stages):]:
+        chk.run_sync()
+
+
 def verify_adjacent(
     trusted_header: SignedHeader,
     untrusted_header: SignedHeader,
@@ -331,12 +453,18 @@ def verify_non_adjacent(
     max_clock_drift: float,
     trust_level: Fraction,
 ) -> None:
-    """verifier.go:33-101: the prepare seam driven synchronously."""
-    for chk in prepare_non_adjacent(
-        trusted_header, trusted_vals, untrusted_header, untrusted_vals,
-        trusting_period, now, max_clock_drift, trust_level,
-    ):
-        chk.run_sync()
+    """verifier.go:33-101: the prepare seam driven synchronously. Both
+    checks are over the SAME commit, so their signatures go as one
+    submission (at 100 validators 34 + 67: one launch, where 34 alone
+    are under the device threshold)."""
+    _verify_together(
+        prepare_non_adjacent(
+            trusted_header, trusted_vals, untrusted_header, untrusted_vals,
+            trusting_period, now, max_clock_drift, trust_level,
+            epoch_lookup=False,
+        ),
+        (trusted_vals, untrusted_vals),
+    )
 
 
 def verify(

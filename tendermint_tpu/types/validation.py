@@ -209,16 +209,18 @@ def prepare_commit_light(chain_id: str, vals: ValidatorSet, block_id: BlockID,
     async seam cannot represent the set."""
     _verify_basic_vals_and_commit(vals, commit, height, block_id)
     voting_power_needed = vals.total_voting_power() * 2 // 3
-    if not _should_batch_prepare(vals, commit):
-        _verify_commit_single(
+    with _span("verify_commit", n=len(commit.signatures), height=height,
+               mode="light"):
+        if not _should_batch_prepare(vals, commit):
+            _verify_commit_single(
+                chain_id, vals, commit, voting_power_needed,
+                _ignore_not_for_block, _count_all, False, True,
+            )
+            return None, None
+        return prepare_commit_batch(
             chain_id, vals, commit, voting_power_needed,
             _ignore_not_for_block, _count_all, False, True,
         )
-        return None, None
-    return prepare_commit_batch(
-        chain_id, vals, commit, voting_power_needed,
-        _ignore_not_for_block, _count_all, False, True,
-    )
 
 
 def prepare_commit_range(chain_id: str, vals: ValidatorSet, items):
@@ -251,11 +253,13 @@ def prepare_commit_range(chain_id: str, vals: ValidatorSet, items):
 
 
 def prepare_commit_light_trusting(chain_id: str, vals: ValidatorSet,
-                                  commit: Commit, trust_level: Fraction):
+                                  commit: Commit, trust_level: Fraction,
+                                  epoch_lookup: bool = True):
     """verify_commit_light_trusting's host half (ISSUE 11 seam): nil and
     overflow checks, by-address selection with double-vote detection and
     the trust-level tally — returning the sig work instead of verifying
-    in place. Same return/raise contract as prepare_commit_light."""
+    in place. Same return/raise contract as prepare_commit_light;
+    `epoch_lookup` as prepare_commit_batch has it."""
     if vals is None:
         raise ValueError("nil validator set")
     if trust_level.denominator == 0:
@@ -277,18 +281,22 @@ def prepare_commit_light_trusting(chain_id: str, vals: ValidatorSet,
         return None, None
     return prepare_commit_batch(
         chain_id, vals, commit, voting_power_needed,
-        _ignore_not_for_block, _count_all, False, False,
+        _ignore_not_for_block, _count_all, False, False, epoch_lookup,
     )
 
 
-def _blame_conclude(sig_idxs, commit):
-    """The verdict half of _verify_commit_batch over a device validity
-    row: all-valid returns, otherwise the FIRST invalid lane maps back
-    through the selection to the reference's blame string
-    (validation.go:242-248)."""
+def _blame_conclude(sig_idxs, commit, by_address: bool = False):
+    """The verdict half of _verify_commit_batch over a validity row:
+    all-valid returns, otherwise the FIRST invalid lane maps back through
+    the selection to the reference's blame string (validation.go:242-248).
+    The caller says where the row was computed, which is known only once
+    the block has been submitted: a by-address check counts its
+    signatures there (light_trusting_sigs)."""
     import numpy as _np
 
-    def conclude(valid) -> None:
+    def conclude(valid, on_device: bool = True) -> None:
+        if by_address:
+            _note_trusting(len(sig_idxs), on_device)
         valid_arr = _np.asarray(valid, dtype=bool)
         if valid_arr.size and valid_arr.all():
             return
@@ -314,6 +322,7 @@ def prepare_commit_batch(
     count_sig: Callable[[CommitSig], bool],
     count_all_signatures: bool,
     look_up_by_index: bool,
+    epoch_lookup: bool = True,
 ):
     """The host half of _verify_commit_batch with the device verify
     EXTRACTED (ISSUE 11): selection, double-vote detection, length
@@ -321,7 +330,11 @@ def prepare_commit_batch(
     bv.verify() the prepared EntryBlock is RETURNED (epoch metadata
     attached, so the shared AsyncBatchVerifier can coalesce it with
     other same-epoch work across requests) together with a
-    conclude(valid) callable reproducing the exact blame errors.
+    conclude(valid, on_device=True) callable reproducing the exact blame
+    errors. With `epoch_lookup` False a by-address selection does not
+    look its set up in the epoch cache: the block ships its keys, and
+    carries its lanes' rows of the SET for a caller who will know later
+    whether a resident table serves it (on_table_of).
     Host-side failures raise exactly what _verify_commit_batch raises
     before its verify call."""
     proposer = vals.get_proposer()
@@ -384,7 +397,8 @@ def prepare_commit_batch(
         scheme = "secp256k1"
         pub_aux = _np.ascontiguousarray(raw[:, 0])
         pub = _np.ascontiguousarray(raw[:, 1:])
-    epoch_key, val_idx = _epoch.table_rows(vals, rows)
+    epoch_key, val_idx = (_epoch.table_rows(vals, rows) if epoch_lookup
+                          else (None, rows))
     sigs_list = commit.signatures
     sig = _np.frombuffer(
         b"".join(sigs_list[i].signature for i in batch_sig_idxs),
@@ -393,10 +407,24 @@ def prepare_commit_batch(
     eblk = EntryBlock(pub, sig, buf, offsets,
                       val_idx=val_idx, epoch_key=epoch_key,
                       scheme=scheme, pub_aux=pub_aux)
-    if not look_up_by_index and cols is not None:
-        # the seam's callers submit the block to the shared pipeline
-        _note_trusting(len(selected), True)
-    return eblk, _blame_conclude(batch_sig_idxs, commit)
+    return eblk, _blame_conclude(batch_sig_idxs, commit,
+                                 by_address=not look_up_by_index)
+
+
+def on_table_of(blk, vals: ValidatorSet, other):
+    """`blk`, prepared over `vals` without an epoch look-up, on the device
+    table `other` gathers from if `vals` maps onto that table (one put
+    and the warm kernel for both, once concatenated); else as it is. The
+    look-up is made here, where a launch can use what it finds."""
+    from ..ops import epoch_cache as _epoch
+    from ..ops.entry_block import EntryBlock
+
+    epoch_key, val_idx = _epoch.table_rows(vals, blk.val_idx)
+    if epoch_key is None or epoch_key != other.epoch_key:
+        return blk
+    return EntryBlock(blk.pub, blk.sig, blk.msgs, blk.offsets,
+                      val_idx=val_idx, epoch_key=epoch_key,
+                      scheme=blk.scheme, pub_aux=blk.pub_aux)
 
 
 def prepare_commit_scheme_split(

@@ -80,11 +80,29 @@ def pytest_runtest_protocol(item):
         signal.signal(signal.SIGALRM, previous)
 
 
+# The one test that holds the program to what ISSUE 36 set out to change:
+# at 100 validators a skipping hop's trusting third verified on the host,
+# in a batch of its own. tests/benchmark is the benchmark's, and ISSUE 36
+# closes it to this PR, so the test cannot say here what a hop does now;
+# tests/light/test_bisect_driver_hop.py does, through the same driver and
+# its check(). strict: the `benchmark` PR that rewrites the test has to
+# take this entry out with it (PERF.md §7).
+PINS_THE_THIRD_TO_THE_HOST = (
+    "tests/benchmark/test_benchmark_bisect.py::"
+    "test_two_thirds_on_the_device_and_the_third_on_the_host")
+
+
 def pytest_collection_modifyitems(config, items):
     """`native_required` tests skip cleanly where tm_native isn't built
     (pure-python containers without a toolchain) — the differential
     suites keep their pure-python halves running everywhere."""
     from tendermint_tpu.native import load as _load_native
+
+    for item in items:
+        if item.nodeid == PINS_THE_THIRD_TO_THE_HOST:
+            item.add_marker(pytest.mark.xfail(
+                reason="ISSUE 36: the third rides the +2/3 check's launch",
+                strict=True))
 
     if _load_native() is None:
         skip = pytest.mark.skip(reason="tm_native module not built")
