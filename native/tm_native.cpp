@@ -8,17 +8,161 @@
 // single-pass, GIL-released fast path here too, each answering None where
 // the Python walk that specifies it must decide: commit_decode_columns
 // (Commit.decode) and valset_decode_columns (ValidatorSet.decode).
+// The entries on a verified commit's path time their own GIL-free sections
+// (gil::Free below): how long the work ran without the interpreter lock and
+// how long the thread then waited to win it back.
 // CPython C API (no pybind11 in this image), built by g++ on the first
 // tendermint_tpu.native.load().
 
 #define PY_SSIZE_T_CLEAN
 #include <Python.h>
+#include <atomic>
 #include <cstdint>
 #include <cstring>
 #include <sched.h>
 #include <stdlib.h>
 #include <thread>
+#include <time.h>
 #include <vector>
+
+// --------------------------------------------------------------------------
+// GIL-free sections that time themselves.
+//
+// GIL_FREE_BEGIN(ENTRY) ...work... GIL_FREE_END is Py_BEGIN_ALLOW_THREADS /
+// Py_END_ALLOW_THREADS with three reads of CLOCK_MONOTONIC — the clock of
+// time.perf_counter on Linux, so of the span tracer and, through the
+// benchmark's marks, of the device trace: t_released just after the GIL
+// is given up, t_wanted just before PyEval_RestoreThread, t_got just after
+// it. With one thread calling, RestoreThread returns at once; with many it
+// is a contest the thread may lose for a switch interval or more, and a
+// span around the call reads that wait as the work it interrupted.
+//
+//   gil_stats()      {entry: (sections, free_s, wait_s)}: process-wide,
+//                    always on (three relaxed adds a section), only rises;
+//                    free = wanted - released, wait = got - wanted
+//   last_sections()  [(t_released, t_wanted, t_got), ...] in perf_counter
+//                    seconds: the calling thread's last call of an entry
+//                    below, one tuple a section (reset where the entry
+//                    starts; at most MAX_SECTIONS)
+namespace gil {
+
+enum Entry {
+  COMMIT_DECODE_COLUMNS,
+  VALSET_DECODE_COLUMNS,
+  COMMIT_PREP_FUSED,
+  ED25519_RLC_PREP,
+  N_ENTRIES
+};
+static const char *const ENTRY_NAMES[N_ENTRIES] = {
+    "commit_decode_columns", "valset_decode_columns", "commit_prep_fused",
+    "ed25519_rlc_prep"};
+static const int MAX_SECTIONS = 4;
+
+struct Stats {
+  std::atomic<uint64_t> sections{0}, free_ns{0}, wait_ns{0};
+};
+static Stats stats[N_ENTRIES];
+
+struct Last {
+  int n;
+  int64_t t[MAX_SECTIONS][3];
+};
+static thread_local Last last = {0, {}};
+
+static inline int64_t now_ns() {
+  struct timespec ts;
+  clock_gettime(CLOCK_MONOTONIC, &ts);
+  return (int64_t)ts.tv_sec * 1000000000LL + ts.tv_nsec;
+}
+
+// An entry's first statement: the thread's sections are this call's.
+static inline void enter() { last.n = 0; }
+
+class Free {
+  PyThreadState *save_;
+  Entry entry_;
+  int64_t released_;
+
+ public:
+  explicit Free(Entry e) : save_(PyEval_SaveThread()), entry_(e) {
+    released_ = now_ns();
+  }
+  Free(const Free &) = delete;
+  Free &operator=(const Free &) = delete;
+  ~Free() {
+    int64_t wanted = now_ns();
+    PyEval_RestoreThread(save_);
+    int64_t got = now_ns();
+    Stats &s = stats[entry_];
+    s.sections.fetch_add(1, std::memory_order_relaxed);
+    s.free_ns.fetch_add((uint64_t)(wanted - released_),
+                        std::memory_order_relaxed);
+    s.wait_ns.fetch_add((uint64_t)(got - wanted), std::memory_order_relaxed);
+    if (last.n < MAX_SECTIONS) {
+      int64_t *t = last.t[last.n++];
+      t[0] = released_;
+      t[1] = wanted;
+      t[2] = got;
+    }
+  }
+};
+
+// The pair that takes Py_BEGIN_ALLOW_THREADS / Py_END_ALLOW_THREADS' place
+// (a block, as theirs is)
+#define GIL_FREE_BEGIN(entry) { gil::Free gil_free_(gil::entry);
+#define GIL_FREE_END }
+
+// time.perf_counter's own conversion (pytime.c _PyTime_AsSecondsDouble):
+// a reading here and one there of the same instant are the same double
+static double seconds(int64_t ns) {
+  volatile double d;
+  if (ns % 1000000000LL == 0) {
+    d = (double)(ns / 1000000000LL);
+  } else {
+    d = (double)ns;
+    d /= 1e9;
+  }
+  return d;
+}
+
+}  // namespace gil
+
+static PyObject *py_gil_stats(PyObject *, PyObject *) {
+  PyObject *out = PyDict_New();
+  if (!out) return nullptr;
+  for (int e = 0; e < gil::N_ENTRIES; e++) {
+    const gil::Stats &s = gil::stats[e];
+    PyObject *v = Py_BuildValue(
+        "(Kdd)",
+        (unsigned long long)s.sections.load(std::memory_order_relaxed),
+        (double)s.free_ns.load(std::memory_order_relaxed) / 1e9,
+        (double)s.wait_ns.load(std::memory_order_relaxed) / 1e9);
+    if (!v || PyDict_SetItemString(out, gil::ENTRY_NAMES[e], v) < 0) {
+      Py_XDECREF(v);
+      Py_DECREF(out);
+      return nullptr;
+    }
+    Py_DECREF(v);
+  }
+  return out;
+}
+
+static PyObject *py_last_sections(PyObject *, PyObject *) {
+  const gil::Last &l = gil::last;
+  PyObject *out = PyList_New(l.n);
+  if (!out) return nullptr;
+  for (int i = 0; i < l.n; i++) {
+    PyObject *v = Py_BuildValue("(ddd)", gil::seconds(l.t[i][0]),
+                                gil::seconds(l.t[i][1]),
+                                gil::seconds(l.t[i][2]));
+    if (!v) {
+      Py_DECREF(out);
+      return nullptr;
+    }
+    PyList_SET_ITEM(out, i, v);
+  }
+  return out;
+}
 
 // --------------------------------------------------------------------------
 // SHA-256 (FIPS 180-4), self-contained.
@@ -2021,6 +2165,7 @@ static PyObject *py_ed25519_prep_fused(PyObject *, PyObject *args) {
 // multiple of m, >= n) is the padded live-lane signature count; rows
 // n..total-1 are padding lanes (s = k = 0, s_ok = 1, U = 0).
 static PyObject *py_ed25519_rlc_prep(PyObject *, PyObject *args) {
+  gil::enter();
   Py_buffer pubs, sigs, msgs, offs, zb;
   Py_ssize_t m, total;
   int no_ossl = 0;
@@ -2058,7 +2203,7 @@ static PyObject *py_ed25519_rlc_prep(PyObject *, PyObject *args) {
   const uint8_t *mp = (const uint8_t *)msgs.buf;
   const uint8_t *zp = (const uint8_t *)zb.buf;
   ossl_sha512_fn fast = no_ossl ? nullptr : ossl_sha512();
-  Py_BEGIN_ALLOW_THREADS
+  GIL_FREE_BEGIN(ED25519_RLC_PREP)
   // lane-disjoint: each lane reads rows base..base+m-1 and writes only
   // its own S/U/k/s_ok slots
   parallel_ranges(g, 256, [&](Py_ssize_t lane_lo, Py_ssize_t lane_hi) {
@@ -2113,7 +2258,7 @@ static PyObject *py_ed25519_rlc_prep(PyObject *, PyObject *args) {
       }
     }
   });
-  Py_END_ALLOW_THREADS
+  GIL_FREE_END
   PyBuffer_Release(&pubs);
   PyBuffer_Release(&sigs);
   PyBuffer_Release(&msgs);
@@ -2227,9 +2372,12 @@ static PyObject *py_vote_sign_bytes_batch_buf(PyObject *, PyObject *args) {
 //   -> (sel, tallied, pub (m*32), sig (m*64), msgs, offs ((m+1)*8))
 //                                                        otherwise
 //
-// The ENTIRE commit-side host prep of types.verify_commit in one
-// GIL-released call over CommitBlock + ValidatorSet columns
-// (ops/commit_prep.py): flag selection, voting-power tally vs the 2/3
+// The ENTIRE commit-side host prep of types.verify_commit in one call
+// over CommitBlock + ValidatorSet columns (ops/commit_prep.py). The GIL is
+// released THREE times in it — around selection + tally, around the
+// sign-bytes sizes, around sign bytes + gather — and taken back between
+// them to allocate the outputs: three waits for it a call (each timed:
+// gil::Free). The stages: flag selection, voting-power tally vs the 2/3
 // threshold (validation.go:152 loop semantics, incl. early-stop keeping
 // the crossing lane), canonical sign-bytes composition into ONE
 // contiguous buffer (vote_sign_bytes_batch_buf layout, prefix chosen per
@@ -2247,6 +2395,7 @@ static size_t uvarint_len(uint64_t v) {
 }
 
 static PyObject *py_commit_prep_fused(PyObject *, PyObject *args) {
+  gil::enter();
   Py_buffer flags, sigs, tsec, tnan, pubs, power, pfxc, pfxn, sfx;
   Py_ssize_t threshold, mode;
   if (!PyArg_ParseTuple(args, "y*y*y*y*y*y*y*y*y*nn", &flags, &sigs, &tsec,
@@ -2280,7 +2429,7 @@ static PyObject *py_commit_prep_fused(PyObject *, PyObject *args) {
   const bool sel_commit = mode & 1, count_fb = mode & 2, early = mode & 4;
   std::vector<int64_t> sel;
   int64_t tallied = 0;
-  Py_BEGIN_ALLOW_THREADS
+  GIL_FREE_BEGIN(COMMIT_PREP_FUSED)
   sel.reserve((size_t)n);
   for (Py_ssize_t i = 0; i < n; i++) {
     uint8_t f = fp[i];
@@ -2289,7 +2438,7 @@ static PyObject *py_commit_prep_fused(PyObject *, PyObject *args) {
     if (!count_fb || f == 2) tallied += pw[i];
     if (early && tallied > (int64_t)threshold) break;
   }
-  Py_END_ALLOW_THREADS
+  GIL_FREE_END
   Py_ssize_t m = (Py_ssize_t)sel.size();
   PyObject *sel_out = PyBytes_FromStringAndSize(
       (const char *)sel.data(), m * 8);
@@ -2313,7 +2462,7 @@ static PyObject *py_commit_prep_fused(PyObject *, PyObject *args) {
     return nullptr;
   }
   int64_t *offs = (int64_t *)PyBytes_AS_STRING(offs_out);
-  Py_BEGIN_ALLOW_THREADS
+  GIL_FREE_BEGIN(COMMIT_PREP_FUSED)
   offs[0] = 0;
   for (Py_ssize_t j = 0; j < m; j++) {
     Py_ssize_t i = (Py_ssize_t)sel[(size_t)j];
@@ -2325,7 +2474,7 @@ static PyObject *py_commit_prep_fused(PyObject *, PyObject *args) {
     size_t body = plen + 1 + uvarint_len(tn) + tn + (size_t)sfx.len;
     offs[j + 1] = offs[j] + (int64_t)(uvarint_len(body) + body);
   }
-  Py_END_ALLOW_THREADS
+  GIL_FREE_END
   PyObject *pub_out = PyBytes_FromStringAndSize(nullptr, m * 32);
   PyObject *sig_out = PyBytes_FromStringAndSize(nullptr, m * 64);
   PyObject *msgs_out = PyBytes_FromStringAndSize(nullptr, offs[m]);
@@ -2338,7 +2487,7 @@ static PyObject *py_commit_prep_fused(PyObject *, PyObject *args) {
   uint8_t *pub_d = (uint8_t *)PyBytes_AS_STRING(pub_out);
   uint8_t *sig_d = (uint8_t *)PyBytes_AS_STRING(sig_out);
   uint8_t *msg_d = (uint8_t *)PyBytes_AS_STRING(msgs_out);
-  Py_BEGIN_ALLOW_THREADS
+  GIL_FREE_BEGIN(COMMIT_PREP_FUSED)
   parallel_ranges(m, 1024, [&](Py_ssize_t lo_j, Py_ssize_t hi_j) {
     for (Py_ssize_t j = lo_j; j < hi_j; j++) {
       Py_ssize_t i = (Py_ssize_t)sel[(size_t)j];
@@ -2377,7 +2526,7 @@ static PyObject *py_commit_prep_fused(PyObject *, PyObject *args) {
       memcpy(p, sfx.buf, sfx.len);
     }
   });
-  Py_END_ALLOW_THREADS
+  GIL_FREE_END
   release_all();
   PyObject *t = PyLong_FromLongLong((long long)tallied);
   PyObject *tup =
@@ -2541,6 +2690,7 @@ static bool parse_record(const uint8_t *p, const uint8_t *end, uint8_t *flag,
 }  // namespace commitdec
 
 static PyObject *py_commit_decode_columns(PyObject *, PyObject *arg) {
+  gil::enter();
   // bytes only: the Python walk hands slices of its input on (bytearray
   // and memoryview slices are other types than these columns' bytes)
   if (!PyBytes_Check(arg)) Py_RETURN_NONE;
@@ -2551,7 +2701,7 @@ static PyObject *py_commit_decode_columns(PyObject *, PyObject *arg) {
   // the 8- and 4-byte lanes are aligned; copied out under the GIL below
   uint8_t *cols = nullptr;
   bool ok;
-  Py_BEGIN_ALLOW_THREADS
+  GIL_FREE_BEGIN(COMMIT_DECODE_COLUMNS)
   ok = commitdec::scan_outer(data, end, o);
   if (ok && o.n) {
     cols = (uint8_t *)malloc(o.n * (8 + 4 + 64 + 20 + 1));
@@ -2569,7 +2719,7 @@ static PyObject *py_commit_decode_columns(PyObject *, PyObject *arg) {
                                    secs + i, nanos + i, addr + 20 * i);
     }
   }
-  Py_END_ALLOW_THREADS
+  GIL_FREE_END
   if (!ok) {
     free(cols);
     Py_RETURN_NONE;
@@ -2689,6 +2839,7 @@ static bool walk(const uint8_t *p, const uint8_t *end, size_t &n,
 }  // namespace valsetdec
 
 static PyObject *py_valset_decode_columns(PyObject *, PyObject *arg) {
+  gil::enter();
   // bytes only, as commit_decode_columns: the walk's addresses are slices
   // of its input, of the input's own type
   if (!PyBytes_Check(arg)) Py_RETURN_NONE;
@@ -2701,14 +2852,14 @@ static PyObject *py_valset_decode_columns(PyObject *, PyObject *arg) {
   int64_t prop_nums[2] = {0, 0};
   size_t n = 0;
   bool ok;
-  Py_BEGIN_ALLOW_THREADS
+  GIL_FREE_BEGIN(VALSET_DECODE_COLUMNS)
   ok = valsetdec::walk(data, end, n, nullptr, nullptr, nullptr);
   if (ok) {
     cols = (uint8_t *)malloc(n * 68);
     ok = cols != nullptr &&
          valsetdec::walk(data, end, n, cols, prop, prop_nums);
   }
-  Py_END_ALLOW_THREADS
+  GIL_FREE_END
   if (!ok) {
     free(cols);
     Py_RETURN_NONE;
@@ -2727,7 +2878,7 @@ static PyObject *py_valset_decode_columns(PyObject *, PyObject *arg) {
 static PyMethodDef Methods[] = {
     {"commit_prep_fused", py_commit_prep_fused, METH_VARARGS,
      "Fused columnar commit prep: selection + tally + sign-bytes + "
-     "pub/sig gather, one GIL-released call"},
+     "pub/sig gather in one call (three GIL-free sections)"},
     {"ed25519_batch_verify", py_ed25519_batch_verify, METH_VARARGS,
      "Host RLC batch ed25519 verification (Pippenger MSM); returns bool"},
     {"ed25519_rlc_scalars", py_ed25519_rlc_scalars, METH_VARARGS,
@@ -2762,6 +2913,12 @@ static PyMethodDef Methods[] = {
     {"valset_decode_columns", py_valset_decode_columns, METH_O,
      "ValidatorSet wire bytes -> address/key/power/priority columns in one "
      "GIL-released walk; None for any input off the canonical shape"},
+    {"gil_stats", py_gil_stats, METH_NOARGS,
+     "{entry: (sections, free_s, wait_s)} of the entries that time their "
+     "GIL-free sections: process-wide, always on, only rises"},
+    {"last_sections", py_last_sections, METH_NOARGS,
+     "[(t_released, t_wanted, t_got), ...] in perf_counter seconds: the "
+     "GIL-free sections of the calling thread's last call of such an entry"},
     {nullptr, nullptr, 0, nullptr}};
 
 static struct PyModuleDef moduledef = {PyModuleDef_HEAD_INIT, "tm_native",

@@ -969,6 +969,8 @@ def _by_width(counter) -> dict:
 
 def ops_stats() -> dict:
     """Verify-engine snapshot for /status — no jax import, cheap reads."""
+    from .. import native as _native
+
     m = ops_metrics()
     im = ingress_metrics()
     sigs_device = m.sigs_verified.value(path="device")
@@ -1037,6 +1039,10 @@ def ops_stats() -> dict:
             for k, (s, c) in m.queue_wait_seconds.snapshot().items()
         },
         "cpu_seconds_by_thread": cpu_seconds_by_thread(),
+        # {entry: (sections, free_s, wait_s)}: the native module's timed
+        # entries, seconds run with the GIL given up and seconds waited
+        # to win it back (native.gil_stats)
+        "native_gil": _native.gil_stats(),
     }
 
 

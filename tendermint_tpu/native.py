@@ -7,6 +7,12 @@ pure-Python path with identical outputs. That degradation is LOUD: the
 failure, with the compiler's own stderr, is logged once; chip_smoke.py
 refuses to pass without the module. TM_TPU_NO_NATIVE=1 is the explicit
 way to run the pure-Python paths.
+
+The entries on a verified commit's path time their own GIL-free sections
+(tm_native.cpp, namespace gil). `traced_call` is how the program calls
+them: with the span tracer on, each section becomes two records on the
+calling thread, `<prefix>.native` (the work, the GIL given up) and
+`<prefix>.gil` (the wait to win it back).
 """
 
 from __future__ import annotations
@@ -17,6 +23,9 @@ import os
 import sys
 import sysconfig
 import threading
+import time
+
+from .observability.trace import TRACER as _TRACER
 
 _log = logging.getLogger("tendermint_tpu.native")
 
@@ -96,11 +105,50 @@ def load():
         return _module
 
 
+# tm_native reads CLOCK_MONOTONIC; the tracer reads time.perf_counter.
+# Where that is another clock, no native span is recorded: never a span
+# on a second clock.
+_ONE_CLOCK = (time.get_clock_info("perf_counter").implementation
+              == "clock_gettime(CLOCK_MONOTONIC)")
+
+
+def traced_call(mod, entry: str, prefix: str, *args):
+    """`mod.entry(*args)`, `mod` the loaded module. With the tracer on,
+    the call's GIL-free sections (tm_native.last_sections(), the module's
+    own clock reads) are recorded on the calling thread after the fact:
+    `<prefix>.native` over [t_released, t_wanted] and `<prefix>.gil` over
+    [t_wanted, t_got], one pair a section, args `entry` and `section`.
+    With it off this is the call and one attribute check."""
+    res = getattr(mod, entry)(*args)
+    if _TRACER.enabled and _ONE_CLOCK:
+        # a shared object built before the sections existed records none
+        spans = []
+        for i, (released, wanted, got) in enumerate(
+                getattr(mod, "last_sections", list)()):
+            at = {"entry": entry, "section": i}
+            spans.append((prefix + ".native", released, wanted, at))
+            spans.append((prefix + ".gil", wanted, got, at))
+        _TRACER.record_all(spans)
+    return res
+
+
+def gil_stats() -> dict:
+    """{entry: (sections, free_s, wait_s)} since the module was loaded:
+    the seconds its timed entries ran with the GIL given up, and the
+    seconds their threads then waited to win it back. Empty until the
+    module is loaded (a snapshot builds nothing)."""
+    fn = getattr(_module, "gil_stats", None)
+    return fn() if fn is not None else {}
+
+
 def columns(entry: str, data):
     """`entry(data)` of the module — one of the single-pass wire parses
     (commit_decode_columns, valset_decode_columns): its column tuple, or
     None where the parse answers None, the module is absent, or the module
     was built before `entry` existed (native/_build is not tracked: a stale
-    shared object reads as absent, never as an error)."""
-    fn = getattr(load(), entry, None)
-    return fn(data) if fn is not None else None
+    shared object reads as absent, never as an error). Spans
+    `wire.columns.native` / `wire.columns.gil` (traced_call)."""
+    mod = load()
+    if not hasattr(mod, entry):
+        return None
+    return traced_call(mod, entry, "wire.columns", data)

@@ -8,8 +8,11 @@ Design constraints (ISSUE 1):
 - **Thread-safe ring buffer.** Records are fixed-size tuples written under
   a lock into a preallocated ring; the buffer never grows, old spans are
   overwritten (wraparound), and recording is O(1) per span. Spans are
-  recorded per *batch* (host prep, device dispatch, device wait), never
-  per signature, so the lock is uncontended in practice.
+  recorded per *batch* or per commit (host prep, device dispatch, device
+  wait), never per signature. The lock is NOT uncontended: a commit's
+  caller writes ~25 records of its own, so 32 callers block-syncing
+  (with the tracer on) meet at it; it is held for two stores, and a
+  thread that loses it waits inside whatever span it was recording in.
 - **Nested spans.** Nesting falls out of the `with` discipline: a child's
   [start, end) interval is contained in its parent's on the same thread,
   which is exactly how Chrome-trace/Perfetto reconstruct the flame graph
@@ -177,6 +180,23 @@ class SpanTracer:
         with self._mtx:
             self._buf[self._n % self._cap] = rec
             self._n += 1
+
+    def record_all(self, spans) -> None:
+        """record() for several completed spans of the calling thread,
+        each (name, start, end, args): one look at the thread's args and
+        one hold of the lock for all of them. How intervals timed
+        elsewhere (the native module's GIL-free sections) enter the ring
+        after the fact."""
+        ambient = getattr(self._thread_args, "args", None)
+        tid = threading.get_ident()
+        recs = [(name, start, end, tid,
+                 ({**ambient, **args} if args else ambient) if ambient
+                 else args)
+                for name, start, end, args in spans]
+        with self._mtx:
+            for rec in recs:
+                self._buf[self._n % self._cap] = rec
+                self._n += 1
 
     def span(self, name: str, flow: Optional[int] = None,
              flow_phase: Optional[str] = None, **args) -> object:

@@ -23,7 +23,6 @@ below, the mesh's segments) asks it.
 
 from __future__ import annotations
 
-import contextlib
 import hashlib
 import os
 import time
@@ -793,23 +792,21 @@ def _verify_batch_direct(entries, step: int, scheme: str = "") -> np.ndarray:
         # dispatch vs wait split: jax dispatch returns before the device
         # finishes; materializing the result blocks until it has
         t0 = time.perf_counter()
-        with (_span("ops.device_rlc", n=len(chunk))
-              if rlc_entries is not None else contextlib.nullcontext()):
-            with _span("ops.device_dispatch", bucket=bucket):
-                dev = fn(*args)
-            with _span("ops.device_wait", bucket=bucket):
-                # owned copy, not a view: under donation a later chunk's
-                # launch recycles the output page and would mutate earlier
-                # chunks' verdicts still sitting in `out` (the PR-7 bug
-                # class, here across the chunks of ONE oversized batch)
-                res = np.array(dev)
-            if res.ndim == 2:  # pallas rows are (1, N) int32
-                res = res[0].astype(bool)
-            if rlc_entries is not None:
-                from . import pallas_rlc
+        with _span("ops.device_dispatch", bucket=bucket):
+            dev = fn(*args)
+        with _span("ops.device_wait", bucket=bucket):
+            # owned copy, not a view: under donation a later chunk's
+            # launch recycles the output page and would mutate earlier
+            # chunks' verdicts still sitting in `out` (the PR-7 bug
+            # class, here across the chunks of ONE oversized batch)
+            res = np.array(dev)
+        if res.ndim == 2:  # pallas rows are (1, N) int32
+            res = res[0].astype(bool)
+        if rlc_entries is not None:
+            from . import pallas_rlc
 
-                res = pallas_rlc.expand_lanes(
-                    res, rlc_entries, bucket // len(res))
+            res = pallas_rlc.expand_lanes(
+                res, rlc_entries, bucket // len(res))
         _note_device_batch(
             len(chunk), bucket, device_s=time.perf_counter() - t0
         )

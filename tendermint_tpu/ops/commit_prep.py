@@ -18,9 +18,12 @@ a dispatch-ready EntryBlock in ONE call:
     gather         pub (m, 32) / sig (m, 64) rows fancy-indexed from the
                    cached columns
 
-With the native module built the whole thing is one GIL-released C call
-(tm_native.commit_prep_fused); the numpy fallback below is differentially
-tested against it and against the object paths. RLC scalar prep stays in
+With the native module built the four stages are one C call
+(tm_native.commit_prep_fused) that gives the GIL up three times — around
+selection + tally, around the sign-bytes sizes, around sign bytes +
+gather — and takes it back between them to allocate its outputs; the
+numpy fallback below is differentially tested against it and against
+the object paths. RLC scalar prep stays in
 the per-batch fused native call (tm_native.ed25519_rlc_prep): the random
 z coefficients are drawn per DEVICE batch, and commits coalesce into
 batches after this stage, so per-commit RLC scalars would pin the batch
@@ -33,6 +36,8 @@ from typing import Optional, Tuple
 
 import numpy as np
 
+from .. import native as _native
+from ..observability.trace import span as _span
 from .entry_block import CommitBlock, EntryBlock
 
 # BlockIDFlag values (types/block.py) — re-declared to keep this module
@@ -140,36 +145,36 @@ def prep_commit_from(
     (CommitBlock present, all-ed25519 validator columns matching the
     commit size) + per-flag template fetch + prep_commit. Returns None
     when this commit/valset is not columnar-representable — callers fall
-    back to the object path and its exact legacy errors."""
-    cblock = commit.commit_block()
-    if cblock is None:
-        return None
-    cols = vals.ed25519_columns()
-    if cols is None or cols[0].shape[0] != cblock.n:
-        return None
-    tpl_c = commit.sign_bytes_template(chain_id, FLAG_COMMIT)
-    tpl_n = commit.sign_bytes_template(chain_id, FLAG_NIL)
-    sel, tallied, block = prep_commit(
-        cblock,
-        cols[0],
-        cols[1],
-        tpl_c[0],
-        tpl_n[0],
-        tpl_c[1],
-        threshold,
-        mode,
-    )
-    if block is not None:
-        # epoch-cache metadata: sel IS the valset row of each lane;
-        # table_rows names the device table the set gathers from and the
-        # lanes' rows there. The key is only attached for WARM sets
-        # (ops/epoch_cache.py) — downstream preps then ship gather
-        # indices instead of pubkey-derived arrays. A disabled cache
-        # returns None and the block is exactly what PR 4 produced.
-        from . import epoch_cache as _epoch
+    back to the object path and its exact legacy errors.
 
-        block.epoch_key, block.val_idx = _epoch.table_rows(
-            vals, sel.astype(np.int32))
+    Spans, inside the caller's (verify_commit.prep_fused or
+    pipeline.commit_prep_fused): ops.commit_prep.columns up to the fused
+    call, ops.commit_prep.native / .gil for each of its GIL-free sections
+    (native.traced_call), ops.commit_prep.block after it."""
+    with _span("ops.commit_prep.columns"):
+        cblock = commit.commit_block()
+        if cblock is None:
+            return None
+        cols = vals.ed25519_columns()
+        if cols is None or cols[0].shape[0] != cblock.n:
+            return None
+        tpl_c = commit.sign_bytes_template(chain_id, FLAG_COMMIT)
+        tpl_n = commit.sign_bytes_template(chain_id, FLAG_NIL)
+        args = _contiguous(cblock, cols[0], cols[1])
+    res = _fused(*args, tpl_c[0], tpl_n[0], tpl_c[1], threshold, mode)
+    with _span("ops.commit_prep.block"):
+        sel, tallied, block = _entry_block(res)
+        if block is not None:
+            # epoch-cache metadata: sel IS the valset row of each lane;
+            # table_rows names the device table the set gathers from and
+            # the lanes' rows there. The key is only attached for WARM sets
+            # (ops/epoch_cache.py) — downstream preps then ship gather
+            # indices instead of pubkey-derived arrays. A disabled cache
+            # returns None and the block is exactly what PR 4 produced.
+            from . import epoch_cache as _epoch
+
+            block.epoch_key, block.val_idx = _epoch.table_rows(
+                vals, sel.astype(np.int32))
     return sel, tallied, block
 
 
@@ -188,48 +193,58 @@ def prep_commit(
     caller raises ErrNotEnoughVotingPowerSigned without any sign-bytes
     work having happened, matching the object path's ordering.
 
-    Native path: ONE GIL-released call does all four stages
-    (tm_native.commit_prep_fused); numpy fallback below is differentially
-    tested (tests/test_commit_block.py)."""
-    from ..native import load as _load_native
+    Native path: ONE call does all four stages, in three GIL-free
+    sections (tm_native.commit_prep_fused); numpy fallback below is
+    differentially tested (tests/test_commit_block.py)."""
+    return _entry_block(_fused(
+        *_contiguous(cblock, pub_col, power_col),
+        prefix_commit, prefix_nil, suffix, threshold, mode))
 
-    native = _load_native()
-    if native is not None and hasattr(native, "commit_prep_fused"):
-        res = native.commit_prep_fused(
-            np.ascontiguousarray(cblock.flags),
-            np.ascontiguousarray(cblock.sig),
-            np.ascontiguousarray(cblock.ts_seconds),
-            np.ascontiguousarray(cblock.ts_nanos),
-            np.ascontiguousarray(pub_col),
-            np.ascontiguousarray(power_col),
-            prefix_commit,
-            prefix_nil,
-            suffix,
-            threshold,
-            mode,
-        )
-        sel = np.frombuffer(res[0], dtype=np.int64)
-        tallied = int(res[1])
-        if len(res) == 2:
-            return sel, tallied, None
-        pub_b, sig_b, msgs, offs_b = res[2:]
-        m = sel.shape[0]
-        block = EntryBlock(
-            np.frombuffer(pub_b, dtype=np.uint8).reshape(m, 32),
-            np.frombuffer(sig_b, dtype=np.uint8).reshape(m, 64),
-            msgs,
-            np.frombuffer(offs_b, dtype=np.int64),
-        )
-        return sel, tallied, block
-    return _prep_commit_numpy(
+
+def _contiguous(cblock: CommitBlock, pub_col, power_col) -> tuple:
+    """(cblock, the six columns the native entry reads, as buffers)."""
+    return (
         cblock,
-        pub_col,
-        power_col,
-        prefix_commit,
-        prefix_nil,
-        suffix,
-        threshold,
-        mode,
+        np.ascontiguousarray(cblock.flags),
+        np.ascontiguousarray(cblock.sig),
+        np.ascontiguousarray(cblock.ts_seconds),
+        np.ascontiguousarray(cblock.ts_nanos),
+        np.ascontiguousarray(pub_col),
+        np.ascontiguousarray(power_col),
+    )
+
+
+def _fused(cblock, flags, sig, ts_seconds, ts_nanos, pub_col, power_col,
+           prefix_commit, prefix_nil, suffix, threshold, mode):
+    """The four stages over _contiguous's columns: tm_native's result
+    tuple, (sel, tallied) or (sel, tallied, pub, sig, msgs, offsets) as
+    bytes, or, without the module, _prep_commit_numpy's triple."""
+    native = _native.load()
+    if native is not None and hasattr(native, "commit_prep_fused"):
+        return _native.traced_call(
+            native, "commit_prep_fused", "ops.commit_prep",
+            flags, sig, ts_seconds, ts_nanos, pub_col, power_col,
+            prefix_commit, prefix_nil, suffix, threshold, mode)
+    return _prep_commit_numpy(
+        cblock, pub_col, power_col, prefix_commit, prefix_nil, suffix,
+        threshold, mode)
+
+
+def _entry_block(res) -> Tuple[np.ndarray, int, Optional[EntryBlock]]:
+    """_fused's result as (sel_idx, tallied, EntryBlock or None)."""
+    if isinstance(res[0], np.ndarray):  # the numpy fallback's own triple
+        return res
+    sel = np.frombuffer(res[0], dtype=np.int64)
+    tallied = int(res[1])
+    if len(res) == 2:
+        return sel, tallied, None
+    pub_b, sig_b, msgs, offs_b = res[2:]
+    m = sel.shape[0]
+    return sel, tallied, EntryBlock(
+        np.frombuffer(pub_b, dtype=np.uint8).reshape(m, 32),
+        np.frombuffer(sig_b, dtype=np.uint8).reshape(m, 64),
+        msgs,
+        np.frombuffer(offs_b, dtype=np.int64),
     )
 
 

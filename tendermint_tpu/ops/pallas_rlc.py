@@ -60,7 +60,9 @@ from jax.experimental.pallas import tpu as pltpu
 
 from . import fe_t
 from . import pallas_verify as pv
+from .. import native as _native
 from ..crypto import _edwards
+from ..observability.trace import span as _span
 
 NL = fe_t.NLIMBS
 
@@ -643,53 +645,67 @@ def _rlc_host_scalars(entries, live: int, g_live: int, m: int):
     buffers (tm_native.ed25519_rlc_prep); tuple lists and native-absent
     builds keep the split numpy/Python path with identical outputs.
 
-    Returns (pub (live, 32), r_enc (live, 32), scal (g_live, 2m, 32),
-    s_ok (live,) bool)."""
-    from .backend import _challenges_any, _pack_rows, _s_below_l
-    from .entry_block import EntryBlock
-    from ..native import load as _load_native
+    Returns (pub (live, 32), r_enc (live, 32), raw, z (live, 32),
+    s_ok (live,) bool): raw is the lanes' S rows then their U rows, what
+    _scal_rows lays out for the kernel inside the caller's fill span.
 
-    n = len(entries)
-    pub, r_enc, s_enc = _pack_rows(entries, live)
-    z = _gen_z(live)
+    Spans, inside pipeline.prep: ops.rlc_prep.pack, ops.rlc_prep.z, then
+    ops.rlc_prep.native / .gil from the fused call's own clock reads
+    (native.traced_call), or ops.rlc_prep.native around the split path
+    (no .gil: its native pieces do not time themselves)."""
+    with _span("ops.rlc_prep.pack"):
+        from .backend import _challenges_any, _pack_rows, _s_below_l
+        from .entry_block import EntryBlock
 
-    native = _load_native()
-    if (
-        n
-        and isinstance(entries, EntryBlock)
-        and native is not None
-        and hasattr(native, "ed25519_rlc_prep")
-    ):
-        buf, offs = entries.msgs_contiguous()
-        k_raw, raw, sok_raw = native.ed25519_rlc_prep(
-            entries.pub.tobytes(),
-            entries.sig.tobytes(),
-            buf,
-            np.ascontiguousarray(offs).tobytes(),
-            z.tobytes(),
-            m,
-            live,
+        n = len(entries)
+        native = _native.load()
+        pub, r_enc, s_enc = _pack_rows(entries, live)
+        fused = bool(
+            n
+            and isinstance(entries, EntryBlock)
+            and native is not None
+            and hasattr(native, "ed25519_rlc_prep")
         )
+        if fused:
+            buf, offs = entries.msgs_contiguous()
+            cols = (
+                entries.pub.tobytes(),
+                entries.sig.tobytes(),
+                buf,
+                np.ascontiguousarray(offs).tobytes(),
+            )
+    with _span("ops.rlc_prep.z"):
+        z = _gen_z(live)
+        z_b = z.tobytes()
+    if fused:
+        _k_raw, raw, sok_raw = _native.traced_call(
+            native, "ed25519_rlc_prep", "ops.rlc_prep", *cols, z_b, m, live)
         s_ok = np.frombuffer(sok_raw, dtype=np.uint8).astype(bool)
     else:
-        s_ok = _s_below_l(s_enc, n, live)
-        k_enc = np.zeros((live, 32), dtype=np.uint8)
-        if n:
-            ks = _challenges_any(r_enc[:n], pub[:n], entries)
-            k_enc[:n] = np.frombuffer(ks, dtype=np.uint8).reshape(n, 32)
-        s_b, k_b, z_b = s_enc.tobytes(), k_enc.tobytes(), z.tobytes()
-        if native is not None and hasattr(native, "ed25519_rlc_scalars"):
-            raw = native.ed25519_rlc_scalars(s_b, k_b, z_b, m)
-        else:
-            raw = _rlc_scalars_py(s_b, k_b, z_b, m)
+        with _span("ops.rlc_prep.native"):
+            s_ok = _s_below_l(s_enc, n, live)
+            k_enc = np.zeros((live, 32), dtype=np.uint8)
+            if n:
+                ks = _challenges_any(r_enc[:n], pub[:n], entries)
+                k_enc[:n] = np.frombuffer(ks, dtype=np.uint8).reshape(n, 32)
+            s_b, k_b = s_enc.tobytes(), k_enc.tobytes()
+            if native is not None and hasattr(native, "ed25519_rlc_scalars"):
+                raw = native.ed25519_rlc_scalars(s_b, k_b, z_b, m)
+            else:
+                raw = _rlc_scalars_py(s_b, k_b, z_b, m)
+    return pub, r_enc, raw, z, s_ok
+
+
+def _scal_rows(raw: bytes, z: np.ndarray, g_live: int, m: int) -> np.ndarray:
+    """(g_live, 2m, 32) uint8 scalar rows of the live lanes: S, the m U
+    rows, then the lane's z rows 1..m-1."""
     S = np.frombuffer(raw[: 32 * g_live], dtype=np.uint8).reshape(g_live, 32)
     U = np.frombuffer(raw[32 * g_live :], dtype=np.uint8).reshape(g_live, m, 32)
-
     scal = np.zeros((g_live, 2 * m, 32), dtype=np.uint8)
     scal[:, 0] = S
     scal[:, 1 : m + 1] = U
     scal[:, m + 1 :] = z.reshape(g_live, m, 32)[:, 1:]
-    return pub, r_enc, scal, s_ok
+    return scal
 
 
 def _live_lanes(n: int, bucket: int, m: int) -> tuple:
@@ -713,30 +729,33 @@ def prepare_rlc(entries, bucket: int, m: int):
     Host work on top of the per-sig prep (pack + SHA-512 challenges +
     s<L): one 128x256-bit mod-L mul-add per signature (see
     _rlc_host_scalars), then the slot-major transposes the kernel layout
-    needs — warm epochs skip those via prepare_rlc_cached."""
+    needs (span ops.rlc_prep.fill) — warm epochs skip those via
+    prepare_rlc_cached."""
     g, g_live = _live_lanes(len(entries), bucket, m)
     live = g_live * m
-    pub, r_enc, scal, s_ok = _rlc_host_scalars(entries, live, g_live, m)
+    pub, r_enc, raw, z, s_ok = _rlc_host_scalars(entries, live, g_live, m)
 
     def slotmajor(arr):  # (live, 32) -> (m*32, g_live)
         return np.ascontiguousarray(
             arr.reshape(g_live, m, 32).transpose(1, 2, 0).reshape(m * 32, g_live)
         )
 
-    a_t = np.zeros((m * 32, g), dtype=np.uint8)
-    r_t = np.zeros((m * 32, g), dtype=np.uint8)
-    scal_t = np.zeros((2 * m * 32, g), dtype=np.uint8)
-    sok_t = np.ones((m, g), dtype=np.int32)
-    # padding lanes: identity encoding = byte 0 of each slot set to 1
-    a_t[np.arange(m) * 32, g_live:] = 1
-    r_t[np.arange(m) * 32, g_live:] = 1
-    if g_live:
-        a_t[:, :g_live] = slotmajor(pub)
-        r_t[:, :g_live] = slotmajor(r_enc)
-        scal_t[:, :g_live] = np.ascontiguousarray(
-            scal.transpose(1, 2, 0).reshape(2 * m * 32, g_live)
-        )
-        sok_t[:, :g_live] = s_ok.reshape(g_live, m).T.astype(np.int32)
+    with _span("ops.rlc_prep.fill"):
+        scal = _scal_rows(raw, z, g_live, m)
+        a_t = np.zeros((m * 32, g), dtype=np.uint8)
+        r_t = np.zeros((m * 32, g), dtype=np.uint8)
+        scal_t = np.zeros((2 * m * 32, g), dtype=np.uint8)
+        sok_t = np.ones((m, g), dtype=np.int32)
+        # padding lanes: identity encoding = byte 0 of each slot set to 1
+        a_t[np.arange(m) * 32, g_live:] = 1
+        r_t[np.arange(m) * 32, g_live:] = 1
+        if g_live:
+            a_t[:, :g_live] = slotmajor(pub)
+            r_t[:, :g_live] = slotmajor(r_enc)
+            scal_t[:, :g_live] = np.ascontiguousarray(
+                scal.transpose(1, 2, 0).reshape(2 * m * 32, g_live)
+            )
+            sok_t[:, :g_live] = s_ok.reshape(g_live, m).T.astype(np.int32)
     return a_t, r_t, scal_t, sok_t
 
 
@@ -749,21 +768,23 @@ def prepare_rlc_cached(entries, bucket: int, ep, m: int):
 
     Returns the 1-tuple (packed,): ONE int32 buffer per launch, filled
     through its four views (packed_views: idx, r_rows, scal_rows,
-    sok_rows) — one host-to-device operation instead of four."""
+    sok_rows; span ops.rlc_prep.fill) — one host-to-device operation
+    instead of four."""
     n = len(entries)
     _g, g_live = _live_lanes(n, bucket, m)
     live = g_live * m
-    _pub, r_enc, scal, s_ok = _rlc_host_scalars(entries, live, g_live, m)
+    _pub, r_enc, raw, z, s_ok = _rlc_host_scalars(entries, live, g_live, m)
 
-    packed = np.zeros((packed_layout(bucket, m)[-1],), dtype=np.int32)
-    idx, r_rows, scal_rows, sok_rows = packed_views(packed, bucket, m)
-    idx[:n] = entries.val_idx
-    idx[n:] = ep.vp - 1  # padding: the table's identity row
-    r_rows[:live] = r_enc
-    r_rows[live:, 0] = 1  # padding lanes: identity encoding
-    scal_rows[:g_live] = scal
-    sok_rows[:g_live] = s_ok.reshape(g_live, m)
-    sok_rows[g_live:] = 1
+    with _span("ops.rlc_prep.fill"):
+        packed = np.zeros((packed_layout(bucket, m)[-1],), dtype=np.int32)
+        idx, r_rows, scal_rows, sok_rows = packed_views(packed, bucket, m)
+        idx[:n] = entries.val_idx
+        idx[n:] = ep.vp - 1  # padding: the table's identity row
+        r_rows[:live] = r_enc
+        r_rows[live:, 0] = 1  # padding lanes: identity encoding
+        scal_rows[:g_live] = _scal_rows(raw, z, g_live, m)
+        sok_rows[:g_live] = s_ok.reshape(g_live, m)
+        sok_rows[g_live:] = 1
     return (packed,)
 
 

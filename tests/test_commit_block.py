@@ -337,13 +337,13 @@ class TestVerifyCommitFused:
         vset, bid, commit = _signed_commit(6, nil=(4,))
         dec = Commit.decode(commit.encode())
         calls = []
-        orig = cp.prep_commit
+        orig = cp._fused  # the four stages, under prep_commit_from's spans
 
         def spy(*a, **k):
             calls.append(1)
             return orig(*a, **k)
 
-        monkeypatch.setattr(cp, "prep_commit", spy)
+        monkeypatch.setattr(cp, "_fused", spy)
         validation.verify_commit(CHAIN_ID, vset, bid, 7, dec)
         assert calls, "fused prep was not taken for a columnar commit"
 

@@ -2,7 +2,10 @@
 the records of one launch join on one `launch` id across the four
 threads and nest as designed, a jax.profiler capture holds the program's
 spans, CPU seconds are read per pipeline thread, and the tracer module
-still imports without jax."""
+still imports without jax. Host prep says what it waits for (ISSUE 37):
+on the chip's host path every stage of both preps is a span inside its
+parent, the native entries' GIL-free sections among them.
+"""
 
 import glob
 import os
@@ -239,3 +242,143 @@ def test_a_virtual_clock_tracer_opens_no_profiler_annotation(monkeypatch):
     with tr.span("pipeline.dispatch"):
         pass
     assert opened == ["pipeline.dispatch"]
+
+
+# -- host prep's stages (ISSUE 37) ----------------------------------------------
+
+# span -> (thread, the span it lies inside)
+PREP_DESIGN = {
+    "wire.columns.native": ("MainThread", "bench.decode"),
+    "wire.columns.gil": ("MainThread", "bench.decode"),
+    "ops.commit_prep.columns": ("MainThread", "verify_commit.prep_fused"),
+    "ops.commit_prep.native": ("MainThread", "verify_commit.prep_fused"),
+    "ops.commit_prep.gil": ("MainThread", "verify_commit.prep_fused"),
+    "ops.commit_prep.block": ("MainThread", "verify_commit.prep_fused"),
+    "ops.rlc_prep.pack": ("verify-coalesce", "pipeline.prep"),
+    "ops.rlc_prep.z": ("verify-coalesce", "pipeline.prep"),
+    "ops.rlc_prep.native": ("verify-coalesce", "pipeline.prep"),
+    "ops.rlc_prep.gil": ("verify-coalesce", "pipeline.prep"),
+    "ops.rlc_prep.fill": ("verify-coalesce", "pipeline.prep"),
+}
+FROM_THE_NATIVE_CLOCK = [n for n in PREP_DESIGN if n.endswith((".native", ".gil"))]
+
+
+def _by_name(records):
+    by = {}
+    for r in records:
+        by.setdefault(r[0], []).append(r)
+    return by
+
+
+def _held_to_the_design(records, names, design):
+    by = _by_name(records)
+    assert set(design) <= set(by), set(design) - set(by)
+    for name, (thread, parent) in design.items():
+        (_p, p0, p1, ptid, _pa), = by[parent]
+        for _n, start, end, tid, _a in by[name]:
+            assert names[tid] == thread, name
+            assert ptid == tid and p0 <= start <= end <= p1, (name, parent)
+    return by
+
+
+@pytest.fixture(scope="module")
+def prep_requests():
+    with lt.chip_host_path():
+        yield lt.traced_requests()
+
+
+@pytest.mark.parametrize("sight", ["cold", "warm"])
+def test_every_stage_of_both_preps_lies_inside_its_parent(prep_requests, sight):
+    (cold, warm), names = prep_requests
+    by = _held_to_the_design(cold if sight == "cold" else warm, names,
+                             PREP_DESIGN)
+    assert by["pipeline.prep"][0][4]["cached"] == int(sight == "warm")
+    # one pair a GIL-free section: three of the fused commit prep, one of
+    # the decode, one of the RLC prep; a pair shares its boundary
+    for prefix, entry, sections in [
+            ("wire.columns", "commit_decode_columns", 1),
+            ("ops.commit_prep", "commit_prep_fused", 3),
+            ("ops.rlc_prep", "ed25519_rlc_prep", 1)]:
+        work, wait = by[prefix + ".native"], by[prefix + ".gil"]
+        assert [r[4]["section"] for r in work] == list(range(sections))
+        assert all(r[4]["entry"] == entry for r in work + wait)
+        assert [w[2] for w in work] == [g[1] for g in wait]
+        assert all(a[2] <= b[1] for a, b in zip(wait, work[1:])), prefix
+    # the stages follow one another and the dispatcher's launch id rides
+    # on the coalescer's records, the native ones too
+    at = {n: by[n][0] for n in ("ops.commit_prep.columns",
+                                "ops.commit_prep.native", "ops.rlc_prep.pack",
+                                "ops.rlc_prep.z", "ops.rlc_prep.native")}
+    assert at["ops.commit_prep.columns"][2] <= at["ops.commit_prep.native"][1]
+    assert by["ops.commit_prep.gil"][-1][2] <= by["ops.commit_prep.block"][0][1]
+    assert (at["ops.rlc_prep.pack"][2] <= at["ops.rlc_prep.z"][1]
+            <= at["ops.rlc_prep.z"][2] <= at["ops.rlc_prep.native"][1])
+    assert by["ops.rlc_prep.gil"][0][2] <= by["ops.rlc_prep.fill"][0][1]
+    launch = by["pipeline.prep"][0][4]["launch"]
+    assert all(r[4]["launch"] == launch for n in PREP_DESIGN
+               if n.startswith("ops.rlc_prep") for r in by[n])
+
+
+@pytest.mark.parametrize("parent", ["verify_commit.prep_fused", "pipeline.prep"])
+def test_the_stages_cover_their_parent_but_for_a_remainder(prep_requests, parent):
+    """What is left of a parent outside its stages is its self time
+    (select_kernel, plan_bucket, the span's own note, frames, the tracer's
+    own cost): under half of it on any host, a tenth on a quiet one."""
+    (_cold, warm), _names = prep_requests
+    by = _by_name(warm)
+    (_n, p0, p1, _tid, _a), = by[parent]
+    kids = sum(r[2] - r[1] for n, (_t, p) in PREP_DESIGN.items() if p == parent
+               for r in by[n])
+    assert 0.5 * (p1 - p0) < kids <= p1 - p0, (kids, p1 - p0)
+
+
+def test_with_the_tracer_off_no_section_is_read_and_nothing_is_recorded(monkeypatch):
+    from tendermint_tpu import native
+    from tendermint_tpu.types import validation
+    from tendermint_tpu.types.block import Commit
+
+    mod = native.load()
+    if mod is None:
+        pytest.skip("tm_native did not build")
+    reads = []
+    real = mod.last_sections
+    monkeypatch.setattr(mod, "last_sections",
+                        lambda: reads.append(1) or real())
+    with lt.chip_host_path():
+        vset, bid, commit = lt.signed_commit(66, height=66, first=3000)
+        wire = commit.encode()
+        for _ in range(2):
+            validation.verify_commit(lt.CHAIN_ID, vset, bid, commit.height,
+                                     Commit.decode(wire))
+        assert reads == [] and tr.TRACER.events() == []
+        before = ops_stats()["native_gil"]
+        tr.configure(enabled=True)
+        validation.verify_commit(lt.CHAIN_ID, vset, bid, commit.height,
+                                 Commit.decode(wire))
+        tr.configure(enabled=False)
+        after = ops_stats()["native_gil"]
+    # one read a call of a timed entry: decode, fused prep, RLC prep
+    assert len(reads) == 3
+    # the counter needs no tracer: it moved by the traced call's sections
+    assert {e: after[e][0] - before[e][0] for e in after} == {
+        "commit_decode_columns": 1, "valset_decode_columns": 0,
+        "commit_prep_fused": 3, "ed25519_rlc_prep": 1}
+
+
+def test_the_pure_python_paths_record_the_stages_and_no_native_section():
+    """TM_TPU_NO_NATIVE=1: the decode is the Python walk, the commit prep
+    the numpy fallback, the RLC scalars the split path, whose span stands
+    where the fused call's `.native` does; nothing waits to win a GIL it
+    never gave up."""
+    with lt.chip_host_path(native=False):
+        (cold, warm), names = lt.traced_requests(68)
+    stages = {n: v for n, v in PREP_DESIGN.items()
+              if n not in FROM_THE_NATIVE_CLOCK or n == "ops.rlc_prep.native"}
+    for records in (cold, warm):
+        by = _held_to_the_design(records, names, stages)
+        assert not [n for n in by if n.endswith(".gil")]
+        assert "wire.columns.native" not in by
+        assert "ops.commit_prep.native" not in by
+        # a `with` span around the fallback: no section of a native entry
+        (fallback,) = by["ops.rlc_prep.native"]
+        assert "entry" not in fallback[4]

@@ -1,0 +1,266 @@
+"""The native module times its own GIL-free sections (ISSUE 37):
+last_sections() is the calling thread's last call, on perf_counter's
+clock; gil_stats() only rises, by what the sections sum to; and a wait
+to win the GIL back shows as a span of its own where a second thread
+holds it."""
+
+import sys
+import threading
+import time
+
+import pytest
+
+pytest.importorskip("cryptography", reason="signs a real commit")
+
+import _launch_trace as lt  # noqa: E402
+
+from tendermint_tpu import native  # noqa: E402
+from tendermint_tpu.observability import trace as tr  # noqa: E402
+from tendermint_tpu.ops import commit_prep as cp  # noqa: E402
+
+pytestmark = pytest.mark.native_required
+
+ENTRIES = {"commit_decode_columns", "valset_decode_columns",
+           "commit_prep_fused", "ed25519_rlc_prep"}
+
+
+@pytest.fixture(scope="module")
+def mod():
+    return native.load()
+
+
+@pytest.fixture(scope="module")
+def commit():
+    """(validator set, decoded commit, its wire bytes), 40 validators."""
+    from tendermint_tpu.types.block import Commit
+
+    vset, _bid, c = lt.signed_commit(40, first=4000)
+    wire = c.encode()
+    return vset, Commit.decode(wire), wire
+
+
+@pytest.fixture(autouse=True)
+def _tracer_off():
+    tr.configure(enabled=False)
+    tr.TRACER.clear()
+    yield
+    tr.configure(enabled=False)
+    tr.TRACER.clear()
+
+
+def _fused_prep(mod, vset, decoded, threshold):
+    """One commit_prep_fused call as ops/commit_prep makes it."""
+    cols = vset.ed25519_columns()
+    tpl_c = decoded.sign_bytes_template(lt.CHAIN_ID, cp.FLAG_COMMIT)
+    tpl_n = decoded.sign_bytes_template(lt.CHAIN_ID, cp.FLAG_NIL)
+    args = cp._contiguous(decoded.commit_block(), cols[0], cols[1])[1:]
+    return mod.commit_prep_fused(*args, tpl_c[0], tpl_n[0], tpl_c[1],
+                                 threshold, cp.MODE_SELECT_COMMIT_ONLY)
+
+
+def test_the_tracers_clock_is_the_modules():
+    assert native._ONE_CLOCK, time.get_clock_info("perf_counter")
+
+
+def test_sections_are_ordered_and_lie_inside_the_call_on_perf_counters_clock(
+        mod, commit):
+    _vset, _decoded, wire = commit
+    t0 = time.perf_counter()
+    assert mod.commit_decode_columns(wire) is not None
+    t1 = time.perf_counter()
+    (released, wanted, got), = mod.last_sections()
+    assert t0 <= released <= wanted <= got <= t1
+    # read again, the same call's: nothing was consumed
+    assert mod.last_sections() == [(released, wanted, got)]
+
+
+def test_the_fused_prep_has_three_sections_and_one_where_it_returns_early(
+        mod, commit):
+    vset, decoded, _wire = commit
+    power = vset.total_voting_power()
+    t0 = time.perf_counter()
+    res = _fused_prep(mod, vset, decoded, power * 2 // 3)
+    t1 = time.perf_counter()
+    sections = mod.last_sections()
+    assert len(res) == 6 and len(sections) == 3
+    flat = [t for s in sections for t in s]
+    assert flat == sorted(flat) and t0 <= flat[0] and flat[-1] <= t1
+    # not enough power: the tally fails after the first section
+    assert len(_fused_prep(mod, vset, decoded, power)) == 2
+    assert len(mod.last_sections()) == 1
+    # an entry that never gives the GIL up (a str is no bytes) has none
+    assert mod.commit_decode_columns("no bytes") is None
+    assert mod.last_sections() == []
+
+
+def test_sections_are_the_calling_threads_own(mod, commit):
+    vset, decoded, wire = commit
+    seen = {}
+
+    def other():
+        _fused_prep(mod, vset, decoded, vset.total_voting_power() * 2 // 3)
+        ready.set()
+        go.wait(timeout=30)
+        seen["other"] = mod.last_sections()
+
+    ready, go = threading.Event(), threading.Event()
+    t = threading.Thread(target=other)
+    t.start()
+    assert ready.wait(timeout=30)
+    mod.commit_decode_columns(wire)     # after the other thread's call
+    mine = mod.last_sections()
+    go.set()
+    t.join(timeout=30)
+    assert not t.is_alive()
+    assert len(mine) == 1 and len(seen["other"]) == 3
+    assert seen["other"][-1][2] <= mine[0][0]
+    assert mod.last_sections() == mine
+
+
+def test_gil_stats_only_rise_by_what_the_sections_sum_to(mod, commit):
+    vset, decoded, wire = commit
+    first = mod.gil_stats()
+    assert set(first) == ENTRIES
+    calls = [("commit_decode_columns", lambda: mod.commit_decode_columns(wire)),
+             ("commit_prep_fused", lambda: _fused_prep(
+                 mod, vset, decoded, vset.total_voting_power() * 2 // 3)),
+             ("valset_decode_columns",
+              lambda: mod.valset_decode_columns(vset.encode()))]
+    for entry, call in calls:
+        before = mod.gil_stats()
+        assert call() is not None
+        sections = mod.last_sections()
+        after = mod.gil_stats()
+        assert after[entry][0] - before[entry][0] == len(sections) >= 1
+        assert after[entry][1] - before[entry][1] == pytest.approx(
+            sum(w - r for r, w, _g in sections), abs=1e-8)
+        assert after[entry][2] - before[entry][2] == pytest.approx(
+            sum(g - w for _r, w, g in sections), abs=1e-8)
+        assert all(a >= b for e in ENTRIES for a, b in zip(after[e], before[e]))
+        assert all(after[e] == before[e] for e in ENTRIES - {entry})
+    # the program's snapshot carries the same counter
+    from tendermint_tpu.libs.metrics import ops_stats
+
+    assert ops_stats()["native_gil"] == mod.gil_stats() == native.gil_stats()
+
+
+def test_traced_call_records_a_pair_a_section_and_nothing_when_off(mod, commit):
+    _vset, _decoded, wire = commit
+    assert native.columns("commit_decode_columns", wire) is not None
+    assert tr.TRACER.events() == []
+    tr.configure(enabled=True)
+    t0 = time.perf_counter()
+    native.columns("commit_decode_columns", wire)
+    t1 = time.perf_counter()
+    tr.configure(enabled=False)
+    work, wait = tr.TRACER.events()
+    at = {"entry": "commit_decode_columns", "section": 0}
+    assert (work[0], wait[0]) == ("wire.columns.native", "wire.columns.gil")
+    assert work[4] == wait[4] == at
+    assert work[3] == wait[3] == threading.get_ident()
+    assert t0 <= work[1] <= work[2] == wait[1] <= wait[2] <= t1
+    assert [(work[1], work[2], wait[2])] == mod.last_sections()
+
+
+def test_on_another_clock_no_native_span_is_recorded(monkeypatch, commit):
+    _vset, _decoded, wire = commit
+    monkeypatch.setattr(native, "_ONE_CLOCK", False)
+    tr.configure(enabled=True)
+    assert native.columns("commit_decode_columns", wire) is not None
+    tr.configure(enabled=False)
+    assert tr.TRACER.events() == []
+
+
+def test_record_all_is_record_for_each_under_one_lock():
+    t = tr.SpanTracer(capacity=16)
+    t.set_thread_args(launch=3)
+    t.record_all([("a", 1.0, 2.0, {"entry": "e"}), ("b", 2.0, 3.0, None)])
+    t.set_thread_args()
+    t.record_all([("c", 3.0, 4.0, None)])
+    tid = threading.get_ident()
+    assert t.events() == [("a", 1.0, 2.0, tid, {"launch": 3, "entry": "e"}),
+                          ("b", 2.0, 3.0, tid, {"launch": 3}),
+                          ("c", 3.0, 4.0, tid, None)]
+    assert t.recorded_total == 3
+
+
+CALLS = 40
+LANES = 50_000
+SWITCH_S = 0.005
+
+
+@pytest.fixture(scope="module")
+def long_wire():
+    """Wire bytes whose native decode runs for about a millisecond with
+    the GIL given up: 50 000 lanes (the decode checks no signature)."""
+    from tendermint_tpu.types.block import (
+        BLOCK_ID_FLAG_COMMIT, BlockID, Commit, CommitSig, PartSetHeader)
+    from tendermint_tpu.wire.canonical import Timestamp
+
+    bid = BlockID(hash=b"\x11" * 32,
+                  part_set_header=PartSetHeader(total=1, hash=b"\x22" * 32))
+    sigs = [CommitSig(block_id_flag=BLOCK_ID_FLAG_COMMIT,
+                      validator_address=i.to_bytes(20, "big"),
+                      timestamp=Timestamp(seconds=1_700_000_000, nanos=i + 1),
+                      signature=i.to_bytes(64, "big"))
+            for i in range(LANES)]
+    return Commit(height=9, round=0, block_id=bid, signatures=sigs).encode()
+
+
+def _gil_wait(wire) -> float:
+    """Seconds of `wire.columns.gil` over CALLS traced native decodes."""
+    tr.TRACER.clear()
+    tr.configure(enabled=True)
+    try:
+        for _ in range(CALLS):
+            assert native.columns("commit_decode_columns", wire) is not None
+    finally:
+        tr.configure(enabled=False)
+    waits = [e - s for n, s, e, _t, _a in tr.TRACER.events()
+             if n == "wire.columns.gil"]
+    assert len(waits) == CALLS
+    return sum(waits)
+
+
+@pytest.mark.time_limit(120)
+def test_a_thread_that_holds_the_gil_shows_as_gil_wait(long_wire):
+    """A second thread spinning in Python asks for the GIL once a switch
+    interval (5 ms); the decode's next release hands it over, and the
+    decode that wants it back waits out the spinner's interval: about one
+    call in four of the 40 waits 5 ms or more, some 50 ms in all, where a
+    lone caller waits about a microsecond a call. The floor lies 5 times
+    and more from both. (A release alone rarely loses the GIL: a section
+    is over before the waiting thread is awake; it changes hands where a
+    drop request is pending when it is released.)"""
+    floor = SWITCH_S                       # 5 ms
+    alone = _gil_wait(long_wire)
+    assert alone < floor, f"a lone caller waited {alone * 1e3:.3f} ms for the GIL"
+
+    stop, spinning = threading.Event(), threading.Event()
+
+    def spin():
+        n = 0
+        while not stop.is_set():
+            n += 1
+            if n == 1000:
+                spinning.set()
+
+    free_before = native.gil_stats()["commit_decode_columns"][1]
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(SWITCH_S)
+    t = threading.Thread(target=spin)
+    t.start()
+    try:
+        assert spinning.wait(timeout=30)
+        contended = _gil_wait(long_wire)
+    finally:
+        stop.set()
+        t.join(timeout=30)
+        sys.setswitchinterval(old)
+    assert not t.is_alive()
+    assert contended > 5 * floor and alone < floor / 5, (contended, alone)
+    # the always-on counter saw the same wait, and the wait is no part of
+    # the work: `free_s` rose by the decodes alone
+    _n, free_s, wait_s = native.gil_stats()["commit_decode_columns"]
+    assert wait_s >= contended
+    assert free_s - free_before < CALLS * 0.02

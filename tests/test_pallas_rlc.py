@@ -139,7 +139,8 @@ def _four_arrays(entries, bucket, ep, m):
     g = bucket // m
     g_live = min((n + m - 1) // m, g)
     live = g_live * m
-    _pub, r_enc, scal, s_ok = pr._rlc_host_scalars(entries, live, g_live, m)
+    _pub, r_enc, raw, z, s_ok = pr._rlc_host_scalars(entries, live, g_live, m)
+    scal = pr._scal_rows(raw, z, g_live, m)
     idx = np.full((bucket,), ep.vp - 1, dtype=np.int32)
     idx[:n] = entries.val_idx
     r_rows = np.zeros((bucket, 32), dtype=np.uint8)
