@@ -8,9 +8,10 @@
 // single-pass, GIL-released fast path here too, each answering None where
 // the Python walk that specifies it must decide: commit_decode_columns
 // (Commit.decode) and valset_decode_columns (ValidatorSet.decode).
-// The entries on a verified commit's path time their own GIL-free sections
-// (gil::Free below): how long the work ran without the interpreter lock and
-// how long the thread then waited to win it back.
+// The entries on a verified commit's path time their own sections (gil::Free
+// below): how long the work ran without the interpreter lock and how long
+// the thread then waited to win it back, or, for a section too short to be
+// worth a hand-over, that it kept the lock.
 // CPython C API (no pybind11 in this image), built by g++ on the first
 // tendermint_tpu.native.load().
 
@@ -37,13 +38,25 @@
 // is a contest the thread may lose for a switch interval or more, and a
 // span around the call reads that wait as the work it interrupted.
 //
-//   gil_stats()      {entry: (sections, free_s, wait_s)}: process-wide,
-//                    always on (three relaxed adds a section), only rises;
-//                    free = wanted - released, wait = got - wanted
-//   last_sections()  [(t_released, t_wanted, t_got), ...] in perf_counter
-//                    seconds: the calling thread's last call of an entry
-//                    below, one tuple a section (reset where the entry
-//                    starts; at most MAX_SECTIONS)
+// GIL_HELD_IF(ENTRY, held) is the same block asked not to let go where
+// `held` is true: a section of a microsecond, or of 20, hands the lock to
+// a waiter who is awake only in time for the NEXT release, and its thread
+// then queues behind every other caller to get it back (PERF.md §6, PR 37
+// and PR 38). Such a section reads the same clock around its work, keeps
+// its entry in last_sections() and waits for nothing: t_got == t_wanted.
+// Whether a section holds is decided by the size of its input, inside the
+// entry, and nowhere else.
+//
+//   gil_stats()      {entry: (sections, free_s, wait_s, held)}:
+//                    process-wide, always on (three relaxed adds a section
+//                    that let go, one for one that held), only rises;
+//                    sections, free = wanted - released and wait = got -
+//                    wanted are of the sections that gave the GIL up,
+//                    held counts those that kept it
+//   last_sections()  [(t_released, t_wanted, t_got, held), ...] in
+//                    perf_counter seconds: the calling thread's last call
+//                    of an entry below, one tuple a section (reset where
+//                    the entry starts; at most MAX_SECTIONS)
 namespace gil {
 
 enum Entry {
@@ -59,15 +72,16 @@ static const char *const ENTRY_NAMES[N_ENTRIES] = {
 static const int MAX_SECTIONS = 4;
 
 struct Stats {
-  std::atomic<uint64_t> sections{0}, free_ns{0}, wait_ns{0};
+  std::atomic<uint64_t> sections{0}, free_ns{0}, wait_ns{0}, held{0};
 };
 static Stats stats[N_ENTRIES];
 
 struct Last {
   int n;
   int64_t t[MAX_SECTIONS][3];
+  bool held[MAX_SECTIONS];
 };
-static thread_local Last last = {0, {}};
+static thread_local Last last = {0, {}, {}};
 
 static inline int64_t now_ns() {
   struct timespec ts;
@@ -79,26 +93,33 @@ static inline int64_t now_ns() {
 static inline void enter() { last.n = 0; }
 
 class Free {
-  PyThreadState *save_;
+  PyThreadState *save_;  // null: this section holds the GIL
   Entry entry_;
   int64_t released_;
 
  public:
-  explicit Free(Entry e) : save_(PyEval_SaveThread()), entry_(e) {
+  explicit Free(Entry e, bool held = false)
+      : save_(held ? nullptr : PyEval_SaveThread()), entry_(e) {
     released_ = now_ns();
   }
   Free(const Free &) = delete;
   Free &operator=(const Free &) = delete;
   ~Free() {
-    int64_t wanted = now_ns();
-    PyEval_RestoreThread(save_);
-    int64_t got = now_ns();
+    int64_t wanted = now_ns(), got = wanted;
     Stats &s = stats[entry_];
-    s.sections.fetch_add(1, std::memory_order_relaxed);
-    s.free_ns.fetch_add((uint64_t)(wanted - released_),
-                        std::memory_order_relaxed);
-    s.wait_ns.fetch_add((uint64_t)(got - wanted), std::memory_order_relaxed);
+    if (save_) {
+      PyEval_RestoreThread(save_);
+      got = now_ns();
+      s.sections.fetch_add(1, std::memory_order_relaxed);
+      s.free_ns.fetch_add((uint64_t)(wanted - released_),
+                          std::memory_order_relaxed);
+      s.wait_ns.fetch_add((uint64_t)(got - wanted),
+                          std::memory_order_relaxed);
+    } else {
+      s.held.fetch_add(1, std::memory_order_relaxed);
+    }
     if (last.n < MAX_SECTIONS) {
+      last.held[last.n] = !save_;
       int64_t *t = last.t[last.n++];
       t[0] = released_;
       t[1] = wanted;
@@ -108,8 +129,10 @@ class Free {
 };
 
 // The pair that takes Py_BEGIN_ALLOW_THREADS / Py_END_ALLOW_THREADS' place
-// (a block, as theirs is)
+// (a block, as theirs is); GIL_HELD_IF opens the same block and keeps the
+// GIL through it where `held` is true
 #define GIL_FREE_BEGIN(entry) { gil::Free gil_free_(gil::entry);
+#define GIL_HELD_IF(entry, held) { gil::Free gil_free_(gil::entry, (held));
 #define GIL_FREE_END }
 
 // time.perf_counter's own conversion (pytime.c _PyTime_AsSecondsDouble):
@@ -133,10 +156,11 @@ static PyObject *py_gil_stats(PyObject *, PyObject *) {
   for (int e = 0; e < gil::N_ENTRIES; e++) {
     const gil::Stats &s = gil::stats[e];
     PyObject *v = Py_BuildValue(
-        "(Kdd)",
+        "(KddK)",
         (unsigned long long)s.sections.load(std::memory_order_relaxed),
         (double)s.free_ns.load(std::memory_order_relaxed) / 1e9,
-        (double)s.wait_ns.load(std::memory_order_relaxed) / 1e9);
+        (double)s.wait_ns.load(std::memory_order_relaxed) / 1e9,
+        (unsigned long long)s.held.load(std::memory_order_relaxed));
     if (!v || PyDict_SetItemString(out, gil::ENTRY_NAMES[e], v) < 0) {
       Py_XDECREF(v);
       Py_DECREF(out);
@@ -152,9 +176,10 @@ static PyObject *py_last_sections(PyObject *, PyObject *) {
   PyObject *out = PyList_New(l.n);
   if (!out) return nullptr;
   for (int i = 0; i < l.n; i++) {
-    PyObject *v = Py_BuildValue("(ddd)", gil::seconds(l.t[i][0]),
+    PyObject *v = Py_BuildValue("(dddO)", gil::seconds(l.t[i][0]),
                                 gil::seconds(l.t[i][1]),
-                                gil::seconds(l.t[i][2]));
+                                gil::seconds(l.t[i][2]),
+                                l.held[i] ? Py_True : Py_False);
     if (!v) {
       Py_DECREF(out);
       return nullptr;
@@ -2373,11 +2398,18 @@ static PyObject *py_vote_sign_bytes_batch_buf(PyObject *, PyObject *args) {
 //                                                        otherwise
 //
 // The ENTIRE commit-side host prep of types.verify_commit in one call
-// over CommitBlock + ValidatorSet columns (ops/commit_prep.py). The GIL is
-// released THREE times in it — around selection + tally, around the
-// sign-bytes sizes, around sign bytes + gather — and taken back between
-// them to allocate the outputs: three waits for it a call (each timed:
-// gil::Free). The stages: flag selection, voting-power tally vs the 2/3
+// over CommitBlock + ValidatorSet columns (ops/commit_prep.py), in three
+// timed sections (gil::Free) with the outputs allocated between them:
+// selection + tally, the sign-bytes sizes, sign bytes + gather. The two
+// scans are ~1 ns a row (~10 us at 10 000 rows) and hold the GIL at every
+// size. The third gives it up where it has COMMIT_PREP_RELEASE_ROWS
+// selected rows or more, the size from which it is spread over threads
+// (3.6 ms at 10 000 rows, through which the coalescer's own prep must be
+// able to run), and holds below (at most a few tenths of a millisecond of
+// one thread): a 150-signature commit keeps the GIL through its whole
+// prep, where three releases had it queue behind every other caller to
+// win the lock back for sections of 1 us and 20 us (PERF.md §6, PR 38).
+// The stages: flag selection, voting-power tally vs the 2/3
 // threshold (validation.go:152 loop semantics, incl. early-stop keeping
 // the crossing lane), canonical sign-bytes composition into ONE
 // contiguous buffer (vote_sign_bytes_batch_buf layout, prefix chosen per
@@ -2393,6 +2425,11 @@ static size_t uvarint_len(uint64_t v) {
   }
   return i;
 }
+
+// The selected rows from which commit_prep_fused's third section is worth
+// a hand-over of the GIL: parallel_ranges' grain there, so a section that
+// runs on more than one thread lets go and a section of one thread holds.
+static const Py_ssize_t COMMIT_PREP_RELEASE_ROWS = 1024;
 
 static PyObject *py_commit_prep_fused(PyObject *, PyObject *args) {
   gil::enter();
@@ -2429,7 +2466,8 @@ static PyObject *py_commit_prep_fused(PyObject *, PyObject *args) {
   const bool sel_commit = mode & 1, count_fb = mode & 2, early = mode & 4;
   std::vector<int64_t> sel;
   int64_t tallied = 0;
-  GIL_FREE_BEGIN(COMMIT_PREP_FUSED)
+  // a scan of the flags: never worth a hand-over
+  GIL_HELD_IF(COMMIT_PREP_FUSED, true)
   sel.reserve((size_t)n);
   for (Py_ssize_t i = 0; i < n; i++) {
     uint8_t f = fp[i];
@@ -2462,7 +2500,8 @@ static PyObject *py_commit_prep_fused(PyObject *, PyObject *args) {
     return nullptr;
   }
   int64_t *offs = (int64_t *)PyBytes_AS_STRING(offs_out);
-  GIL_FREE_BEGIN(COMMIT_PREP_FUSED)
+  // a scan of the selected rows: never worth one either
+  GIL_HELD_IF(COMMIT_PREP_FUSED, true)
   offs[0] = 0;
   for (Py_ssize_t j = 0; j < m; j++) {
     Py_ssize_t i = (Py_ssize_t)sel[(size_t)j];
@@ -2487,8 +2526,9 @@ static PyObject *py_commit_prep_fused(PyObject *, PyObject *args) {
   uint8_t *pub_d = (uint8_t *)PyBytes_AS_STRING(pub_out);
   uint8_t *sig_d = (uint8_t *)PyBytes_AS_STRING(sig_out);
   uint8_t *msg_d = (uint8_t *)PyBytes_AS_STRING(msgs_out);
-  GIL_FREE_BEGIN(COMMIT_PREP_FUSED)
-  parallel_ranges(m, 1024, [&](Py_ssize_t lo_j, Py_ssize_t hi_j) {
+  GIL_HELD_IF(COMMIT_PREP_FUSED, m < COMMIT_PREP_RELEASE_ROWS)
+  parallel_ranges(m, COMMIT_PREP_RELEASE_ROWS,
+                  [&](Py_ssize_t lo_j, Py_ssize_t hi_j) {
     for (Py_ssize_t j = lo_j; j < hi_j; j++) {
       Py_ssize_t i = (Py_ssize_t)sel[(size_t)j];
       memcpy(pub_d + 32 * j, pp + 32 * i, 32);
@@ -2878,7 +2918,8 @@ static PyObject *py_valset_decode_columns(PyObject *, PyObject *arg) {
 static PyMethodDef Methods[] = {
     {"commit_prep_fused", py_commit_prep_fused, METH_VARARGS,
      "Fused columnar commit prep: selection + tally + sign-bytes + "
-     "pub/sig gather in one call (three GIL-free sections)"},
+     "pub/sig gather in one call (three timed sections; the GIL is given "
+     "up in the last alone, from 1 024 selected rows)"},
     {"ed25519_batch_verify", py_ed25519_batch_verify, METH_VARARGS,
      "Host RLC batch ed25519 verification (Pippenger MSM); returns bool"},
     {"ed25519_rlc_scalars", py_ed25519_rlc_scalars, METH_VARARGS,
@@ -2914,11 +2955,12 @@ static PyMethodDef Methods[] = {
      "ValidatorSet wire bytes -> address/key/power/priority columns in one "
      "GIL-released walk; None for any input off the canonical shape"},
     {"gil_stats", py_gil_stats, METH_NOARGS,
-     "{entry: (sections, free_s, wait_s)} of the entries that time their "
-     "GIL-free sections: process-wide, always on, only rises"},
+     "{entry: (sections, free_s, wait_s, held)} of the entries that time "
+     "their sections (those that gave the GIL up; the count that held it): "
+     "process-wide, always on, only rises"},
     {"last_sections", py_last_sections, METH_NOARGS,
-     "[(t_released, t_wanted, t_got), ...] in perf_counter seconds: the "
-     "GIL-free sections of the calling thread's last call of such an entry"},
+     "[(t_released, t_wanted, t_got, held), ...] in perf_counter seconds: "
+     "the sections of the calling thread's last call of such an entry"},
     {nullptr, nullptr, 0, nullptr}};
 
 static struct PyModuleDef moduledef = {PyModuleDef_HEAD_INIT, "tm_native",

@@ -1039,9 +1039,10 @@ def ops_stats() -> dict:
             for k, (s, c) in m.queue_wait_seconds.snapshot().items()
         },
         "cpu_seconds_by_thread": cpu_seconds_by_thread(),
-        # {entry: (sections, free_s, wait_s)}: the native module's timed
-        # entries, seconds run with the GIL given up and seconds waited
-        # to win it back (native.gil_stats)
+        # {entry: (sections, free_s, wait_s, held)}: the native module's
+        # timed entries, sections that gave the GIL up, their seconds
+        # without it, seconds waited to win it back, and sections that
+        # kept it (native.gil_stats)
         "native_gil": _native.gil_stats(),
     }
 

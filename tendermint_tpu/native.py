@@ -8,11 +8,13 @@ failure, with the compiler's own stderr, is logged once; chip_smoke.py
 refuses to pass without the module. TM_TPU_NO_NATIVE=1 is the explicit
 way to run the pure-Python paths.
 
-The entries on a verified commit's path time their own GIL-free sections
+The entries on a verified commit's path time their own sections
 (tm_native.cpp, namespace gil). `traced_call` is how the program calls
-them: with the span tracer on, each section becomes two records on the
-calling thread, `<prefix>.native` (the work, the GIL given up) and
-`<prefix>.gil` (the wait to win it back).
+them: with the span tracer on, a section that gave the GIL up becomes two
+records on the calling thread, `<prefix>.native` (the work) and
+`<prefix>.gil` (the wait to win it back); a section that kept it (too
+short to be worth a hand-over: the entry decides by the size of its input)
+becomes `<prefix>.native` alone, with `held: True` among its args.
 """
 
 from __future__ import annotations
@@ -114,29 +116,37 @@ _ONE_CLOCK = (time.get_clock_info("perf_counter").implementation
 
 def traced_call(mod, entry: str, prefix: str, *args):
     """`mod.entry(*args)`, `mod` the loaded module. With the tracer on,
-    the call's GIL-free sections (tm_native.last_sections(), the module's
+    the call's timed sections (tm_native.last_sections(), the module's
     own clock reads) are recorded on the calling thread after the fact:
     `<prefix>.native` over [t_released, t_wanted] and `<prefix>.gil` over
-    [t_wanted, t_got], one pair a section, args `entry` and `section`.
-    With it off this is the call and one attribute check."""
+    [t_wanted, t_got], one pair a section, args `entry` and `section`. A
+    section that held the GIL records `<prefix>.native` alone, with
+    `held: True`: it waited for nothing, and a wait of length zero would
+    only dilute the percentiles of those that did.
+    With the tracer off this is the call and one attribute check."""
     res = getattr(mod, entry)(*args)
     if _TRACER.enabled and _ONE_CLOCK:
         # a shared object built before the sections existed records none
         spans = []
-        for i, (released, wanted, got) in enumerate(
+        for i, (released, wanted, got, held) in enumerate(
                 getattr(mod, "last_sections", list)()):
             at = {"entry": entry, "section": i}
+            if held:
+                at["held"] = True
             spans.append((prefix + ".native", released, wanted, at))
-            spans.append((prefix + ".gil", wanted, got, at))
+            if not held:
+                spans.append((prefix + ".gil", wanted, got, at))
         _TRACER.record_all(spans)
     return res
 
 
 def gil_stats() -> dict:
-    """{entry: (sections, free_s, wait_s)} since the module was loaded:
-    the seconds its timed entries ran with the GIL given up, and the
-    seconds their threads then waited to win it back. Empty until the
-    module is loaded (a snapshot builds nothing)."""
+    """{entry: (sections, free_s, wait_s, held)} since the module was
+    loaded: how many sections of its timed entries gave the GIL up, the
+    seconds they ran without it, the seconds their threads then waited to
+    win it back, and how many sections kept it (commit_prep_fused: all
+    three of a commit under 1 024 selected rows, two of three above).
+    Empty until the module is loaded (a snapshot builds nothing)."""
     fn = getattr(_module, "gil_stats", None)
     return fn() if fn is not None else {}
 

@@ -19,12 +19,17 @@ a dispatch-ready EntryBlock in ONE call:
                    cached columns
 
 With the native module built the four stages are one C call
-(tm_native.commit_prep_fused) that gives the GIL up three times — around
-selection + tally, around the sign-bytes sizes, around sign bytes +
-gather — and takes it back between them to allocate its outputs; the
-numpy fallback below is differentially tested against it and against
-the object paths. RLC scalar prep stays in
-the per-batch fused native call (tm_native.ed25519_rlc_prep): the random
+(tm_native.commit_prep_fused) in three timed sections — selection +
+tally, the sign-bytes sizes, sign bytes + gather — with the outputs
+allocated between them. It keeps the GIL through the two scans at every
+size and gives it up in the third alone, from 1 024 selected rows (where
+that section is spread over threads and runs for milliseconds); a
+150-validator commit keeps the GIL from the first stage to the last, so
+among many callers it queues for the interpreter once a prep and not
+three times (PERF.md §6, PR 38). The numpy fallback below is
+differentially tested against it and against the object paths. RLC
+scalar prep stays in the per-batch fused native call
+(tm_native.ed25519_rlc_prep): the random
 z coefficients are drawn per DEVICE batch, and commits coalesce into
 batches after this stage, so per-commit RLC scalars would pin the batch
 composition before the coalescer has seen the traffic.
@@ -149,7 +154,8 @@ def prep_commit_from(
 
     Spans, inside the caller's (verify_commit.prep_fused or
     pipeline.commit_prep_fused): ops.commit_prep.columns up to the fused
-    call, ops.commit_prep.native / .gil for each of its GIL-free sections
+    call, ops.commit_prep.native for each of its three sections and
+    ops.commit_prep.gil after one that gave the GIL up
     (native.traced_call), ops.commit_prep.block after it."""
     with _span("ops.commit_prep.columns"):
         cblock = commit.commit_block()
@@ -193,8 +199,9 @@ def prep_commit(
     caller raises ErrNotEnoughVotingPowerSigned without any sign-bytes
     work having happened, matching the object path's ordering.
 
-    Native path: ONE call does all four stages, in three GIL-free
-    sections (tm_native.commit_prep_fused); numpy fallback below is
+    Native path: ONE call does all four stages, in three timed sections
+    of which the last gives the GIL up from 1 024 selected rows
+    (tm_native.commit_prep_fused); numpy fallback below is
     differentially tested (tests/test_commit_block.py)."""
     return _entry_block(_fused(
         *_contiguous(cblock, pub_col, power_col),

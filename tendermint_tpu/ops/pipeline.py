@@ -1383,8 +1383,8 @@ def commit_entries(
 
     Columnar commits (CommitBlock from wire decode, or built+cached on
     first use) with all-ed25519 validator columns take the FUSED path:
-    selection, tally, sign-bytes and gather in one call (native
-    GIL-released when built)."""
+    selection, tally, sign-bytes and gather in one call (native when
+    built; the GIL is given up from 1 024 selected rows)."""
     from . import commit_prep as _cp
 
     with _span("pipeline.commit_prep_fused", n=len(commit.signatures)):

@@ -756,11 +756,12 @@ def _fused_commit_prep(
     count_all_signatures: bool,
 ):
     """Columnar fast path: CommitBlock + validator columns through ONE
-    fused prep call (ops/commit_prep.py — native GIL-released when
-    built). Returns (sel_idx, tallied, EntryBlock-or-None) or None when
-    this commit/valset/predicate combination is not columnar-
-    representable (the object path below then reproduces the exact
-    legacy behavior and errors)."""
+    fused prep call (ops/commit_prep.py — native when built; it gives
+    the GIL up for the sign bytes + gather of 1 024 rows or more).
+    Returns (sel_idx, tallied, EntryBlock-or-None) or None when this
+    commit/valset/predicate combination is not columnar-representable
+    (the object path below then reproduces the exact legacy behavior and
+    errors)."""
     from ..ops import commit_prep as _cp
 
     if ignore_sig is _ignore_not_for_block:

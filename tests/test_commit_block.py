@@ -232,6 +232,21 @@ class TestCommitSigsView:
         assert commit.commit_block() is None
 
 
+def _native_prep_inputs(vset, commit):
+    """(tm_native, CommitBlock, the set's columns, COMMIT template, NIL
+    template) of a commit as decoded from its wire bytes; skips where the
+    module was built without the fused prep."""
+    from tendermint_tpu.native import load as _load_native
+
+    native = _load_native()
+    if not hasattr(native, "commit_prep_fused"):
+        pytest.skip("tm_native built without commit_prep_fused")
+    dec = Commit.decode(commit.encode())
+    return (native, dec.commit_block(), vset.ed25519_columns(),
+            dec.sign_bytes_template(CHAIN_ID, BLOCK_ID_FLAG_COMMIT),
+            dec.sign_bytes_template(CHAIN_ID, BLOCK_ID_FLAG_NIL))
+
+
 class TestFusedPrepDifferential:
     @pytest.mark.parametrize("mode", [
         0,
@@ -269,11 +284,6 @@ class TestFusedPrepDifferential:
         cp.MODE_COUNT_FOR_BLOCK | cp.MODE_EARLY_STOP,
     ])
     def test_native_matches_numpy(self, mode):
-        from tendermint_tpu.native import load as _load_native
-
-        native = _load_native()
-        if not hasattr(native, "commit_prep_fused"):
-            pytest.skip("tm_native built without commit_prep_fused")
         vset, commit = _random_commit(150, nil=(2, 9, 77), absent=(1, 80))
         # edge-case timestamps: zero seconds, negative nanos, zero nanos
         sigs = list(commit.signatures)
@@ -287,11 +297,7 @@ class TestFusedPrepDifferential:
                 signature=cs.signature,
             )
         commit.signatures = sigs
-        dec = Commit.decode(commit.encode())
-        cb = dec.commit_block()
-        cols = vset.ed25519_columns()
-        pc = dec.sign_bytes_template(CHAIN_ID, BLOCK_ID_FLAG_COMMIT)
-        pn = dec.sign_bytes_template(CHAIN_ID, BLOCK_ID_FLAG_NIL)
+        _native, cb, cols, pc, pn = _native_prep_inputs(vset, commit)
         for thr in (100, vset.total_voting_power() * 2 // 3, 10 ** 12):
             a = cp.prep_commit(cb, cols[0], cols[1], pc[0], pn[0],
                                pc[1], thr, mode)
@@ -306,6 +312,34 @@ class TestFusedPrepDifferential:
             assert np.array_equal(a[2].sig, b[2].sig)
             assert np.array_equal(a[2].offsets, b[2].offsets)
             assert bytes(a[2].msgs) == bytes(b[2].msgs)
+
+    @pytest.mark.native_required
+    @pytest.mark.parametrize("n", [150, 1023, 1024, 1025, 1026, 4096])
+    def test_native_matches_numpy_on_both_sides_of_the_release_floor(self, n):
+        """The fused prep keeps the GIL through its third section under
+        1 024 SELECTED rows and gives it up from there on (ISSUE 38): the
+        six outputs are the numpy fallback's byte for byte on both sides,
+        and the two of the early return (the tally fails) too."""
+        vset, commit = _random_commit(n, seed=n, nil=(2, 9, 77),
+                                      absent=(1, 80))
+        native, cb, cols, pc, pn = _native_prep_inputs(vset, commit)
+        args = cp._contiguous(cb, cols[0], cols[1])
+        for thr in (vset.total_voting_power() * 2 // 3, 10 ** 12):
+            res = native.commit_prep_fused(*args[1:], pc[0], pn[0], pc[1],
+                                           thr, 0)
+            held = [h for *_t, h in native.last_sections()]
+            sel, tallied, blk = cp._prep_commit_numpy(
+                cb, cols[0], cols[1], pc[0], pn[0], pc[1], thr, 0)
+            assert res[0] == sel.tobytes() and res[1] == tallied
+            assert sel.shape[0] == n - 2
+            if thr == 10 ** 12:
+                assert blk is None and len(res) == 2 and held == [True]
+                continue
+            assert held == [True, True, n - 2 < 1024]
+            assert res[2] == blk.pub.tobytes()
+            assert res[3] == blk.sig.tobytes()
+            assert res[4] == bytes(blk.msgs)
+            assert res[5] == np.asarray(blk.offsets, np.int64).tobytes()
 
     def test_commit_entries_fused_matches_legacy(self):
         vset, commit = _random_commit(90, absent=(4,))

@@ -251,8 +251,10 @@ PREP_DESIGN = {
     "wire.columns.native": ("MainThread", "bench.decode"),
     "wire.columns.gil": ("MainThread", "bench.decode"),
     "ops.commit_prep.columns": ("MainThread", "verify_commit.prep_fused"),
+    # no ops.commit_prep.gil: under 1 024 rows the fused prep's sections
+    # all keep the GIL (ISSUE 38; the pair of one that lets go is
+    # tests/test_native_gil.py's)
     "ops.commit_prep.native": ("MainThread", "verify_commit.prep_fused"),
-    "ops.commit_prep.gil": ("MainThread", "verify_commit.prep_fused"),
     "ops.commit_prep.block": ("MainThread", "verify_commit.prep_fused"),
     "ops.rlc_prep.pack": ("verify-coalesce", "pipeline.prep"),
     "ops.rlc_prep.z": ("verify-coalesce", "pipeline.prep"),
@@ -293,24 +295,29 @@ def test_every_stage_of_both_preps_lies_inside_its_parent(prep_requests, sight):
     by = _held_to_the_design(cold if sight == "cold" else warm, names,
                              PREP_DESIGN)
     assert by["pipeline.prep"][0][4]["cached"] == int(sight == "warm")
-    # one pair a GIL-free section: three of the fused commit prep, one of
-    # the decode, one of the RLC prep; a pair shares its boundary
-    for prefix, entry, sections in [
-            ("wire.columns", "commit_decode_columns", 1),
-            ("ops.commit_prep", "commit_prep_fused", 3),
-            ("ops.rlc_prep", "ed25519_rlc_prep", 1)]:
-        work, wait = by[prefix + ".native"], by[prefix + ".gil"]
-        assert [r[4]["section"] for r in work] == list(range(sections))
-        assert all(r[4]["entry"] == entry for r in work + wait)
-        assert [w[2] for w in work] == [g[1] for g in wait]
-        assert all(a[2] <= b[1] for a, b in zip(wait, work[1:])), prefix
+    # one pair a section that gave the GIL up: one of the decode, one of
+    # the RLC prep; a pair shares its boundary
+    for prefix, entry in [("wire.columns", "commit_decode_columns"),
+                          ("ops.rlc_prep", "ed25519_rlc_prep")]:
+        (work,), (wait,) = by[prefix + ".native"], by[prefix + ".gil"]
+        assert work[4]["section"] == wait[4]["section"] == 0
+        assert work[4]["entry"] == wait[4]["entry"] == entry
+        assert work[2] == wait[1] and "held" not in work[4]
+    # the fused commit prep's three sections kept it: the work alone, in
+    # order, marked, and no wait
+    held = by["ops.commit_prep.native"]
+    assert [r[4]["section"] for r in held] == [0, 1, 2]
+    assert all(r[4]["entry"] == "commit_prep_fused" and r[4]["held"] is True
+               for r in held)
+    assert all(a[2] <= b[1] for a, b in zip(held, held[1:]))
+    assert "ops.commit_prep.gil" not in by
     # the stages follow one another and the dispatcher's launch id rides
     # on the coalescer's records, the native ones too
     at = {n: by[n][0] for n in ("ops.commit_prep.columns",
                                 "ops.commit_prep.native", "ops.rlc_prep.pack",
                                 "ops.rlc_prep.z", "ops.rlc_prep.native")}
     assert at["ops.commit_prep.columns"][2] <= at["ops.commit_prep.native"][1]
-    assert by["ops.commit_prep.gil"][-1][2] <= by["ops.commit_prep.block"][0][1]
+    assert held[-1][2] <= by["ops.commit_prep.block"][0][1]
     assert (at["ops.rlc_prep.pack"][2] <= at["ops.rlc_prep.z"][1]
             <= at["ops.rlc_prep.z"][2] <= at["ops.rlc_prep.native"][1])
     assert by["ops.rlc_prep.gil"][0][2] <= by["ops.rlc_prep.fill"][0][1]
@@ -360,9 +367,11 @@ def test_with_the_tracer_off_no_section_is_read_and_nothing_is_recorded(monkeypa
     # one read a call of a timed entry: decode, fused prep, RLC prep
     assert len(reads) == 3
     # the counter needs no tracer: it moved by the traced call's sections
-    assert {e: after[e][0] - before[e][0] for e in after} == {
-        "commit_decode_columns": 1, "valset_decode_columns": 0,
-        "commit_prep_fused": 3, "ed25519_rlc_prep": 1}
+    # (those that gave the GIL up, those that held it)
+    assert {e: (after[e][0] - before[e][0], after[e][3] - before[e][3])
+            for e in after} == {
+        "commit_decode_columns": (1, 0), "valset_decode_columns": (0, 0),
+        "commit_prep_fused": (0, 3), "ed25519_rlc_prep": (1, 0)}
 
 
 def test_the_pure_python_paths_record_the_stages_and_no_native_section():

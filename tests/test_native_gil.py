@@ -1,13 +1,18 @@
-"""The native module times its own GIL-free sections (ISSUE 37):
+"""The native module times its own sections (ISSUE 37):
 last_sections() is the calling thread's last call, on perf_counter's
 clock; gil_stats() only rises, by what the sections sum to; and a wait
 to win the GIL back shows as a span of its own where a second thread
-holds it."""
+holds it. A section too short to be worth a hand-over keeps the GIL
+(ISSUE 38): commit_prep_fused's two scans always, its third under 1 024
+selected rows; it is timed and counted all the same, and waits for
+nothing."""
 
+import contextlib
 import sys
 import threading
 import time
 
+import numpy as np
 import pytest
 
 pytest.importorskip("cryptography", reason="signs a real commit")
@@ -68,10 +73,10 @@ def test_sections_are_ordered_and_lie_inside_the_call_on_perf_counters_clock(
     t0 = time.perf_counter()
     assert mod.commit_decode_columns(wire) is not None
     t1 = time.perf_counter()
-    (released, wanted, got), = mod.last_sections()
-    assert t0 <= released <= wanted <= got <= t1
+    (released, wanted, got, held), = mod.last_sections()
+    assert t0 <= released <= wanted <= got <= t1 and held is False
     # read again, the same call's: nothing was consumed
-    assert mod.last_sections() == [(released, wanted, got)]
+    assert mod.last_sections() == [(released, wanted, got, False)]
 
 
 def test_the_fused_prep_has_three_sections_and_one_where_it_returns_early(
@@ -83,8 +88,10 @@ def test_the_fused_prep_has_three_sections_and_one_where_it_returns_early(
     t1 = time.perf_counter()
     sections = mod.last_sections()
     assert len(res) == 6 and len(sections) == 3
-    flat = [t for s in sections for t in s]
+    flat = [t for s in sections for t in s[:3]]
     assert flat == sorted(flat) and t0 <= flat[0] and flat[-1] <= t1
+    # 40 rows: every section kept the GIL, so none waited for it
+    assert all(held and got == wanted for _r, wanted, got, held in sections)
     # not enough power: the tally fails after the first section
     assert len(_fused_prep(mod, vset, decoded, power)) == 2
     assert len(mod.last_sections()) == 1
@@ -131,11 +138,14 @@ def test_gil_stats_only_rise_by_what_the_sections_sum_to(mod, commit):
         assert call() is not None
         sections = mod.last_sections()
         after = mod.gil_stats()
-        assert after[entry][0] - before[entry][0] == len(sections) >= 1
+        let_go = [s for s in sections if not s[3]]
+        assert len(sections) >= 1
+        assert after[entry][0] - before[entry][0] == len(let_go)
+        assert after[entry][3] - before[entry][3] == len(sections) - len(let_go)
         assert after[entry][1] - before[entry][1] == pytest.approx(
-            sum(w - r for r, w, _g in sections), abs=1e-8)
+            sum(w - r for r, w, _g, _h in let_go), abs=1e-8)
         assert after[entry][2] - before[entry][2] == pytest.approx(
-            sum(g - w for _r, w, g in sections), abs=1e-8)
+            sum(g - w for _r, w, g, _h in let_go), abs=1e-8)
         assert all(a >= b for e in ENTRIES for a, b in zip(after[e], before[e]))
         assert all(after[e] == before[e] for e in ENTRIES - {entry})
     # the program's snapshot carries the same counter
@@ -159,7 +169,7 @@ def test_traced_call_records_a_pair_a_section_and_nothing_when_off(mod, commit):
     assert work[4] == wait[4] == at
     assert work[3] == wait[3] == threading.get_ident()
     assert t0 <= work[1] <= work[2] == wait[1] <= wait[2] <= t1
-    assert [(work[1], work[2], wait[2])] == mod.last_sections()
+    assert [(work[1], work[2], wait[2], False)] == mod.last_sections()
 
 
 def test_on_another_clock_no_native_span_is_recorded(monkeypatch, commit):
@@ -187,6 +197,33 @@ def test_record_all_is_record_for_each_under_one_lock():
 CALLS = 40
 LANES = 50_000
 SWITCH_S = 0.005
+
+
+@contextlib.contextmanager
+def _spinner():
+    """A second thread that spins in Python and asks for the GIL once a
+    switch interval (5 ms), for the block's length."""
+    stop, spinning = threading.Event(), threading.Event()
+
+    def spin():
+        n = 0
+        while not stop.is_set():
+            n += 1
+            if n == 1000:
+                spinning.set()
+
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(SWITCH_S)
+    t = threading.Thread(target=spin)
+    t.start()
+    try:
+        assert spinning.wait(timeout=30)
+        yield
+    finally:
+        stop.set()
+        t.join(timeout=30)
+        sys.setswitchinterval(old)
+    assert not t.is_alive()
 
 
 @pytest.fixture(scope="module")
@@ -236,31 +273,130 @@ def test_a_thread_that_holds_the_gil_shows_as_gil_wait(long_wire):
     alone = _gil_wait(long_wire)
     assert alone < floor, f"a lone caller waited {alone * 1e3:.3f} ms for the GIL"
 
-    stop, spinning = threading.Event(), threading.Event()
-
-    def spin():
-        n = 0
-        while not stop.is_set():
-            n += 1
-            if n == 1000:
-                spinning.set()
-
     free_before = native.gil_stats()["commit_decode_columns"][1]
-    old = sys.getswitchinterval()
-    sys.setswitchinterval(SWITCH_S)
-    t = threading.Thread(target=spin)
-    t.start()
-    try:
-        assert spinning.wait(timeout=30)
+    with _spinner():
         contended = _gil_wait(long_wire)
-    finally:
-        stop.set()
-        t.join(timeout=30)
-        sys.setswitchinterval(old)
-    assert not t.is_alive()
     assert contended > 5 * floor and alone < floor / 5, (contended, alone)
     # the always-on counter saw the same wait, and the wait is no part of
     # the work: `free_s` rose by the decodes alone
-    _n, free_s, wait_s = native.gil_stats()["commit_decode_columns"]
+    _n, free_s, wait_s, _held = native.gil_stats()["commit_decode_columns"]
     assert wait_s >= contended
     assert free_s - free_before < CALLS * 0.02
+
+
+HUB_ROWS = 150               # a hub150 commit: every section holds
+BIG_ROWS = 4096              # past the floor: the third section lets go
+
+
+def _rows(n):
+    """commit_prep_fused's eleven arguments for n COMMIT lanes (the prep
+    checks no signature: random rows do)."""
+    rng = np.random.RandomState(n)
+    return (np.full(n, cp.FLAG_COMMIT, np.uint8),
+            rng.randint(0, 256, (n, 64), dtype=np.uint8),
+            np.full(n, 1_700_000_000, np.int64),
+            np.arange(1, n + 1, dtype=np.int32),
+            rng.randint(0, 256, (n, 32), dtype=np.uint8),
+            np.full(n, 100, np.int64),
+            b"\x08\x02\x11\x07prefix", b"\x08\x02\x11\x07nil", b"\x32\x05chain",
+            100 * n * 2 // 3, cp.MODE_SELECT_COMMIT_ONLY)
+
+
+@pytest.mark.time_limit(120)
+def test_a_short_prep_keeps_the_gil_and_a_long_one_gives_it_up_once(mod):
+    hub, big = _rows(HUB_ROWS), _rows(BIG_ROWS)
+    with _spinner():
+        before = mod.gil_stats()["commit_prep_fused"]
+        for _ in range(CALLS):
+            assert len(mod.commit_prep_fused(*hub)) == 6
+        sections = mod.last_sections()
+        after = mod.gil_stats()["commit_prep_fused"]
+        # beside a thread that wants the GIL: nothing let go, no wait
+        assert len(sections) == 3
+        assert all(held and got == wanted
+                   for _r, wanted, got, held in sections)
+        assert (after[0], after[1], after[2]) == before[:3]
+        assert after[3] - before[3] == 3 * CALLS
+        assert len(mod.commit_prep_fused(*big)) == 6
+        sections = mod.last_sections()
+        last = mod.gil_stats()["commit_prep_fused"]
+    assert [held for *_t, held in sections] == [True, True, False]
+    assert last[0] - after[0] == 1 and last[3] - after[3] == 2
+    released, wanted, got, _held = sections[2]
+    assert last[1] - after[1] == pytest.approx(wanted - released, abs=1e-8)
+    assert last[2] - after[2] == pytest.approx(got - wanted, abs=1e-8)
+
+
+def test_sixteen_threads_in_the_held_prep_give_what_one_gives(mod):
+    """A held section takes no lock a released one did not take: many
+    callers at once (the interpreter switches them between calls, never
+    inside one) compute what one computes."""
+    hub = _rows(HUB_ROWS)
+    alone = mod.commit_prep_fused(*hub)
+    before = mod.gil_stats()["commit_prep_fused"]
+    results, go = {}, threading.Barrier(16)
+
+    def call(k):
+        go.wait(timeout=30)
+        results[k] = [mod.commit_prep_fused(*hub) for _ in range(50)]
+
+    threads = [threading.Thread(target=call, args=(k,)) for k in range(16)]
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)      # switch the callers as often as can be
+    try:
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=60)
+    finally:
+        sys.setswitchinterval(old)
+    assert not any(t.is_alive() for t in threads)
+    assert len(results) == 16
+    assert all(r == alone for rs in results.values() for r in rs)
+    after = mod.gil_stats()["commit_prep_fused"]
+    assert after[3] - before[3] == 16 * 50 * 3 and after[:3] == before[:3]
+
+
+@pytest.mark.parametrize("rows,held", [(HUB_ROWS, [True, True, True]),
+                                       (BIG_ROWS, [True, True, False])])
+def test_traced_call_records_no_gil_span_for_a_section_that_held(
+        mod, rows, held):
+    args = _rows(rows)
+    tr.configure(enabled=True)
+    res = native.traced_call(mod, "commit_prep_fused", "ops.commit_prep",
+                             *args)
+    tr.configure(enabled=False)
+    assert len(res) == 6
+    sections = mod.last_sections()
+    assert [h for *_t, h in sections] == held
+    want = []
+    for i, (released, wanted, got, h) in enumerate(sections):
+        at = {"entry": "commit_prep_fused", "section": i}
+        if h:
+            want.append(("ops.commit_prep.native", released, wanted,
+                         {**at, "held": True}))
+        else:
+            want.append(("ops.commit_prep.native", released, wanted, at))
+            want.append(("ops.commit_prep.gil", wanted, got, at))
+    tid = threading.get_ident()
+    assert tr.TRACER.events() == [(n, a, b, tid, at) for n, a, b, at in want]
+
+
+@pytest.mark.time_limit(120)
+def test_the_gil_probe_runs_without_a_device(tmp_path, capsys):
+    """tools/gil_probe.py, two callers for a second on hub150's pool: the
+    commits it counts are the fused preps it made (three held sections
+    each at 150 rows, none that let go), one decode a commit."""
+    import json
+
+    from tools import gil_probe
+
+    assert gil_probe.main(["--callers", "2", "--sleep-ms", "16",
+                           "--seconds", "1", "--root", str(tmp_path)]) == 0
+    out = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+    assert out["callers"] == 2 and out["sigs_per_commit"] == HUB_ROWS
+    assert 2 <= out["commits"] and 0 < out["commits_per_s"]
+    assert out["gil"]["commit_prep_fused"] == {
+        "released": 0, "held": 3 * out["commits"], "free_s": 0.0,
+        "wait_s": 0.0}
+    assert out["gil"]["commit_decode_columns"]["released"] == out["commits"]
