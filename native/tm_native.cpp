@@ -64,11 +64,12 @@ enum Entry {
   VALSET_DECODE_COLUMNS,
   COMMIT_PREP_FUSED,
   ED25519_RLC_PREP,
+  SR25519_CHALLENGES,
   N_ENTRIES
 };
 static const char *const ENTRY_NAMES[N_ENTRIES] = {
     "commit_decode_columns", "valset_decode_columns", "commit_prep_fused",
-    "ed25519_rlc_prep"};
+    "ed25519_rlc_prep", "sr25519_challenges_buf"};
 static const int MAX_SECTIONS = 4;
 
 struct Stats {
@@ -811,10 +812,10 @@ static void append_message(Strobe &s, const uint8_t *label, size_t ln,
 
 }  // namespace merlin
 
-// sr25519_challenges(ctx, pubs, rs, msgs) -> n x 64-byte challenge bytes.
 // Shared schnorrkel signing-transcript framing (consensus-critical label
 // sequence) -> the 64-byte "sign:c" challenge. Used by both the
-// challenge-only and full-verify lanes so the framing cannot diverge.
+// challenge-only (sr25519_challenges_buf) and full-verify lanes so the
+// framing cannot diverge.
 static void sr25519_challenge_64(const uint8_t *ctx, size_t ctx_len,
                                  const uint8_t *msg, size_t msg_len,
                                  const uint8_t *pub, const uint8_t *r,
@@ -833,45 +834,6 @@ static void sr25519_challenge_64(const uint8_t *ctx, size_t ctx_len,
   s.meta_ad((const uint8_t *)"sign:c", 6, false);
   s.meta_ad(le, 4, true);
   s.prf(out, 64);
-}
-
-static PyObject *py_sr25519_challenges(PyObject *, PyObject *args) {
-  const char *ctx_buf, *pubs, *rs;
-  Py_ssize_t ctx_len, pubs_len, rs_len;
-  PyObject *msgs;
-  if (!PyArg_ParseTuple(args, "y#y#y#O", &ctx_buf, &ctx_len, &pubs, &pubs_len,
-                        &rs, &rs_len, &msgs))
-    return nullptr;
-  PyObject *seq = PySequence_Fast(msgs, "expected a sequence of messages");
-  if (!seq) return nullptr;
-  Py_ssize_t n = PySequence_Fast_GET_SIZE(seq);
-  if (pubs_len != 32 * n || rs_len != 32 * n) {
-    Py_DECREF(seq);
-    PyErr_SetString(PyExc_ValueError, "pubs/rs must be n*32 bytes");
-    return nullptr;
-  }
-  PyObject *out = PyBytes_FromStringAndSize(nullptr, n * 64);
-  if (!out) {
-    Py_DECREF(seq);
-    return nullptr;
-  }
-  uint8_t *dst = (uint8_t *)PyBytes_AS_STRING(out);
-  for (Py_ssize_t i = 0; i < n; i++) {
-    PyObject *item = PySequence_Fast_GET_ITEM(seq, i);
-    char *m;
-    Py_ssize_t mlen;
-    if (PyBytes_AsStringAndSize(item, &m, &mlen) < 0) {
-      Py_DECREF(seq);
-      Py_DECREF(out);
-      return nullptr;
-    }
-    sr25519_challenge_64((const uint8_t *)ctx_buf, (size_t)ctx_len,
-                         (const uint8_t *)m, (size_t)mlen,
-                         (const uint8_t *)(pubs + 32 * i),
-                         (const uint8_t *)(rs + 32 * i), dst + 64 * i);
-  }
-  Py_DECREF(seq);
-  return out;
 }
 
 // --------------------------------------------------------------------------
@@ -1337,7 +1299,7 @@ static PyObject *py_ed25519_challenges(PyObject *, PyObject *args) {
 
 // sr25519_verify_batch(ctx: bytes, pubs: n*32, sigs: n*64, msgs: seq)
 //   -> bytes (n): 1 where R == [s]B - [k]A (schnorrkel verify), else 0.
-// Transcript framing identical to sr25519_challenges; k = challenge mod L.
+// Transcript framing of sr25519_challenge_64; k = challenge mod L.
 static PyObject *py_sr25519_verify_batch(PyObject *, PyObject *args) {
   const char *ctx_buf;
   Py_ssize_t ctx_len;
@@ -1424,7 +1386,7 @@ static PyObject *py_sr25519_verify_batch(PyObject *, PyObject *args) {
       ed::point A, R;
       if (!ed::ristretto_decode(A, pub)) continue;
       if (!ed::ristretto_decode(R, sig)) continue;
-      // k = merlin challenge mod L (same framing as sr25519_challenges)
+      // k = merlin challenge mod L (sr25519_challenge_64's framing)
       uint8_t k_wide[64], k_bytes[32];
       sr25519_challenge_64(ctx_p, ctx_l, mptrs[i], mlens[i], pub, sig, k_wide);
       sha512::mod_l(k_wide, k_bytes);
@@ -2578,6 +2540,53 @@ static PyObject *py_commit_prep_fused(PyObject *, PyObject *args) {
   return tup;
 }
 
+// sr25519_challenges_buf(ctx, pubs: n*32, rs: n*32, msgs: buffer,
+//                        offsets: (n+1)*int64) -> n*32 bytes
+// The schnorrkel challenge scalars k_i = merlin "sign:c" mod L of a
+// columnar batch (ops/pallas_sr25519.py prepare_sr25519): the
+// sr25519_challenge_64 transcript over one contiguous sign-bytes buffer,
+// reduced mod L here. One timed section, which keeps the GIL below
+// COMMIT_PREP_RELEASE_ROWS signatures (a 150-signature commit's pass is
+// tens of microseconds) and is spread over threads without it above.
+static PyObject *py_sr25519_challenges_buf(PyObject *, PyObject *args) {
+  gil::enter();
+  Py_buffer ctx, pubs, rs, msgs, offs;
+  if (!PyArg_ParseTuple(args, "y*y*y*y*y*", &ctx, &pubs, &rs, &msgs, &offs))
+    return nullptr;
+  Py_ssize_t n = offs.len / 8 - 1;
+  const int64_t *op = (const int64_t *)offs.buf;
+  bool ok = n >= 0 && offs.len % 8 == 0 && pubs.len >= 32 * n &&
+            rs.len >= 32 * n && offsets_valid(op, n, msgs.len);
+  PyObject *out = ok ? PyBytes_FromStringAndSize(nullptr, n * 32) : nullptr;
+  if (out) {
+    uint8_t *dst = (uint8_t *)PyBytes_AS_STRING(out);
+    const uint8_t *cp = (const uint8_t *)ctx.buf;
+    const uint8_t *pp = (const uint8_t *)pubs.buf;
+    const uint8_t *rp = (const uint8_t *)rs.buf;
+    const uint8_t *mp = (const uint8_t *)msgs.buf;
+    GIL_HELD_IF(SR25519_CHALLENGES, n < COMMIT_PREP_RELEASE_ROWS)
+    parallel_ranges(n, COMMIT_PREP_RELEASE_ROWS,
+                    [&](Py_ssize_t lo, Py_ssize_t hi) {
+      uint8_t wide[64];
+      for (Py_ssize_t i = lo; i < hi; i++) {
+        sr25519_challenge_64(cp, (size_t)ctx.len, mp + op[i],
+                             (size_t)(op[i + 1] - op[i]), pp + 32 * i,
+                             rp + 32 * i, wide);
+        sha512::mod_l(wide, dst + 32 * i);
+      }
+    });
+    GIL_FREE_END
+  }
+  PyBuffer_Release(&ctx);
+  PyBuffer_Release(&pubs);
+  PyBuffer_Release(&rs);
+  PyBuffer_Release(&msgs);
+  PyBuffer_Release(&offs);
+  if (!out && ok) return nullptr;
+  if (!out) PyErr_SetString(PyExc_ValueError, "bad columnar challenge inputs");
+  return out;
+}
+
 // --------------------------------------------------------------------------
 // commit_decode_columns(data: bytes)
 //   -> None
@@ -2944,8 +2953,8 @@ static PyMethodDef Methods[] = {
      "SHA-256 of each item, concatenated"},
     {"pack_le_limbs", py_pack_le_limbs, METH_VARARGS,
      "pack 32B LE encodings into 13-bit limb arrays"},
-    {"sr25519_challenges", py_sr25519_challenges, METH_VARARGS,
-     "Batch merlin signing-transcript challenges for sr25519 verification"},
+    {"sr25519_challenges_buf", py_sr25519_challenges_buf, METH_VARARGS,
+     "Columnar sr25519 challenge scalars (mod L), one timed section"},
     {"pack_bits_le", py_pack_bits_le, METH_VARARGS,
      "pack 32B LE scalars into transposed bit arrays"},
     {"commit_decode_columns", py_commit_decode_columns, METH_O,

@@ -125,12 +125,18 @@ def create_batch_verifier(pk: PubKey) -> Optional[BatchVerifier]:
     from . import sr25519 as _sr25519
 
     if pk.type() == _sr25519.KEY_TYPE:
+        # the commit path hands it the fused prep's block, tagged
+        # "sr25519", and it submits to the shared dispatcher, where
+        # ops/backend.select_kernel runs the ristretto kernel; small
+        # batches and engines without Pallas stay on the host
+        # (ops/mixed.py). A key of another type in the set fails its
+        # add_block, as upstream's Add does.
         from ..ops.mixed import Sr25519DeviceBatchVerifier
 
         return Sr25519DeviceBatchVerifier()
     # secp256k1 has no batch VERIFIER (batch.go:26-33) and must stay
-    # None here: _verify_commit_batch's add_block path is ed25519-shaped
-    # and would choke on 33-byte keys. Batched secp verification exists
+    # None here: _verify_commit_batch's add_block path takes 32-byte keys
+    # and would choke on 33-byte ones. Batched secp verification exists
     # anyway (ISSUE 19) — it routes through the scheme lanes instead:
     # types/validation.prepare_commit_batch (all-secp committees),
     # prepare_commit_scheme_split + the mesh packer (mixed committees),

@@ -745,6 +745,17 @@ class OpsMetrics:
             "Signatures verified by address against a trusted validator "
             "set, by path label (device|host).",
         )
+        # the sr25519 lane (schnorrkel over ristretto255): signatures by
+        # where they were verified, and the device launches that carried
+        # them; sigs_verified and batches count them too
+        self.sr25519_sigs = registry.counter(
+            "ops", "sr25519_sigs_total",
+            "sr25519 signatures verified, by path label (device|host).",
+        )
+        self.sr25519_launches = registry.counter(
+            "ops", "sr25519_launches_total",
+            "Device launches of the sr25519 ristretto kernel.",
+        )
         self.h2d_bytes_per_commit = registry.gauge(
             "ops", "h2d_bytes_per_commit",
             "Host bytes shipped to the device by the last dispatched "
@@ -1022,6 +1033,9 @@ def ops_stats() -> dict:
             m.light_trusting_sigs.value(path="device")),
         "light_trusting_sigs_host": int(
             m.light_trusting_sigs.value(path="host")),
+        "sr25519_sigs_device": int(m.sr25519_sigs.value(path="device")),
+        "sr25519_sigs_host": int(m.sr25519_sigs.value(path="host")),
+        "sr25519_launches": int(m.sr25519_launches.total()),
         "h2d_bytes_per_commit": float(m.h2d_bytes_per_commit.value()),
         "h2d_ops": int(m.h2d_ops.total()),
         "transfer_overlap_ratio": float(m.transfer_overlap_ratio.value()),

@@ -23,6 +23,7 @@ below, the mesh's segments) asks it.
 
 from __future__ import annotations
 
+import functools
 import hashlib
 import os
 import time
@@ -55,13 +56,17 @@ def _ops_m() -> "_metrics.OpsMetrics":
 
 
 def _note_device_batch(n: int, bucket: int, prep_s: float = -1.0,
-                       device_s: float = -1.0) -> None:
+                       device_s: float = -1.0, scheme: str = "") -> None:
     """One dispatched device batch: counters + pad accounting (+ optional
-    prep/device timing histograms when the caller measured them)."""
+    prep/device timing histograms when the caller measured them). An
+    sr25519 batch also counts in the scheme's own series."""
     m = _ops_m()
     b = str(bucket)
     m.batches.inc(bucket=b)
     m.sigs_verified.inc(n, path="device")
+    if scheme == "sr25519":
+        m.sr25519_sigs.inc(n, path="device")
+        m.sr25519_launches.inc()
     if bucket > n:
         m.padded_lanes.inc(bucket - n)
     m.pad_waste_ratio.set(max(bucket - n, 0) / bucket if bucket else 0.0)
@@ -100,6 +105,19 @@ def _secp_bucket_for(n: int) -> int:
         if n <= b:
             return b
     return SECP_BUCKETS[-1]
+
+
+# The sr25519 lane's ladder: the ristretto kernel is per-signature (no RLC
+# lanes), so its time follows the padded width; powers of two from one
+# 128-lane kernel block keep a 150-signature commit in 256 lanes.
+SR_BUCKETS = (128, 256, 512, 1024, 2048, 4096, 8192, 10240)
+
+
+def _sr_bucket_for(n: int) -> int:
+    for b in SR_BUCKETS:
+        if n <= b:
+            return b
+    return SR_BUCKETS[-1]
 
 
 def _pack_le_limbs(enc: np.ndarray) -> np.ndarray:
@@ -624,8 +642,10 @@ def _pallas_bucket(n: int) -> int:
     return max(b, min(((n + b - 1) // b) * b, BUCKETS[-1]))
 
 
-def quantized_bucket(n: int) -> int:
+def quantized_bucket(n: int, scheme: str = "") -> int:
     """Device bucket (in signatures) a batch of n will be padded to."""
+    if scheme == "sr25519":
+        return _sr_bucket_for(n)
     if engine().rlc:
         from . import pallas_rlc
 
@@ -642,6 +662,12 @@ def max_coalesce() -> int:
 
         return pallas_rlc.MAX_SIGS
     return BUCKETS[-1]
+
+
+def scheme_cap(scheme: str, cap: int) -> int:
+    """`cap` rows, or fewer for a scheme whose bucket ladder stops short
+    of it (sr25519's at 10 240)."""
+    return min(cap, SR_BUCKETS[-1]) if scheme == "sr25519" else cap
 
 
 def warm_epoch(entries, scheme: str = ""):
@@ -681,7 +707,8 @@ def select_kernel(entries, bucket: int = 0, lanes: int = 0, mesh=None,
     mesh    a jax Mesh to shard the batch axis over: the launch is then
             the family's shard_map twin (ops/sharded.py) and carries its
             per-argument transfer placements as `launch_fn.shardings`.
-            secp256k1 and bls12381 have no twin and launch on one device
+            secp256k1, sr25519 and bls12381 have no twin and launch on
+            one device
     scheme  for tuple lists, which carry none (blocks carry their own)
     """
     n = len(entries)
@@ -709,6 +736,19 @@ def select_kernel(entries, bucket: int = 0, lanes: int = 0, mesh=None,
                     None, bucket)
         return (secp_kernel(donate), prepare_batch_secp(entries, bucket),
                 None, bucket)
+    if scheme == "sr25519":
+        # schnorrkel over ristretto255: the Pallas ristretto kernel, the
+        # lane's one engine (interpret mode off the TPU); per signature,
+        # no epoch table, no donation
+        from . import pallas_sr25519 as _ps
+        from .pallas_verify import pick_block
+
+        bucket = bucket or _sr_bucket_for(n)
+        args = _ps.prepare_sr25519(entries, bucket)
+        fn = functools.partial(_ps.verify_sr25519_compact,
+                               block=pick_block(bucket),
+                               interpret=eng.interpret)
+        return fn, args, None, bucket
     if mesh is not None:
         from . import sharded as _sharded
     if eng.pallas:
@@ -779,7 +819,8 @@ def verify_batch(entries) -> np.ndarray:
     if scheme == "bls12381":
         return verify_batch_bls(entries)
     with _devcheck.exempt():
-        return _verify_batch_direct(entries, max_coalesce())
+        return _verify_batch_direct(entries,
+                                    scheme_cap(scheme, max_coalesce()))
 
 
 def _verify_batch_direct(entries, step: int, scheme: str = "") -> np.ndarray:
@@ -808,32 +849,39 @@ def _verify_batch_direct(entries, step: int, scheme: str = "") -> np.ndarray:
             res = pallas_rlc.expand_lanes(
                 res, rlc_entries, bucket // len(res))
         _note_device_batch(
-            len(chunk), bucket, device_s=time.perf_counter() - t0
+            len(chunk), bucket, device_s=time.perf_counter() - t0,
+            scheme=scheme or getattr(chunk, "scheme", ""),
         )
         out.append(res[: len(chunk)])
     return np.concatenate(out) if out else np.zeros((0,), dtype=bool)
 
 
-class Ed25519DeviceBatchVerifier(BatchVerifier):
-    """Accumulate-then-verify on the device engine.
+class DeviceBatchVerifier(BatchVerifier):
+    """Accumulate-then-verify for one scheme's keys: add() and add_block()
+    gather the batch, verify() runs it on the scheme's host lane or
+    submits it to the shared dispatcher (ops/pipeline.py), whose
+    select_kernel picks the kernel by the block's scheme.
 
     Length/type validation on add() mirrors curve25519-voi's BatchVerifier
     Add (crypto/ed25519/ed25519.go:203-217); verify() returns
-    (all_valid, per_sig_valid) like BatchVerifier.Verify (:219-227).
+    (all_valid, per_sig_valid) like BatchVerifier.Verify (:219-227). A
+    scheme's subclass names `scheme`, `key_class` and its host lane
+    (_host_lane, _verify_host).
     """
 
-    def __init__(self, force_device: bool = False):
+    scheme = ""
+    key_class = PubKey
+    sig_size = 64      # ed25519's and sr25519's: a block's (n, 64) column
+
+    def __init__(self):
         self._entries: List[Tuple[bytes, bytes, bytes]] = []
         self._blocks: List[EntryBlock] = []
         self.on_device = False     # where the last verify() ran
-        self._force = force_device or bool(
-            int(os.environ.get("TM_TPU_FORCE_DEVICE", "0"))
-        )
 
     def add(self, key: PubKey, msg: bytes, sig: bytes) -> None:
-        if not isinstance(key, _ed25519.PubKey):
-            raise TypeError("pubkey is not ed25519")
-        if len(sig) != _ed25519.SIGNATURE_SIZE:
+        if not isinstance(key, self.key_class):
+            raise TypeError(f"pubkey is not {self.scheme}")
+        if len(sig) != self.sig_size:
             raise ValueError("invalid signature length")
         self._entries.append((key.bytes(), msg, sig))
 
@@ -846,10 +894,10 @@ class Ed25519DeviceBatchVerifier(BatchVerifier):
         exactly as per-entry add() does. lengths_checked=True skips only
         the signature-length scan for callers that already enforced it
         (validation.py checks lengths during selection)."""
-        if any(not isinstance(k, _ed25519.PubKey) for k, _, _ in entries):
-            raise TypeError("pubkey is not ed25519")
+        if any(not isinstance(k, self.key_class) for k, _, _ in entries):
+            raise TypeError(f"pubkey is not {self.scheme}")
         if not lengths_checked and any(
-            len(s) != _ed25519.SIGNATURE_SIZE for _, _, s in entries
+            len(s) != self.sig_size for _, _, s in entries
         ):
             raise ValueError("invalid signature length")
         self._entries.extend((k.bytes(), m, s) for k, m, s in entries)
@@ -861,44 +909,51 @@ class Ed25519DeviceBatchVerifier(BatchVerifier):
         check as add()/add_entries; lengths are structural in the block's
         (n, 32)/(n, 64) shape."""
         if keys is not None and any(
-            not isinstance(k, _ed25519.PubKey) for k in keys
+            not isinstance(k, self.key_class) for k in keys
         ):
-            raise TypeError("pubkey is not ed25519")
+            raise TypeError(f"pubkey is not {self.scheme}")
         if len(block):
             # flush interleaved add() entries first so verify order (and
             # blame indices) match submission order
             if self._entries:
-                self._blocks.append(EntryBlock.from_entries(self._entries))
+                self._blocks.append(self._entry_block())
                 self._entries = []
             self._blocks.append(block)
 
-    def _collect(self) -> EntryBlock:
-        blocks = list(self._blocks)
-        if self._entries:
-            blocks.append(EntryBlock.from_entries(self._entries))
-        return EntryBlock.concat(blocks)
+    def _entry_block(self) -> EntryBlock:
+        return EntryBlock.from_entries(self._entries, scheme=self.scheme)
+
+    def _host_lane(self, n: int) -> bool:
+        """Whether a batch of n is verified on the host."""
+        raise NotImplementedError
+
+    def _verify_host(self, block: EntryBlock):
+        """The host lane's (n,) verdicts."""
+        raise NotImplementedError
+
+    def _direct(self, n: int) -> bool:
+        """Whether a device batch of n launches on the caller's thread
+        (verify_batch) instead of through the dispatcher."""
+        return False
 
     def verify(self) -> Tuple[bool, List[bool]]:
         n = len(self._entries) + sum(len(b) for b in self._blocks)
         if n == 0:
             return False, []
-        if n < DEVICE_THRESHOLD and not self._force:
-            m = _ops_m()
-            m.host_fallback.inc()
-            m.sigs_verified.inc(n, path="host")
-            with _span("ops.verify_host", n=n):
-                valid = [
-                    _ed25519.verify_zip215_fast(pk, mg, s)
-                    for pk, mg, s in self._collect().iter_entries()
-                ]
-            return all(valid), valid
-        block = self._collect()
-        self.on_device = True
-        # Default path is the shared async pipeline:
-        # one worker thread owns every device dispatch, so concurrent
-        # commit verifies coalesce into full buckets and overlap host prep
-        # + D2H with device compute instead of serializing RTTs.
-        if n <= BUCKETS[-1]:
+        blocks = list(self._blocks)
+        if self._entries:
+            blocks.append(self._entry_block())
+        block = EntryBlock.concat(blocks)
+        self.on_device = not self._host_lane(n)
+        if not self.on_device:
+            res = self._verify_host(block)
+        elif self._direct(n):
+            res = verify_batch(block)
+        else:
+            # the shared async pipeline: one worker thread owns every
+            # device dispatch, so concurrent commit verifies coalesce into
+            # full buckets and overlap host prep + D2H with device compute
+            # instead of serializing RTTs
             from .pipeline import resolved_at, shared_verifier
 
             with _span("ops.pipeline_wait", n=n):
@@ -912,12 +967,41 @@ class Ed25519DeviceBatchVerifier(BatchVerifier):
                             "ops.pipeline_wait.wake", t_res,
                             time.perf_counter(), {"launch": launch},
                         )
-        else:
-            res = verify_batch(block)
         res = np.asarray(res).astype(bool)
         # .all() and .tolist() both run in C — keeps the documented
         # (bool, List[bool]) interface without a 10k-iteration Python loop
         return bool(res.all()), res.tolist()
+
+
+class Ed25519DeviceBatchVerifier(DeviceBatchVerifier):
+    """ed25519: ZIP-215 on the host under DEVICE_THRESHOLD signatures
+    (unless forced to the device), a batch past the largest bucket on the
+    caller's thread (verify_batch), the dispatcher between."""
+
+    scheme = _ed25519.KEY_TYPE
+    key_class = _ed25519.PubKey
+
+    def __init__(self, force_device: bool = False):
+        super().__init__()
+        self._force = force_device or bool(
+            int(os.environ.get("TM_TPU_FORCE_DEVICE", "0"))
+        )
+
+    def _host_lane(self, n: int) -> bool:
+        return n < DEVICE_THRESHOLD and not self._force
+
+    def _verify_host(self, block: EntryBlock):
+        m = _ops_m()
+        m.host_fallback.inc()
+        m.sigs_verified.inc(len(block), path="host")
+        with _span("ops.verify_host", n=len(block)):
+            return [
+                _ed25519.verify_zip215_fast(pk, mg, s)
+                for pk, mg, s in block.iter_entries()
+            ]
+
+    def _direct(self, n: int) -> bool:
+        return n > BUCKETS[-1]
 
 
 def warmup(bucket: int = BUCKETS[0]) -> None:

@@ -144,13 +144,16 @@ def prep_commit_from(
     chain_id: str,
     threshold: int,
     mode: int,
+    scheme: str = "ed25519",
 ) -> Optional[Tuple[np.ndarray, int, Optional[EntryBlock]]]:
     """The shared fused-path entry for commit-level callers
     (types/validation and ops/pipeline): columnar-eligibility checks
-    (CommitBlock present, all-ed25519 validator columns matching the
-    commit size) + per-flag template fetch + prep_commit. Returns None
-    when this commit/valset is not columnar-representable — callers fall
-    back to the object path and its exact legacy errors.
+    (CommitBlock present, validator columns of `scheme` — ed25519 or
+    sr25519, both 32-byte keys — matching the commit size) + per-flag
+    template fetch + prep_commit. Returns None when this commit/valset is
+    not columnar-representable — callers fall back to the object path
+    and its exact legacy errors. An sr25519 block carries its scheme and
+    no epoch table (the tables hold decompressed edwards keys).
 
     Spans, inside the caller's (verify_commit.prep_fused or
     pipeline.commit_prep_fused): ops.commit_prep.columns up to the fused
@@ -161,7 +164,8 @@ def prep_commit_from(
         cblock = commit.commit_block()
         if cblock is None:
             return None
-        cols = vals.ed25519_columns()
+        cols = (vals.ed25519_columns() if scheme == "ed25519"
+                else vals.sr25519_columns())
         if cols is None or cols[0].shape[0] != cblock.n:
             return None
         tpl_c = commit.sign_bytes_template(chain_id, FLAG_COMMIT)
@@ -170,7 +174,9 @@ def prep_commit_from(
     res = _fused(*args, tpl_c[0], tpl_n[0], tpl_c[1], threshold, mode)
     with _span("ops.commit_prep.block"):
         sel, tallied, block = _entry_block(res)
-        if block is not None:
+        if block is not None and scheme != "ed25519":
+            block.scheme = scheme
+        elif block is not None:
             # epoch-cache metadata: sel IS the valset row of each lane;
             # table_rows names the device table the set gathers from and
             # the lanes' rows there. The key is only attached for WARM sets

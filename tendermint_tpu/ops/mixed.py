@@ -1,15 +1,21 @@
-"""Mixed-curve batch verification (BASELINE config #4).
+"""Mixed-curve batch verification (BASELINE config #4) and the sr25519
+batch verifier.
 
 Reference parity: crypto/batch/batch.go:11-33 — batch verifiers exist for
 ed25519 and sr25519; secp256k1 never batches (batch.go:26-33). Here every
-curve gets a DEVICE lane: ed25519 and sr25519 as before
-(ops.pallas_verify / ops.pallas_sr25519), and since ISSUE 19 secp256k1
-batches through the Strauss+GLV ECDSA kernel (ops.secp_verify) — the
-reference's "no secp batching" is a verifier-interface fact, not a
-verdict change, so the device lane stays bit-identical to per-signature
-verification. The per-signature host loop survives as the
-small-batch / TM_TPU_SECP_DEVICE=0 fallback, thread-pooled because each
-OpenSSL ECDSA_verify releases the GIL.
+curve gets a DEVICE lane. ed25519 and sr25519 blocks are submitted to the
+shared dispatcher (ops/pipeline.py), which launches each scheme's kernel
+as backend.select_kernel picks it (the RLC kernel; the ristretto kernel
+of ops/pallas_sr25519.py) and never fuses two schemes into one launch.
+Since ISSUE 19 secp256k1 batches through the Strauss+GLV ECDSA kernel
+(ops.secp_verify) — the reference's "no secp batching" is a
+verifier-interface fact, not a verdict change, so the device lane stays
+bit-identical to per-signature verification. The per-signature host
+loops survive as the small-batch fallbacks: secp256k1's under
+SECP_DEVICE_THRESHOLD or TM_TPU_SECP_DEVICE=0, thread-pooled because
+each OpenSSL ECDSA_verify releases the GIL; sr25519's (the native
+schnorrkel batch) under SR_DEVICE_THRESHOLD, with TM_TPU_SR_DEVICE=0 or
+on an engine without Pallas.
 
 verify_mixed() partitions one heterogeneous batch by key type, dispatches
 all lanes, and reassembles per-signature verdicts in input order.
@@ -23,14 +29,13 @@ from typing import List, Sequence, Tuple
 import numpy as np
 
 from ..crypto import PubKey
-from ..crypto import ed25519 as _ed
 from ..crypto import secp256k1 as _secp
 from ..crypto import sr25519 as _sr
 from . import backend as _backend
 
 # Below this many sr25519 signatures the device round-trip loses to the
-# (pure-Python, ~10 ms/sig) host path only for very small counts; the
-# device wins early because host schnorr math is so slow.
+# host path only for very small counts; the device wins early because
+# host schnorr math is so slow.
 SR_DEVICE_THRESHOLD = int(os.environ.get("TM_TPU_SR_DEVICE_THRESHOLD", "8"))
 
 # secp256k1 scheme lane (ISSUE 19): below this many signatures the
@@ -114,9 +119,13 @@ def _sr_native_batch_available() -> bool:
 def _host_sr_batch(entries) -> np.ndarray:
     """Host sr25519 verdicts, thread-pooled over native batch chunks.
     Small batches (or the pure-Python fallback, where threads would only
-    interleave GIL-held math) run the single verify_batch call."""
+    interleave GIL-held math) run the single verify_batch call. Counted
+    in sigs_verified and sr25519_sigs under path="host"."""
     entries = list(entries)
     n = len(entries)
+    m = _backend._ops_m()
+    m.sigs_verified.inc(n, path="host")
+    m.sr25519_sigs.inc(n, path="host")
     workers = _sr_host_workers()
     if (
         n < SR_HOST_POOL_MIN
@@ -136,31 +145,9 @@ def _host_sr_batch(entries) -> np.ndarray:
 def _sr_device_enabled() -> bool:
     """sr25519 device lane: on by default; TM_TPU_SR_DEVICE=0 is the
     explicit way to use the native host lane instead. There is no
-    automatic fallback: a kernel that fails to compile or launch raises
-    to the caller."""
+    automatic fallback: a kernel that fails to compile or launch fails
+    the caller's future."""
     return os.environ.get("TM_TPU_SR_DEVICE", "1") == "1"
-
-
-def _verify_sr25519_batch(entries: List[Tuple[bytes, bytes, bytes]]) -> np.ndarray:
-    eng = _backend.engine()
-    if (
-        len(entries) < SR_DEVICE_THRESHOLD
-        or not _sr_device_enabled()
-        or not eng.pallas
-    ):
-        return _host_sr_batch(entries)
-    from . import pallas_sr25519 as ps
-
-    out = []
-    i = 0
-    while i < len(entries):
-        chunk = entries[i : i + _backend.BUCKETS[-1]]
-        bucket = _backend._pallas_bucket(len(chunk))
-        args = ps.prepare_sr25519(chunk, bucket)
-        res = ps.verify_sr25519_compact(*args, interpret=eng.interpret)
-        out.append(res[: len(chunk)])
-        i += len(chunk)
-    return np.concatenate(out)
 
 
 def verify_mixed(
@@ -177,14 +164,13 @@ def verify_mixed(
         lanes[kind].append((pk, msg, sig))
 
     # Lanes run CONCURRENTLY: the ed25519 batch rides the shared async
-    # pipeline (a future), the sr25519 and secp256k1 device batches
-    # dispatch on helper threads, and any host loops fill the main
+    # pipeline (a future), the secp256k1 device batch dispatches on a
+    # helper thread, and the host loops and then the sr25519 batch (the
+    # same dispatcher, never fused with ed25519's launch) fill the main
     # thread while the device works — the mixed batch costs max(lanes),
     # not sum(lanes).
     results = {}
     ed_future = None
-    sr_thread = None
-    sr_holder: dict = {}
     secp_thread = None
     secp_holder: dict = {}
     if lanes["ed25519"]:
@@ -195,19 +181,6 @@ def verify_mixed(
             ed_future = shared_verifier().submit(ed_entries)
         else:
             results["ed25519"] = _backend.verify_batch(ed_entries)
-    if lanes["sr25519"]:
-        import threading
-
-        sr_entries = [(pk.bytes(), m, s) for pk, m, s in lanes["sr25519"]]
-
-        def _sr_run():
-            try:
-                sr_holder["res"] = _verify_sr25519_batch(sr_entries)
-            except Exception as e:  # noqa: BLE001
-                sr_holder["err"] = e
-
-        sr_thread = threading.Thread(target=_sr_run, daemon=True)
-        sr_thread.start()
     if lanes["secp256k1"]:
         import threading
 
@@ -226,15 +199,14 @@ def verify_mixed(
             [pk.verify_signature(m, s) for pk, m, s in lanes["other"]],
             dtype=bool,
         )
+    if lanes["sr25519"]:
+        # submitted and waited for here, while the ed25519 launch and the
+        # secp256k1 thread are under way
+        sr_bv = Sr25519DeviceBatchVerifier()
+        sr_bv.add_entries(lanes["sr25519"], lengths_checked=True)
+        results["sr25519"] = sr_bv.verify()[1]
     if ed_future is not None:
         results["ed25519"] = np.asarray(ed_future.result(timeout=600))
-    if sr_thread is not None:
-        sr_thread.join(timeout=600)
-        if sr_thread.is_alive():
-            raise TimeoutError("sr25519 device lane did not finish in 600s")
-        if "err" in sr_holder:
-            raise sr_holder["err"]
-        results["sr25519"] = sr_holder["res"]
     if secp_thread is not None:
         secp_thread.join(timeout=600)
         if secp_thread.is_alive():
@@ -245,26 +217,24 @@ def verify_mixed(
     return [bool(results[kind][j]) for kind, j in order]
 
 
-class Sr25519DeviceBatchVerifier:
-    """crypto.BatchVerifier for sr25519 on the device ristretto lane
-    (crypto/sr25519/batch.go parity)."""
+class Sr25519DeviceBatchVerifier(_backend.DeviceBatchVerifier):
+    """crypto.BatchVerifier for sr25519 (crypto/sr25519/batch.go parity):
+    the device verifier's accumulate, submit and wait, with sr25519's keys
+    and host lane. `add_block` takes the columnar block the fused commit
+    prep builds (types/validation.py)."""
 
-    def __init__(self):
-        self._entries: List[Tuple[bytes, bytes, bytes]] = []
+    scheme = _sr.KEY_TYPE
+    key_class = _sr.PubKey
 
-    def add(self, key, msg: bytes, sig: bytes) -> None:
-        if key.type() != _sr.KEY_TYPE:
-            raise TypeError("pubkey is not sr25519")
-        if len(sig) != _sr.SIGNATURE_SIZE:
-            raise ValueError("invalid signature length")
-        self._entries.append((key.bytes(), msg, sig))
+    def _host_lane(self, n: int) -> bool:
+        return (
+            n < SR_DEVICE_THRESHOLD
+            or not _sr_device_enabled()
+            or not _backend.engine().pallas
+        )
 
-    def verify(self) -> Tuple[bool, List[bool]]:
-        if not self._entries:
-            return False, []
-        res = _verify_sr25519_batch(self._entries)
-        valid = [bool(v) for v in res]
-        return all(valid), valid
+    def _verify_host(self, block):
+        return _host_sr_batch(block.iter_entries())
 
 
 class Secp256k1DeviceBatchVerifier:

@@ -7,19 +7,20 @@ shares the joint double-scalar ladder with the ed25519 kernel
 (ops.pallas_verify K2/K3 shapes); what differs is point DECODING
 (ristretto255 DECODE instead of ZIP-215 edwards decompression) and the
 final test (exact ristretto equality against R instead of cofactored
-identity). The merlin challenges are host-side via the native C++
-transcript (native/tm_native.cpp sr25519_challenges; pure-Python
-fallback), s/k scalars feed the same shift-grouped digit layout.
+identity). The merlin challenges are host-side, reduced mod L in the
+native C++ transcript (native/tm_native.cpp sr25519_challenges_buf;
+pure-Python fallback); s/k scalars feed the same shift-grouped digit
+layout.
 
-Round-3 measured context: pure-Python sr25519 verify is ~10 ms/sig — the
-mixed-curve BASELINE config #4 was host-bound; this lane moves the EC
-math (2 scalar mults/sig) onto the device and the transcripts into C.
-
-The lane is ON by default (TM_TPU_SR_DEVICE=0 selects the native host
-lane). A kernel that fails to compile or launch raises to the caller of
-ops.mixed — there is no watchdog and no silent host fallback. Whether it
-compiles under the installed libtpu is recorded in the README's scheme
-matrix ("runs on v5e").
+The lane's batches come through the shared dispatcher like every other
+scheme's: ops/backend.py select_kernel picks prepare_sr25519 and
+verify_sr25519_compact for an EntryBlock tagged "sr25519", on the prep
+thread and the dispatch-owner thread; nothing here launches on a
+caller's thread. TM_TPU_SR_DEVICE=0, a batch under ops.mixed's
+SR_DEVICE_THRESHOLD or an engine without Pallas verify on the host
+instead (ops/mixed.py). A kernel that fails to compile or launch fails
+its callers' futures with the dispatcher's DispatchError — there is no
+watchdog and no silent host fallback.
 """
 
 from __future__ import annotations
@@ -36,6 +37,8 @@ from jax.experimental.pallas import tpu as pltpu
 
 from . import fe_t, pallas_verify as pv
 from ..crypto import _edwards
+from ..observability.trace import span as _span
+from .entry_block import EntryBlock
 
 NL = fe_t.NLIMBS
 P = _edwards.P
@@ -185,12 +188,30 @@ def _jitted_sr25519_verify(n: int, block: int, interpret: bool):
         interpret=interpret,
     )
 
-    def pipeline(a_t, r_t, s_t, k_t, aok_t, rok_t, sok_t):
+    def pipeline(packed):
+        a_t, r_t, s_t, k_t, aok_t, rok_t, sok_t = packed_views(packed)
         coords, ok, sdig, kdig = k1(a_t, r_t, s_t, k_t, aok_t, rok_t)
         tbl = k2(coords)
         return k3(tbl, sdig, kdig, coords, ok, sok_t)
 
+    # the name the device trace files the launch's operations under, so
+    # that a reader of kernel time can find them (PERF.md §7)
+    pipeline.__name__ = f"sr25519_verify_n{n}_b{block}"
     return jax.jit(pipeline)
+
+
+# One launch's arguments are ONE int32 buffer (a put costs the same
+# whatever it carries, PERF.md §6 PR 29): rows of the A, R, s and k
+# encodings, 32 bytes a lane each, then the a_ok, r_ok, s_ok flags.
+PACKED_ROWS = 4 * 32 + 3
+
+
+def packed_views(packed):
+    """(a_t, r_t, s_t, k_t, aok_t, rok_t, sok_t): the kernel's arguments
+    as row slices of the packed buffer (numpy on the host, jax inside
+    the jitted launch)."""
+    return (packed[0:32], packed[32:64], packed[64:96], packed[96:128],
+            packed[128:129], packed[129:130], packed[130:131])
 
 
 _P_BE = np.frombuffer(P.to_bytes(32, "big"), dtype=np.uint8)
@@ -212,75 +233,76 @@ def _canonical_even(enc: np.ndarray, n: int, bucket: int) -> np.ndarray:
     return ok
 
 
-def prepare_sr25519(entries, bucket: int):
-    """(pub32, msg, sig64) schnorrkel triples -> kernel args. Host work:
-    v1-marker/s<L checks, canonical-encoding flags, merlin challenges
-    (native C++, pure-Python fallback) reduced mod L."""
+def _challenges(block: EntryBlock) -> bytes:
+    """k_i = merlin "sign:c" challenge mod L of each row, 32 bytes LE a
+    row: tm_native.sr25519_challenges_buf over the block's contiguous
+    sign bytes (spans ops.sr_prep.challenges.native / .gil from its own
+    clock reads, native.traced_call), else the pure-Python transcript."""
+    from .. import native as _native
     from ..crypto._edwards import L
     from ..crypto.sr25519 import SIGNING_CTX, _signing_transcript
-    from ..native import load as _load_native
-    from .backend import _pack_rows, _s_below_l
 
-    n = len(entries)
-    marker_ok = np.zeros((bucket,), dtype=bool)
-    marker_ok[n:] = True
-    cleaned = []
-    for i, (pk, msg, sig) in enumerate(entries):
-        if len(sig) != 64 or len(pk) != 32:
-            marker_ok[i] = False
-            cleaned.append((bytes(32), msg, bytes(64)))
-            continue
-        sig = bytearray(sig)
-        marker_ok[i] = bool(sig[63] & 0x80)
-        sig[63] &= 0x7F
-        cleaned.append((pk, msg, bytes(sig)))
-    pub, r_enc, s_enc = _pack_rows(cleaned, bucket)
-    # padding: _pack_rows pads with the EDWARDS identity encoding (0x01),
-    # which is an odd (invalid) ristretto encoding — the ristretto
-    # identity is the all-zero string
-    pub[n:] = 0
-    r_enc[n:] = 0
-    s_ok = _s_below_l(s_enc, n, bucket) & marker_ok
-    a_ok = _canonical_even(pub, n, bucket)
-    r_ok = _canonical_even(r_enc, n, bucket)
-
-    k_enc = np.zeros((bucket, 32), dtype=np.uint8)
-    if n:
-        native = _load_native()
-        pubs = b"".join(pk for pk, _, _ in cleaned)
-        rss = bytes(r_enc[:n].tobytes())
-        msgs = [m for _, m, _ in cleaned]
-        if native is not None:
-            raw = native.sr25519_challenges(SIGNING_CTX, pubs, rss, msgs)
-            digests = [raw[64 * i : 64 * (i + 1)] for i in range(n)]
-        else:
-            digests = []
-            for (pk, msg, _), i in zip(cleaned, range(n)):
-                t = _signing_transcript(msg)
-                t.append_message(b"proto-name", b"Schnorr-sig")
-                t.append_message(b"sign:pk", pk)
-                t.append_message(b"sign:R", rss[32 * i : 32 * (i + 1)])
-                digests.append(t.challenge_bytes(b"sign:c", 64))
-        ks = b"".join(
-            (int.from_bytes(d, "little") % L).to_bytes(32, "little") for d in digests
-        )
-        k_enc[:n] = np.frombuffer(ks, dtype=np.uint8).reshape(n, 32)
-
-    return (
-        np.ascontiguousarray(pub.T),
-        np.ascontiguousarray(r_enc.T),
-        np.ascontiguousarray(s_enc.T),
-        np.ascontiguousarray(k_enc.T),
-        np.ascontiguousarray(a_ok.astype(np.int32)[None, :]),
-        np.ascontiguousarray(r_ok.astype(np.int32)[None, :]),
-        np.ascontiguousarray(s_ok.astype(np.int32)[None, :]),
-    )
+    pubs = block.pub.tobytes()
+    rs = np.ascontiguousarray(block.sig[:, :32]).tobytes()
+    mod = _native.load()
+    if mod is not None and hasattr(mod, "sr25519_challenges_buf"):
+        buf, offs = block.msgs_contiguous()
+        return _native.traced_call(
+            mod, "sr25519_challenges_buf", "ops.sr_prep.challenges",
+            SIGNING_CTX, pubs, rs, buf, np.ascontiguousarray(offs).tobytes())
+    out = []
+    for i, msg in enumerate(block.msg_views()):
+        t = _signing_transcript(bytes(msg))
+        t.append_message(b"proto-name", b"Schnorr-sig")
+        t.append_message(b"sign:pk", pubs[32 * i : 32 * i + 32])
+        t.append_message(b"sign:R", rs[32 * i : 32 * i + 32])
+        k = int.from_bytes(t.challenge_bytes(b"sign:c", 64), "little") % L
+        out.append(k.to_bytes(32, "little"))
+    return b"".join(out)
 
 
-def verify_sr25519_compact(*args, block: int = 0, interpret: bool = False):
+def prepare_sr25519(entries, bucket: int):
+    """An sr25519 EntryBlock (or (pub32, msg, sig64) schnorrkel triples)
+    -> the kernel's one argument, `(packed,)`: PACKED_ROWS x `bucket`
+    int32 (packed_views). Spans, inside pipeline.prep:
+    ops.sr_prep.challenges (the merlin challenges, see _challenges), then
+    ops.sr_prep.fill (v1-marker and s < L checks, canonical-encoding
+    flags, the rows written in place). Padding lanes are the ristretto
+    identity (all-zero encodings, s = k = 0) and verify."""
+    from .backend import _s_below_l
+
+    block = (entries if isinstance(entries, EntryBlock)
+             else EntryBlock.from_entries(list(entries), scheme="sr25519"))
+    n = len(block)
+    with _span("ops.sr_prep.challenges", n=n):
+        ks = _challenges(block) if n else b""
+    with _span("ops.sr_prep.fill", n=n, bucket=bucket):
+        packed = np.zeros((PACKED_ROWS, bucket), dtype=np.int32)
+        a_t, r_t, s_t, k_t, aok, rok, sok = packed_views(packed)
+        aok[:] = rok[:] = sok[:] = 1
+        if n:
+            r_enc = block.sig[:, :32]
+            s_enc = block.sig[:, 32:].copy()
+            # schnorrkel v1 marks s's top bit; the scalar is s without it
+            marked = (s_enc[:, 31] & 0x80) != 0
+            s_enc[:, 31] &= 0x7F
+            a_t[:, :n] = block.pub.T
+            r_t[:, :n] = r_enc.T
+            s_t[:, :n] = s_enc.T
+            k_t[:, :n] = np.frombuffer(ks, dtype=np.uint8).reshape(n, 32).T
+            aok[0] = _canonical_even(block.pub, n, bucket)
+            rok[0] = _canonical_even(r_enc, n, bucket)
+            sok[0, :n] = _s_below_l(s_enc, n, n) & marked
+        return (packed,)
+
+
+def verify_sr25519_compact(packed, block: int = 0, interpret: bool = False):
+    """Launches the ristretto kernel over prepare_sr25519's buffer and
+    returns its (1, n) int32 verdict row as the device gives it: the
+    dispatcher launches, the resolver waits (ops/backend.py select_kernel
+    is the one caller in the program)."""
     block = block or pv.BLOCK
-    n = args[0].shape[-1]
+    n = packed.shape[-1]
     if n % block:
         raise ValueError(f"batch {n} not a multiple of block {block}")
-    out = _jitted_sr25519_verify(n, block, interpret)(*args)
-    return np.asarray(out)[0].astype(bool)
+    return _jitted_sr25519_verify(n, block, interpret)(packed)

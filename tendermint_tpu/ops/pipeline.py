@@ -405,7 +405,7 @@ class AsyncBatchVerifier:
             raise RuntimeError("verifier is closed")
         t_submit = time.perf_counter() if _trace.TRACER.enabled else 0.0
         block = as_block(entries)
-        max_b = _backend.max_coalesce()
+        max_b = _backend.scheme_cap(block.scheme, _backend.max_coalesce())
         if self._mesh_lanes:
             # mesh mode packs WHOLE jobs into lanes — chunk oversized
             # submissions at the lane capacity so every chunk fits one
@@ -530,18 +530,18 @@ class AsyncBatchVerifier:
         labels. Which kernel, and its argument layout, is
         backend.select_kernel's business."""
         n = len(entries)
+        scheme = getattr(entries, "scheme", "ed25519")
         with _span("pipeline.prep", n=n) as sp:
             res = _backend.select_kernel(entries)
             if _trace.TRACER.enabled:
                 # what was chosen, for whoever reads the span
-                scheme = getattr(entries, "scheme", "ed25519")
                 sp.note(
                     bucket=res[3],
                     cached=int(_backend.warm_epoch(entries) is not None),
                     **({"scheme": scheme} if scheme != "ed25519" else {}),
                     **_rlc_width_arg(res[2], res[3]),
                 )
-        _backend._note_device_batch(n, res[3])
+        _backend._note_device_batch(n, res[3], scheme=scheme)
         return res
 
     @classmethod
@@ -569,7 +569,10 @@ class AsyncBatchVerifier:
             res = _mesh.prepare_superbatch(block, plan)
         # prep timing histograms are recorded inside prepare_batch*; the
         # dispatch counters note the LIVE rows against the full bucket
-        _backend._note_device_batch(plan.live, plan.bucket)
+        schemes = plan.schemes()
+        _backend._note_device_batch(
+            plan.live, plan.bucket,
+            scheme=schemes[0] if len(schemes) == 1 else "")
         return res
 
     @classmethod
@@ -753,12 +756,13 @@ class AsyncBatchVerifier:
                 # 8 ms: value not measured on this machine
                 busy = self._inflight > 0 or self._dispatch_q.qsize() > 0
                 deadline = time.monotonic() + 0.008 if busy else 0.0
+                cap = _backend.scheme_cap(scheme0, max_b)
                 if job.priority <= PRIORITY_CONSENSUS:
-                    limit = max_b
+                    limit = cap
                 elif job.priority <= PRIORITY_REPLAY:
-                    limit = min(max_b, rep_cap)
+                    limit = min(cap, rep_cap)
                 else:
-                    limit = min(max_b, ing_cap)
+                    limit = min(cap, ing_cap)
                 while total < limit:
                     try:
                         nxt = self._q.get_nowait()
@@ -789,11 +793,11 @@ class AsyncBatchVerifier:
                 # while doing so lands the batch in a smaller bucket
                 # with less waste
                 while len(jobs) > 1 and hold is None:
-                    b = _backend.quantized_bucket(total)
+                    b = _backend.quantized_bucket(total, scheme0)
                     if b - total <= max(b // 8, 1024):
                         break
                     shorter = _backend.quantized_bucket(
-                        total - len(jobs[-1].entries)
+                        total - len(jobs[-1].entries), scheme0
                     )
                     if shorter >= b:
                         break
@@ -846,7 +850,8 @@ class AsyncBatchVerifier:
                 )
                 if tracing:
                     self._trace_coalesced(
-                        jobs, t_c0, total, _backend.quantized_bucket(total)
+                        jobs, t_c0, total,
+                        _backend.quantized_bucket(total, scheme0)
                     )
                 m.dispatch_queue_depth.set(self._dispatch_q.qsize())
                 m.pipeline_queue_depth.set(self._q.qsize())

@@ -754,10 +754,12 @@ def _fused_commit_prep(
     ignore_sig: Callable[[CommitSig], bool],
     count_sig: Callable[[CommitSig], bool],
     count_all_signatures: bool,
+    scheme: str = "ed25519",
 ):
     """Columnar fast path: CommitBlock + validator columns through ONE
     fused prep call (ops/commit_prep.py — native when built; it gives
-    the GIL up for the sign bytes + gather of 1 024 rows or more).
+    the GIL up for the sign bytes + gather of 1 024 rows or more), over
+    the `scheme` columns of the set (ed25519 or sr25519).
     Returns (sel_idx, tallied, EntryBlock-or-None) or None when this
     commit/valset/predicate combination is not columnar-representable
     (the object path below then reproduces the exact legacy behavior and
@@ -778,7 +780,7 @@ def _fused_commit_prep(
         mode |= _cp.MODE_EARLY_STOP
     with _span("verify_commit.prep_fused", n=len(commit.signatures)):
         return _cp.prep_commit_from(
-            commit, vals, chain_id, voting_power_needed, mode
+            commit, vals, chain_id, voting_power_needed, mode, scheme
         )
 
 
@@ -800,6 +802,9 @@ def _verify_commit_batch(
             "unsupported signature algorithm or insufficient signatures for batch verification"
         )
     add_block = getattr(bv, "add_block", None)
+    # the verifier's key scheme (the proposer's, validation.go:153):
+    # the set's columns of that scheme feed the fused prep
+    scheme = getattr(bv, "scheme", "ed25519")
     if look_up_by_index and add_block is not None:
         fused = _fused_commit_prep(
             chain_id,
@@ -809,6 +814,7 @@ def _verify_commit_batch(
             ignore_sig,
             count_sig,
             count_all_signatures,
+            scheme,
         )
         if fused is not None:
             import numpy as _np
@@ -818,9 +824,9 @@ def _verify_commit_batch(
                 raise ErrNotEnoughVotingPowerSigned(
                     got=tallied, needed=voting_power_needed
                 )
-            # key TYPE safety is proven by ed25519_columns (all-ed25519
-            # or the fused path is not taken); signature lengths are
-            # structural in the CommitBlock's (n, 64) column
+            # key TYPE safety is proven by the scheme's columns (all of
+            # that scheme or the fused path is not taken); signature
+            # lengths are structural in the CommitBlock's (n, 64) column
             add_block(eblk)
             with _span("verify_commit.verify", n=len(eblk)):
                 ok, valid_sigs = bv.verify()
@@ -870,13 +876,14 @@ def _verify_commit_batch(
             # a wrong-size key (e.g. secp256k1 in an ed25519 set) must
             # surface as the same error per-entry add() raised, not as a
             # reshape failure
-            raise TypeError("pubkey is not ed25519")
+            raise TypeError(f"pubkey is not {scheme}")
         pub = _np.frombuffer(pub_b, dtype=_np.uint8).reshape(n_sel, 32)
         sig = _np.frombuffer(
             b"".join(sigs_list[idx].signature for idx, _ in selected),
             dtype=_np.uint8,
         ).reshape(n_sel, 64)
-        add_block(EntryBlock(pub, sig, buf, offsets), keys=keys)
+        add_block(EntryBlock(pub, sig, buf, offsets, scheme=scheme),
+                  keys=keys)
     else:
         # one batch sign-bytes composition for all selected lanes (native
         # composer; the per-lane Python encode was the dominant host cost

@@ -30,6 +30,9 @@ _NO_SECP_COLS = object()
 # bls12381_columns cache sentinel (same protocol)
 _NO_BLS_COLS = object()
 
+# sr25519_columns cache sentinel (same protocol)
+_NO_SR_COLS = object()
+
 
 def _clip64(v: int) -> int:
     return max(INT64_MIN, min(INT64_MAX, v))
@@ -145,6 +148,7 @@ class ValidatorSet:
         self._ed_cols: Optional[tuple] = None
         self._secp_cols: Optional[tuple] = None
         self._bls_cols: Optional[tuple] = None
+        self._sr_cols: Optional[tuple] = None
 
     # ---- construction -------------------------------------------------
 
@@ -184,6 +188,7 @@ class ValidatorSet:
         c._ed_cols = self._ed_cols
         c._secp_cols = self._secp_cols
         c._bls_cols = self._bls_cols
+        c._sr_cols = self._sr_cols
         return c
 
     # ---- queries ------------------------------------------------------
@@ -266,110 +271,73 @@ class ValidatorSet:
             )
         return self._hash
 
-    def ed25519_columns(self) -> Optional[tuple]:
-        """(pub (n, 32) uint8, power (n,) int64) columns over the set, or
-        None unless EVERY validator key is ed25519 — the commit verify
-        fast path (types/validation.py fused branch) gathers selected
-        lanes from these instead of walking Validator objects per
-        signature. Cached; invalidated with the hash cache on membership/
-        power changes (everything flows through _update_with_change_set).
-        A None result also serves as the per-key TYPE check: a mixed-key
-        set falls back to the object path, which raises exactly as
-        per-entry add() did."""
-        if self._ed_cols is not None:
-            cols = self._ed_cols
-            return cols if cols is not _NO_ED_COLS else None
+    def _key_columns(self, attr: str, none, pub_cls, width: int):
+        """The (pub (n, width) uint8, power (n,) int64) columns of a set
+        whose EVERY key is a `pub_cls`, else None; kept in `attr` (`none`
+        kept there means "computed, not representable"). Cleared with the
+        hash cache by _update_with_change_set, shared by copy()."""
+        cols = getattr(self, attr)
+        if cols is not None:
+            return cols if cols is not none else None
         import numpy as np
-
-        from ..crypto import ed25519 as _ed25519
 
         vals = self.validators
         n = len(vals)
-        cols = None
-        if n and all(
-            isinstance(v.pub_key, _ed25519.PubKey) for v in vals
-        ):
+        if n and all(isinstance(v.pub_key, pub_cls) for v in vals):
             pub_b = b"".join(v.pub_key.bytes() for v in vals)
-            if len(pub_b) == 32 * n:
+            if len(pub_b) == width * n:
                 cols = (
-                    np.frombuffer(pub_b, dtype=np.uint8).reshape(n, 32),
+                    np.frombuffer(pub_b, dtype=np.uint8).reshape(n, width),
                     np.fromiter(
                         (v.voting_power for v in vals),
                         dtype=np.int64,
                         count=n,
                     ),
                 )
-        self._ed_cols = cols if cols is not None else _NO_ED_COLS
+        setattr(self, attr, cols if cols is not None else none)
         return cols
+
+    def ed25519_columns(self) -> Optional[tuple]:
+        """(pub (n, 32) uint8, power (n,) int64) columns over the set, or
+        None unless EVERY validator key is ed25519 — the commit verify
+        fast path (types/validation.py fused branch) gathers selected
+        lanes from these instead of walking Validator objects per
+        signature. A None result also serves as the per-key TYPE check: a
+        mixed-key set falls back to the object path, which raises exactly
+        as per-entry add() did."""
+        from ..crypto import ed25519 as _ed25519
+
+        return self._key_columns("_ed_cols", _NO_ED_COLS, _ed25519.PubKey, 32)
+
+    def sr25519_columns(self) -> Optional[tuple]:
+        """ed25519_columns for a set whose every key is sr25519: the same
+        32-byte key column, which the fused commit prep gathers from as it
+        does an ed25519 set's (types/validation.py)."""
+        from ..crypto import sr25519 as _sr25519
+
+        return self._key_columns("_sr_cols", _NO_SR_COLS, _sr25519.PubKey, 32)
 
     def secp256k1_columns(self) -> Optional[tuple]:
         """(pub (n, 33) uint8, power (n,) int64) columns over the set, or
         None unless EVERY validator key is secp256k1 — the scheme-lane
         analog of ed25519_columns (ISSUE 19): the batched commit prep
         gathers selected 33-byte SEC1 keys from here and the epoch cache
-        keys its decompressed affine Q table on the same hash(). Cached;
-        invalidated alongside the hash cache by _update_with_change_set
-        and shared by copy(). A None result is the TYPE check: mixed or
-        non-secp sets fall back to the object path."""
-        if self._secp_cols is not None:
-            cols = self._secp_cols
-            return cols if cols is not _NO_SECP_COLS else None
-        import numpy as np
-
+        keys its decompressed affine Q table on the same hash(). A None
+        result is the TYPE check: mixed or non-secp sets fall back to the
+        object path."""
         from ..crypto import secp256k1 as _secp
 
-        vals = self.validators
-        n = len(vals)
-        cols = None
-        if n and all(
-            isinstance(v.pub_key, _secp.PubKey) for v in vals
-        ):
-            pub_b = b"".join(v.pub_key.bytes() for v in vals)
-            if len(pub_b) == 33 * n:
-                cols = (
-                    np.frombuffer(pub_b, dtype=np.uint8).reshape(n, 33),
-                    np.fromiter(
-                        (v.voting_power for v in vals),
-                        dtype=np.int64,
-                        count=n,
-                    ),
-                )
-        self._secp_cols = cols if cols is not None else _NO_SECP_COLS
-        return cols
+        return self._key_columns("_secp_cols", _NO_SECP_COLS, _secp.PubKey, 33)
 
     def bls12381_columns(self) -> Optional[tuple]:
         """(pub (n, 48) uint8, power (n,) int64) columns over the set, or
         None unless EVERY validator key is bls12381 — the aggregation
         lane's committee snapshot (ISSUE 20): prepare_aggregated_commit
         carries these compressed G1 rows on the AggBlock and the epoch
-        cache keys its decompressed G1 limb table on the same hash().
-        Cached; invalidated alongside the hash cache by
-        _update_with_change_set and shared by copy()."""
-        if self._bls_cols is not None:
-            cols = self._bls_cols
-            return cols if cols is not _NO_BLS_COLS else None
-        import numpy as np
-
+        cache keys its decompressed G1 limb table on the same hash()."""
         from ..crypto import bls12381 as _bls
 
-        vals = self.validators
-        n = len(vals)
-        cols = None
-        if n and all(
-            isinstance(v.pub_key, _bls.PubKey) for v in vals
-        ):
-            pub_b = b"".join(v.pub_key.bytes() for v in vals)
-            if len(pub_b) == 48 * n:
-                cols = (
-                    np.frombuffer(pub_b, dtype=np.uint8).reshape(n, 48),
-                    np.fromiter(
-                        (v.voting_power for v in vals),
-                        dtype=np.int64,
-                        count=n,
-                    ),
-                )
-        self._bls_cols = cols if cols is not None else _NO_BLS_COLS
-        return cols
+        return self._key_columns("_bls_cols", _NO_BLS_COLS, _bls.PubKey, 48)
 
     def scheme_rows(self) -> Optional[tuple]:
         """Per-validator scheme partition for MIXED device-batchable sets
@@ -492,6 +460,7 @@ class ValidatorSet:
         self._ed_cols = None
         self._secp_cols = None
         self._bls_cols = None
+        self._sr_cols = None
         if not changes:
             return
         updates, deletes = _process_changes(changes)

@@ -146,8 +146,13 @@ def test_first_small_commit_is_counted():
 
 
 def test_raising_sr25519_kernel_propagates(monkeypatch):
+    """The kernel is launched by the dispatcher: its failure reaches the
+    caller as the batch's DispatchError, with the kernel's own exception
+    as the cause, and is not retried on the host."""
+    from tendermint_tpu.crypto import sr25519
     from tendermint_tpu.ops import backend, mixed
     from tendermint_tpu.ops import pallas_sr25519 as ps
+    from tendermint_tpu.ops.pipeline import DispatchError
 
     class Boom(RuntimeError):
         pass
@@ -162,12 +167,14 @@ def test_raising_sr25519_kernel_propagates(monkeypatch):
         "a failed kernel must not be retried on the host"))
     backend.engine.cache_clear()
     try:
-        entries = [(b"\x00" * 32, b"m", b"\x00" * 64)] * mixed.SR_DEVICE_THRESHOLD
-        with pytest.raises(Boom):
-            mixed._verify_sr25519_batch(entries)
-        # and again: no sticky "device is broken, use the host" state
-        with pytest.raises(Boom):
-            mixed._verify_sr25519_batch(entries)
+        key = sr25519.PubKey(b"\x00" * 32)
+        for _ in range(2):  # and again: no sticky "use the host" state
+            bv = mixed.Sr25519DeviceBatchVerifier()
+            for _i in range(mixed.SR_DEVICE_THRESHOLD):
+                bv.add(key, b"m", b"\x00" * 64)
+            with pytest.raises(DispatchError, match="Mosaic refused") as e:
+                bv.verify()
+            assert isinstance(e.value.__cause__, Boom)
     finally:
         monkeypatch.undo()
         backend.engine.cache_clear()

@@ -371,7 +371,8 @@ def test_with_the_tracer_off_no_section_is_read_and_nothing_is_recorded(monkeypa
     assert {e: (after[e][0] - before[e][0], after[e][3] - before[e][3])
             for e in after} == {
         "commit_decode_columns": (1, 0), "valset_decode_columns": (0, 0),
-        "commit_prep_fused": (0, 3), "ed25519_rlc_prep": (1, 0)}
+        "commit_prep_fused": (0, 3), "ed25519_rlc_prep": (1, 0),
+        "sr25519_challenges_buf": (0, 0)}
 
 
 def test_the_pure_python_paths_record_the_stages_and_no_native_section():

@@ -26,7 +26,7 @@ from tendermint_tpu.ops import commit_prep as cp  # noqa: E402
 pytestmark = pytest.mark.native_required
 
 ENTRIES = {"commit_decode_columns", "valset_decode_columns",
-           "commit_prep_fused", "ed25519_rlc_prep"}
+           "commit_prep_fused", "ed25519_rlc_prep", "sr25519_challenges_buf"}
 
 
 @pytest.fixture(scope="module")
@@ -400,3 +400,35 @@ def test_the_gil_probe_runs_without_a_device(tmp_path, capsys):
         "released": 0, "held": 3 * out["commits"], "free_s": 0.0,
         "wait_s": 0.0}
     assert out["gil"]["commit_decode_columns"]["released"] == out["commits"]
+
+
+def test_sr25519_challenges_keep_the_gil_under_the_floor_and_match(mod):
+    """sr25519_challenges_buf: one timed section, held below 1 024 rows
+    (a 150-signature commit) and given up from there, and the scalars
+    of the pure-Python merlin transcript."""
+    import numpy as np
+
+    from tendermint_tpu.crypto import sr25519
+    from tendermint_tpu.crypto._edwards import L
+
+    for n, held in ((150, True), (1024, False)):
+        rng = np.random.default_rng(n)
+        pubs = rng.integers(0, 256, 32 * n, dtype=np.uint8).tobytes()
+        rs = rng.integers(0, 256, 32 * n, dtype=np.uint8).tobytes()
+        msgs = [bytes([i % 251]) * (100 + i % 7) for i in range(n)]
+        offs = np.cumsum([0] + [len(m) for m in msgs]).astype(np.int64)
+        before = mod.gil_stats()["sr25519_challenges_buf"]
+        k = mod.sr25519_challenges_buf(sr25519.SIGNING_CTX, pubs, rs,
+                                       b"".join(msgs), offs.tobytes())
+        (section,) = mod.last_sections()
+        after = mod.gil_stats()["sr25519_challenges_buf"]
+        assert section[3] is held
+        assert (after[0] - before[0], after[3] - before[3]) == (
+            (0, 1) if held else (1, 0))
+        for i in range(0, n, 37):
+            t = sr25519._signing_transcript(msgs[i])
+            t.append_message(b"proto-name", b"Schnorr-sig")
+            t.append_message(b"sign:pk", pubs[32 * i:32 * i + 32])
+            t.append_message(b"sign:R", rs[32 * i:32 * i + 32])
+            want = int.from_bytes(t.challenge_bytes(b"sign:c", 64), "little")
+            assert int.from_bytes(k[32 * i:32 * i + 32], "little") == want % L

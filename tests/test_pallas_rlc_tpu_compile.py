@@ -2,8 +2,10 @@
 for a v5e that is described and not attached (no chip, nothing runs):
 what interpret mode cannot refuse — a block that overflows the scoped
 VMEM, a slice off the tiling. One shape per lane width, the ones the
-benchmark's cells launch. All in this one file: only one process at a
-time may hold the TPU's library (tests/_rlc.py has the kernels' verdicts)."""
+benchmark's cells launch, and the sr25519 ristretto kernel at the two
+smallest buckets of its ladder. All in this one file: only one process
+at a time may hold the TPU's library (tests/_rlc.py has the kernels'
+verdicts)."""
 
 import pytest
 
@@ -107,3 +109,28 @@ def test_churn_cells_cached_pipeline_compiles_for_v5e(one_chip):
         arg((4 * 32, 128)), arg((1, 128)),
         arg((pr.packed_layout(bucket, m)[-1],))).compile()
     assert "rlc_verify_cached_g64_m2_b64_vp128" in compiled.as_text()
+
+
+@pytest.mark.time_limit(420)
+@pytest.mark.parametrize("n", [70, 150], ids=["b128", "b256"])
+def test_sr25519_kernel_compiles_for_v5e(one_chip, n):
+    """The ristretto kernel as select_kernel's sr25519 arm launches it:
+    bucket and block from the lane's own ladder (150 signatures, the
+    cell's commit, in 256 lanes of one block)."""
+    import jax
+    import jax.numpy as jnp
+
+    from tendermint_tpu.ops import backend
+    from tendermint_tpu.ops import pallas_sr25519 as ps
+    from tendermint_tpu.ops.pallas_verify import pick_block
+
+    bucket = backend._sr_bucket_for(n)
+    block = pick_block(bucket)
+    assert block == bucket == {70: 128, 150: 256}[n]
+
+    compiled = ps._jitted_sr25519_verify(bucket, block, False).lower(
+        jax.ShapeDtypeStruct((ps.PACKED_ROWS, bucket), jnp.int32,
+                             sharding=one_chip)).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") >= 3  # K1r, K2, K3r
+    assert f"sr25519_verify_n{bucket}_b{block}" in text

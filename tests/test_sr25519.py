@@ -86,8 +86,9 @@ class TestSr25519:
 
 
 class TestNativeMerlin:
-    """native/tm_native.cpp sr25519_challenges must match the pure-Python
-    merlin transcript bit-for-bit (the host half of the device lane)."""
+    """native/tm_native.cpp sr25519_challenges_buf must match the
+    pure-Python merlin transcript, reduced mod L (the host half of the
+    device lane)."""
 
     def test_challenges_match_pure_python(self):
         from tendermint_tpu.crypto.sr25519 import (
@@ -115,18 +116,26 @@ class TestNativeMerlin:
             want.append(t.challenge_bytes(b"sign:c", 64))
             msgs.append(msg)
             rss.append(sig[:32])
-        got = nat.sr25519_challenges(
-            SIGNING_CTX, pub * len(msgs), b"".join(rss), msgs
+        import numpy as np
+
+        from tendermint_tpu.crypto._edwards import L
+
+        offs = np.cumsum([0] + [len(m) for m in msgs]).astype(np.int64)
+        got = nat.sr25519_challenges_buf(
+            SIGNING_CTX, pub * len(msgs), b"".join(rss), b"".join(msgs),
+            offs.tobytes(),
         )
         assert all(
-            got[64 * i : 64 * (i + 1)] == want[i] for i in range(len(msgs))
+            int.from_bytes(got[32 * i : 32 * (i + 1)], "little")
+            == int.from_bytes(want[i], "little") % L
+            for i in range(len(msgs))
         )
 
 
 class TestSr25519Prep:
     def test_prepare_flags(self):
         from tendermint_tpu.crypto.sr25519 import gen_priv_key
-        from tendermint_tpu.ops.pallas_sr25519 import prepare_sr25519
+        from tendermint_tpu.ops.pallas_sr25519 import packed_views, prepare_sr25519
 
         sk = gen_priv_key(b"\x32" * 32)
         msg = b"prep"
@@ -150,7 +159,9 @@ class TestSr25519Prep:
             ),  # s = L + 1
             (b"\xff" * 32, msg, sig),  # non-canonical A encoding
         ]
-        a_t, r_t, s_t, k_t, aok, rok, sok = prepare_sr25519(entries, 8)
+        (packed,) = prepare_sr25519(entries, 8)
+        assert packed.shape == (4 * 32 + 3, 8)
+        a_t, r_t, s_t, k_t, aok, rok, sok = packed_views(packed)
         assert sok[0, 0] == 1 and aok[0, 0] == 1 and rok[0, 0] == 1
         assert sok[0, 1] == 0  # missing marker
         assert sok[0, 2] == 0  # s >= L
@@ -217,7 +228,7 @@ class TestSr25519DeviceLaneK1:
         bad_enc = (2).to_bytes(32, "little")
         assert _ristretto.decode(bad_enc) is None
         entries = [(pub, b"k1", sig), (bad_enc, b"x", sig)]
-        args = ps.prepare_sr25519(entries, 8)
+        args = ps.packed_views(*ps.prepare_sr25519(entries, 8))
         assert args[4][0, 1] == 1, "bad_enc must pass the host-side flags"
 
         n = block = 8
@@ -263,6 +274,8 @@ class TestSr25519DeviceLaneK1:
 @pytest.mark.slow
 class TestSr25519DeviceLane:
     def test_interpret_differential(self):
+        import numpy as np
+
         from tendermint_tpu.crypto import sr25519
         from tendermint_tpu.ops import pallas_sr25519 as ps
 
@@ -272,7 +285,9 @@ class TestSr25519DeviceLane:
         pub = sk.pub_key().bytes()
         entries = [(pub, msg, sig), (pub, b"bad", sig)]
         expect = [sr25519.verify(p, m, s) for p, m, s in entries]
-        args = ps.prepare_sr25519(entries, 8)
-        res = ps.verify_sr25519_compact(*args, block=8, interpret=True)
+        (packed,) = ps.prepare_sr25519(entries, 8)
+        res = np.asarray(
+            ps.verify_sr25519_compact(packed, block=8, interpret=True)
+        )[0].astype(bool)
         assert res[:2].tolist() == expect
         assert res[2:].all(), "padding lanes (ristretto identity) must verify"
