@@ -203,7 +203,7 @@ def worker() -> None:
             prep_t += time.perf_counter() - p0
             with _tr.span("bench.device", bucket=_b):
                 lanes = pallas_rlc.verify_rlc_compact(
-                    *args, block=_blk, interpret=not on_accel
+                    *args, _m, block=_blk, interpret=not on_accel
                 )
             assert bool(lanes.all())
         elif use_pallas:
@@ -306,8 +306,8 @@ def worker() -> None:
         from tendermint_tpu.ops import pallas_rlc as _prw
 
         for _b in _prw.RLC_BUCKETS:
-            _wargs = _prw.prepare_rlc([], _b, _prw.lane_width(_b))
-            _prw.verify_rlc_compact(*_wargs)
+            _wm = _prw.lane_width(_b)
+            _prw.verify_rlc_compact(*_prw.prepare_rlc([], _b, _wm), _wm)
     if on_accel and use_pallas:
         from concurrent.futures import ThreadPoolExecutor
 

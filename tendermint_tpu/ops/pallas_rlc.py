@@ -430,13 +430,10 @@ def _rlc_kernels(m: int, g: int, block: int, interpret: bool, vma,
     return k1, lambda coords: k2(coords, coords), k3
 
 
-@functools.lru_cache(maxsize=None)
-def _jitted_rlc_verify(m: int, g: int, block: int, interpret: bool,
-                       vma: frozenset | None = None,
-                       donate: bool = False):
-    """g lanes of m signatures, block lanes per kernel invocation.
-    donate=True donates the per-batch inputs (ISSUE 7; see
-    ed25519_verify's donation note)."""
+def _uncached_body(m: int, g: int, block: int, interpret: bool, vma):
+    """The uncached pipeline on its four slot-major arrays: a_t, r_t
+    (m*32, g) uint8, scal_t (2m*32, g) uint8, sok_t (m, g) int32; g
+    lanes of m signatures, block lanes per kernel invocation."""
     k1, k2, k3 = _rlc_kernels(m, g, block, interpret, vma, cached=False)
 
     def pipeline(a_t, r_t, scal_t, sok_t):
@@ -446,68 +443,128 @@ def _jitted_rlc_verify(m: int, g: int, block: int, interpret: bool,
     # a stable, shape-bearing name: it is what compile logs, the
     # persistent-cache counters and profiler traces show for this launch
     pipeline.__name__ = f"rlc_verify_g{g}_m{m}_b{block}"
+    return pipeline
+
+
+@functools.lru_cache(maxsize=None)
+def _jitted_rlc_verify_slot_major(m: int, g: int, block: int,
+                                  interpret: bool,
+                                  vma: frozenset | None = None):
+    """The uncached pipeline in the mesh's form: the four slot-major
+    arrays of slot_major_args, which ops.sharded lane-shards across the
+    chips and runs this under shard_map. No single-chip launch takes
+    it."""
+    return jax.jit(_uncached_body(m, g, block, interpret, vma))
+
+
+@functools.lru_cache(maxsize=None)
+def _jitted_rlc_verify(m: int, g: int, block: int, interpret: bool,
+                       donate: bool = False):
+    """The uncached single-chip pipeline: splits the launch's ONE packed
+    argument buffer (prepare_rlc: pub_rows | r_rows | scal_rows |
+    sok_rows) back into its sections, lays them slot-major ON DEVICE, as
+    the cached pipeline does, and runs K1/K2/K3 on them. donate=True
+    donates the buffer (see ed25519_verify's donation note)."""
+    body = _uncached_body(m, g, block, interpret, None)
+
+    def pipeline(packed):
+        pub_rows, r_rows, scal_rows, sok_rows = split_packed(
+            packed, g * m, m, PUB_WORDS)
+        return body(_slot_major(pub_rows, g, m), _slot_major(r_rows, g, m),
+                    _slot_major(scal_rows, g, 2 * m), sok_rows.T)
+
+    pipeline.__name__ = body.__name__
     if donate:
-        return jax.jit(pipeline, donate_argnums=(0, 1, 2, 3))
+        return jax.jit(pipeline, donate_argnums=(0,))
     return jax.jit(pipeline)
 
 
-# -- the warm-epoch launch's one argument buffer ------------------------------
+# -- a launch's one argument buffer -------------------------------------------
 #
 # A host-to-device copy is priced per operation on the v5e (~0.25 ms
-# whether it carries 600 B or 650 kB), so the warm-epoch launch ships its
+# whether it carries 600 B or 650 kB), so both RLC launches ship their
 # four per-signature arrays as ONE buffer of 32-bit words:
 #
-#     idx (bucket,) int32 | r_rows (bucket, 32) uint8
+#     head | r_rows (bucket, 32) uint8
 #         | scal_rows (g, 2m, 32) uint8 | sok_rows (g, m) int32
 #
-# Every offset is a static function of bucket and m, every section is a
-# whole number of words, so no section needs padding, and the whole is
-# 104 bytes a slot at any width. int32 is the element type because that
-# is what the v5e splits cheapest: idx and sok_rows are plain slices, and
-# the byte rows come back through one bitcast each (a uint8 buffer with
-# idx/sok bitcast up to int32 cost the 10k launch 0.3 ms more on the
-# device: PERF.md §6, PR 29).
+# where the head is the warm-epoch launch's gather indices, idx (bucket,)
+# int32 (IDX_WORDS a slot), or the uncached launch's public keys, pub_rows
+# (bucket, 32) uint8 (PUB_WORDS a slot). Every offset is a static function
+# of bucket, m and the head, every section is a whole number of words, so
+# no section needs padding, and the whole is 104 bytes a slot warm and 132
+# cold at any width. int32 is the element type because that is what the
+# v5e splits cheapest: idx and sok_rows are plain slices, and the byte
+# rows come back through one bitcast each (a uint8 buffer with idx/sok
+# bitcast up to int32 cost the 10k launch 0.3 ms more on the v5e).
+
+IDX_WORDS = 1
+PUB_WORDS = 8
 
 
-def packed_layout(bucket: int, m: int) -> tuple:
+def packed_layout(bucket: int, m: int, head: int = IDX_WORDS) -> tuple:
     """Word offsets (r_rows, scal_rows, sok_rows, end) of the packed
-    buffer's sections; idx starts at 0."""
+    buffer's sections, with a head of `head` words a slot at 0."""
     g = bucket // m
-    o_r = bucket
+    o_r = head * bucket
     o_scal = o_r + 8 * bucket
     o_sok = o_scal + 8 * 2 * m * g
     return o_r, o_scal, o_sok, o_sok + m * g
 
 
-def packed_views(packed: np.ndarray, bucket: int, m: int) -> tuple:
+def packed_views(packed: np.ndarray, bucket: int, m: int,
+                 head: int = IDX_WORDS) -> tuple:
     """The four host arrays of a packed buffer, as writable VIEWS of it:
-    (idx (bucket,) int32, r_rows (bucket, 32) uint8,
-    scal_rows (g, 2m, 32) uint8, sok_rows (g, m) int32). The byte
-    rows lie in the words little-endian, as the host has them."""
+    (idx (bucket,) int32 or pub_rows (bucket, 32) uint8, by the head;
+    r_rows (bucket, 32) uint8, scal_rows (g, 2m, 32) uint8, sok_rows
+    (g, m) int32). The byte rows lie in the words little-endian, as the
+    host has them."""
     g = bucket // m
-    o_r, o_scal, o_sok, end = packed_layout(bucket, m)
+    o_r, o_scal, o_sok, end = packed_layout(bucket, m, head)
+    first = packed[:o_r]
     return (
-        packed[:o_r],
+        first if head == IDX_WORDS else first.view(np.uint8).reshape(
+            bucket, 32),
         packed[o_r:o_scal].view(np.uint8).reshape(bucket, 32),
         packed[o_scal:o_sok].view(np.uint8).reshape(g, 2 * m, 32),
         packed[o_sok:end].reshape(g, m),
     )
 
 
-def split_packed(packed, bucket: int, m: int) -> tuple:
+def split_packed(packed, bucket: int, m: int, head: int = IDX_WORDS) -> tuple:
     """packed_views on device, inside the jitted pipeline: static slices,
     and each word of the byte rows bitcast back to its four bytes."""
     g = bucket // m
-    o_r, o_scal, o_sok, end = packed_layout(bucket, m)
+    o_r, o_scal, o_sok, end = packed_layout(bucket, m, head)
+
+    def rows(words, *shape):
+        return lax.bitcast_convert_type(words, jnp.uint8).reshape(*shape)
+
     return (
-        packed[:o_r],
-        lax.bitcast_convert_type(packed[o_r:o_scal], jnp.uint8).reshape(
-            bucket, 32
-        ),
-        lax.bitcast_convert_type(packed[o_scal:o_sok], jnp.uint8).reshape(
-            g, 2 * m, 32
-        ),
+        packed[:o_r] if head == IDX_WORDS else rows(packed[:o_r], bucket, 32),
+        rows(packed[o_r:o_scal], bucket, 32),
+        rows(packed[o_scal:o_sok], g, 2 * m, 32),
         packed[o_sok:end].reshape(g, m),
+    )
+
+
+def _slot_major(rows, g: int, w: int):
+    """(g*w, 32) or (g, w, 32) byte rows, lane-major -> (w*32, g), the
+    kernels' slot-major layout (numpy on the host, jnp on the device)."""
+    return rows.reshape(g, w, 32).transpose(1, 2, 0).reshape(w * 32, g)
+
+
+def slot_major_args(packed: np.ndarray, bucket: int, m: int) -> tuple:
+    """An uncached launch's packed buffer (prepare_rlc) as the four
+    slot-major host arrays of _jitted_rlc_verify_slot_major, the mesh's
+    form: what the single-chip pipeline lays out on the device."""
+    g = bucket // m
+    pub_rows, r_rows, scal_rows, sok_rows = packed_views(
+        packed, bucket, m, PUB_WORDS)
+    return tuple(
+        np.ascontiguousarray(a)
+        for a in (_slot_major(pub_rows, g, m), _slot_major(r_rows, g, m),
+                  _slot_major(scal_rows, g, 2 * m), sok_rows.T)
     )
 
 
@@ -521,9 +578,7 @@ def _jitted_rlc_verify_cached(m: int, g: int, block: int, vp: int,
     the committee's decompressed coords from the persistent (4*32, vp)
     device table, rearranges them (and the raw row-major per-sig inputs)
     into the slot-major kernel layout ON DEVICE, and runs
-    K1-cached/K2/K3. The host ships only val_idx + raw rows —
-    prepare_rlc's slot-major transposes (the bulk of its 31 ms at 10k
-    sigs) become device work."""
+    K1-cached/K2/K3. The host ships only val_idx + raw rows."""
     k1, k2, k3 = _rlc_kernels(m, g, block, interpret, vma, cached=True)
 
     def pipeline(coords_tbl, ok_tbl, packed):
@@ -537,8 +592,8 @@ def _jitted_rlc_verify_cached(m: int, g: int, block: int, vp: int,
             .reshape(m * _POINT_ROWS, g)
         )
         aok = ok_tbl[:, idx].reshape(g, m).T
-        r_t = r_rows.reshape(g, m, 32).transpose(1, 2, 0).reshape(m * 32, g)
-        scal_t = scal_rows.transpose(1, 2, 0).reshape(2 * m * 32, g)
+        r_t = _slot_major(r_rows, g, m)
+        scal_t = _slot_major(scal_rows, g, 2 * m)
         coords, ok, dig = k1(ac, aok, r_t, scal_t)
         return k3(k2(coords), dig, coords, ok, sok_rows.T)
 
@@ -723,48 +778,53 @@ def _live_lanes(n: int, bucket: int, m: int) -> tuple:
     return g, min((n + m - 1) // m, g)
 
 
+def _pack_launch(bucket: int, m: int, head: int, r_enc, raw, z, s_ok):
+    """(packed, head view): a launch's ONE int32 buffer (packed_layout)
+    with its r, scal and sok sections filled row-major through their
+    views, padding lanes given the identity encoding, zero scalars and
+    s_ok 1; the head section is the caller's to fill."""
+    g_live = len(s_ok) // m
+    live = g_live * m
+    packed = np.zeros((packed_layout(bucket, m, head)[-1],), dtype=np.int32)
+    first, r_rows, scal_rows, sok_rows = packed_views(packed, bucket, m, head)
+    r_rows[:live] = r_enc
+    r_rows[live:, 0] = 1  # padding lanes: identity encoding
+    scal_rows[:g_live] = _scal_rows(raw, z, g_live, m)
+    sok_rows[:g_live] = s_ok.reshape(g_live, m)
+    sok_rows[g_live:] = 1
+    return packed, first
+
+
 def prepare_rlc(entries, bucket: int, m: int):
-    """EntryBlock or (pub32, msg, sig64) triples -> RLC kernel args,
-    padded to `bucket` signatures in bucket // m lanes of width m.
-    Host work on top of the per-sig prep (pack + SHA-512 challenges +
-    s<L): one 128x256-bit mod-L mul-add per signature (see
-    _rlc_host_scalars), then the slot-major transposes the kernel layout
-    needs (span ops.rlc_prep.fill) — warm epochs skip those via
-    prepare_rlc_cached."""
-    g, g_live = _live_lanes(len(entries), bucket, m)
+    """EntryBlock or (pub32, msg, sig64) triples -> the uncached RLC
+    launch's arguments, padded to `bucket` signatures in bucket // m
+    lanes of width m. Host work on top of the per-sig prep (pack +
+    SHA-512 challenges + s<L): one 128x256-bit mod-L mul-add per
+    signature (see _rlc_host_scalars).
+
+    Returns the 1-tuple (packed,): ONE int32 buffer a launch (packed_views
+    at PUB_WORDS: pub_rows, r_rows, scal_rows, sok_rows; span
+    ops.rlc_prep.fill), filled row-major as prepare_rlc_cached fills its
+    own; the slot-major transposes the kernels need run on the device
+    (_jitted_rlc_verify), and slot_major_args gives the mesh its four
+    arrays."""
+    _g, g_live = _live_lanes(len(entries), bucket, m)
     live = g_live * m
     pub, r_enc, raw, z, s_ok = _rlc_host_scalars(entries, live, g_live, m)
 
-    def slotmajor(arr):  # (live, 32) -> (m*32, g_live)
-        return np.ascontiguousarray(
-            arr.reshape(g_live, m, 32).transpose(1, 2, 0).reshape(m * 32, g_live)
-        )
-
     with _span("ops.rlc_prep.fill"):
-        scal = _scal_rows(raw, z, g_live, m)
-        a_t = np.zeros((m * 32, g), dtype=np.uint8)
-        r_t = np.zeros((m * 32, g), dtype=np.uint8)
-        scal_t = np.zeros((2 * m * 32, g), dtype=np.uint8)
-        sok_t = np.ones((m, g), dtype=np.int32)
-        # padding lanes: identity encoding = byte 0 of each slot set to 1
-        a_t[np.arange(m) * 32, g_live:] = 1
-        r_t[np.arange(m) * 32, g_live:] = 1
-        if g_live:
-            a_t[:, :g_live] = slotmajor(pub)
-            r_t[:, :g_live] = slotmajor(r_enc)
-            scal_t[:, :g_live] = np.ascontiguousarray(
-                scal.transpose(1, 2, 0).reshape(2 * m * 32, g_live)
-            )
-            sok_t[:, :g_live] = s_ok.reshape(g_live, m).T.astype(np.int32)
-    return a_t, r_t, scal_t, sok_t
+        packed, pub_rows = _pack_launch(bucket, m, PUB_WORDS, r_enc, raw, z,
+                                        s_ok)
+        pub_rows[:live] = pub
+        pub_rows[live:, 0] = 1  # padding lanes: identity encoding
+    return (packed,)
 
 
 def prepare_rlc_cached(entries, bucket: int, ep, m: int):
     """Warm-epoch RLC prep: same host scalar stage as prepare_rlc, but
     the committee ships as val_idx gather indices (the kernel gathers the
-    cached decompressed A coords on device) and every per-sig array ships
-    ROW-major — the slot-major transposes happen on device in the jitted
-    cached pipeline. entries must be an EntryBlock with val_idx set.
+    cached decompressed A coords on device). entries must be an
+    EntryBlock with val_idx set.
 
     Returns the 1-tuple (packed,): ONE int32 buffer per launch, filled
     through its four views (packed_views: idx, r_rows, scal_rows,
@@ -776,28 +836,21 @@ def prepare_rlc_cached(entries, bucket: int, ep, m: int):
     _pub, r_enc, raw, z, s_ok = _rlc_host_scalars(entries, live, g_live, m)
 
     with _span("ops.rlc_prep.fill"):
-        packed = np.zeros((packed_layout(bucket, m)[-1],), dtype=np.int32)
-        idx, r_rows, scal_rows, sok_rows = packed_views(packed, bucket, m)
+        packed, idx = _pack_launch(bucket, m, IDX_WORDS, r_enc, raw, z, s_ok)
         idx[:n] = entries.val_idx
         idx[n:] = ep.vp - 1  # padding: the table's identity row
-        r_rows[:live] = r_enc
-        r_rows[live:, 0] = 1  # padding lanes: identity encoding
-        scal_rows[:g_live] = _scal_rows(raw, z, g_live, m)
-        sok_rows[:g_live] = s_ok.reshape(g_live, m)
-        sok_rows[g_live:] = 1
     return (packed,)
 
 
-def verify_rlc_compact(a_t, r_t, scal_t, sok_t, block: int = 0,
+def verify_rlc_compact(packed, m: int, block: int = 0,
                        interpret: bool = False) -> np.ndarray:
-    """Run the RLC kernel; returns (g,) bool LANE validity (a lane is
-    valid iff the RLC equation holds and every slot's flags pass). The
-    lane width is the arguments' own: sok_t is (m, g)."""
+    """Run the uncached RLC pipeline on prepare_rlc's packed buffer of
+    lanes of width m; returns (g,) bool LANE validity (a lane is valid
+    iff the RLC equation holds and every slot's flags pass)."""
     block = block or BLOCK_LANES
-    m, g = sok_t.shape
+    g = packed.size // packed_layout(m, m, PUB_WORDS)[-1]
     # rlc_launch's own call, keyword for keyword: one cache entry, one trace
-    out = _jitted_rlc_verify(m, g, block, interpret, donate=False)(
-        a_t, r_t, scal_t, sok_t)
+    out = _jitted_rlc_verify(m, g, block, interpret, donate=False)(packed)
     return np.asarray(out)[0].astype(bool)
 
 
@@ -840,7 +893,9 @@ def rlc_launch(entries, ep=None, bucket: int = 0, block: int = 0,
     bucket, m), sized for max(len(entries), bucket) signatures in lanes
     of the width plan_bucket gives that size. With a warm epoch entry the
     committee gathers from the device-resident table (prepare_rlc_cached
-    + rlc_cached_fn); without one the batch ships its pubs.
+    + rlc_cached_fn); without one the batch ships its pubs (prepare_rlc
+    + _jitted_rlc_verify). Either way the arguments are one packed
+    buffer: one host-to-device operation a launch.
     backend.select_kernel's RLC arm and verify_batch_rlc are both this."""
     from ..libs import metrics as _metrics
 

@@ -397,10 +397,13 @@ def sharded_rlc_verifier(mesh: Mesh, g_per_shard: int, block: int,
     from . import pallas_rlc as _pr
 
     m = RLC_M
+    # the pipeline's slot-major form: its four arrays shard lane by lane
+    # (the single-chip launch ships one packed buffer instead)
     if interpret:
-        kern = _pr._jitted_rlc_verify(m, g_per_shard, block, interpret)
+        kern = _pr._jitted_rlc_verify_slot_major(m, g_per_shard, block,
+                                                 interpret)
     else:
-        kern = _pr._jitted_rlc_verify(
+        kern = _pr._jitted_rlc_verify_slot_major(
             m, g_per_shard, block, interpret, vma=frozenset({AXIS})
         )
 
@@ -440,7 +443,10 @@ def verify_commit_sharded_rlc(
     their valid signatures' power is added back on the host — identical
     accept/tally semantics to the single-chip RLC path). The batch size
     is derived from the mesh (per-shard lane count is pow2) — unlike the
-    siblings there is no bucket parameter to pin."""
+    siblings there is no bucket parameter to pin. The launch takes the
+    pipeline's four slot-major arrays (pallas_rlc.slot_major_args), each
+    lane-sharded across the chips, where a single-chip launch ships one
+    packed buffer."""
     from . import pallas_rlc as _pr
 
     n = len(entries)
@@ -456,7 +462,8 @@ def verify_commit_sharded_rlc(
     bucket = g * m
 
     with _span("sharded.host_prep", n=n, bucket=bucket):
-        a_t, r_t, scal_t, sok_t = _pr.prepare_rlc(entries, bucket, m)
+        a_t, r_t, scal_t, sok_t = _pr.slot_major_args(
+            *_pr.prepare_rlc(entries, bucket, m), bucket, m)
         live = np.zeros((bucket,), dtype=bool)
         live[:n] = True
         pw = np.zeros((bucket, POWER_LANES), dtype=np.int32)

@@ -33,6 +33,33 @@ def _sign_batch(n, tamper=()):
     return entries
 
 
+CASES = ["valid", "padding", "forged_first", "forged_last", "s_ge_L",
+         "noncanonical_pub"]
+
+
+def _case_batch(case, n, m):
+    """A batch for KernelSuite's shape (four lanes of m), broken as `case`
+    says: n valid signatures, the last lane straddling live and padding;
+    m + 1 of them (lanes 2 and 3 all padding); a forged signature in slot
+    0 or slot m-1 of lane 1; s >= L in lane 2; or, in lane 0, a public
+    key that encodes the identity non-canonically (y = 1 + p), which
+    ZIP-215 decodes, so with R = [s]B it signs any message."""
+    if case == "padding":
+        return _sign_batch(m + 1)
+    tamper = {"forged_first": {m}, "forged_last": {2 * m - 1}}.get(case, ())
+    entries = _sign_batch(n, tamper=tamper)
+    if case == "s_ge_L":
+        pub, msg, sig = entries[2 * m]
+        s = int.from_bytes(sig[32:], "little") + E.L
+        entries[2 * m] = (pub, msg, sig[:32] + s.to_bytes(32, "little"))
+    elif case == "noncanonical_pub":
+        s = 0x1234567
+        r = E.compress(E.scalar_mult(s, E.BASE))
+        entries[1] = ((1 + E.P).to_bytes(32, "little"), b"nc",
+                      r + s.to_bytes(32, "little"))
+    return entries
+
+
 def _warm_block(entries):
     """(EntryBlock with gather indices, its epoch entry): the block as
     the pipeline sees it once the validator set's tables are resident."""
@@ -103,8 +130,32 @@ class KernelSuite:
         ident_pk = (1).to_bytes(32, "little")
         entries = [(ident_pk, b"m%d" % i, bytes(64)) for i in range(self.M)]
         args = pr.prepare_rlc(entries, bucket, self.M)  # the shape above
-        lanes = pr.verify_rlc_compact(*args, block=block, interpret=True)
+        lanes = pr.verify_rlc_compact(*args, self.M, block=block,
+                                      interpret=True)
         assert lanes.tolist() == [True] * 4  # lane 0 small-order, 1-3 padding
+
+    @pytest.mark.time_limit(600)  # the slot-major form's own trace
+    @pytest.mark.parametrize("case", CASES)
+    def test_packed_launch_equals_slot_major_and_blame(self, case):
+        """The single-chip launch (one packed buffer, laid slot-major on
+        the device) rejects the lanes the mesh's slot-major form of the
+        same arguments rejects, which are the lanes that hold a signature
+        ZIP-215 rejects; expand_lanes then blames exactly those."""
+        from tendermint_tpu.ops import pallas_rlc as pr
+
+        bucket, g, block = self._plan()
+        m = self.M
+        entries = _case_batch(case, self.N, m)
+        (packed,) = pr.prepare_rlc(entries, bucket, m)
+        lanes = pr.verify_rlc_compact(packed, m, block=block, interpret=True)
+        slot_major = pr._jitted_rlc_verify_slot_major(m, g, block, True)(
+            *pr.slot_major_args(packed, bucket, m))
+        assert lanes.tolist() == np.asarray(slot_major)[0].astype(bool).tolist()
+        want = _oracle(entries)
+        assert lanes.tolist() == [all(want[lane * m:(lane + 1) * m])
+                                  for lane in range(g)]
+        assert pr.expand_lanes(lanes, entries, m).tolist() == want
+        assert all(want) == (case in ("valid", "padding", "noncanonical_pub"))
 
 
 class CachedSuite:
@@ -124,7 +175,7 @@ class CachedSuite:
         bucket, g, block, m = pr.plan_bucket(len(blk), 4)
         assert m == self.M
         lanes_u = pr.verify_rlc_compact(
-            *pr.prepare_rlc(blk, bucket, m), block=block, interpret=True)
+            *pr.prepare_rlc(blk, bucket, m), m, block=block, interpret=True)
         dev = pr.rlc_cached_fn(ep, m, g, block, True)(
             *pr.prepare_rlc_cached(blk, bucket, ep, m))
         lanes_c = np.asarray(dev)[0].astype(bool)

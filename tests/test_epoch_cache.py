@@ -650,7 +650,7 @@ class TestCachedPallasInterpret:
         blk, ep = self._blk(6)
         bucket, g, b, m = pr.plan_bucket(len(blk))
         lanes_u = pr.verify_rlc_compact(
-            *pr.prepare_rlc(blk, bucket, m), block=b, interpret=True
+            *pr.prepare_rlc(blk, bucket, m), m, block=b, interpret=True
         )
         dev = pr.rlc_cached_fn(ep, m, g, b, True)(
             *pr.prepare_rlc_cached(blk, bucket, ep, m)

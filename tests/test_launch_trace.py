@@ -295,6 +295,8 @@ def test_every_stage_of_both_preps_lies_inside_its_parent(prep_requests, sight):
     by = _held_to_the_design(cold if sight == "cold" else warm, names,
                              PREP_DESIGN)
     assert by["pipeline.prep"][0][4]["cached"] == int(sight == "warm")
+    # either sight ships its launch as one packed buffer: one put
+    assert len(by["pipeline.transfer.put"]) == 1
     # one pair a section that gave the GIL up: one of the decode, one of
     # the RLC prep; a pair shares its boundary
     for prefix, entry in [("wire.columns", "commit_decode_columns"),

@@ -320,7 +320,7 @@ _ED_CHOICE = {
             ({"prepare_batch_cached", "cached_kernel"}, 5)),
     "pallas": (({"prepare_compact", "_jitted_pallas_verify"}, 5),
                ({"prepare_compact_cached", "cached_compact_fn"}, 5)),
-    "pallas_rlc": (({"prepare_rlc", "_jitted_rlc_verify"}, 4),
+    "pallas_rlc": (({"prepare_rlc", "_jitted_rlc_verify"}, 1),
                    ({"prepare_rlc_cached", "rlc_cached_fn"}, 1)),
 }
 _FAMILY_ENV = {"xla": ("0", "0"), "pallas": ("1", "0"),

@@ -347,12 +347,13 @@ class TestOverlapStructure:
 
 
 class TestOneTransferPerWarmRlcLaunch:
-    def test_warm_launch_is_one_put_uncached_is_four(self, monkeypatch):
+    def test_warm_and_uncached_launches_are_one_put_each(self, monkeypatch):
         """ISSUE 29: a warm-epoch RLC launch hands device_pool.transfer
         ONE host array, so the dispatcher records one
         `pipeline.transfer.put` inside its `pipeline.transfer` and
-        `h2d_ops` rises by 1; the uncached launch still ships its four.
-        The bytes are the old four arrays' sum, to the byte. The Pallas
+        `h2d_ops` rises by 1. So does the uncached launch, its public
+        keys in the buffer's head where the warm one has gather indices. Each buffer carries the bytes its launch's four
+        arrays did, to the byte. The Pallas
         pipelines are stood in for by all-accepting launches: this is the
         dispatcher's accounting, tests/test_pallas_rlc.py has the kernels."""
         import jax.numpy as jnp
@@ -409,7 +410,8 @@ class TestOneTransferPerWarmRlcLaunch:
         assert (len(puts_w), ops_w) == (1, 1)
         # idx 4 + r 32 + scal 2m*32/m = 64 + sok 4 bytes a signature
         assert puts_w[0][4]["bytes"] == bytes_w == 104 * bucket
-        assert (len(puts_c), ops_c) == (4, 4)
-        # slot-major a_t, r_t, scal_t (uint8) and sok_t (int32) per lane
-        assert sum(e[4]["bytes"] for e in puts_c) == bytes_c == g * (
+        assert (len(puts_c), ops_c) == (1, 1)
+        # pub 32 + r 32 + scal 2m*32/m = 64 + sok 4 bytes a signature: the
+        # slot-major a_t, r_t, scal_t and sok_t it used to ship four times
+        assert puts_c[0][4]["bytes"] == bytes_c == 132 * bucket == g * (
             2 * m * 32 + 2 * m * 32 + 4 * m)

@@ -117,7 +117,7 @@ class TestLaneWidthRule:
         before = ops_stats()
         shapes = [pr.rlc_launch(_sign_batch(n), block=4)
                   for n in (3, 5, 9, 20, 21)]
-        # (kernel for (m, g), its four arrays, bucket, m)
+        # (kernel for (m, g), its packed buffer, bucket, m)
         assert [(fn, bucket, m) for fn, _args, bucket, m in shapes] == [
             ((2, 2), 4, 2), ((2, 4), 8, 2), ((4, 4), 16, 4),
             ((8, 4), 32, 8), ((8, 4), 32, 8)]
@@ -132,17 +132,23 @@ class TestLaneWidthRule:
 
 
 def _four_arrays(entries, bucket, ep, m):
-    """The four arrays the warm-epoch prep shipped, one device_put each,
-    before they became views of one buffer (PR 29): what the packed
-    buffer's sections have to hold, byte for byte."""
+    """The four arrays a prep shipped, one device_put each, before they
+    became views of one buffer: what the packed buffer's sections have
+    to hold, byte for byte. The head is the gather indices of a warm
+    epoch (ep), the public keys of an uncached launch (ep None)."""
     n = len(entries)
     g = bucket // m
     g_live = min((n + m - 1) // m, g)
     live = g_live * m
-    _pub, r_enc, raw, z, s_ok = pr._rlc_host_scalars(entries, live, g_live, m)
+    pub, r_enc, raw, z, s_ok = pr._rlc_host_scalars(entries, live, g_live, m)
     scal = pr._scal_rows(raw, z, g_live, m)
-    idx = np.full((bucket,), ep.vp - 1, dtype=np.int32)
-    idx[:n] = entries.val_idx
+    if ep is None:
+        head = np.zeros((bucket, 32), dtype=np.uint8)
+        head[:live] = pub
+        head[live:, 0] = 1
+    else:
+        head = np.full((bucket,), ep.vp - 1, dtype=np.int32)
+        head[:n] = entries.val_idx
     r_rows = np.zeros((bucket, 32), dtype=np.uint8)
     r_rows[:live] = r_enc
     r_rows[live:, 0] = 1
@@ -150,7 +156,7 @@ def _four_arrays(entries, bucket, ep, m):
     scal_rows[:g_live] = scal
     sok_rows = np.ones((g, m), dtype=np.int32)
     sok_rows[:g_live] = s_ok.reshape(g_live, m).astype(np.int32)
-    return idx, r_rows, scal_rows, sok_rows
+    return head, r_rows, scal_rows, sok_rows
 
 
 class TestPackedLaunchBuffer(CachedSuite):
@@ -199,3 +205,46 @@ class TestPackedLaunchBuffer(CachedSuite):
         assert not scal_rows[live // m:].any()
         assert (sok_rows[live // m:] == 1).all()
         assert n < 16 or 0 < sok_rows[: live // m].sum() < live
+
+    @pytest.mark.parametrize("m", pr.WIDTHS)
+    @pytest.mark.parametrize("n", [1, 3, 150, 255, 256, 10_000])
+    def test_uncached_split_equals_the_four_arrays(self, n, m):
+        """The uncached launch's buffer, public keys at its head: the
+        host's views and the jitted prologue's split are its four arrays,
+        132 bytes a slot, and slot_major_args lays them out for the mesh
+        as the kernels read them: slot j of lane l in column l."""
+        import jax
+
+        rng = np.random.RandomState(n)
+        entries = [(rng.bytes(32), b"rlc-%d" % i, rng.bytes(64))
+                   for i in range(n)]
+        bucket, _g, _block, planned = pr.plan_bucket(n)
+        if planned != m:
+            bucket = -(-n // (8 * m)) * 8 * m
+        g = bucket // m
+        want = _four_arrays(entries, bucket, None, m)
+        (packed,) = pr.prepare_rlc(entries, bucket, m)
+        assert packed.ndim == 1 and packed.dtype == np.int32
+        assert packed.nbytes == sum(a.nbytes for a in want) == 132 * bucket
+        host = pr.packed_views(packed, bucket, m, pr.PUB_WORDS)
+        dev = jax.jit(pr.split_packed, static_argnums=(1, 2, 3))(
+            packed, bucket, m, pr.PUB_WORDS)
+        for name, w, h, d in zip(("pub_rows", "r_rows", "scal_rows",
+                                  "sok_rows"), want, host, dev):
+            d = np.asarray(d)
+            assert np.shares_memory(h, packed), name
+            assert w.dtype == h.dtype == d.dtype, name
+            assert w.shape == h.shape == d.shape, name
+            assert w.tobytes() == h.tobytes() == d.tobytes(), name
+        pub_rows, r_rows, scal_rows, sok_rows = want
+        a_t, r_t, scal_t, sok_t = pr.slot_major_args(packed, bucket, m)
+        assert a_t.shape == r_t.shape == (m * 32, g)
+        assert scal_t.shape == (2 * m * 32, g) and sok_t.shape == (m, g)
+        for j in range(m):
+            assert (a_t[j * 32:(j + 1) * 32] == pub_rows[j::m].T).all()
+            assert (r_t[j * 32:(j + 1) * 32] == r_rows[j::m].T).all()
+            assert (sok_t[j] == sok_rows[:, j]).all()
+        for q in range(2 * m):
+            assert (scal_t[q * 32:(q + 1) * 32] == scal_rows[:, q].T).all()
+        live = -(-n // m) * m
+        assert (pub_rows[live:, 0] == 1).all() and not pub_rows[live:, 1:].any()

@@ -48,8 +48,10 @@ class TestRlcPipelineDispatch:
         reject = {}  # lane width -> the lane the stand-in rejects
 
         def lanes_but_one(m, g, *_a, **_k):
-            def launch(a_t, r_t, scal_t, sok_t):
-                assert sok_t.shape == (m, g) and a_t.shape == (m * 32, g)
+            def launch(packed):
+                # the launch's one buffer, at its own width and lanes
+                assert packed.shape == (
+                    pr.packed_layout(g * m, m, pr.PUB_WORDS)[-1],)
                 return jnp.ones((1, g), jnp.int32).at[0, reject[m]].set(0)
 
             return launch

@@ -112,6 +112,28 @@ def test_churn_cells_cached_pipeline_compiles_for_v5e(one_chip):
 
 
 @pytest.mark.time_limit(420)
+def test_bisect_cells_uncached_pipeline_compiles_for_v5e(one_chip):
+    """A skipping hop's launch of 101 signatures from a set no table
+    holds: bucket 128 at m=2, its ONE packed buffer (public keys at the
+    head) split and laid slot-major on the device before K1. The
+    buffer is donated."""
+    import jax
+    import jax.numpy as jnp
+
+    from tendermint_tpu.ops import pallas_rlc as pr
+
+    bucket, g, block, m = pr.plan_bucket(101)
+    assert (bucket, g, block, m) == (128, 64, 64, 2)
+    words = pr.packed_layout(bucket, m, pr.PUB_WORDS)[-1]
+    assert 4 * words == 132 * bucket
+    compiled = pr._jitted_rlc_verify(m, g, block, False, donate=True).lower(
+        jax.ShapeDtypeStruct((words,), jnp.int32, sharding=one_chip)).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") >= 3  # K1, K2, K3 are kernels
+    assert "rlc_verify_g64_m2_b64" in text
+
+
+@pytest.mark.time_limit(420)
 @pytest.mark.parametrize("n", [70, 150], ids=["b128", "b256"])
 def test_sr25519_kernel_compiles_for_v5e(one_chip, n):
     """The ristretto kernel as select_kernel's sr25519 arm launches it:

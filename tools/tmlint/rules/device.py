@@ -52,6 +52,7 @@ ENTRY_POINTS = frozenset({
     "rlc_cached_fn",
     "cached_compact_fn",
     "_jitted_rlc_verify",
+    "_jitted_rlc_verify_slot_major",
     "_jitted_pallas_verify",
     "verify_kernel_cached",
     "xla_tables",
