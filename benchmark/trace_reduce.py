@@ -93,18 +93,22 @@ def idle_share(trace: dict):
 
 def device_op_seconds(trace: dict, line: str, pattern: str):
     """Device seconds of the events on `line` whose name matches, clipped
-    to the stretch and averaged over the device planes."""
+    to the stretch and averaged over the device planes; None where no
+    event inside the stretch matches (a renamed kernel reads nothing, not
+    0.0)."""
     planes = device_planes(trace)
     if not planes:
         return None
     rx = re.compile(pattern)
     a, b = trace["t_a"], trace["t_b"]
-    total = 0.0
+    total, found = 0.0, False
     for ev in trace["device_events"]:
         if ev[1] == line and rx.search(ev[2]):
             s, e = _clip(ev[3], ev[3] + ev[4], a, b)
-            total += max(e - s, 0.0)
-    return total / len(planes)
+            if e > s:
+                total += e - s
+                found = True
+    return total / len(planes) if found else None
 
 
 def top_device_ops(trace: dict, n: int = 10):

@@ -3,6 +3,7 @@ loads by name, a cell a later PR would add as new files loads and runs
 over a driver that needs no JAX, the end-to-end arithmetic counts what it
 says, and the command refuses to run without the chip."""
 
+import importlib.util
 import json
 import os
 import re
@@ -159,6 +160,64 @@ def test_a_later_pr_adds_a_cell_as_files_and_entries(grown_root):
     assert spec.load_cell(grown_root, MAN["workloads"][0]["name"]).per_layer
     with pytest.raises(spec.SpecError):
         spec.load_cell(grown_root, "no-such-cell")
+
+
+def _sibling(name):
+    """A test module of this directory, loaded on its own: its pins."""
+    s = importlib.util.spec_from_file_location(
+        f"_pins_{name}", os.path.join(HERE, name + ".py"))
+    mod = importlib.util.module_from_spec(s)
+    s.loader.exec_module(mod)
+    return mod
+
+
+def test_the_pins_of_what_stands_take_a_later_prs_additions(grown_root, tmp_path):
+    """What the next PR that brings a metric or a cell does to the
+    manifest: a per-layer metric after the last entry (grown_root's), and
+    a cell appended to the `workloads` of the prep stage metrics and of
+    kernel_us_per_sig.lat. The tests that hold what the benchmark had pass
+    on that copy, and still fail where an entry is put before one of
+    theirs or a cell is taken from a list."""
+    prep = _sibling("test_benchmark_prep_metrics")
+    sr = _sibling("test_benchmark_sr25519")
+    root = str(tmp_path / "next")
+    shutil.copytree(grown_root, root)
+    man = spec.manifest(root)
+    assert man["per_layer"][-1]["name"] == "fx_calls_per_launch"
+    added = {"prep_native_us_per_sig.lat": "fx-fx_closed",
+             "kernel_us_per_sig.lat": "fx-fx_closed",
+             "gil_wait_us_per_sig.lat": "sr150-lastcommit1"}
+    for m in man["per_layer"]:
+        if m["name"] in added:
+            m["workloads"] = m["workloads"] + [added[m["name"]]]
+
+    def write(manifest):
+        with open(os.path.join(root, "BENCHMARK.json"), "w") as f:
+            json.dump(manifest, f)
+
+    write(man)
+    prep.pin_thirteen(spec.manifest(root))
+    sr.pin_the_cell(root)
+    names = {m["name"] for m, _d in spec.load_cell(root, "fx-fx_closed").per_layer}
+    assert {"prep_native_us_per_sig.lat", "kernel_us_per_sig.lat",
+            "fx_calls_per_launch"} <= names
+    assert "gil_wait_us_per_sig.lat" in {
+        m["name"] for m, _d in spec.load_cell(root, sr.CELL).per_layer}
+
+    before = json.loads(json.dumps(man))
+    before["per_layer"].insert(prep.FIRST, before["per_layer"].pop())
+    with pytest.raises(AssertionError):
+        prep.pin_thirteen(before)
+    fewer = json.loads(json.dumps(man))
+    fewer["per_layer"][prep.FIRST + 1]["workloads"].pop(0)
+    with pytest.raises(AssertionError):
+        prep.pin_thirteen(fewer)
+    for m in man["per_layer"]:
+        if m["name"] == "kernel_us_per_sig.lat":
+            m["workloads"].remove(sr.CELL)
+    write(man)
+    with pytest.raises(AssertionError):
+        sr.pin_the_cell(root)
 
 
 @pytest.mark.parametrize("traffic", ["fx_closed", "fx_open"])

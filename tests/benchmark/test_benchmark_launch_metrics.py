@@ -86,8 +86,11 @@ EXPECT = {
     "resolve_us_per_sig": (0.040 + 0.020) * 1e6 / 200,
 }
 
-NEW = [m for m in MAN["per_layer"]
-       if m["name"].rsplit(".", 1)[0] in EXPECT]
+# the fifteen launch-path metrics, by name: a later PR may declare
+# another form of one of these quantities for a cell of its own
+FIFTEEN = [f"{base}.{form}" for base in EXPECT for form in ("lat", "thr")
+           if f"{base}.{form}" != "linger_p50_ms.lat"]
+NEW = [m for name in FIFTEEN for m in MAN["per_layer"] if m["name"] == name]
 
 
 @pytest.mark.parametrize("name", WAITING)
@@ -132,7 +135,7 @@ def test_idle_gaps_name_the_hand_offs_instead_of_the_callers_wait():
 
 def test_sixteen_less_one_new_metrics_are_declared():
     assert len(NEW) == 15, [m["name"] for m in NEW]
-    assert "linger_p50_ms.lat" not in {m["name"] for m in NEW}, \
+    assert "linger_p50_ms.lat" not in {m["name"] for m in MAN["per_layer"]}, \
         "a lone caller never lingers: nothing to read in a serial cell"
 
 

@@ -151,8 +151,11 @@ def test_the_pool_decodes_to_the_programs_commit_and_sign_bytes():
 # -- the cell and its driver ---------------------------------------------------------
 
 
-def test_the_cell_loads_by_name_and_no_harness_code_names_it():
-    cell = spec.load_cell(ROOT, CELL)
+def pin_the_cell(root):
+    """The cell as a checkout at `root` declares it: its driver, its
+    configuration and the metrics it reports, which a later PR may add
+    to, but not take from."""
+    cell = spec.load_cell(root, CELL)
     assert cell.driver.__name__.endswith("sr_commit_from_wire")
     assert cell.config["key_type"] == "sr25519" and cell.config["reduced"] == []
     assert [m["name"] for m, _d in cell.end_to_end] == ["commit_p50_ms",
@@ -161,12 +164,14 @@ def test_the_cell_loads_by_name_and_no_harness_code_names_it():
     assert {"decode_us_per_sig.lat", "prep_us_per_sig.lat",
             "transfer_us_per_sig.lat", "kernel_wait_p50_ms.lat",
             "device_idle_share.lat", "h2d_bytes_per_sig.lat",
-            "host_verified_share.lat"} <= names
-    # kernel_us_per_sig reads 0.0 in every cell; the epoch cache is not on
-    # this path; PR 37's own metrics keep the cells they were declared for
-    assert "kernel_us_per_sig.lat" not in names
-    assert not any(n.startswith(("epoch_", "gil_wait", "prep_native",
-                                 "prep_caller")) for n in names)
+            "host_verified_share.lat", "kernel_us_per_sig.lat",
+            "sr_challenge_us_per_sig.lat", "sr_fill_us_per_sig.lat"} <= names
+    # the epoch cache is not on this path: its metrics would read nothing
+    assert not any(n.startswith("epoch_") for n in names)
+
+
+def test_the_cell_loads_by_name_and_no_harness_code_names_it():
+    pin_the_cell(ROOT)
     bdir = os.path.join(ROOT, "benchmark")
     for path in (os.path.join(bdir, f) for f in (
             "reference_sr25519.py", "data_sr25519.py", "roofline_sr25519.py",
@@ -177,8 +182,9 @@ def test_the_cell_loads_by_name_and_no_harness_code_names_it():
 
 
 # one second, two commits of 150 signatures, one launch each: the prep 1 ms
-# a launch with the challenges and the fill inside it, the kernel 0.6 ms on
-# the device under the profiler's name and 0.1 ms of an ed25519 launch beside it
+# a launch with the challenges (0.3 ms) and the fill (0.2 ms) inside it, the
+# kernel 0.6 ms on the device under the profiler's and the ledger's name and
+# 0.1 ms of an ed25519 launch beside it
 SR_STRETCH = {
     "t_a": 0.0, "t_b": 1.0, "sigs": 300, "spans_recorded": 8,
     "ring_capacity": 262144,
@@ -202,8 +208,12 @@ SR_COUNTERS = {"before": {"sigs_verified_device": 0, "sigs_verified_host": 0,
                "after": {"sigs_verified_device": 300, "sigs_verified_host": 0,
                          "launches": 2}}
 # the prep's stages nest inside pipeline.prep and count once; both kernel
-# launches and the ed25519 one are busy time; every signature on the device
+# launches and the ed25519 one are busy time, and kernel time of both
+# schemes; every signature on the device
 SR_METRICS = {"prep_us_per_sig.lat": 2 * 1000.0 / 300,
+              "sr_challenge_us_per_sig.lat": 2 * 300.0 / 300,
+              "sr_fill_us_per_sig.lat": 2 * 200.0 / 300,
+              "kernel_us_per_sig.lat": (2 * 600.0 + 100.0) / 300,
               "device_idle_share.lat": 100.0 * (1 - 0.0013),
               "sigs_per_launch.lat": 150.0,
               "host_verified_share.lat": 0.0}

@@ -5,6 +5,7 @@ on the chip (benchmark/fixtures/trace_small.json)."""
 import copy
 import json
 import os
+import re
 import sys
 
 import pytest
@@ -62,6 +63,64 @@ def test_kernel_time_by_jit_name():
     d = {"reader": "device_ops_per_sig",
          "params": {"line": MODS, "pattern": "rlc_verify"}}
     assert readers.read(d, {"trace": HAND}) == pytest.approx(0.15e6 / 200)
+
+
+# the verify kernels' ops as the profiler names them (`%`), as the ledger's
+# breakdown prints them (`_`) and bare; the ops of a launch's own reshapes
+# and fusions, and a jitted step's module name, are not kernels
+KERNEL_OPS = ["%rlc_verify_cached_g128_m2_b128_vp256.5",
+              "_rlc_verify_cached_g5120_m8_b128_vp16384.3____s32_2048_5120__1_0",
+              "_rlc_verify_g64_m2_b64.5___s32_1_64__1_0:T_1_128___custom-call_s",
+              "%rlc_verify_g64_m2_b64.4",
+              "%sr25519_verify_n256_b256.5",
+              "_sr25519_verify_n256_b256.3____s32_256_256__1_0:T_8_128_S_1____s",
+              "epoch_coords_table.2", "rlc_verify_cached_g64_b64_vp256.3"]
+OTHER_OPS = ["_fusion.1___s32_256__0:T_256_S_1___fusion_s32_256__0:T_256_S_1__",
+             "_reshape.16___s32_3328_2__1_0:T_8_128_S_1___reshape_s32_6656__0:",
+             "%fusion.1", "%copy.8", "_while.5____s32___:T_128____s32_20_128__",
+             "jit_rlc_verify_cached_g64_b64_vp256(1)", "%sr25519_prep.2",
+             "_epoch_coords_patch.1"]
+KERNEL_FILES = ["kernel_us_per_sig.lat", "kernel_us_per_sig.thr"]
+
+
+def _kernel_metric(name):
+    with open(os.path.join(ROOT, "benchmark", "layer_metrics",
+                           name + ".json")) as f:
+        return json.load(f)
+
+
+@pytest.mark.parametrize("name", KERNEL_FILES)
+@pytest.mark.parametrize("op", KERNEL_OPS + OTHER_OPS)
+def test_the_kernel_pattern_takes_the_verify_kernels_alone(name, op):
+    d = _kernel_metric(name)
+    assert d["reader"] == "device_ops_per_sig" and d["params"]["line"] == OPS
+    assert bool(re.search(d["params"]["pattern"], op)) == (op in KERNEL_OPS)
+
+
+@pytest.mark.parametrize("name", KERNEL_FILES)
+def test_kernel_time_a_signature_on_a_stretch_of_both_schemes(name):
+    """One second, 300 signatures: every kernel op of the list once, each
+    1 ms, the last running 0.5 ms past the stretch's end, and every other
+    op 2 ms: the kernels' device time over the signatures."""
+    n = len(KERNEL_OPS)
+    events = [["/device:TPU:0", OPS, op, 0.1 * i, 0.001]
+              for i, op in enumerate(KERNEL_OPS[:-1])]
+    events += [["/device:TPU:0", OPS, KERNEL_OPS[-1], 0.9995, 0.001]]
+    events += [["/device:TPU:0", OPS, op, 0.05 + 0.1 * i, 0.002]
+               for i, op in enumerate(OTHER_OPS)]
+    stretch = {"t_a": 0.0, "t_b": 1.0, "sigs": 300, "spans_recorded": 0,
+               "ring_capacity": 16, "device_events": events, "spans": []}
+    d = _kernel_metric(name)
+    assert readers.read(d, {"trace": stretch}) == \
+        pytest.approx(((n - 1) * 1.0 + 0.5) * 1e3 / 300)
+    # a program whose kernels the pattern does not know reads nothing,
+    # not 0.0; so does a stretch in which no kernel ran
+    renamed = dict(stretch, device_events=[
+        ev[:2] + ["%verify_k" + ev[2][1:]] + ev[3:] for ev in events[:n]])
+    assert readers.read(d, {"trace": renamed}) is None
+    late = dict(stretch, device_events=[
+        ev[:3] + [ev[3] + 2.0] + ev[4:] for ev in events])
+    assert readers.read(d, {"trace": late}) is None
 
 
 def test_idle_gaps_are_charged_to_what_the_host_was_doing():
